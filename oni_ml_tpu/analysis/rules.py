@@ -90,9 +90,8 @@ class MonotonicClockRule(Rule):
 class TunedConstantRule(Rule):
     """Measured knob names may take numeric-literal defaults only in
     config.py (the tuned-constant home) and under oni_ml_tpu/plans/
-    (the registry/seeds).  A literal re-hardcoded at a consumer is
-    exactly the drift the plan cache exists to end (the r05
-    device-chunk / break-even constants were smeared this way)."""
+    (the registry).  A literal re-hardcoded at a consumer is exactly
+    the drift the plan cache exists to end."""
 
     id = "tuned-constant"
     description = ("tuned-knob name assigned a numeric literal outside "

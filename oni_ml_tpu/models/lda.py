@@ -1267,6 +1267,42 @@ class LDATrainer:
                         max(filter(None, kibs))
                     )
                 }
+        # Name what will actually run, next to the knobs that chose it:
+        # the kernel behind the engine family, and the devices that hold
+        # corpus shards (on a mesh, one distinct slice per data shard).
+        if use_dense and self.vocab_sharded:
+            kernel = "dense_vocab_sharded_xla"
+        elif use_dense:
+            kernel = ("dense_wmajor" if use_wmajor else "dense_rowmajor") + (
+                "_shard_map" if self.mesh is not None else "")
+        elif compact is not None:
+            kernel = "compact_wmajor" if use_wmajor else "compact_rowmajor"
+        elif getattr(self._e_base, "_oni_sparse_engine", False):
+            kernel = "sparse_fused"
+        elif getattr(self._e_base, "_oni_vocab_sharded", False):
+            kernel = "xla_vocab_sharded"
+        elif self._e_base is estep.e_step or getattr(
+                self._e_base, "_oni_data_parallel", False):
+            # estep.e_step's own preference order, at the shape each
+            # device's call sees (it reports the refusals itself).
+            kernel = "+".join(sorted({
+                estep.resolve_backend(
+                    "auto", self._local_batch(b), b.word_idx.shape[1], k,
+                    self.num_terms,
+                )[0]
+                for b in batches
+            }))
+        else:
+            kernel = "custom"
+        corpus = groups.arrays[0][0]
+        self.plan_record["estep_kernel"] = {
+            "value": kernel,
+            "corpus_devices": sorted(
+                s.device.id for s in corpus.addressable_shards),
+            "corpus_slices": len(
+                {str(s.index) for s in corpus.addressable_shards}),
+            "platform": jax.default_backend(),
+        }
         run_chunk = fused.make_chunk_runner(
             num_docs=num_docs,
             num_topics=k,
@@ -1824,7 +1860,11 @@ def train_corpus(
         batches = make_batches(
             corpus, batch_size=config.batch_size,
             min_bucket_len=config.min_bucket_len,
-            pad_multiple=data_size if mesh is not None else 8,
+            # Every device's slice of every batch — the tail batches
+            # too — is a multiple of the 8-row sublane tile, or one
+            # ragged tail takes the Pallas kernels away from the whole
+            # run (their doc blocks must divide the per-shard batch).
+            pad_multiple=8 * data_size,
         )
     trainer = LDATrainer(
         config,
@@ -2062,7 +2102,7 @@ def _train_corpus_distributed(
                 for b in make_batches(
                     sc, batch_size=config.batch_size,
                     min_bucket_len=config.min_bucket_len,
-                    pad_multiple=data_size if mesh is not None else 8,
+                    pad_multiple=8 * data_size,
                 )
             ]
             for s, sc in shard_corpora.items()

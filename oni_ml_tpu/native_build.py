@@ -6,6 +6,12 @@ its own .so beside the Python wrapper that binds it.  Loading strategy
 .so (``make -C native``); if missing or older than its source, compile
 once on demand with g++; if neither works the caller falls back to pure
 Python.  ``ONI_ML_TPU_NO_NATIVE=1`` forces the Python paths.
+
+The .so files are git-ignored build products, so a clean checkout
+builds them on first use.  ``NativeLib.status`` says which of the three
+happened (``prebuilt`` | ``built`` | ``python-fallback``) and
+``load_all()`` reports it for every library — chip_smoke.py prints the
+result and refuses a day featurized by the fallback.
 """
 
 from __future__ import annotations
@@ -17,6 +23,10 @@ import threading
 from typing import Callable
 
 import numpy as np
+
+# Every NativeLib constructed in this process, in construction order
+# (each wrapper module owns exactly one, built at import).
+_LIBRARIES: "list[NativeLib]" = []
 
 
 # PyBytes_FromStringAndSize with a true Py_ssize_t size.  CPython's
@@ -77,6 +87,16 @@ class NativeLib:
         self._lock = threading.Lock()
         self._lib: ctypes.CDLL | None = None
         self._failed = False
+        # "unloaded" until load() runs; then how the library was
+        # obtained: "prebuilt" (an existing .so was used), "built"
+        # (compiled by this process), or "python-fallback" (neither
+        # worked, or ONI_ML_TPU_NO_NATIVE forced it).
+        self.status = "unloaded"
+        _LIBRARIES.append(self)
+
+    @property
+    def name(self) -> str:
+        return os.path.basename(self._lib_path)
 
     def _stale(self) -> bool:
         try:
@@ -121,13 +141,20 @@ class NativeLib:
             if self._lib is not None or self._failed:
                 return self._lib
             if os.environ.get("ONI_ML_TPU_NO_NATIVE"):
-                self._failed = True
-                return None
+                return self._fail()
+            self.status = "prebuilt"
             if not os.path.exists(self._lib_path) or self._stale():
-                if not self._build() and not os.path.exists(self._lib_path):
-                    self._failed = True
-                    return None
+                if self._build():
+                    self.status = "built"
+                elif not os.path.exists(self._lib_path):
+                    return self._fail()
             return self._load_configured()
+
+    def _fail(self) -> None:
+        """Record the fallback.  Caller holds self._lock."""
+        self._failed = True
+        self.status = "python-fallback"
+        return None
 
     def _load_configured(self) -> ctypes.CDLL | None:
         """CDLL + configure with one rebuild retry.  The retry loads
@@ -145,8 +172,7 @@ class NativeLib:
                     self._lib = lib
                     return self._lib
                 except OSError:
-                    self._failed = True
-                    return None
+                    return self._fail()
                 except AttributeError:
                     # A prebuilt .so missing a newly added export even
                     # though mtimes looked fresh (copied binary, touch,
@@ -166,6 +192,7 @@ class NativeLib:
                             )
                             os.close(fd)
                             shutil.copy2(self._lib_path, load_path)
+                            self.status = "built"
                             continue
                         except OSError:
                             pass  # full/RO tempdir: degrade, don't raise
@@ -176,10 +203,8 @@ class NativeLib:
                         "failed after rebuild attempt; using the Python "
                         "fallback paths"
                     )
-                    self._failed = True
-                    return None
-            self._failed = True
-            return None
+                    return self._fail()
+            return self._fail()
         finally:
             if load_path != self._lib_path:
                 # Linux keeps the mapping alive after unlink; don't
@@ -191,3 +216,17 @@ class NativeLib:
 
     def available(self) -> bool:
         return self.load() is not None
+
+
+def load_all() -> "dict[str, str]":
+    """Load every native library the package has and return
+    {library file name: status}.  Imports the four wrapper modules so
+    their NativeLib instances exist; loading builds from native_src/
+    where no usable .so is on disk."""
+    from . import native_emit  # noqa: F401
+    from .features import native_dns, native_flow  # noqa: F401
+    from .io import native  # noqa: F401
+
+    for lib in _LIBRARIES:
+        lib.load()
+    return {lib.name: lib.status for lib in _LIBRARIES}

@@ -3,7 +3,7 @@
 // Profiling the score stage on a 400k-event day: the device dot
 // products cost ~0.05s while Python row assembly — featurized_row()
 // per kept event (blob slice, decode, split, list concat, str() per
-// float) — cost ~1.8s, >90% of the stage (VERDICT r1 item 5; the stage
+// float) — cost ~1.8s, >90% of the stage (an early review's finding; the stage
 // it replaces is the reference's executor-side CSV write,
 // flow_post_lda.scala:245-248).  This TU assembles the entire output
 // buffer in one pass over the kept-row order instead.

@@ -61,11 +61,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.scipy.special import gammaln
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 from . import estep
 # newton_recip: the [BB, V] ratio = C/q divide was ~2/3 of the
 # fixed-point body's time (7.1 -> 2.1 us per iteration per 128-doc
@@ -593,7 +588,7 @@ def dense_fixed_point_w(
             jax.ShapeDtypeStruct((1, b), dtype),
             jax.ShapeDtypeStruct((grid, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision)
         ),
         interpret=interpret,
@@ -686,7 +681,7 @@ def dense_fixed_point(
             jax.ShapeDtypeStruct((b, 1), dtype),
             jax.ShapeDtypeStruct((grid, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision)
         ),
         interpret=interpret,

@@ -203,6 +203,98 @@ def batch_likelihood(gamma, phinorm, counts, alpha, doc_mask):
     return batch_likelihood_from_tok(gamma, tok_ll, alpha, doc_mask)
 
 
+_BACKENDS = ("auto", "xla", "pallas", "sparse", "dense")
+
+
+def resolve_backend(backend: str, b: int, l: int, k: int,
+                    v: int) -> "tuple[str, dict]":
+    """Which implementation an `e_step` call with these shapes runs:
+    (engine, refused), where `refused` maps every engine that stood
+    before it in the preference order to the gate that said no.
+
+    "auto" prefers the fused sparse Pallas kernel, then the
+    fixed-point-only Pallas kernel, then plain XLA; both kernels need a
+    TPU backend and a VMEM-feasible doc block.  A forced engine whose
+    shape gate says no raises — it never falls through."""
+    import os
+
+    if backend == "auto":
+        env = os.environ.get("ONI_ML_TPU_ESTEP", "auto")
+        # "dense"/"compact" in the env are DRIVER-level hints (models/lda.py
+        # picks them up in _use_dense/_plan_compact, where the densification
+        # is amortized across the run).  Honoring them per call here would
+        # re-scatter the batch every EM iteration — the exact cost the dense
+        # paths exist to avoid — so auto dispatch ignores them; only an
+        # explicit backend="dense" argument densifies inline.  "sparse"
+        # passes through: the fused sparse kernel has no per-call setup
+        # to amortize, so forcing it per call is well-defined.
+        backend = "auto" if env in ("dense", "compact") else env
+    if backend not in _BACKENDS:
+        raise ValueError(
+            f"unknown E-step backend {backend!r} (set via ONI_ML_TPU_ESTEP "
+            "or the backend= argument); expected auto, xla, pallas, "
+            "sparse, or dense"
+        )
+    if backend == "xla":
+        return "xla", {}
+    from . import dense_estep, pallas_estep, sparse_estep
+
+    picks = {
+        "dense": lambda: dense_estep.pick_block(b, v, k),
+        "sparse": lambda: sparse_estep.pick_block(b, l, k),
+        "pallas": lambda: pallas_estep.pick_block(b, l, k),
+    }
+    if backend != "auto":
+        if picks[backend]() is None:
+            shape = (f"B={b}, V={v}, K={k}" if backend == "dense"
+                     else f"B={b}, L={l}, K={k}")
+            raise ValueError(
+                f"{backend} E-step forced but {shape} has no "
+                "VMEM-feasible doc block (unset "
+                f"ONI_ML_TPU_ESTEP={backend} or reduce the batch"
+                + ("/vocab size)" if backend == "dense" else ")")
+            )
+        return backend, {}
+    refused = {}
+    platform = jax.default_backend()
+    for engine in ("sparse", "pallas"):
+        if platform != "tpu":
+            refused[engine] = f"backend is {platform}, not tpu"
+        elif picks[engine]() is None:
+            refused[engine] = (
+                f"no VMEM-feasible doc block for B={b}, L={l}, K={k}"
+            )
+        else:
+            return engine, refused
+    return "xla", refused
+
+
+def _report_dispatch(engine: str, refused: dict, requested: str,
+                     shape: str) -> None:
+    """Say which engine a traced E-step took and which gates refused
+    the ones preferred over it: a log line always, and an
+    {"kind": "estep_dispatch"} journal record under an active recorder
+    (docs/observability.md).  Runs at trace time, once per compiled
+    shape — a Pallas gate never hands a call to XLA without a word."""
+    import logging
+
+    logging.getLogger(__name__).info(
+        "e_step %s -> %s (requested %s%s)", shape, engine, requested,
+        "".join(f"; {e} refused: {why}" for e, why in refused.items()),
+    )
+    from ..telemetry.spans import current_recorder
+
+    rec = current_recorder()
+    if rec is not None:
+        rec.journal_record({
+            "kind": "estep_dispatch",
+            "engine": engine,
+            "requested": requested,
+            "refused": refused,
+            "shape": shape,
+        })
+
+
 def e_step(
     log_beta: jnp.ndarray,   # [K, V] log p(word|topic)
     alpha: jnp.ndarray,      # scalar symmetric Dirichlet prior
@@ -223,88 +315,38 @@ def e_step(
     kernel (ops/pallas_estep.py), else pure XLA; "xla" / "pallas" /
     "sparse" / "dense" force a path (ONI_ML_TPU_ESTEP env var overrides
     "auto").  "dense" densifies the batch per call — drivers that own the
-    batches amortize the densification instead (models/fused.py).
+    batches amortize the densification instead (models/fused.py).  The
+    choice is `resolve_backend`'s, and every call reports it.
     """
-    import os
-
-    if backend == "auto":
-        env = os.environ.get("ONI_ML_TPU_ESTEP", "auto")
-        # "dense"/"compact" in the env are DRIVER-level hints (models/lda.py
-        # picks them up in _use_dense/_plan_compact, where the densification
-        # is amortized across the run).  Honoring them per call here would
-        # re-scatter the batch every EM iteration — the exact cost the dense
-        # paths exist to avoid — so auto dispatch ignores them; only an
-        # explicit backend="dense" argument densifies inline.  "sparse"
-        # passes through: the fused sparse kernel has no per-call setup
-        # to amortize, so forcing it per call is well-defined.
-        backend = "auto" if env in ("dense", "compact") else env
-    if backend not in ("auto", "xla", "pallas", "sparse", "dense"):
-        raise ValueError(
-            f"unknown E-step backend {backend!r} (set via ONI_ML_TPU_ESTEP "
-            "or the backend= argument); expected auto, xla, pallas, "
-            "sparse, or dense"
-        )
-    if backend == "dense":
+    b, l = word_idx.shape
+    k, v = log_beta.shape
+    engine, refused = resolve_backend(backend, b, l, k, v)
+    _report_dispatch(engine, refused, backend, f"b{b}.l{l}.k{k}.v{v}")
+    interpret = jax.default_backend() != "tpu"
+    if engine == "dense":
         from . import dense_estep
 
-        b = word_idx.shape[0]
-        k, v = log_beta.shape
-        if dense_estep.pick_block(b, v, k) is None:
-            raise ValueError(
-                f"dense E-step forced but B={b}, V={v}, K={k} has no "
-                "VMEM-feasible doc block (unset ONI_ML_TPU_ESTEP=dense "
-                "or reduce the batch/vocab size)"
-            )
         dense = dense_estep.densify(word_idx, counts, v)
         return dense_estep.e_step_dense(
             log_beta, alpha, dense, doc_mask, var_max_iters, var_tol,
-            interpret=jax.default_backend() != "tpu",
-            gamma_prev=gamma_prev, warm=warm,
+            interpret=interpret, gamma_prev=gamma_prev, warm=warm,
         )
-    if backend in ("auto", "sparse"):
+    if engine == "sparse":
         from . import sparse_estep
 
-        b, l = word_idx.shape
-        if backend == "sparse":
-            if sparse_estep.pick_block(b, l, log_beta.shape[0]) is None:
-                raise ValueError(
-                    f"sparse E-step forced but B={b}, L={l}, "
-                    f"K={log_beta.shape[0]} has no VMEM-feasible doc "
-                    "block (unset ONI_ML_TPU_ESTEP=sparse or reduce "
-                    "the batch)"
-                )
-            return sparse_estep.e_step(
-                log_beta, alpha, word_idx, counts, doc_mask,
-                var_max_iters, var_tol,
-                interpret=jax.default_backend() != "tpu",
-                gamma_prev=gamma_prev, warm=warm,
-            )
-        if sparse_estep.available(b, l, log_beta.shape[0]):
-            return sparse_estep.e_step(
-                log_beta, alpha, word_idx, counts, doc_mask,
-                var_max_iters, var_tol,
-                gamma_prev=gamma_prev, warm=warm,
-            )
-    if backend != "xla":
+        return sparse_estep.e_step(
+            log_beta, alpha, word_idx, counts, doc_mask,
+            var_max_iters, var_tol, interpret=interpret,
+            gamma_prev=gamma_prev, warm=warm,
+        )
+    if engine == "pallas":
         from . import pallas_estep
 
-        b, l = word_idx.shape
-        if backend == "pallas" and (
-            pallas_estep.pick_block(b, l, log_beta.shape[0]) is None
-        ):
-            raise ValueError(
-                f"pallas E-step forced but B={b}, L={l}, "
-                f"K={log_beta.shape[0]} has no VMEM-feasible doc block "
-                "(unset ONI_ML_TPU_ESTEP=pallas or reduce the batch)"
-            )
-        if backend == "pallas" or pallas_estep.available(
-            b, l, log_beta.shape[0]
-        ):
-            return pallas_estep.e_step(
-                log_beta, alpha, word_idx, counts, doc_mask,
-                var_max_iters, var_tol,
-                gamma_prev=gamma_prev, warm=warm,
-            )
+        return pallas_estep.e_step(
+            log_beta, alpha, word_idx, counts, doc_mask,
+            var_max_iters, var_tol, interpret=interpret,
+            gamma_prev=gamma_prev, warm=warm,
+        )
     V = log_beta.shape[1]
     beta_bt = gather_beta(log_beta, word_idx)
     gamma, iters = fixed_point(beta_bt, alpha, counts, doc_mask,

@@ -69,14 +69,11 @@ def newton_recip(q: jnp.ndarray) -> jnp.ndarray:
     reciprocal (~1.6e-5 max rel error on v5e) plus one Newton step,
     landing ~1.4e-7 — about 1 ulp of f32, i.e. numerically
     interchangeable with the exact divide at a third of its cost (the
-    vector divide dominated the fixed-point bodies).  Interpret mode
-    (CPU tests) computes the exact reciprocal, so the polish is a
-    no-op there.  jax 0.4.x pallas has no reciprocal primitive at all —
-    the exact divide is the correct (slower) fallback."""
-    recip = getattr(pl, "reciprocal", None)
-    if recip is None:
-        return 1.0 / q
-    r0 = recip(q, approx=True)
+    vector divide dominated the fixed-point bodies).  Measured again
+    inside a Mosaic kernel on the v5e under jax 0.9.0 (PERF.md, PR 21):
+    1.6e-5 before the step, 1.4e-7 after.  Interpret mode (CPU tests)
+    emulates a coarser approximation (~4e-3, 1.4e-5 after the step)."""
+    r0 = pl.reciprocal(q, approx=True)
     return r0 * (2.0 - q * r0)
 
 
@@ -303,11 +300,3 @@ def e_step(
         gamma, phinorm, counts, alpha, doc_mask
     )
     return estep.EStepResult(gamma, suff, alpha_ss, likelihood, iters)
-
-
-def available(b: int, l: int, k: int, precision: str = "f32") -> bool:
-    """True when shapes admit a VMEM-feasible block and we're on TPU."""
-    return (
-        jax.default_backend() == "tpu"
-        and pick_block(b, l, k, precision) is not None
-    )

@@ -63,11 +63,6 @@ from . import estep
 from .pallas_estep import digamma_pos, gammaln_pos, newton_recip as _recip
 from .stop import fp_continue
 
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 # VMEM working-set model, mirroring ops/dense_estep.py's: the ceiling
 # gates the analytic block pick and _vmem_limit sizes the per-kernel
 # scoped limit (2x headroom over the model, like dense — Mosaic's real
@@ -372,7 +367,7 @@ def fixed_point_full(
             jax.ShapeDtypeStruct((b, 1), jnp.float32),
             jax.ShapeDtypeStruct((grid, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(bb, l, k_topics, precision)
         ),
         interpret=interpret,
@@ -454,14 +449,6 @@ def make_e_step_fn(precision: str = "f32", interpret: "bool | None" = None):
     sparse_e_step._oni_sparse_engine = True
     sparse_e_step.precision = precision
     return sparse_e_step
-
-
-def available(b: int, l: int, k: int, precision: str = "f32") -> bool:
-    """True when shapes admit a VMEM-feasible block and we're on TPU."""
-    return (
-        jax.default_backend() == "tpu"
-        and pick_block(b, l, k, precision) is not None
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -582,8 +569,7 @@ def measure_crossover(k: int, v: int, b: int, l: int, *,
         t = float("inf")
         for _ in range(reps):
             t0 = time.perf_counter()
-            res = fn()
-            float(np.asarray(res.likelihood))   # sync
+            jax.block_until_ready(fn())
             t = min(t, time.perf_counter() - t0)
         return t
 
@@ -594,7 +580,7 @@ def measure_crossover(k: int, v: int, b: int, l: int, *,
             precision=precision,
         ))
         run = lambda: sparse_fn(log_beta, alpha, word_idx, counts, mask)  # noqa: E731
-        float(np.asarray(run().likelihood))     # compile + warm
+        jax.block_until_ready(run())            # compile + warm
         sparse_s = best_of(run)
     if dense_estep.pick_block(b, v, k, precision) is not None:
         store = dense_estep.corpus_dtype(
@@ -606,12 +592,21 @@ def measure_crossover(k: int, v: int, b: int, l: int, *,
             interpret=interp, precision=precision,
         ))
         run_d = lambda: dense_fn(log_beta, alpha, dense, mask)  # noqa: E731
-        float(np.asarray(run_d().likelihood))   # compile + warm
+        jax.block_until_ready(run_d())          # compile + warm
         dense_s = best_of(run_d)
     if sparse_s is not None and (dense_s is None or sparse_s <= dense_s):
         engine = "sparse"
     else:
         engine = "dense"
+    # Leave no trace in jax's tracing caches.  A cached inner jaxpr
+    # keeps the source location of whoever traced it first, a Mosaic
+    # kernel is serialized with its locations, and jax cannot strip
+    # them from the compilation-cache key: without this, the training
+    # program of a process that MEASURED here carries this function's
+    # locations inside its kernels and misses the persistent cache in
+    # every later process that loads the plan instead (seen on the
+    # v5e: PERF.md, PR 21).
+    jax.clear_caches()
     return {
         "engine": engine,
         "dense_s": dense_s,

@@ -47,7 +47,6 @@ carries per-op bytes, rounds, and wall.
 
 from __future__ import annotations
 
-import base64
 import functools
 import os
 import pickle
@@ -65,18 +64,13 @@ class PeerFailure(BackendLost):
     structured rc=3 exit path (runner/ml_ops.py main) applies."""
 
 
-# Per-KV-value chunk bound (characters of the base64 text actually
-# stored): the coordination service is a control-plane store with a
-# 4 MiB gRPC message cap, so bulk payloads ship in bounded slices
-# instead of one arbitrarily large message.
-#
-# Why base64 text at all: jaxlib 0.4.36's *_bytes KV variants crash the
-# process (SIGSEGV/abort in the watch callback) whenever the value
-# arrives while the get is BLOCKED — exactly the allreduce wait
-# pattern — while the string variants deliver mid-wait arrivals
-# reliably (verified empirically; the multihost suite would be
-# unrunnable on the bytes API).  The ~4/3 size overhead is priced into
-# the journaled byte counts.
+# Per-KV-value chunk bound (bytes actually stored): the coordination
+# service is a control-plane store with a 4 MiB gRPC message cap, so
+# bulk payloads ship in bounded slices instead of one arbitrarily
+# large message.  Values ride the client's *_bytes KV variants, which
+# under the pinned jaxlib 0.9.0 deliver a value that arrives while the
+# get is BLOCKED — the allreduce wait pattern — reliably (the
+# multihost and allreduce suites run on them).
 DEFAULT_MAX_CHUNK_BYTES = 2 << 20
 # Bound on any single collective wait.  Ranks run EM iterations in
 # lockstep, so legitimate skew is one iteration's wall-clock variance;
@@ -334,11 +328,9 @@ class Collective:
         if self._client is None:
             return
         try:
-            self._client.key_value_set(
+            self._client.key_value_set_bytes(
                 self._ns + "/fail",
-                base64.b64encode(
-                    pickle.dumps((self.rank, str(reason)[:500]))
-                ).decode("ascii"),
+                pickle.dumps((self.rank, str(reason)[:500])),
                 allow_overwrite=True,
             )
         except Exception:
@@ -349,12 +341,12 @@ class Collective:
         if self._client is None:
             return
         try:
-            raw = self._client.blocking_key_value_get(
+            raw = self._client.blocking_key_value_get_bytes(
                 self._ns + "/fail", 1
             )
         except Exception:
             return
-        rank, reason = pickle.loads(base64.b64decode(raw))
+        rank, reason = pickle.loads(raw)
         if rank == self.rank:
             return
         raise PeerFailure(
@@ -368,7 +360,7 @@ class Collective:
         self._seq += 1
         return f"{self._ns}/{self._seq}-{tag}"
 
-    def _kv_get(self, key: str) -> str:
+    def _kv_get(self, key: str) -> bytes:
         """Blocking get with a bounded deadline and peer-failure polling
         between wait slices — the coordination-client health barrier of
         the failure-relay contract."""
@@ -392,24 +384,24 @@ class Collective:
                 )
             slice_ms = max(1, int(min(POLL_SLICE_S, remaining) * 1000))
             try:
-                return self._client.blocking_key_value_get(key, slice_ms)
+                return self._client.blocking_key_value_get_bytes(
+                    key, slice_ms)
             except Exception as e:
                 if "DEADLINE_EXCEEDED" not in str(e):
                     raise
                 self.check_peer_failure()
 
     def _put_chunked(self, key: str, data: bytes) -> None:
-        """Publish `data` under `key` in bounded base64 chunks; the
+        """Publish `data` under `key` in bounded chunks; the
         chunk-count marker lands LAST so a reader never observes a
         partial value."""
-        enc = base64.b64encode(data).decode("ascii")
-        n = -(-len(enc) // self.max_chunk_bytes) if enc else 0
+        n = -(-len(data) // self.max_chunk_bytes) if data else 0
         for i in range(n):
-            self._client.key_value_set(
+            self._client.key_value_set_bytes(
                 f"{key}/c{i}",
-                enc[i * self.max_chunk_bytes:(i + 1) * self.max_chunk_bytes],
+                data[i * self.max_chunk_bytes:(i + 1) * self.max_chunk_bytes],
             )
-        self._client.key_value_set(f"{key}/n", str(n))
+        self._client.key_value_set_bytes(f"{key}/n", str(n).encode())
 
     def _get_chunked(self, key: str, delete: bool = False) -> bytes:
         n = int(self._kv_get(f"{key}/n"))
@@ -423,7 +415,7 @@ class Collective:
                 self._client.key_value_delete(f"{key}/n")
             except Exception:
                 pass
-        return base64.b64decode("".join(parts))
+        return b"".join(parts)
 
     # -- control plane ----------------------------------------------------
 

@@ -38,34 +38,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:                                   # jax >= 0.5 exports it top-level
-    shard_map = jax.shard_map
-except AttributeError:                 # 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        """0.4.x compat: the varying-mesh-axes check is spelled
-        check_rep there, and its replication checker has no rule for
-        while_loop — which every in-package E-step kernel contains —
-        so when the caller didn't ask for the check it is disabled
-        (the documented workaround; purely a static verification,
-        numerics are unchanged)."""
-        kw.setdefault("check_rep",
-                      False if check_vma is None else check_vma)
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-
-def _pcast_varying(x, axis):
-    """lax.pcast(to="varying") where the jax version has it; 0.4.x has
-    no varying-axes type system, so the value passes through unchanged
-    (the compat shard_map above runs with the check disabled there)."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        return x
-    return pcast(x, axis, to="varying")
 
 from ..ops import estep
 from ..ops.stop import fp_continue
@@ -295,8 +269,8 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
         gamma0 = jnp.where(warm != 0, gamma_prev, fresh0)
         # delta varies over `data` (each data row stops independently);
         # the initial scalar must carry the same varying-axes type.
-        delta0 = _pcast_varying(
-            jnp.asarray(jnp.inf, jnp.float32), DATA_AXIS
+        delta0 = jax.lax.pcast(
+            jnp.asarray(jnp.inf, jnp.float32), DATA_AXIS, to="varying"
         )
         gamma, iters, _, _ = jax.lax.while_loop(
             cond, body,

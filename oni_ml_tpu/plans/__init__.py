@@ -1,21 +1,17 @@
 """Measured execution plans: persistent autotune + plan cache.
 
-Round 5 proved this framework's throughput is set by *tuning
-constants*, not kernels: retuning `fused_em_chunk` alone moved fused EM
-from 821k to a projected 2.9M docs/s, the scoring engine's
-host-vs-device break-even had to be re-measured to stop the device path
-from losing, and every one of those measurements died with the chip
-grant and had to be re-derived by hand into `config.py` defaults.  This
-package turns those scattered hand-tuned knobs into measured, persisted,
-per-(backend, shape) execution plans:
+Several of this framework's throughput knobs — the EM iterations per
+dispatch, the host-vs-device scoring break-even — trade a per-dispatch
+cost (not measured on the current machine; ROADMAP A1) against device
+work, so their right values belong to a backend and a shape, not to
+`config.py`.  This package turns those hand-tuned knobs into measured,
+persisted, per-(backend, shape) execution plans:
 
 - `store.PlanStore` — a versioned on-disk JSONL store (atomic
   single-write lines like the telemetry journal, corrupt-tail tolerant)
   keyed by backend fingerprint + shape signature + code schema version.
-  Live entries append to `~/.cache/oni_ml_tpu/plans.jsonl` (or
-  `ONI_ML_TPU_PLAN_CACHE`); checked-in seed plans under
-  `plans/seeds/` carry captured evidence (e.g. the r05 v5e chunk sweep)
-  so a fresh host on a known backend starts tuned.
+  Entries append to `~/.cache/oni_ml_tpu/plans.jsonl` (or
+  `ONI_ML_TPU_PLAN_CACHE`).
 - `autotune.autotune` — a bounded sweep harness: measure a declared
   candidate space under a wall-clock budget, record the winner WITH its
   measurements so every constant in the cache carries provenance.
@@ -27,8 +23,7 @@ per-(backend, shape) execution plans:
   under.
 - `warmup` — AOT warmup + persistent-compilation-cache wiring
   (`jax_compilation_cache_dir`), so both traced-program and tuned-plan
-  state survive process death — the wedged-grant loss mode of rounds
-  3–5.
+  state survive process death.
 
 `ONI_ML_TPU_PLANS=0` disables every lookup and record (consumers fall
 back to config/default exactly as before this package existed).
@@ -48,7 +43,6 @@ from .store import (
     PlanEntry,
     PlanStore,
     default_path,
-    seed_paths,
 )
 
 __all__ = [
@@ -73,7 +67,6 @@ __all__ = [
     "note_sweep",
     "record_value",
     "resolve",
-    "seed_paths",
     "use_store",
 ]
 
@@ -132,15 +125,6 @@ def device_fingerprint() -> str:
     return _DEVICE_FP
 
 
-def device_fingerprint_cached() -> "str | None":
-    """The device fingerprint IF this process already computed one,
-    else None — never initializes a backend.  The public form of the
-    guard bench.py's salvage path needs (probing a wedged grant for a
-    fingerprint could hang the path whose contract is to always print
-    a last line)."""
-    return _DEVICE_FP
-
-
 def fingerprint(scope: str) -> str:
     return host_fingerprint() if scope == "host" else device_fingerprint()
 
@@ -161,9 +145,8 @@ def plans_enabled() -> bool:
 
 def default_store() -> PlanStore:
     """The process default store at `default_path()` (env
-    ONI_ML_TPU_PLAN_CACHE or ~/.cache/oni_ml_tpu/plans.jsonl), with the
-    checked-in seed plans merged under live entries.  Re-resolved when
-    the env path changes (tests repoint it)."""
+    ONI_ML_TPU_PLAN_CACHE or ~/.cache/oni_ml_tpu/plans.jsonl).
+    Re-resolved when the env path changes (tests repoint it)."""
     global _DEFAULT
     path = default_path()
     if _DEFAULT is None or _DEFAULT.path != path:
@@ -216,8 +199,7 @@ def resolve(knob: str, config_value, *, shape: str = "*", store=_UNSET):
 
     The config-vs-default comparison is by VALUE: setting a knob
     explicitly to its shipped default is indistinguishable from leaving
-    it alone, and a matching plan may override it — documented in
-    docs/performance.md."""
+    it alone, and a matching plan may override it (ROADMAP C9)."""
     spec = KNOBS[knob]
     if config_value is not None and config_value != spec.default:
         counters["config"] += 1

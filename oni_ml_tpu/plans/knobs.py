@@ -146,8 +146,8 @@ KNOBS = {
         # are queueing/latency knobs, not device properties, and a
         # device fingerprint would make BatchScorer.__init__ initialize
         # the jax backend even for host-pinned serving
-        # (device_score_min=None) — a startup HANG against a wedged
-        # grant, the loss mode this repo guards everywhere else.
+        # (device_score_min=None) — a host-only service must start
+        # beside a process that holds the chip.
         Knob(
             "serve_max_batch", ServingConfig.max_batch, scope="host",
             candidates=(512, 1024, 2048, 4096, 8192),

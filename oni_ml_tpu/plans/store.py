@@ -21,15 +21,10 @@ Invalidation is by omission: entries whose `schema` differs from this
 code's SCHEMA_VERSION are dropped at load, and lookups match the
 CURRENT backend fingerprint — a cache written on one backend simply
 misses on another.  Latest entry per (knob, backend, shape) wins.
-
-Seed plans: JSONL files under `plans/seeds/` ship captured evidence
-with the repo (e.g. the r05 v5e chunk sweep).  They load underneath the
-live file, so a live measurement always overrides a seed.
 """
 
 from __future__ import annotations
 
-import glob
 import os
 from typing import NamedTuple
 
@@ -60,19 +55,12 @@ def default_path() -> str:
     return os.path.join(cache_base(), "plans.jsonl")
 
 
-def seed_paths() -> list[str]:
-    """Checked-in seed plan files, sorted for deterministic layering."""
-    here = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "seeds")
-    return sorted(glob.glob(os.path.join(here, "*.jsonl")))
-
-
 class PlanEntry(NamedTuple):
     knob: str
     backend: str
     shape: str
     value: object
-    source: str          # "autotune" | "probe" | "seed" | ...
+    source: str          # "autotune" | "probe" | ...
     measurements: "dict | None"
     record: dict         # the full on-disk record (provenance)
 
@@ -101,16 +89,15 @@ def _entry_from_record(rec: dict) -> "PlanEntry | None":
 
 
 class PlanStore:
-    """Lazy-loaded plan cache over one JSONL file plus the seed files.
+    """Lazy-loaded plan cache over one JSONL file.
 
     Reads replay the file with the journal's truncated-tail tolerance;
     appends go through a Journal (single-write atomic lines).  The
     in-memory map updates on record(), so a process sees its own
     appends without re-reading the file."""
 
-    def __init__(self, path: str, seeds: bool = True) -> None:
+    def __init__(self, path: str) -> None:
         self.path = path
-        self._seeds = seeds
         self._entries: "dict | None" = None   # key -> PlanEntry
         self._dropped = 0
         self._journal: "Journal | None" = None
@@ -120,19 +107,13 @@ class PlanStore:
         if self._entries is not None:
             return self._entries
         entries: dict = {}
-        dropped = 0
-        paths = (seed_paths() if self._seeds else []) + [self.path]
-        for path in paths:
-            records, bad = Journal.replay_report(path)
-            dropped += bad
-            for rec in records:
-                entry = _entry_from_record(rec)
-                if entry is None:
-                    dropped += 1
-                    continue
-                if path != self.path and entry.source == "unknown":
-                    entry = entry._replace(source="seed")
-                entries[entry.key] = entry   # latest (and live) wins
+        records, dropped = Journal.replay_report(self.path)
+        for rec in records:
+            entry = _entry_from_record(rec)
+            if entry is None:
+                dropped += 1
+                continue
+            entries[entry.key] = entry   # latest wins
         self._entries = entries
         self._dropped = dropped
         return entries
@@ -197,7 +178,7 @@ class PlanStore:
         return stamped
 
     def clear(self) -> None:
-        """Remove the LIVE file (seeds are code, not cache)."""
+        """Remove the file."""
         self.close()
         try:
             os.unlink(self.path)

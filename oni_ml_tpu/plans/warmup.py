@@ -1,14 +1,13 @@
 """AOT warmup + persistent-compilation-cache wiring.
 
-Two mechanisms, one goal — no compiled state dies with the process
-(rounds 3–5 each lost tuned constants AND every traced program to a
-wedged grant):
+Two mechanisms, one goal — no compiled state dies with the process:
 
-- `setup_compilation_cache()` wires `jax_compilation_cache_dir` (env
-  `JAX_COMPILATION_CACHE_DIR` wins, else `~/.cache/oni_ml_tpu/jax_cache`)
-  with the min-compile-time/min-entry-size gates opened, so every XLA
-  executable this process builds is serialized to disk and the next
-  process deserializes instead of recompiling.
+- `setup_compilation_cache()` wires `jax_compilation_cache_dir` to
+  `cache_dir()` (env `JAX_COMPILATION_CACHE_DIR` when set, else the
+  fixed `<checkout>/.jax_cache`) with the min-compile-time/
+  min-entry-size gates opened, so every XLA executable this process
+  builds is serialized to disk and the next process deserializes
+  instead of recompiling.
 - `warmup_*()` AOT-compiles the scoring entry points at the active
   plan's shapes (`jax.jit(...).lower(shapes).compile()` against
   `jax.ShapeDtypeStruct`s — no data needed), so `ml_ops serve` has its
@@ -27,7 +26,8 @@ from __future__ import annotations
 import os
 import time
 
-_COUNTS = {"compile_requests": 0, "cache_hits": 0}
+_COUNTS = {"compile_requests": 0, "cache_hits": 0,
+           "trace_s": 0.0, "compile_s": 0.0}
 _LISTENING: "bool | None" = False
 
 
@@ -49,7 +49,21 @@ def _ensure_listener() -> bool:
             elif name == "/jax/compilation_cache/cache_hits":
                 _COUNTS["cache_hits"] += 1
 
+        def _on_duration(name: str, secs: float, **kw) -> None:
+            # jax times tracing, lowering and the backend compile (or
+            # the cache retrieval that replaces it) apart.  Traces nest
+            # — an inner jit's trace is inside the outer one's — so
+            # trace_s is an upper bound and is kept out of compile_s.
+            if name == "/jax/core/compile/jaxpr_trace_duration":
+                _COUNTS["trace_s"] += secs
+            elif name in (
+                "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                "/jax/core/compile/backend_compile_duration",
+            ):
+                _COUNTS["compile_s"] += secs
+
         monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
         _LISTENING = True
     except Exception:
         _LISTENING = None
@@ -58,9 +72,12 @@ def _ensure_listener() -> bool:
 
 
 def compile_counts() -> dict:
-    """Cumulative per-process compile-cache counters.  `traces` is the
+    """Cumulative per-process compile counters.  `traces` is the
     number of compile requests the persistent cache could NOT serve —
-    the quantity a warmed second run drives to zero."""
+    the quantity a warmed second run drives to zero.  `compile_s` is
+    the seconds jax spent lowering and compiling (or fetching from the
+    cache), `trace_s` the seconds it spent tracing: what a stage's wall
+    time has to shed before it says anything about the steady state."""
     c = dict(_COUNTS)
     c["traces"] = c["compile_requests"] - c["cache_hits"]
     return c
@@ -68,16 +85,25 @@ def compile_counts() -> dict:
 
 def counts_delta(before: dict) -> dict:
     now = compile_counts()
-    return {k: now[k] - before.get(k, 0) for k in now}
+    return {
+        k: (round(now[k] - before.get(k, 0), 3)
+            if isinstance(now[k], float) else now[k] - before.get(k, 0))
+        for k in now
+    }
 
 
-def default_cache_dir() -> str:
+def cache_dir() -> str:
+    """The one compilation-cache placement rule every entry point goes
+    through.  `JAX_COMPILATION_CACHE_DIR`, when set, is the directory
+    and nothing in code names another.  Unset, the cache is the fixed
+    `<checkout>/.jax_cache` (git-ignored): the directory is part of the
+    cache key, so a path derived from the home directory, a pid or the
+    clock would never hit on a machine that is rebuilt per run."""
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    from .store import cache_base
-
-    return os.path.join(cache_base(), "jax_cache")
+    package = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package), ".jax_cache")
 
 
 def cache_entries(cache_dir: str) -> int:
@@ -90,41 +116,43 @@ def cache_entries(cache_dir: str) -> int:
         return 0
 
 
-def setup_compilation_cache(enabled: bool = True,
-                            cache_dir: str = "") -> dict:
-    """Point jax at a persistent compilation cache and open its gates
-    (min compile time / entry size → 0: the point is surviving process
-    death, not only skipping slow compiles).  Returns the record the
-    runner/serve put in their metrics: {enabled, dir, entries,
-    counting}."""
+def setup_compilation_cache(enabled: bool = True) -> dict:
+    """Point jax at the persistent compilation cache (`cache_dir()`)
+    and open its gates (min compile time / entry size → 0: the point is
+    surviving process death, not only skipping slow compiles).  Returns
+    the record the runner/serve put in their metrics: {enabled, dir,
+    entries, counting}."""
     if not enabled:
         return {"enabled": False}
-    d = cache_dir or default_cache_dir()
+    d = cache_dir()
     try:
         os.makedirs(d, exist_ok=True)
         import jax
 
-        prev = getattr(jax.config, "jax_compilation_cache_dir", None)
-        jax.config.update("jax_compilation_cache_dir", d)
-        for opt, val in (
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-        ):
-            try:
-                jax.config.update(opt, val)
-            except Exception:
-                pass            # older jax: gate names differ; dir alone
-        if prev is not None and prev != d:
-            # jax materializes its cache object lazily and does NOT
-            # re-read the dir config afterwards — a process whose cache
-            # already initialized elsewhere must drop it, or entries
-            # silently keep landing in the old dir.
-            try:
+        prev = jax.config.jax_compilation_cache_dir
+        if prev != d:
+            # jax read the env var at import, so this only runs for the
+            # checkout default or an env var set after jax was imported.
+            jax.config.update("jax_compilation_cache_dir", d)
+            if prev is not None:
+                # jax materializes its cache object lazily and does NOT
+                # re-read the dir config afterwards — a process whose
+                # cache already initialized elsewhere must drop it, or
+                # entries silently keep landing in the old dir.
                 from jax._src.compilation_cache import reset_cache
 
                 reset_cache()
-            except Exception:
-                pass
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        # A Mosaic kernel travels inside its custom call as serialized
+        # MLIR, source locations included, where jax's stripping of
+        # metadata from the cache key cannot reach.  With full
+        # tracebacks in those locations the key depends on the line
+        # numbers of the kernel's callers: the same kernel lowered
+        # from two call sites is two programs (PERF.md, PR 21).
+        # Innermost frame only, so the key is the kernel's, not the
+        # caller's.
+        jax.config.update("jax_include_full_tracebacks_in_locations", False)
     except Exception as e:
         return {"enabled": False, "error": repr(e)[:200]}
     counting = _ensure_listener()

@@ -951,10 +951,9 @@ def run_continuous(
     nothing without it), and drive the slice stream to exhaustion."""
     from ..plans import warmup as plans_warmup
 
-    if config.plans.compilation_cache:
-        plans_warmup.setup_compilation_cache(
-            cache_dir=config.plans.compilation_cache_dir
-        )
+    plans_warmup.setup_compilation_cache(
+        enabled=config.plans.compilation_cache
+    )
     plans_warmup._ensure_listener()
     service = ContinuousService(
         config, dsource, out_dir=out_dir, tenant=tenant,
@@ -1133,6 +1132,7 @@ class FleetContinuousService:
         collective=None,
         warmup_refreshes: "int | None" = None,
         replica_extra: "list[str] | None" = None,
+        replica_platform: "str | None" = None,
     ) -> None:
         from ..serving import CoScheduler
         from ..telemetry import Journal, Recorder, RunJournal
@@ -1184,7 +1184,16 @@ class FleetContinuousService:
         self.replica_procs: dict = {}
         self._workdir = None
         if self.router is None and replicated:
-            self._spawn_fleet(int(replicated), replica_extra or [])
+            if not replica_platform:
+                # This process trains on the device it holds; replicas
+                # it starts run where the caller says, never on a
+                # platform picked behind the caller's back.
+                raise ValueError(
+                    "replicated=N spawns replica processes and needs "
+                    "replica_platform (--replica-platform cpu|tpu)"
+                )
+            self._spawn_fleet(int(replicated), replica_extra or [],
+                              replica_platform)
         self.binding = None
         if self.router is not None:
             self.binding = RouterBinding(
@@ -1215,7 +1224,7 @@ class FleetContinuousService:
             daemon=True)
         self._worker.start()
 
-    def _spawn_fleet(self, n: int, extra: list) -> None:
+    def _spawn_fleet(self, n: int, extra: list, platform: str) -> None:
         from ..parallel import FileKVClient
         from ..serving import FleetRouter
         from .route import _spawn_replica
@@ -1232,7 +1241,7 @@ class FleetContinuousService:
         for i in range(n):
             rid = f"r{i}"
             proc, host, port = _spawn_replica(
-                rid, kv_dir, workdir, list(extra))
+                rid, kv_dir, workdir, list(extra), platform=platform)
             self.replica_procs[rid] = proc
             router.connect_replica(rid, host, port)
         with self._plock:
@@ -1455,20 +1464,21 @@ def run_fleet_continuous(
     coscheduler: bool = True,
     collective=None,
     warmup_refreshes: "int | None" = None,
+    replica_platform: "str | None" = None,
 ) -> dict:
     """Convenience wrapper for the composed mode: compilation cache +
     compile counters, then drive the tagged stream to exhaustion."""
     from ..plans import warmup as plans_warmup
 
-    if config.plans.compilation_cache:
-        plans_warmup.setup_compilation_cache(
-            cache_dir=config.plans.compilation_cache_dir
-        )
+    plans_warmup.setup_compilation_cache(
+        enabled=config.plans.compilation_cache
+    )
     plans_warmup._ensure_listener()
     fleet = FleetContinuousService(
         config, streams, out_dir=out_dir, replicated=replicated,
         router=router, coscheduler=coscheduler, collective=collective,
         warmup_refreshes=warmup_refreshes,
+        replica_platform=replica_platform,
     )
     return fleet.run(tagged)
 
@@ -1495,6 +1505,11 @@ def build_parser() -> argparse.ArgumentParser:
                    help="serve through the fleet router over N "
                    "spawned replica subprocesses (ml_ops replica) "
                    "instead of the in-process scorer")
+    p.add_argument("--replica-platform", default=None, metavar="PLATFORM",
+                   help="jax platform the --replicated replicas run on "
+                   "(cpu, tpu) — required with --replicated: this "
+                   "process holds the device it trains on, and a "
+                   "replica's platform is never defaulted")
     p.add_argument("--multihost", action="store_true",
                    help="distributed window refreshes over the "
                    "ambient collective (parallel/allreduce env "
@@ -1579,6 +1594,7 @@ def _main_fleet(args, config: PipelineConfig) -> int:
         out_dir=os.path.join(config.data_dir, "continuous_fleet"),
         replicated=args.replicated, collective=collective,
         coscheduler=not args.no_cosched,
+        replica_platform=args.replica_platform,
     )
     print(json.dumps(payload), flush=True)
     return 0

@@ -249,6 +249,9 @@ def _run_stage(ctx: RunContext, stage: Stage, fn: Callable[[], dict]) -> None:
         ctx.heartbeat.check()
     if ctx.journal is not None:
         ctx.journal.stage_begin(stage.value)
+    from ..plans import warmup as _plans_warmup
+
+    cc0 = _plans_warmup.compile_counts()
     t0 = time.perf_counter()
     try:
         with maybe_span(f"stage.{stage.value}", fdate=ctx.fdate,
@@ -263,6 +266,13 @@ def _run_stage(ctx: RunContext, stage: Stage, fn: Callable[[], dict]) -> None:
             )
         raise
     wall_s = round(time.perf_counter() - t0, 3)
+    # What the stage compiled (requests, cache hits, fresh traces, and
+    # the seconds jax spent on them): the stage wall minus these is the
+    # steady-state share.  Every stage ends on host data, so the wall
+    # covers the device work it started.
+    compiled = _plans_warmup.counts_delta(cc0)
+    if compiled["compile_requests"] or compiled["trace_s"]:
+        info = {**info, "compile": compiled}
     ctx.emit({"stage": stage.value, "wall_s": wall_s, **info})
     if ctx.journal is not None:
         # sync=True inside stage_end: the resume contract is durable
@@ -373,6 +383,10 @@ def _pre_record(ctx: RunContext, features, fb_rows, workers, workers_src,
     merge_wall = timings.pop("merge_s", None)
     out = {
         "events": features.num_events,
+        # Native containers carry interned id arrays (wc_ip); the pure-
+        # Python oracle does not.  Which one featurized the day depends
+        # on whether the C++ library built (native_build.py).
+        "featurizer": "native" if hasattr(features, "wc_ip") else "python",
         "word_count_rows": n_wc,
         "feedback_rows": len(fb_rows),
         "pre_workers": workers,
@@ -941,7 +955,12 @@ def _score_day(ctx: RunContext, features, model, prep,
     from ..plans import resolve
     from ..scoring.score import _score_engine
 
-    device = _score_engine(sc.engine) == "device"
+    engine = _score_engine(sc.engine)
+    engine_src = (
+        "config" if sc.engine
+        else "env" if os.environ.get("ONI_ML_TPU_SCORE") else "default"
+    )
+    device = engine == "device"
     chunk = sc.device_chunk
     plans_rec = None
     if device:
@@ -997,6 +1016,7 @@ def _score_day(ctx: RunContext, features, model, prep,
             f.write(blob)
     out = {
         "scored_events": features.num_raw_events,
+        "scorer": {"value": engine, "source": engine_src},
         "flagged": int(len(scores)),
         "min_score": float(scores[0]) if len(scores) else None,
         "features": feat_src,
@@ -1135,15 +1155,14 @@ def run_pipeline(
     from ..plans import warmup as _plans_warmup
 
     cc_rec = _plans_warmup.setup_compilation_cache(
-        enabled=plc.compilation_cache,
-        cache_dir=plc.compilation_cache_dir,
+        enabled=plc.compilation_cache
     )
     if not plc.enabled:
         plan_store: "PlanStore | NullStore | None" = NullStore()
     elif plc.cache_path:
         plan_store = PlanStore(plc.cache_path)
     else:
-        plan_store = None        # the default store (seeds + user cache)
+        plan_store = None        # the default store (the user cache)
     plans_cc0 = _plans_warmup.compile_counts()
     plans_ctr0 = counters_snapshot()
     from ..telemetry import roofline as _rl0
@@ -1694,13 +1713,14 @@ def build_parser() -> argparse.ArgumentParser:
         "--plan-cache", default=None, metavar="PATH",
         help="plan-cache JSONL file for this run (default: "
         "ONI_ML_TPU_PLAN_CACHE env, else ~/.cache/oni_ml_tpu/"
-        "plans.jsonl; checked-in seed plans always load underneath)",
+        "plans.jsonl)",
     )
     p.add_argument(
         "--no-compilation-cache", action="store_true",
         help="do not wire jax_compilation_cache_dir (by default every "
-        "compiled program persists to ~/.cache/oni_ml_tpu/jax_cache — "
-        "or JAX_COMPILATION_CACHE_DIR — so a re-run re-traces nothing; "
+        "compiled program persists to JAX_COMPILATION_CACHE_DIR — or, "
+        "when it is unset, <checkout>/.jax_cache — so a re-run "
+        "re-traces nothing; "
         "the run's metrics record compile requests vs cache hits)",
     )
     p.add_argument(

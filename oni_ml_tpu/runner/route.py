@@ -46,6 +46,12 @@ def build_replica_parser() -> argparse.ArgumentParser:
     )
     p.add_argument("--id", required=True, help="replica id (becomes "
                    "the membership/journal key)")
+    p.add_argument("--platform", required=True, metavar="PLATFORM",
+                   help="jax platform this replica runs on (cpu, tpu): "
+                   "named by whoever starts the replica, never "
+                   "defaulted — one process holds a chip at a time, so "
+                   "a replica started beside a process that holds the "
+                   "chip says so and exits instead of waiting for it")
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument("--port", type=int, default=0,
                    help="listen port (0 = ephemeral)")
@@ -99,6 +105,18 @@ def replica_main(argv: "list[str] | None" = None) -> int:
     from ..serving import ReplicaServer
 
     args = build_replica_parser().parse_args(argv)
+    # The platform is the caller's, fixed before any backend exists,
+    # and claimed NOW: a chip another process holds fails here, at
+    # start-up, with the reason in the log the spawner reads.
+    import jax
+
+    jax.config.update("jax_platforms", args.platform)
+    try:
+        platform = jax.devices()[0].platform
+    except Exception as e:
+        print(f"REPLICA_FAILED {args.id} platform={args.platform}: "
+              f"{e!r}"[:600], flush=True)
+        return 1
     cfg = ServingConfig(
         device_score_min=_parse_device_score_min(args.device_score_min),
     )
@@ -126,8 +144,8 @@ def replica_main(argv: "list[str] | None" = None) -> int:
         with open(tmp, "w") as f:
             f.write(f"{server.host} {server.port}\n")
         os.replace(tmp, args.port_file)
-    print(f"REPLICA_READY {args.id} {server.host} {server.port}",
-          flush=True)
+    print(f"REPLICA_READY {args.id} {server.host} {server.port} "
+          f"platform={platform}", flush=True)
     stop = threading.Event()
     for sig in (signal.SIGTERM, signal.SIGINT):
         signal.signal(sig, lambda *_: stop.set())
@@ -156,6 +174,10 @@ def build_route_parser() -> argparse.ArgumentParser:
     p.add_argument("--replicas", type=int, default=0, metavar="N",
                    help="spawn N replica subprocesses (ml_ops "
                    "replica) on this host")
+    p.add_argument("--replica-platform", default=None, metavar="PLATFORM",
+                   help="jax platform the spawned replicas run on (cpu, "
+                   "tpu) — required whenever this router spawns "
+                   "replicas (--replicas, --autoscale); never defaulted")
     p.add_argument("--connect", default="", metavar="ID=HOST:PORT,...",
                    help="attach to already-running replicas instead "
                    "of spawning")
@@ -193,11 +215,14 @@ def build_route_parser() -> argparse.ArgumentParser:
 
 def _spawn_replica(rid: str, kv_flags: "str | list[str]", workdir: str,
                    extra: "list[str] | None" = None,
-                   timeout_s: float = 120.0):
+                   timeout_s: float = 120.0, *, platform: str):
     """One `ml_ops replica` subprocess; returns (proc, host, port)
     after the port-file handshake.  `kv_flags` is either the shared
     file-KV directory (the historical signature) or a ready-made flag
-    list (["--kv-connect", "host:port"] for the TCP control plane)."""
+    list (["--kv-connect", "host:port"] for the TCP control plane).
+    `platform` is the jax platform the replica runs on: every caller
+    names it, and it rides the child's command line (placing one
+    replica per chip is ROADMAP A5)."""
     if isinstance(kv_flags, str):
         kv_flags = ["--kv-dir", kv_flags]
     port_file = os.path.join(workdir, f"{rid}.port")
@@ -207,10 +232,11 @@ def _spawn_replica(rid: str, kv_flags: "str | list[str]", workdir: str,
         pass
     cmd = [
         sys.executable, "-m", "oni_ml_tpu.runner.ml_ops", "replica",
-        "--id", rid, "--port-file", port_file,
+        "--id", rid, "--platform", platform, "--port-file", port_file,
     ] + kv_flags + (extra or [])
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # The --platform flag decides; an inherited pin must not fight it.
+    env.pop("JAX_PLATFORMS", None)
     # The child must import THIS checkout's package wherever the
     # router was launched from (the repo is run in place, not
     # installed).
@@ -222,7 +248,8 @@ def _spawn_replica(rid: str, kv_flags: "str | list[str]", workdir: str,
     # The child's stdout must not interleave with the router's (a
     # bench phase's stdout is a JSON contract); the port file is the
     # readiness handshake, so the log file is purely diagnostic.
-    log = open(os.path.join(workdir, f"{rid}.log"), "ab")
+    log_path = os.path.join(workdir, f"{rid}.log")
+    log = open(log_path, "ab")
     try:
         proc = subprocess.Popen(cmd, env=env, stdout=log,
                                 stderr=subprocess.STDOUT)
@@ -231,9 +258,11 @@ def _spawn_replica(rid: str, kv_flags: "str | list[str]", workdir: str,
     deadline = time.monotonic() + timeout_s
     while time.monotonic() < deadline:
         if proc.poll() is not None:
+            with open(log_path, "rb") as f:
+                tail = f.read()[-600:].decode("utf-8", "replace")
             raise RuntimeError(
-                f"replica {rid} exited rc={proc.returncode} before "
-                "listening"
+                f"replica {rid} (platform {platform}) exited "
+                f"rc={proc.returncode} before listening: {tail.strip()}"
             )
         try:
             with open(port_file) as f:
@@ -299,7 +328,7 @@ class _FlagCollector:
 
 
 def _rolling_redeploy(router, procs: dict, kv_flags, workdir: str,
-                      extra: "list[str]") -> "list[dict]":
+                      extra: "list[str]", platform: str) -> "list[dict]":
     """Drain-one-respawn-one over every spawned replica: the fleet
     keeps serving throughout (the router promotes each drained
     replica's tenants to their warm shadows, then the placement pulls
@@ -312,7 +341,7 @@ def _rolling_redeploy(router, procs: dict, kv_flags, workdir: str,
         proc.wait(timeout=60.0)
         new_id = f"{rid}v2"
         proc2, host, port = _spawn_replica(
-            new_id, kv_flags, workdir, extra)
+            new_id, kv_flags, workdir, extra, platform=platform)
         procs[new_id] = proc2
         joined = router.join_replica(new_id, host, port)
         out.append({"drained": drained, "joined": joined})
@@ -361,11 +390,17 @@ def route_stream(args) -> int:
     router = FleetRouter(cfg, kv=kv)
     scaler = None
     try:
+        if (args.replicas or args.autoscale) \
+                and not args.replica_platform:
+            print("route: spawning replicas needs --replica-platform "
+                  "(cpu or tpu)", file=sys.stderr)
+            return 2
         if args.replicas:
             for i in range(args.replicas):
                 rid = f"r{i}"
                 proc, host, port = _spawn_replica(
-                    rid, kv_flags, workdir, extra)
+                    rid, kv_flags, workdir, extra,
+                    platform=args.replica_platform)
                 procs[rid] = proc
                 router.connect_replica(rid, host, port)
         elif args.connect:
@@ -405,7 +440,8 @@ def route_stream(args) -> int:
                 rid = f"as{spawn_seq[0]}"
                 spawn_seq[0] += 1
                 proc, host, port = _spawn_replica(
-                    rid, kv_flags, workdir, extra)
+                    rid, kv_flags, workdir, extra,
+                    platform=args.replica_platform)
                 procs[rid] = proc
                 return rid, host, port
 
@@ -438,7 +474,8 @@ def route_stream(args) -> int:
             if (args.redeploy_after and procs
                     and routed == args.redeploy_after):
                 redeploys = _rolling_redeploy(
-                    router, procs, kv_flags, workdir, extra)
+                    router, procs, kv_flags, workdir, extra,
+                    args.replica_platform)
         router.flush()
         collector.close()
         summary = {

@@ -373,9 +373,18 @@ def serve_stream(args) -> int:
             )
         except Exception as e:  # warmup must never block serving
             warm = {"error": repr(e)[:200]}
+        calibration = None
+        if cfg.device_score_min in (0, "auto"):
+            # The measurement the host-vs-device choice rests on (the
+            # scorer's constructor took it, or loaded it from the plan
+            # cache): a stream that never leaves the host says why.
+            from ..scoring import dispatch_calibration
+
+            calibration = dispatch_calibration()
         metrics.emit({
             "stage": "serve", "event": "plans",
             "knobs": scorer.plan,
+            "dispatch_calibration": calibration,
             "compilation_cache": cc_rec,
             "warmup": warm,
         })

@@ -158,9 +158,8 @@ def _batched_scores(model: ScoringModel, ip_idx, word_idx, batch: int = 1 << 20)
     event against two gathered rows — pure memory-bound host work on
     data that already lives host-side (the featurized day), while a
     device round trip ships the index arrays out and the scores back
-    for no arithmetic advantage (measured through the remote-relay
-    backend it was the whole scoring stage's wall-clock; even
-    PCIe-attached the transfer beats the compute).  float64
+    for no arithmetic advantage (the transfer outweighs the compute;
+    not measured on the current machine, ROADMAP A4).  float64
     accumulation matches the reference's double-precision scoring
     (the earlier device path computed f32 — a deliberate re-pin of
     the golden scoring bytes); chunking bounds the gathered
@@ -325,11 +324,10 @@ _CALIBRATION: dict | None = None
 
 def dispatch_calibration(force: bool = False) -> dict:
     """Measured break-even batch size for the host-vs-device dispatch
-    decision — the r05 fix for the device path silently LOSING to host
-    (BENCH_r05: 516k/621k host events/sec vs 150k/326k on-chip): a raw
-    size threshold can route day-scale batches onto a path whose
-    per-dispatch glue exceeds the host's whole stage, so the decision
-    is now priced from this process's own measurements.
+    decision: a raw size threshold can route batches onto a path whose
+    per-dispatch cost exceeds the host's whole stage, so the decision
+    is priced from this process's own measurements on the machine it
+    runs on (first measured on the v5e in PR 21: PERF.md).
 
     Returns {"dispatch_s", "host_event_s", "device_event_s",
     "break_even", "source"}; break_even None means the device's marginal

@@ -879,7 +879,7 @@ class FleetScorer:
         """Caller holds self._cond.  Lanes the worker may drain NOW:
         pending events whose tenant is HBM-hot (or residency off).
         After close() every lane drains — a still-paging tenant's
-        events resolve through the solo fallback instead of wedging
+        events resolve through the solo fallback instead of blocking
         shutdown.  A paging tenant's lane is simply invisible to the
         flush triggers: its events wait out the promotion in their own
         bounded queue while resident tenants keep flushing."""

@@ -3,10 +3,10 @@
 A *replica* is the unit of blast radius: a full single-process serving
 stack (FleetRegistry -> dynamic FleetScorer, the PR 10/12 machinery
 unchanged) behind a small framed socket protocol, plus a KV heartbeat
-(parallel/membership.py) so the router can tell a wedged replica from a
+(parallel/membership.py) so the router can tell a stuck replica from a
 slow one.  N replicas on one or several hosts each run their own
 Python process, their own JAX backend, their own compiled-program
-family — a wedged backend (heartbeat -> BackendLost) now kills ONE
+family — a dead backend (heartbeat -> BackendLost) now kills ONE
 replica's tenants for the promotion window instead of the whole fleet
 (ROADMAP item 5).
 
@@ -179,7 +179,7 @@ class ReplicaServer:
         self.config = config or ServingConfig()
         # Optional backend-liveness probe (e.g. a bound
         # telemetry/heartbeat.HeartbeatMonitor.check): raising marks
-        # this replica WEDGED — fail key posted, heartbeats stop.
+        # this replica STUCK — fail key posted, heartbeats stop.
         self._health_check = health_check
         self._journal = getattr(journal, "journal", journal)
         self.fleet = FleetRegistry(journal=journal)
@@ -227,11 +227,11 @@ class ReplicaServer:
     # -- accept / per-connection loops --------------------------------------
 
     def _hb_payload(self) -> dict:
-        """Heartbeat payload doubling as the wedge detector: a
+        """Heartbeat payload doubling as the stuck-replica detector: a
         heartbeat is only worth sending if the scoring stack behind it
         is actually alive.  A dead scorer worker, or a failing
         `health_check` (e.g. telemetry/heartbeat.HeartbeatMonitor's
-        check() raising BackendLost — the wedged-backend mode), posts
+        check() raising BackendLost — the dead-backend mode), posts
         the membership FAIL KEY and stops the beat: the router's
         monitor promotes this replica's shadows within one poll
         instead of trusting a liveness signal decoupled from

@@ -377,7 +377,7 @@ class ResidencyManager:
     def drainable(self) -> frozenset:
         """Tenants the scorer may flush right now: the HBM-hot set plus
         any tenant whose promotion FAILED (its lane drains through the
-        solo fallback, failing tenant-scoped instead of wedging the
+        solo fallback, failing tenant-scoped instead of blocking the
         queue).  Lock-free read of an immutable snapshot."""
         return self._drainable
 

@@ -534,7 +534,7 @@ class FleetRouter:
             if t0 is None:
                 t0 = time.perf_counter()
             # Timed slices: a response, failover, or close notifies,
-            # but a lost wakeup must not wedge admission forever.
+            # but a lost wakeup must not block admission forever.
             self._cond.wait(0.05)
         if t0 is not None:
             e = self._edge.get(target)
@@ -926,7 +926,7 @@ class FleetRouter:
 
     def _monitor_loop(self) -> None:
         """Liveness beyond connection EOF: KV heartbeats catch a
-        WEDGED replica (process alive, drain loop stuck — the
+        STUCK replica (process alive, drain loop stuck — the
         BackendLost mode), the fail key catches a replica that knew it
         was dying.  Detection latency = heartbeat_s * miss, the
         documented failover budget."""

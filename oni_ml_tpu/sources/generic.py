@@ -406,7 +406,7 @@ class ProxySource(TableSourceSpec):
 
     The word bins the request shape a C2 channel distorts: method and
     status raw, then decile duration, quintile response bytes, quintile
-    host-name entropy (DGA/tunnel hosts score high).  Time-of-day stays
+    host-name entropy (DGA/covert-channel hosts score high).  Time-of-day stays
     a declared field — it orders continuous-mode slices — but is left
     OUT of the word: a polling implant's cadence is already visible in
     duration/bytes regularity, and a time bin would multiply the benign

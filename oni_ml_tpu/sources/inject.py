@@ -137,7 +137,7 @@ def _dns_tunneling(rng: np.random.Generator,
         sub = "".join(rng.choice(alphabet, size=40))
         lines.append(
             f"t,{ts},{int(rng.integers(200, 400))},{cli},"
-            f"{sub}.tunnel.example,1,16,0"
+            f"{sub}.covert.example,1,16,0"
         )
     return lines, cli
 

@@ -204,11 +204,6 @@ class RunJournal:
     def backend_lost(self, **info) -> None:
         self.append({"kind": "backend_lost", **info}, sync=True)
 
-    def phase(self, name: str, ok: bool = True, **info) -> None:
-        """Bench phase completion/failure (bench.py)."""
-        self.append({"kind": "phase", "name": name, "ok": bool(ok),
-                     **info}, sync=True)
-
     def annotation(self, kind: str, **info) -> None:
         self.append({"kind": kind, **info})
 

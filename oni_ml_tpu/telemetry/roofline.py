@@ -71,11 +71,10 @@ PEAK_SPECS: "tuple[tuple[tuple[str, ...], PeakSpec], ...]" = (
             flops_per_s=197e12,
             hbm_bytes_per_s=819e9,
             provenance=(
-                "TPU v5e public spec: 197 TFLOP/s bf16 matmul (the MXU "
-                "path XLA feeds f32 inputs at DEFAULT precision), "
-                "819 GB/s HBM — the denominators of the r03 capture's "
-                "10.5% MXU / 3.1% HBM headline "
-                "(docs/bench_captures/r03_session_capture.json)"
+                "TPU v5e public spec (Google Cloud documentation, "
+                "\"TPU v5e\"): 197 TFLOP/s bf16 matmul (the MXU path "
+                "XLA feeds f32 inputs at DEFAULT precision), "
+                "819 GB/s HBM"
             ),
         ),
     ),
@@ -130,17 +129,13 @@ def _pick(analysis: dict, *keys: str) -> "float | None":
 def harvest_compiled(name: str, compiled, *, shape: str = "") -> dict:
     """Read `compiled.cost_analysis()` off an AOT-compiled/lowered
     program and register its per-dispatch cost under `name`.  Never
-    raises: unavailability (older jax, backends without cost models)
-    registers `source: "unavailable"` so emit() degrades to
-    wall-time-only records."""
+    raises: unavailability (a backend without a cost model) registers
+    `source: "unavailable"` so emit() degrades to wall-time-only
+    records."""
     flops = bytes_accessed = None
     source = "unavailable"
     try:
         analysis = compiled.cost_analysis()
-        # jax has returned both a bare dict and a one-element list of
-        # dicts across versions.
-        if isinstance(analysis, (list, tuple)) and analysis:
-            analysis = analysis[0]
         if isinstance(analysis, dict):
             flops = _pick(analysis, "flops")
             bytes_accessed = _pick(analysis, "bytes accessed",

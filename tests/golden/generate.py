@@ -1,4 +1,4 @@
-"""Golden-day fixture generator (VERDICT r1 item 6).
+"""Golden-day fixture generator.
 
 Writes the committed inputs (a tiny synthetic flow day, DNS day, and
 whitelist) and the expected outputs for every stage-boundary file

@@ -138,14 +138,14 @@ class _MemKV:
         self._d = {}
         self._cv = threading.Condition()
 
-    def key_value_set(self, key, value, allow_overwrite=False):
+    def key_value_set_bytes(self, key, value, allow_overwrite=False):
         with self._cv:
             if key in self._d and not allow_overwrite:
                 raise RuntimeError(f"ALREADY_EXISTS: {key}")
             self._d[key] = value
             self._cv.notify_all()
 
-    def blocking_key_value_get(self, key, timeout_in_ms):
+    def blocking_key_value_get_bytes(self, key, timeout_in_ms):
         deadline = time.monotonic() + timeout_in_ms / 1000.0
         with self._cv:
             while key not in self._d:
@@ -344,10 +344,8 @@ def test_fail_post_is_idempotent():
     c = Collective(client=kv, rank=0, nprocs=2, transport="kvring")
     c.fail("first")
     c.fail("second")  # allow_overwrite — must not raise
-    raw = kv.blocking_key_value_get("oni/ar/fail", 1)
-    import base64
-
-    rank, reason = pickle.loads(base64.b64decode(raw))
+    raw = kv.blocking_key_value_get_bytes("oni/ar/fail", 1)
+    rank, reason = pickle.loads(raw)
     assert rank == 0 and reason == "second"
 
 

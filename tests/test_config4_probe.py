@@ -1,7 +1,7 @@
 """tools/config4_hbm_probe.py mechanics at small width.
 
-The tool's value is the real-width record (V=512k — produced by the
-tool run, kept under docs/bench_captures/); here we pin that the
+The tool's value is the real-width record (V=512k, produced by a tool
+run); here we pin that the
 compile-only pipeline works on the virtual mesh and that the
 per-device buffer accounting matches the sharding arithmetic the
 architecture doc argues from.

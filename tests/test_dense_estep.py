@@ -585,7 +585,7 @@ def test_bf16_precision_close_and_validated(wmajor):
 def test_bf16_refused_under_matmul_precision_override():
     """The 'bf16 changes no results' promise only holds under XLA's
     DEFAULT matmul precision; a process-wide "highest"/"float32"
-    override must be refused, not silently degraded (ADVICE r2)."""
+    override must be refused, not silently degraded."""
     import jax
 
     jax.config.update("jax_default_matmul_precision", "float32")

@@ -677,7 +677,7 @@ def test_metrics_endpoint_exposes_per_tenant_series(days):
 
 
 def test_fleet_scorer_resolves_plan_knobs(days, tmp_path):
-    st = PlanStore(str(tmp_path / "plans.jsonl"), seeds=False)
+    st = PlanStore(str(tmp_path / "plans.jsonl"))
     fp = plans.fingerprint(KNOBS["fleet_max_batch"].scope)
     st.record("fleet_max_batch", fp, "*", 512, source="probe")
     with use_store(st):

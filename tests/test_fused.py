@@ -294,7 +294,7 @@ def test_host_sync_every_bounds_dispatch_without_changing_results(
 ):
     """host_sync_every caps EM iterations per device dispatch
     independently of fused_em_chunk (likelihood.dat / progress stream at
-    least that often — the ADVICE r05 crash-safety note), and the
+    least that often — the crash-safety note in config.py), and the
     trajectory is unchanged: the chunk program just runs with a smaller
     dynamic step count."""
     from oni_ml_tpu.models import fused

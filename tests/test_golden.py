@@ -1,4 +1,4 @@
-"""Frozen golden-day byte contract (VERDICT r1 item 6).
+"""Frozen golden-day byte contract.
 
 Recomputes every stage-boundary file from the committed inputs in
 tests/golden/inputs/ and compares BYTES against the committed expected

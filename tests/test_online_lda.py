@@ -287,7 +287,7 @@ def test_dense_em_validation():
             num_terms=50, total_docs=10,
         )
     # forced dense + custom e_step_fn is contradictory — and must fail
-    # at construction, not at the first step() (ADVICE r2)
+    # at construction, not at the first step()
     with pytest.raises(ValueError, match="dense_em='on'"):
         OnlineLDATrainer(
             OnlineLDAConfig(num_topics=4, dense_em="on"),
@@ -298,7 +298,7 @@ def test_dense_em_validation():
 
 def test_update_cache_is_bounded():
     """The per-(B, L) jitted-update cache must not grow without bound
-    when fed un-bucketed ragged micro-batch shapes (ADVICE r2)."""
+    when fed un-bucketed ragged micro-batch shapes."""
     tr = OnlineLDATrainer(
         OnlineLDAConfig(num_topics=4, dense_em="off"),
         num_terms=50, total_docs=10_000,

@@ -359,7 +359,7 @@ def test_capacity_tier_shape_stability_and_retraces(plans_on, tmp_path):
                                 n_doms=n_doms)
         for i in range(8)
     }
-    store = (PlanStore(str(tmp_path / "plans.jsonl"), seeds=False)
+    store = (PlanStore(str(tmp_path / "plans.jsonl"))
              if plans_on else NullStore())
     with use_store(store):
         tenants = tuple(f"t{i}" for i in range(8))
@@ -539,7 +539,7 @@ def test_resolve_hot_capacity_precedence(tmp_path):
     with use_store(NullStore()):
         assert resolve_hot_capacity(cfg_off) == (0, "default")
         assert resolve_hot_capacity(cfg_on) == (5, "config")
-    st = PlanStore(str(tmp_path / "plans.jsonl"), seeds=False)
+    st = PlanStore(str(tmp_path / "plans.jsonl"))
     fp = plans.fingerprint(KNOBS["fleet_hot_tenants"].scope)
     st.record("fleet_hot_tenants", fp, "*", 16, source="probe")
     with use_store(st):
@@ -931,7 +931,7 @@ def test_refreshed_model_survives_cold_demotion(days, tmp_path):
 
 def test_publish_while_cold_is_adopted(days, tmp_path):
     """A RefreshLoop publish landing while the tenant is checkpoint-
-    cold must not wedge promotion: the pager adopts the newer
+    cold must not block promotion: the pager adopts the newer
     published model instead of restoring over it."""
     tenants = ("t0", "t1")
     fleet, mgr, featurizers, _, scorer = _tiered_fleet(
@@ -953,7 +953,7 @@ def test_publish_while_cold_is_adopted(days, tmp_path):
         mgr.close()
 
 
-def test_never_published_warm_tenant_does_not_wedge_pager(days):
+def test_never_published_warm_tenant_does_not_stall_pager(days):
     """A registered-but-never-published tenant over the warm bound has
     nothing to unload: the enforcement sweep must skip it and return
     (the review caught an infinite pager spin here), and the pager

@@ -196,8 +196,8 @@ def test_membership_roster_heartbeats_fail_relay(tmp_path):
         assert "r1" not in m.alive(5.0)     # never beat
     finally:
         hb.stop()
-    m.fail("r0", "injected wedge")
-    assert m.failures()["r0"]["reason"] == "injected wedge"
+    m.fail("r0", "injected stall")
+    assert m.failures()["r0"]["reason"] == "injected stall"
     m.clear_failure("r0")
     assert m.failures() == {}
     m.deregister("r0")
@@ -692,7 +692,8 @@ def test_replica_subprocess_spawn_and_shutdown(tmp_path):
     from oni_ml_tpu.runner.route import _spawn_replica
 
     kv_dir = str(tmp_path / "kv")
-    proc, host, port = _spawn_replica("rsub", kv_dir, str(tmp_path))
+    proc, host, port = _spawn_replica("rsub", kv_dir, str(tmp_path),
+                                      platform="cpu")
     link = None
     try:
         link = ReplicaLink("rsub", host, port, op_timeout_s=60.0,
@@ -707,11 +708,44 @@ def test_replica_subprocess_spawn_and_shutdown(tmp_path):
         assert "rsub" in m.alive(5.0)
         link.call({"op": "shutdown"})
         assert proc.wait(timeout=60) == 0
+        # The ready line names the platform the replica actually runs
+        # on — the one its spawner named, nothing defaulted.
+        with open(tmp_path / "rsub.log") as f:
+            ready = [ln for ln in f if ln.startswith("REPLICA_READY rsub")]
+        assert ready and ready[0].rstrip().endswith("platform=cpu")
     finally:
         if link is not None:
             link.close()
         if proc.poll() is None:
             proc.kill()
+
+
+def test_replica_without_its_platform_fails_at_once(tmp_path):
+    """A replica told to run on a platform it cannot have — here a TPU
+    on a machine without one; on the chip, the TPU its parent holds —
+    exits at start-up with the reason, and the spawner raises with it
+    instead of waiting out the handshake."""
+    from oni_ml_tpu.runner.route import _spawn_replica
+
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError) as err:
+        _spawn_replica("rtpu", str(tmp_path / "kv"), str(tmp_path),
+                       timeout_s=120.0, platform="tpu")
+    assert time.monotonic() - t0 < 60.0
+    assert "REPLICA_FAILED rtpu platform=tpu" in str(err.value)
+
+
+def test_spawning_replicas_needs_a_named_platform():
+    """No entry point picks a replica's platform: the library spawner
+    takes it as a required argument, and the CLIs refuse to spawn
+    without --replica-platform."""
+    from oni_ml_tpu.runner import route
+    from oni_ml_tpu.runner.route import _spawn_replica
+
+    with pytest.raises(TypeError, match="platform"):
+        _spawn_replica("r0", "kv", ".")
+    with pytest.raises(SystemExit):
+        route.build_replica_parser().parse_args(["--id", "r0"])
 
 
 def test_trace_view_route_lanes_and_summary():
@@ -794,7 +828,7 @@ def test_dynamic_scorer_reapplies_plan_guard(tmp_path):
     from oni_ml_tpu.plans import KNOBS, PlanStore, use_store
     from oni_ml_tpu.serving import FleetRegistry, FleetScorer
 
-    st = PlanStore(str(tmp_path / "plans.jsonl"), seeds=False)
+    st = PlanStore(str(tmp_path / "plans.jsonl"))
     fp = plans.fingerprint(KNOBS["fleet_max_batch"].scope)
     st.record("fleet_max_batch", fp, "*", 100, source="probe")
     rows, model, cuts = _synthetic_day(n_events=24, seed=700)
@@ -826,8 +860,8 @@ def test_dynamic_scorer_reapplies_plan_guard(tmp_path):
             scorer.close()
 
 
-def test_replica_wedge_posts_fail_key_and_stops_beating(tmp_path):
-    """Review regression: a WEDGED replica (healthy process, broken
+def test_stuck_replica_posts_fail_key_and_stops_beating(tmp_path):
+    """Review regression: a STUCK replica (healthy process, broken
     scoring backend) must post the membership fail key and stop
     heartbeating — the router's monitor then promotes its shadows
     instead of trusting a liveness signal decoupled from scoring."""
@@ -835,10 +869,10 @@ def test_replica_wedge_posts_fail_key_and_stops_beating(tmp_path):
     cfg = ServingConfig(fleet_max_batch=32, fleet_max_wait_ms=5.0,
                         device_score_min=None,
                         replica_heartbeat_s=0.03)
-    state = {"wedged": False}
+    state = {"stuck": False}
 
     def health():
-        if state["wedged"]:
+        if state["stuck"]:
             raise RuntimeError("backend lost (injected)")
 
     rep = ReplicaServer("rw", cfg, kv=kv, health_check=health)
@@ -849,7 +883,7 @@ def test_replica_wedge_posts_fail_key_and_stops_beating(tmp_path):
             time.sleep(0.02)
         assert "rw" in m.alive(5.0)
         assert m.failures() == {}
-        state["wedged"] = True
+        state["stuck"] = True
         deadline = time.monotonic() + 10.0
         while time.monotonic() < deadline and "rw" not in m.failures():
             time.sleep(0.02)

@@ -370,7 +370,7 @@ def test_pre_stage_spills_raw_lines(flow_day):
     """stage_pre streams raw rows to raw_lines.bin (native path):
     features.pkl must reference the spill file, not embed the bytes,
     and a vanished spill file must fail the score stage with a
-    recoverable message (VERDICT r2 weak-item 2)."""
+    recoverable message."""
     import pickle
 
     from oni_ml_tpu.features import native_flow

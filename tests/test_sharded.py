@@ -232,11 +232,11 @@ def test_vocab_sharded_dense_guards(problem):
 
 @pytest.mark.parametrize("wmajor", [False, True])
 def test_data_parallel_dense_one_one_mesh_parity(problem, wmajor):
-    """Interpret-mode variant of tools/tpu_smoke.py check 1: the
+    """Interpret-mode variant of chip_smoke.py's shard_map kernel check: the
     shard_map'd dense kernel under a degenerate (1,1) mesh must equal
     the unwrapped kernel (on the real chip the same comparison runs
     Mosaic-compiled — the suite is CPU-pinned, so that half lives in
-    tools/tpu_smoke.py)."""
+    chip_smoke.py's kernels leg)."""
     from oni_ml_tpu.ops import dense_estep
     from oni_ml_tpu.parallel.sharded import make_data_parallel_dense_e_step
 

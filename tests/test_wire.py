@@ -576,7 +576,7 @@ def test_autoscaler_decisions_are_journaled():
 # ---------------------------------------------------------------------------
 
 
-def test_claim_promotion_error_taxonomy():
+def test_claim_promotion_error_classes():
     """Only a genuine ALREADY_EXISTS loses the promotion election; a
     KV transport failure mid-failover claims by default — duplicate
     backfills are router_version-idempotent on the replica, zero

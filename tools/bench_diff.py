@@ -1,6 +1,6 @@
 """Compare two bench payloads and fail loudly on regression.
 
-The post-bench CI step (docs/performance.md "Catching regressions"):
+The post-bench CI step:
 feed it the previous round's captured payload (BENCH_rNN.json — the
 driver wrapper with a "parsed" object — or a raw `python bench.py`
 headline line) and the fresh one, and it diffs every comparable number:
@@ -20,7 +20,7 @@ set for dashboards.
 
 Usage:
 
-    python tools/bench_diff.py BENCH_r05.json BENCH_r06.json \
+    python tools/bench_diff.py old_record.json new_record.json \
         [--threshold-pct 10] [--efficiency-drop 0.05] [--json]
 """
 

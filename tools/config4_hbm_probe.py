@@ -1,4 +1,4 @@
-"""Compile-only HBM feasibility probe for config 4 (VERDICT r4 item 6).
+"""Compile-only HBM feasibility probe for config 4.
 
 The architecture doc's config-4 claims were arithmetic: "the densified
 [B, V] corpus alone is ~4 GB/chip under data parallelism (infeasible on

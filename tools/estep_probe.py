@@ -1,7 +1,6 @@
 """Density × bucket-shape sweep for the sparse E-step engine — the
-EM twin of tools/score_probe.py, so the next live grant can tune the
-sparse engine's block shapes and the dense-vs-sparse crossover in one
-command:
+EM twin of tools/score_probe.py: tunes the sparse engine's block shapes
+and the dense-vs-sparse crossover on the chip in one command:
 
     python tools/estep_probe.py [--k K] [--v V] [--b B]
         [--densities 0.5,1,2,5,10] [--precision bf16] [--reps 2]
@@ -26,9 +25,7 @@ One JSON line per measurement; a final `plan_cache_update` line names
 every knob recorded.  Runs on any backend (CPU numbers exercise the
 machinery and pin the interpret-mode crossover; the cache is
 backend-fingerprint-keyed, so a CPU record can never leak onto a
-chip).  `tools/plan_cache.py export` turns a TPU session's records
-into committable `plans/seeds/` entries — the shipped v5e seeds for
-these knobs were produced this way (see their provenance notes).
+chip).
 """
 
 import argparse

@@ -649,6 +649,12 @@ def run_fleet_slo(n_tenants: int = 4, mix: str = "poisson:1,bursty:1",
 # replicated fleet harness (bench.py serving_slo_replicated)
 # ---------------------------------------------------------------------------
 
+# Replica subprocesses this harness starts run on the host CPU, by
+# name: what it loads is the serving plane's host path (routing, wire,
+# demux), several replicas cannot share one chip, and placing one
+# replica per chip is ROADMAP A5.
+REPLICA_PLATFORM = "cpu"
+
 
 def _replicated_stack(n_replicas: int, tenant_mix, models, cuts, *,
                       max_batch: int, max_wait_ms: float,
@@ -683,8 +689,8 @@ def _replicated_stack(n_replicas: int, tenant_mix, models, cuts, *,
             ]
             if device_score_min is None:
                 extra += ["--device-score-min", "none"]
-            proc, host, port = _spawn_replica(rid, kv_dir, workdir,
-                                              extra)
+            proc, host, port = _spawn_replica(
+                rid, kv_dir, workdir, extra, platform=REPLICA_PLATFORM)
             procs[rid] = proc
         else:
             from oni_ml_tpu.serving import ReplicaServer
@@ -1604,7 +1610,8 @@ def run_router_fanin(router_counts=(1, 2), *, n_replicas: int = 1,
     try:
         for i in range(n_replicas):
             rid = f"r{i}"
-            proc, _, _ = _spawn_replica(rid, kv_dir, workdir, extra)
+            proc, _, _ = _spawn_replica(rid, kv_dir, workdir, extra,
+                                        platform=REPLICA_PLATFORM)
             procs[rid] = proc
         worker_cfg = {
             "kv_dir": kv_dir, "n_tenants": n_tenants,

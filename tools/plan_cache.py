@@ -6,20 +6,16 @@ execution plans (oni_ml_tpu/plans):
     python tools/plan_cache.py export [DEST]
 
 `show` prints the resolved view: one JSON line per entry (latest per
-(knob, backend, shape), seeds included), plus a header naming the live
-store path and this process's fingerprints so "why didn't my entry
-match" is answerable at a glance.  By default only entries matching
-THIS host/backend print; `--all-backends` shows everything, including
-seed plans for hardware you are not on.
+(knob, backend, shape)), plus a header naming the store path and this
+process's fingerprints so "why didn't my entry match" is answerable at
+a glance.  By default only entries matching THIS host/backend print;
+`--all-backends` shows everything.
 
-`clear` removes the LIVE cache file only — checked-in seed plans are
-code, not cache, and survive.
+`clear` removes the cache file.
 
 `export` writes the current resolved entries as a standalone JSONL
-stream (stdout, or DEST) in exactly the seed-file format, so a live
-grant session's captured measurements can be committed under
-`oni_ml_tpu/plans/seeds/` — the workflow that turned the r05 chunk
-sweep into the shipped v5e seed.
+stream (stdout, or DEST) in the store's own line format, so a
+session's measurements can be carried to another machine's cache.
 """
 
 import argparse
@@ -45,7 +41,6 @@ def cmd_show(args) -> int:
         "store": store.path,
         "schema": plans.SCHEMA_VERSION,
         "host": plans.host_fingerprint(),
-        "seeds": plans.seed_paths(),
         "dropped_records": store.dropped_records,
     }
     if not args.no_device:
@@ -69,11 +64,8 @@ def cmd_clear(args) -> int:
     store = _store()
     existed = os.path.exists(store.path)
     store.clear()
-    print(json.dumps({
-        "cleared": store.path, "existed": existed,
-        "note": "seed plans under oni_ml_tpu/plans/seeds/ are code and "
-                "were not touched",
-    }), flush=True)
+    print(json.dumps({"cleared": store.path, "existed": existed}),
+          flush=True)
     return 0
 
 
@@ -107,14 +99,13 @@ def main(argv=None) -> int:
     show = sub.add_parser("show", help="print resolved entries")
     show.add_argument("--knob", default=None)
     show.add_argument("--all-backends", action="store_true",
-                      help="include entries for other fingerprints "
-                      "(e.g. seed plans for hardware you are not on)")
+                      help="include entries for other fingerprints")
     show.add_argument("--no-device", action="store_true",
                       help="skip the device fingerprint (does not "
                       "initialize a jax backend; host-scoped view only)")
-    sub.add_parser("clear", help="remove the live cache file")
+    sub.add_parser("clear", help="remove the cache file")
     exp = sub.add_parser("export",
-                         help="write entries as a seed-able JSONL stream")
+                         help="write entries as a JSONL stream")
     exp.add_argument("dest", nargs="?", default=None)
     exp.add_argument("--knob", default=None)
     args = p.parse_args(argv)

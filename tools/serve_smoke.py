@@ -1,6 +1,7 @@
 """Fast serving sanity check: run `ml_ops serve --dry-run` (single
 model) AND `ml_ops serve --dry-run --fleet synthetic` (2-tenant fleet)
-in clean subprocesses (CPU pinned) and verify both summary lines.
+in clean subprocesses, on the jax platform named on the command line,
+and verify both summary lines.
 
 The single dry run exercises the whole serving stack — registry
 publish, micro-batch flush triggers, host scoring, mid-stream
@@ -10,11 +11,11 @@ snapshots, cross-tenant packed flushes, per-tenant demux, and
 hot-swap isolation (tenant 0 republish leaves tenant 1's versions and
 futures untouched).  Both run against synthetic in-memory days, so
 this is the one-command check that the streaming paths still work on a
-box with no chip grant and no day data.  tests/test_serving.py /
-tests/test_fleet.py carry the same paths as tier-1 tests; this wrapper
-is the operator/CI front door:
+box with no day data.  tests/test_serving.py / tests/test_fleet.py
+carry the same paths as tier-1 tests; this wrapper is the operator/CI
+front door:
 
-    python tools/serve_smoke.py
+    python tools/serve_smoke.py cpu     # or: tpu, on a machine with a free chip
 """
 
 import json
@@ -29,9 +30,12 @@ MODES = {
 _OK_KEYS = {"single": "serve_dry_run", "fleet": "serve_fleet_dry_run"}
 
 
-def run_smoke(mode: str = "single", timeout_s: float = 300.0) -> dict:
+def run_smoke(mode: str = "single", timeout_s: float = 300.0, *,
+              platform: str) -> dict:
+    """One dry run in a child process on the jax platform the caller
+    names (nothing here picks one behind the caller's back)."""
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = platform
     proc = subprocess.run(
         [sys.executable, "-m", "oni_ml_tpu.runner.ml_ops",
          *MODES[mode]],
@@ -53,10 +57,14 @@ def run_smoke(mode: str = "single", timeout_s: float = 300.0) -> dict:
 
 
 def main() -> int:
+    if len(sys.argv) != 2:
+        print("usage: serve_smoke.py PLATFORM   (cpu | tpu)",
+              file=sys.stderr)
+        return 2
     out = {}
     ok = True
     for mode in MODES:
-        res = run_smoke(mode)
+        res = run_smoke(mode, platform=sys.argv[1])
         mode_ok = (
             res["rc"] == 0
             and isinstance(res["summary"], dict)
