@@ -26,6 +26,7 @@ data-parallel and vocab-sharded plans unchanged.
 
 from __future__ import annotations
 
+import itertools
 from contextlib import nullcontext
 from typing import Callable, NamedTuple, Sequence
 
@@ -35,7 +36,7 @@ import numpy as np
 
 from ..io import Batch
 from ..ops import estep
-from ..telemetry.spans import current_recorder
+from ..telemetry.spans import current_recorder, maybe_span
 
 
 # Which chunk impl the most recent run_chunk TRACE selected ("fast" |
@@ -72,20 +73,19 @@ def stack_batches(
         groups.setdefault(b.word_idx.shape, []).append(i)
     arrays = []
     slots = []
-    for shape in sorted(groups):
-        idxs = groups[shape]
-        arrays.append(
-            (
-                put(np.stack([batches[i].word_idx for i in idxs])),
-                put(
-                    np.stack([batches[i].counts for i in idxs]).astype(dtype)
-                ),
-                put(
-                    np.stack([batches[i].doc_mask for i in idxs]).astype(dtype)
-                ),
+    with maybe_span("fit.stack", groups=len(groups)) as sp:
+        h2d_bytes = 0
+        for shape in sorted(groups):
+            idxs = groups[shape]
+            host = (
+                np.stack([batches[i].word_idx for i in idxs]),
+                np.stack([batches[i].counts for i in idxs]).astype(dtype),
+                np.stack([batches[i].doc_mask for i in idxs]).astype(dtype),
             )
-        )
-        slots.append(tuple(idxs))
+            h2d_bytes += sum(a.nbytes for a in host)
+            arrays.append(tuple(put(a) for a in host))
+            slots.append(tuple(idxs))
+        sp.annotate(h2d_bytes=h2d_bytes)
     return StackedGroups(tuple(arrays), tuple(slots))
 
 
@@ -113,11 +113,13 @@ def densify_groups(
         return d.T if wmajor else d
 
     arrays = []
-    for widx, cnts, mask in groups.arrays:
-        dense = jax.jit(jax.vmap(one))(widx, cnts)
-        if put is not None:  # e.g. shard the doc axis over a mesh
-            dense = put(dense)
-        arrays.append((dense, mask))
+    with maybe_span("fit.densify", groups=len(groups.arrays)) as sp:
+        for widx, cnts, mask in groups.arrays:
+            dense = jax.jit(jax.vmap(one))(widx, cnts)
+            if put is not None:  # e.g. shard the doc axis over a mesh
+                dense = put(dense)
+            arrays.append((dense, mask))
+        sp.annotate(dense_bytes=sum(d.nbytes for d, _ in arrays))
     return StackedGroups(tuple(arrays), groups.batch_slots)
 
 
@@ -223,33 +225,37 @@ def compact_stack_batches(
         groups.setdefault(b.word_idx.shape, []).append(i)
     arrays = []
     slots = []
-    for g, shape in enumerate(sorted(groups)):
-        idxs = groups[shape]
-        wc = plan.widths[g]
+    with maybe_span("fit.stack", groups=len(groups), compact=True) as sp:
+        h2d_bytes = 0
+        for g, shape in enumerate(sorted(groups)):
+            idxs = groups[shape]
+            wc = plan.widths[g]
 
-        local_idx, cnts, masks, vmaps = [], [], [], []
-        for j, i in enumerate(idxs):
-            u = plan.uniques[g][j]
-            local_idx.append(
-                np.searchsorted(u, batches[i].word_idx).astype(np.int32)
+            local_idx, cnts, masks, vmaps = [], [], [], []
+            for j, i in enumerate(idxs):
+                u = plan.uniques[g][j]
+                local_idx.append(
+                    np.searchsorted(u, batches[i].word_idx).astype(np.int32)
+                )
+                cnts.append(batches[i].counts.astype(dtype))
+                masks.append(batches[i].doc_mask.astype(dtype))
+                vm = np.zeros(wc, np.int32)
+                vm[: len(u)] = u
+                vmaps.append(vm)
+
+            def one(w, c):
+                d = dense_estep.densify(w, c, wc, width=wc, dtype=corpus_store)
+                return d.T if plan.wmajor else d
+
+            host = (np.stack(local_idx), np.stack(cnts), np.stack(masks),
+                    np.stack(vmaps))
+            h2d_bytes += sum(a.nbytes for a in host)
+            dense = jax.jit(jax.vmap(one))(
+                jnp.asarray(host[0]), jnp.asarray(host[1])
             )
-            cnts.append(batches[i].counts.astype(dtype))
-            masks.append(batches[i].doc_mask.astype(dtype))
-            vm = np.zeros(wc, np.int32)
-            vm[: len(u)] = u
-            vmaps.append(vm)
-
-        def one(w, c):
-            d = dense_estep.densify(w, c, wc, width=wc, dtype=corpus_store)
-            return d.T if plan.wmajor else d
-
-        dense = jax.jit(jax.vmap(one))(
-            jnp.asarray(np.stack(local_idx)), jnp.asarray(np.stack(cnts))
-        )
-        arrays.append(
-            (put(dense), put(np.stack(masks)), put(np.stack(vmaps)))
-        )
-        slots.append(tuple(idxs))
+            arrays.append((put(dense), put(host[2]), put(host[3])))
+            slots.append(tuple(idxs))
+        sp.annotate(h2d_bytes=h2d_bytes)
     return StackedGroups(tuple(arrays), tuple(slots))
 
 
@@ -286,8 +292,11 @@ def make_em_accumulator(
     warm_start: bool = False,
 ):
     """Build `accumulate(log_beta, alpha, groups, gammas_prev, warm) ->
-    (suff_stats [V, K], likelihood, alpha_ss, gammas, vi_max)` — one EM
-    iteration's E-step over stacked groups WITHOUT the M-step tail.
+    (suff_stats [V, K], likelihood, alpha_ss, gammas, vi_max,
+    doc_sweeps)` — one EM iteration's E-step over stacked groups WITHOUT
+    the M-step tail.  `vi_max` is the most sweeps any batch ran,
+    `doc_sweeps` the sum over batches of the document-sweeps they ran
+    (EStepResult.doc_sweeps); the device work carries the scope `estep`.
 
     This is the partial-sufficient-statistics return path: the chunk
     runner composes it with the M-step/alpha update inside one compiled
@@ -346,6 +355,7 @@ def make_em_accumulator(
         total_ll = jnp.zeros((), dtype)
         total_ass = jnp.zeros((), dtype)
         vi_max = jnp.zeros((), jnp.int32)
+        sweeps = jnp.zeros((), jnp.int32)
         gammas = []
 
         def run_batch(batch, g_in):
@@ -368,41 +378,36 @@ def make_em_accumulator(
                 var_max_iters=var_max_iters, var_tol=var_tol,
             )
 
-        for group, g_prev in zip(groups, gammas_prev):
-            if group[0].shape[0] == 1:
-                # Single-batch group (the common case after bucketing):
-                # call the E-step directly instead of a length-1
-                # lax.scan, whose slice-in/stack-out machinery adds
-                # fixed per-EM-iteration ops inside the chunk loop.
-                res = run_batch(
-                    tuple(a[0] for a in group), g_prev[0]
-                )
-                total_ss = total_ss + res.suff_stats
-                total_ll = total_ll + res.likelihood
-                total_ass = total_ass + res.alpha_ss
-                vi_max = jnp.maximum(
-                    vi_max, jnp.asarray(res.vi_iters, jnp.int32)
-                )
-                gammas.append(res.gamma[None])
-                continue
-
-            def scan_body(carry, batch_and_gamma):
-                ss, ll, ass, vi = carry
-                batch, g_in = batch_and_gamma
-                res = run_batch(batch, g_in)
-                return (
-                    (ss + res.suff_stats, ll + res.likelihood,
-                     ass + res.alpha_ss,
-                     jnp.maximum(vi, jnp.asarray(res.vi_iters, jnp.int32))),
-                    res.gamma,
-                )
-
-            (total_ss, total_ll, total_ass, vi_max), g = jax.lax.scan(
-                scan_body, (total_ss, total_ll, total_ass, vi_max),
-                (group, g_prev)
+        def scan_body(carry, batch_and_gamma):
+            ss, ll, ass, vi, sw = carry
+            batch, g_in = batch_and_gamma
+            res = run_batch(batch, g_in)
+            return (
+                (ss + res.suff_stats, ll + res.likelihood,
+                 ass + res.alpha_ss,
+                 jnp.maximum(vi, jnp.asarray(res.vi_iters, jnp.int32)),
+                 sw + jnp.asarray(res.doc_sweeps, jnp.int32)),
+                res.gamma,
             )
-            gammas.append(g)
-        return total_ss, total_ll, total_ass, tuple(gammas), vi_max
+
+        carry = (total_ss, total_ll, total_ass, vi_max, sweeps)
+        with jax.named_scope("estep"):
+            for group, g_prev in zip(groups, gammas_prev):
+                if group[0].shape[0] == 1:
+                    # Single-batch group (the common case after
+                    # bucketing): call the E-step directly instead of a
+                    # length-1 lax.scan, whose slice-in/stack-out
+                    # machinery adds fixed per-EM-iteration ops inside
+                    # the chunk loop.
+                    carry, g = scan_body(
+                        carry, (tuple(a[0] for a in group), g_prev[0])
+                    )
+                    gammas.append(g[None])
+                    continue
+                carry, g = jax.lax.scan(scan_body, carry, (group, g_prev))
+                gammas.append(g)
+        total_ss, total_ll, total_ass, vi_max, sweeps = carry
+        return total_ss, total_ll, total_ass, tuple(gammas), vi_max, sweeps
 
     return accumulate
 
@@ -430,6 +435,9 @@ class ChunkResult(NamedTuple):
                                 # per executed EM step (observability:
                                 # shows the var_tol early exit + warm
                                 # start collapsing the inner loop)
+    doc_sweeps: jax.Array       # [chunk] int32 document-sweeps the E-step
+                                # ran per executed EM step: the sum over
+                                # batches of EStepResult.doc_sweeps
 
 
 def make_chunk_runner(
@@ -479,17 +487,18 @@ def make_chunk_runner(
     )
 
     def em_iteration(log_beta, alpha, groups, gammas_prev, warm):
-        total_ss, total_ll, total_ass, gammas, vi_max = accumulate(
+        total_ss, total_ll, total_ass, gammas, vi_max, sweeps = accumulate(
             log_beta, alpha, groups, gammas_prev, warm
         )
-        new_beta = m_fn(total_ss)
+        with jax.named_scope("mstep"):
+            new_beta = m_fn(total_ss)
         new_alpha = (
             update_alpha(total_ass, alpha, num_docs, k,
                          max_iters=alpha_max_iters)
             if estimate_alpha
             else alpha
         )
-        return new_beta, new_alpha, total_ll, tuple(gammas), vi_max
+        return new_beta, new_alpha, total_ll, tuple(gammas), vi_max, sweeps
 
     def _resolve_gammas(groups, gammas_in, have_prev, dtype):
         """Gamma buffers must exist in the carry before the first
@@ -513,18 +522,19 @@ def make_chunk_runner(
         for both the generic impl and the dense fast path (a change to
         the stop rule or the warm gate must not be able to land in one
         and not the other).  `iterate(model, alpha, gammas, warm) ->
-        (model', alpha', ll, gammas', vi)` supplies the EM iteration
-        body; `model` is whatever beta representation the path carries
+        (model', alpha', ll, gammas', vi, doc_sweeps)` supplies the EM
+        iteration body; `model` is whatever beta representation the path carries
         (log-space [K, V], or padded exp-space [K, W])."""
         lls0 = jnp.zeros((chunk,), dtype)
         vi0 = jnp.zeros((chunk,), jnp.int32)
 
         def cond(state):
-            _, _, _, step, _, _, converged, _ = state
+            _, _, _, step, _, _, _, converged, _ = state
             return (step < jnp.minimum(n_steps, chunk)) & ~converged
 
         def body(state):
-            model, alpha, ll_prev, step, lls, vis, _, gammas_prev = state
+            (model, alpha, ll_prev, step, lls, vis, sws, _,
+             gammas_prev) = state
             # Warm start once ANY gamma exists: produced this chunk
             # (step>0) or carried in from the previous one (have_prev).
             warm = (
@@ -532,7 +542,7 @@ def make_chunk_runner(
                 if warm_start
                 else jnp.asarray(False)
             )
-            model, new_alpha, ll, gammas, vi = iterate(
+            model, new_alpha, ll, gammas, vi, sweeps = iterate(
                 model, alpha, gammas_prev, warm
             )
             # The first-ever iteration (ll_prev = nan) never stops — the
@@ -548,13 +558,14 @@ def make_chunk_runner(
                 step + 1,
                 lls.at[step].set(ll),
                 vis.at[step].set(jnp.asarray(vi, jnp.int32)),
+                sws.at[step].set(jnp.asarray(sweeps, jnp.int32)),
                 converged,
                 gammas,
             )
 
         state = (
             model0, alpha, ll_prev, jnp.asarray(0, jnp.int32),
-            lls0, vi0, jnp.asarray(False), gammas0,
+            lls0, vi0, vi0, jnp.asarray(False), gammas0,
         )
         return jax.lax.while_loop(cond, body, state)
 
@@ -567,12 +578,12 @@ def make_chunk_runner(
         def iterate(log_beta, alpha, gammas_prev, warm):
             return em_iteration(log_beta, alpha, groups, gammas_prev, warm)
 
-        log_beta, alpha, ll_prev, step, lls, vis, converged, gammas = (
+        log_beta, alpha, ll_prev, step, lls, vis, sws, converged, gammas = (
             _chunk_loop(log_beta, alpha, ll_prev, gamma0, n_steps,
                         have_prev, iterate, dtype)
         )
         return ChunkResult(
-            log_beta, alpha, ll_prev, lls, step, converged, gammas, vis
+            log_beta, alpha, ll_prev, lls, step, converged, gammas, vis, sws
         )
 
     # -- single-dense-group fast path ------------------------------------
@@ -627,26 +638,29 @@ def make_chunk_runner(
         exp_zero = jnp.asarray(np.exp(np.float64(estep.LOG_ZERO)), dtype)
 
         def iterate(exp_beta, alpha, g_prev, warm):
-            gamma, t, docll, ass, iters = fp(
-                exp_beta, alpha, C, mask, var_max_iters, var_tol,
-                interpret=interp, gamma_prev=g_prev,
-                warm=jnp.asarray(warm, jnp.int32),
-                precision=dense_precision,
-            )
-            alpha_const = gammaln(k * alpha) - k * gammaln(alpha)
-            ll = docll.sum() + mask.sum() * alpha_const
+            with jax.named_scope("estep"):
+                gamma, t, docll, ass, iters, sweeps = fp(
+                    exp_beta, alpha, C, mask, var_max_iters, var_tol,
+                    interpret=interp, gamma_prev=g_prev,
+                    warm=jnp.asarray(warm, jnp.int32),
+                    precision=dense_precision,
+                )
+            with jax.named_scope("elbo"):
+                alpha_const = gammaln(k * alpha) - k * gammaln(alpha)
+                ll = docll.sum() + mask.sum() * alpha_const
             new_alpha = (
                 update_alpha(ass.sum(), alpha, num_docs, k,
                              max_iters=alpha_max_iters)
                 if estimate_alpha
                 else alpha
             )
-            suff = exp_beta * t                       # [K, W]
-            total = suff.sum(-1, keepdims=True)       # pad cols are 0
-            new_exp = jnp.where(suff > 0, suff / total, exp_zero)
-            return new_exp, new_alpha, ll, gamma, iters
+            with jax.named_scope("mstep"):
+                suff = exp_beta * t                       # [K, W]
+                total = suff.sum(-1, keepdims=True)       # pad cols are 0
+                new_exp = jnp.where(suff > 0, suff / total, exp_zero)
+            return new_exp, new_alpha, ll, gamma, iters, sweeps
 
-        exp_beta, alpha, ll_prev, step, lls, vis, converged, gamma = (
+        exp_beta, alpha, ll_prev, step, lls, vis, sws, converged, gamma = (
             _chunk_loop(exp_beta0, alpha, ll_prev, gamma0[0][0], n_steps,
                         have_prev, iterate, dtype)
         )
@@ -660,7 +674,7 @@ def make_chunk_runner(
         log_out = jnp.where(step > 0, new_log, log_beta)
         return ChunkResult(
             log_out, alpha, ll_prev, lls, step, converged,
-            (gamma[None],), vis,
+            (gamma[None],), vis, sws,
         )
 
     def run_chunk_dispatch(log_beta, alpha, ll_prev, groups, n_steps,
@@ -679,28 +693,27 @@ def make_chunk_runner(
         )
 
     jitted = jax.jit(run_chunk_dispatch, compiler_options=compiler_options)
+    dispatch_no = itertools.count()
 
     def runner(log_beta, alpha, ll_prev, groups, n_steps, *args, **kw):
-        """Host-side dispatch wrapper: when a telemetry Recorder is
-        active (telemetry/spans.py), each chunk dispatch records an
-        `em.run_chunk` span and counter.  JAX dispatch is asynchronous,
-        so the span measures ENQUEUE (trace/lower on first call, then
-        the per-dispatch cost, not measured on the current machine) —
-        the quantity the chunked driver exists to amortize — not device
-        compute; the driver's host-sync span covers the blocking side.
-        No recorder -> straight through."""
+        """Host-side dispatch wrapper: each chunk dispatch is an
+        `em.run_chunk` span (telemetry/spans.py: recorded under a
+        Recorder, in the profiler's trace under a profiler session, a
+        no-op otherwise).  JAX dispatch is asynchronous, so the span
+        measures ENQUEUE — the runner's `first` dispatch holds the chunk
+        program's trace, lowering and cache fetch, the later ones the
+        per-dispatch cost the chunked driver exists to amortize — not
+        device compute; the driver's host-sync span covers the blocking
+        side."""
         slot = yield_hook() if yield_hook is not None else nullcontext()
-        rec = current_recorder()
-        if rec is None:
-            with slot:
-                return jitted(log_beta, alpha, ll_prev, groups, n_steps,
-                              *args, **kw)
-        with slot, rec.span("em.run_chunk", chunk=chunk,
-                            n_steps=int(n_steps)
-                            if isinstance(n_steps, int) else None):
+        with slot, maybe_span("em.run_chunk", chunk=chunk,
+                              n_steps=int(n_steps)
+                              if isinstance(n_steps, int) else None,
+                              first=next(dispatch_no) == 0):
             out = jitted(log_beta, alpha, ll_prev, groups, n_steps,
                          *args, **kw)
-        rec.counter("em.chunk_dispatches").add(1)
+        if current_recorder() is None:
+            return out
         # Roofline harvest, once per shape, only under an active
         # recorder — AFTER the live dispatch, so the program is already
         # traced and in the persistent compilation cache: the AOT

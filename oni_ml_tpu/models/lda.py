@@ -81,40 +81,43 @@ def update_alpha(alpha_ss: jnp.ndarray, alpha_init: jnp.ndarray, d: int, k: int,
     loop machinery that an unrolled scalar chain (one fused kernel)
     does not.  The mask replicates the while_loop exit exactly —
     trips after |df| <= 1e-5 leave the state untouched — so the two
-    lowerings compute the same value (pinned in tests/test_lda.py)."""
-    ss = alpha_ss
+    lowerings compute the same value (pinned in tests/test_lda.py).
 
-    def body(state):
-        log_a, _, it = state
-        a, df, d2f = _alpha_objective_grads(log_a, ss, d, k)
-        log_a_new = log_a - df / (d2f * a + df)
-        return log_a_new, jnp.abs(df), it + 1
+    The device work carries the scope `alpha`."""
+    with jax.named_scope("alpha"):
+        ss = alpha_ss
 
-    def cond(state):
-        log_a, df_abs, it = state
-        return jnp.logical_and(it < max_iters, df_abs > 1e-5)
+        def body(state):
+            log_a, _, it = state
+            a, df, d2f = _alpha_objective_grads(log_a, ss, d, k)
+            log_a_new = log_a - df / (d2f * a + df)
+            return log_a_new, jnp.abs(df), it + 1
 
-    log_a0 = jnp.log(alpha_init)
-    if max_iters <= 16:
-        log_a = log_a0
-        df_abs = jnp.asarray(jnp.inf, log_a0.dtype)
-        for _ in range(max_iters):
-            a_it, df, d2f = _alpha_objective_grads(log_a, ss, d, k)
-            step = log_a - df / (d2f * a_it + df)
-            active = df_abs > 1e-5
-            log_a = jnp.where(active, step, log_a)
-            df_abs = jnp.where(active, jnp.abs(df), df_abs)
-    else:
-        log_a, _, _ = jax.lax.while_loop(
-            cond, body,
-            (log_a0, jnp.asarray(jnp.inf, log_a0.dtype),
-             jnp.asarray(0, jnp.int32)),
-        )
-    a = jnp.exp(log_a)
-    # Guard divergence (lda-c restarts with alpha*10; we fall back to the
-    # previous value, which keeps EM monotone-safe).
-    bad = jnp.logical_or(jnp.isnan(a), jnp.logical_or(a <= 0, jnp.isinf(a)))
-    return jnp.where(bad, alpha_init, a)
+        def cond(state):
+            log_a, df_abs, it = state
+            return jnp.logical_and(it < max_iters, df_abs > 1e-5)
+
+        log_a0 = jnp.log(alpha_init)
+        if max_iters <= 16:
+            log_a = log_a0
+            df_abs = jnp.asarray(jnp.inf, log_a0.dtype)
+            for _ in range(max_iters):
+                a_it, df, d2f = _alpha_objective_grads(log_a, ss, d, k)
+                step = log_a - df / (d2f * a_it + df)
+                active = df_abs > 1e-5
+                log_a = jnp.where(active, step, log_a)
+                df_abs = jnp.where(active, jnp.abs(df), df_abs)
+        else:
+            log_a, _, _ = jax.lax.while_loop(
+                cond, body,
+                (log_a0, jnp.asarray(jnp.inf, log_a0.dtype),
+                 jnp.asarray(0, jnp.int32)),
+            )
+        a = jnp.exp(log_a)
+        # Guard divergence (lda-c restarts with alpha*10; we fall back to the
+        # previous value, which keeps EM monotone-safe).
+        bad = jnp.logical_or(jnp.isnan(a), jnp.logical_or(a <= 0, jnp.isinf(a)))
+        return jnp.where(bad, alpha_init, a)
 
 
 # ---------------------------------------------------------------------------
@@ -133,6 +136,13 @@ class LDAResult:
     # {knob: {"value", "source": "config"|"plan"|"default"}} — surfaced
     # in the runner's lda stage record.
     plan: dict = field(default_factory=dict)
+    # Document-sweeps the E-step ran over the whole fit (the sum over EM
+    # iterations and batches of EStepResult.doc_sweeps: padded rows
+    # count, so it lies between padded rows x em_iters and that x
+    # var_max_iters; distributed: this rank's shards), and the most
+    # sweeps any batch ran in one E-step.
+    doc_sweeps: int = 0
+    vi_max: int = 0
 
     def save(
         self,
@@ -316,91 +326,92 @@ class LDATrainer:
         likelihood history) is persisted every `config.checkpoint_every`
         EM iterations and, if the file already exists, training resumes
         from it instead of reinitializing."""
-        cfg = self.config
-        k, v = cfg.num_topics, self.num_terms
-        dtype = jnp.dtype(cfg.compute_dtype)
+        with maybe_span("fit.init"):
+            cfg = self.config
+            k, v = cfg.num_topics, self.num_terms
+            dtype = jnp.dtype(cfg.compute_dtype)
 
-        restored: list[tuple[float, float]] = []
-        start_it = 0
-        if checkpoint_path and os.path.exists(checkpoint_path):
-            ckpt = load_checkpoint(checkpoint_path)
-            if ckpt["log_beta"].shape != (k, v):
-                raise ValueError(
-                    f"checkpoint beta shape {ckpt['log_beta'].shape} does "
-                    f"not match config ({k}, {v})"
-                )
-            initial_log_beta = ckpt["log_beta"]
-            initial_alpha = ckpt["alpha"]
-            restored = ckpt["likelihoods"]
-            # Resuming a run checkpointed at (or past) the last iteration
-            # re-runs one iteration: gamma comes from the final E-step.
-            start_it = min(ckpt["em_iter"], cfg.em_max_iters - 1)
-
-        if initial_log_beta is not None:
-            log_beta = jnp.asarray(initial_log_beta, dtype)
-        else:
-            log_beta = init_log_beta(jax.random.PRNGKey(cfg.seed), k, v, dtype)
-        alpha = jnp.asarray(
-            cfg.alpha_init if initial_alpha is None else initial_alpha, dtype
-        )
-        if self.mesh is not None:
-            from ..parallel.mesh import (
-                DATA_AXIS,
-                batch_sharding,
-                beta_sharding,
-                replicated,
-            )
-
-            data_size = self.mesh.shape[DATA_AXIS]
-            for b in batches:
-                if b.word_idx.shape[0] % data_size:
+            restored: list[tuple[float, float]] = []
+            start_it = 0
+            if checkpoint_path and os.path.exists(checkpoint_path):
+                ckpt = load_checkpoint(checkpoint_path)
+                if ckpt["log_beta"].shape != (k, v):
                     raise ValueError(
-                        f"batch of {b.word_idx.shape[0]} docs not divisible "
-                        f"by data axis {data_size}"
+                        f"checkpoint beta shape {ckpt['log_beta'].shape} does "
+                        f"not match config ({k}, {v})"
                     )
-            log_beta = jax.device_put(
-                log_beta,
-                beta_sharding(self.mesh)
-                if self.vocab_sharded
-                else replicated(self.mesh),
+                initial_log_beta = ckpt["log_beta"]
+                initial_alpha = ckpt["alpha"]
+                restored = ckpt["likelihoods"]
+                # Resuming a run checkpointed at (or past) the last iteration
+                # re-runs one iteration: gamma comes from the final E-step.
+                start_it = min(ckpt["em_iter"], cfg.em_max_iters - 1)
+
+            if initial_log_beta is not None:
+                log_beta = jnp.asarray(initial_log_beta, dtype)
+            else:
+                log_beta = init_log_beta(jax.random.PRNGKey(cfg.seed), k, v, dtype)
+            alpha = jnp.asarray(
+                cfg.alpha_init if initial_alpha is None else initial_alpha, dtype
             )
+            if self.mesh is not None:
+                from ..parallel.mesh import (
+                    DATA_AXIS,
+                    batch_sharding,
+                    beta_sharding,
+                    replicated,
+                )
 
-            def put(x):
-                return jax.device_put(jnp.asarray(x), batch_sharding(self.mesh))
+                data_size = self.mesh.shape[DATA_AXIS]
+                for b in batches:
+                    if b.word_idx.shape[0] % data_size:
+                        raise ValueError(
+                            f"batch of {b.word_idx.shape[0]} docs not divisible "
+                            f"by data axis {data_size}"
+                        )
+                log_beta = jax.device_put(
+                    log_beta,
+                    beta_sharding(self.mesh)
+                    if self.vocab_sharded
+                    else replicated(self.mesh),
+                )
 
-        else:
+                def put(x):
+                    return jax.device_put(jnp.asarray(x), batch_sharding(self.mesh))
 
-            def put(x):
-                return jnp.asarray(x)
+            else:
 
-        gamma_out = np.zeros((num_docs, k), dtype=np.float64)
-        likelihoods: list[tuple[float, float]] = list(restored[:start_it])
-        # Only the coordinator streams likelihood.dat: in multi-host runs
-        # every process executes fit() against a shared day dir, and two
-        # appenders on one file would interleave.
-        ll_file = (
-            open(likelihood_file, "w")
-            if likelihood_file and _is_coordinator()
-            else None
-        )
-        if ll_file:
-            for ll_r, conv_r in likelihoods:
-                formats.append_likelihood(ll_file, ll_r, conv_r)
-        ll_prev = likelihoods[-1][0] if likelihoods else None
-        if self._shard_batches is not None:
-            # Distributed EM: one explicit reduce per EM iteration, so
-            # the chunk/host-sync knobs don't apply — the reduce IS the
-            # host sync.
-            self.plan_record = {}
-            loop = self._distributed_loop
-        else:
-            self._em_chunk, self._em_sync = self._resolve_em_plan(batches)
-            loop = (
-                self._fused_loop if self._em_chunk > 1
-                else self._stepwise_loop
+                def put(x):
+                    return jnp.asarray(x)
+
+            gamma_out = np.zeros((num_docs, k), dtype=np.float64)
+            likelihoods: list[tuple[float, float]] = list(restored[:start_it])
+            # Only the coordinator streams likelihood.dat: in multi-host runs
+            # every process executes fit() against a shared day dir, and two
+            # appenders on one file would interleave.
+            ll_file = (
+                open(likelihood_file, "w")
+                if likelihood_file and _is_coordinator()
+                else None
             )
+            if ll_file:
+                for ll_r, conv_r in likelihoods:
+                    formats.append_likelihood(ll_file, ll_r, conv_r)
+            ll_prev = likelihoods[-1][0] if likelihoods else None
+            if self._shard_batches is not None:
+                # Distributed EM: one explicit reduce per EM iteration, so
+                # the chunk/host-sync knobs don't apply — the reduce IS the
+                # host sync.
+                self.plan_record = {}
+                loop = self._distributed_loop
+            else:
+                self._em_chunk, self._em_sync = self._resolve_em_plan(batches)
+                loop = (
+                    self._fused_loop if self._em_chunk > 1
+                    else self._stepwise_loop
+                )
         try:
-            log_beta, alpha, it = loop(
+            log_beta, alpha, it, doc_sweeps, vi_max = loop(
                 batches, put, log_beta, alpha, ll_prev, start_it, num_docs,
                 likelihoods, ll_file, progress, checkpoint_path, gamma_out,
             )
@@ -414,13 +425,18 @@ class LDATrainer:
         ):
             os.remove(checkpoint_path)  # run completed; day dir stays clean
 
+        with maybe_span("fit.readback", what="log_beta"):
+            beta_host = to_host(log_beta, self.mesh)
+            alpha = float(alpha)
         return LDAResult(
-            log_beta=to_host(log_beta, self.mesh),
+            log_beta=beta_host,
             gamma=gamma_out,
-            alpha=float(alpha),
+            alpha=alpha,
             likelihoods=likelihoods,
             em_iters=it,
             plan=getattr(self, "plan_record", {}),
+            doc_sweeps=doc_sweeps,
+            vi_max=vi_max,
         )
 
     def _resolve_em_plan(self, batches) -> tuple[int, int]:
@@ -462,10 +478,12 @@ class LDATrainer:
 
     # -- EM drivers ---------------------------------------------------------
     #
-    # Both share the fit() contract: advance (log_beta, alpha) from
+    # All share the fit() contract: advance (log_beta, alpha) from
     # `start_it` until convergence or em_max_iters, appending to
     # `likelihoods`, streaming `ll_file`/`progress`/checkpoints, and
-    # scattering the final E-step's gammas into `gamma_out`.
+    # scattering the final E-step's gammas into `gamma_out`; they return
+    # (log_beta, alpha, last iteration, doc_sweeps, vi_max) — the last
+    # two are LDAResult's.
 
     def _log_iteration(
         self, it, ll, ll_prev, likelihoods, ll_file, progress
@@ -507,14 +525,15 @@ class LDATrainer:
         cfg = self.config
         k, v = cfg.num_topics, self.num_terms
         dtype = jnp.dtype(cfg.compute_dtype)
-        dev_batches = [
-            (
-                put(b.word_idx),
-                put(b.counts.astype(dtype)),
-                put(b.doc_mask.astype(dtype)),
-            )
-            for b in batches
-        ]
+        with maybe_span("fit.stack", batches=len(batches)):
+            dev_batches = [
+                (
+                    put(b.word_idx),
+                    put(b.counts.astype(dtype)),
+                    put(b.doc_mask.astype(dtype)),
+                )
+                for b in batches
+            ]
         # Warm start mirrors the fused driver's semantics (same gammas
         # seed the next iteration's fixed point) so the stepwise loop
         # stays its numerical cross-check under the default config.
@@ -530,6 +549,7 @@ class LDATrainer:
             from ..telemetry import roofline as rl
         t_loop0 = now_ns()
         n_e_disp = n_a_disp = n_warm_disp = 0
+        doc_sweeps = vi_max = 0
         gammas = []
         it = start_it
         for it in range(start_it + 1, cfg.em_max_iters + 1):
@@ -541,10 +561,14 @@ class LDATrainer:
             # iterations.
             slot = (self.yield_hook() if self.yield_hook is not None
                     else nullcontext())
-            with slot:
+            # One EM iteration's dispatches are this driver's chunk: the
+            # same span as the fused driver's enqueue.
+            with slot, maybe_span("em.run_chunk", chunk=1, n_steps=1,
+                                  first=it == start_it + 1):
                 total_ss = jnp.zeros((v, k), dtype)
                 total_ll = jnp.zeros((), dtype)
                 total_ass = jnp.zeros((), dtype)
+                sweeps = vi = jnp.zeros((), jnp.int32)
                 prev_gammas = gammas if use_warm else []
                 gammas = []
                 for bi, (widx, cnts, mask) in enumerate(dev_batches):
@@ -561,6 +585,8 @@ class LDATrainer:
                     total_ss = total_ss + res.suff_stats
                     total_ll = total_ll + res.likelihood
                     total_ass = total_ass + res.alpha_ss
+                    sweeps = sweeps + res.doc_sweeps
+                    vi = jnp.maximum(vi, res.vi_iters)
                     gammas.append(res.gamma)
                     n_e_disp += 1
 
@@ -574,8 +600,12 @@ class LDATrainer:
             # driver's one deliberate device sync; span it like the
             # fused driver's em.host_sync so the flight recorder
             # prices the stall instead of it hiding in iteration wall.
-            with maybe_span("em.host_sync", it=it):
+            with maybe_span("em.host_sync", it=it) as sp:
                 ll = float(total_ll)
+                sweeps, vi = int(sweeps), int(vi)
+                sp.annotate(steps=1, doc_sweeps=sweeps, vi_max=vi)
+            doc_sweeps += sweeps
+            vi_max = max(vi_max, vi)
             conv = self._log_iteration(
                 it, ll, ll_prev, likelihoods, ll_file, progress
             )
@@ -627,11 +657,12 @@ class LDATrainer:
                 rl.emit("em.update_alpha", wall_s, dispatches=n_a_disp,
                         wall_shared="em.e_step")
 
-        for g, b in zip(gammas, batches):
-            g = to_host(g, self.mesh)
-            sel = b.doc_mask == 1
-            gamma_out[b.doc_index[sel]] = g[sel]
-        return log_beta, alpha, it
+        with maybe_span("fit.readback", what="gamma"):
+            for g, b in zip(gammas, batches):
+                g = to_host(g, self.mesh)
+                sel = b.doc_mask == 1
+                gamma_out[b.doc_index[sel]] = g[sel]
+        return log_beta, alpha, it, doc_sweeps, vi_max
 
     def _distributed_loop(
         self, batches, put, log_beta, alpha, ll_prev, start_it, num_docs,
@@ -739,6 +770,7 @@ class LDATrainer:
         ar0 = dict(coll.stats)
         t_loop0 = now_ns()
         n_reduce = 0
+        doc_sweeps = vi_max = 0
         it = start_it
         for it in range(start_it + 1, cfg.em_max_iters + 1):
             warm = jnp.asarray(
@@ -754,7 +786,7 @@ class LDATrainer:
                     else nullcontext())
             with slot:
                 for si, sg, gp in zip(owned, shard_groups, gammas_prev):
-                    ss, ll, ass, gammas, _ = runner(
+                    ss, ll, ass, gammas, vi, sweeps = runner(
                         log_beta, alpha, sg.arrays, gp, warm
                     )
                     new_gammas.append(gammas)
@@ -763,12 +795,17 @@ class LDATrainer:
                     # iteration); span it so the flight recorder prices
                     # it next to the allreduce wait instead of it
                     # hiding in iteration wall.
-                    with maybe_span("em.host_sync", it=it, shard=si):
+                    with maybe_span("em.host_sync", it=it,
+                                    shard=si) as sp:
                         shard_stats[si] = dict(zip(
                             estep.PARTIAL_STAT_FIELDS,
                             (np.asarray(ss), np.asarray(ll),
                              np.asarray(ass)),
                         ))
+                        sweeps, vi = int(sweeps), int(vi)
+                        sp.annotate(steps=1, doc_sweeps=sweeps, vi_max=vi)
+                    doc_sweeps += sweeps
+                    vi_max = max(vi_max, vi)
             gammas_prev, have_prev = new_gammas, True
             reduced = reduce_partials(coll, plan, shard_stats,
                                       f"em{it}", precision=ar_precision)
@@ -815,14 +852,15 @@ class LDATrainer:
         # Scatter owned shards' final posteriors (global doc ids), then
         # merge across ranks: unowned rows are exact zeros, so the sum
         # is a disjoint union whatever the combine order.
-        for si, sg, gms in zip(owned, shard_groups, gammas_prev):
-            bs = self._shard_batches[si]
-            for g_arr, slots in zip(gms, sg.batch_slots):
-                g_group = to_host(g_arr, self.mesh)
-                for j, bi in enumerate(slots):
-                    b = bs[bi]
-                    sel = b.doc_mask == 1
-                    gamma_out[b.doc_index[sel]] = g_group[j][sel]
+        with maybe_span("fit.readback", what="gamma"):
+            for si, sg, gms in zip(owned, shard_groups, gammas_prev):
+                bs = self._shard_batches[si]
+                for g_arr, slots in zip(gms, sg.batch_slots):
+                    g_group = to_host(g_arr, self.mesh)
+                    for j, bi in enumerate(slots):
+                        b = bs[bi]
+                        sel = b.doc_mask == 1
+                        gamma_out[b.doc_index[sel]] = g_group[j][sel]
         if coll.num_processes > 1:
             # Ship only the OWNED contiguous row blocks (a rank owns
             # 1/P of the documents; gathering the full mostly-zero
@@ -855,7 +893,7 @@ class LDATrainer:
             raise RuntimeError(
                 f"distributed EM rank parity violated: {digests}"
             )
-        return log_beta, alpha, it
+        return log_beta, alpha, it, doc_sweeps, vi_max
 
     def _local_batch(self, batch) -> int:
         """Documents each data shard's kernel sees for one batch."""
@@ -1106,240 +1144,232 @@ class LDATrainer:
             def put_stacked(x):
                 return jax.device_put(jnp.asarray(x), stacked_sh)
 
-        compiler_options = None
-        use_dense = self._use_dense(batches)
-        self._compact_cell_max = None  # set by _plan_compact's scan
-        compact = None if use_dense else self._plan_compact(batches)
-        use_wmajor = False
-        dense_e_fn = None
-        corpus_store = None
-        if use_dense or compact is not None:
-            from ..ops import dense_estep as _de
+        # -- the plan: host-only decisions, before anything is placed ----
+        with maybe_span("fit.plan", batches=len(batches)) as sp:
+            compiler_options = None
+            use_dense = self._use_dense(batches)
+            self._compact_cell_max = None  # set by _plan_compact's scan
+            compact = None if use_dense else self._plan_compact(batches)
+            use_wmajor = False
+            dense_e_fn = None
+            dense_put = None
+            dense_width = None
+            corpus_store = None
+            kibs = []
+            if use_dense or compact is not None:
+                from ..ops import dense_estep
 
-            # bf16 corpus storage when exact and the run is already in
-            # bf16 operand mode — halves the corpus' HBM streaming with
-            # bit-identical results.  The gate bounds the DENSIFIED
-            # cells (duplicate (doc, word) tokens sum — the DUPFACTOR
-            # feedback path makes ~1000-count cells out of count-1
-            # tokens), not the raw counts.
-            cell_max = self._compact_cell_max
-            if cell_max is None:
-                cell_max = max(
-                    _de.max_dense_cell(b.word_idx, b.counts)
+                # bf16 corpus storage when exact and the run is already
+                # in bf16 operand mode — halves the corpus' HBM streaming
+                # with bit-identical results.  The gate bounds the
+                # DENSIFIED cells (duplicate (doc, word) tokens sum — the
+                # DUPFACTOR feedback path makes ~1000-count cells out of
+                # count-1 tokens), not the raw counts.
+                cell_max = self._compact_cell_max
+                if cell_max is None:
+                    cell_max = max(
+                        dense_estep.max_dense_cell(b.word_idx, b.counts)
+                        for b in batches
+                    )
+                corpus_store = dense_estep.corpus_dtype(
+                    cell_max, cfg.dense_precision)
+            if compact is not None:
+                # Compact-vocab dense groups are built straight from the
+                # host batches (no sparse stacked upload to discard).
+                # The chunk runner dispatches on the group layout itself
+                # (fused._compact_dense gathers beta columns and scatters
+                # suff-stats rows per batch).
+                use_wmajor = compact.wmajor
+                shapes = sorted({b.word_idx.shape for b in batches})
+                kibs = [
+                    dense_estep.scoped_vmem_kib(
+                        shape[0], wc, k, wmajor=use_wmajor,
+                        precision=cfg.dense_precision,
+                    )
+                    for shape, wc in zip(shapes, compact.widths)
+                ]
+            elif use_dense and self.vocab_sharded:
+                from functools import partial as _partial
+
+                from jax.sharding import NamedSharding, PartitionSpec as P
+
+                from ..parallel import sharded
+                from ..parallel.mesh import (
+                    DATA_AXIS as _DA, MODEL_AXIS as _MA)
+
+                # XLA-level vocab-sharded dense plan: stacked dense
+                # groups [NB, B, W] shard docs over `data` and vocab
+                # columns over `model`; width == the (model-divisible)
+                # padded vocab, so suff-stats land exactly in the sparse
+                # plan's shard layout and the vocab-sharded m_step
+                # consumes them unchanged.
+                dense_sh = NamedSharding(self.mesh, P(None, _DA, _MA))
+                dense_put = lambda x: jax.device_put(x, dense_sh)  # noqa: E731
+                dense_width = self.num_terms
+                dense_e_fn = _partial(
+                    sharded.make_vocab_sharded_dense_e_step(
+                        self.mesh, precision=cfg.dense_precision
+                    ),
+                    var_max_iters=cfg.var_max_iters,
+                    var_tol=cfg.var_tol,
+                )
+            elif use_dense:
+                from functools import partial as _partial
+
+                # Feasibility checks run against the PER-SHARD batch
+                # (each data shard's kernel sees its local slice).
+                # W-major needs the doc axis on the 128-lane dimension;
+                # fall back to row-major when any batch shape can't
+                # block that way.
+                use_wmajor = cfg.dense_wmajor and all(
+                    dense_estep.pick_block_w(self._local_batch(b),
+                                             self.num_terms, k,
+                                             cfg.dense_precision)
                     for b in batches
                 )
-            corpus_store = _de.corpus_dtype(cell_max, cfg.dense_precision)
-        if compact is not None:
-            from ..ops import dense_estep
+                if self.mesh is not None:
+                    from jax.sharding import (
+                        NamedSharding, PartitionSpec as P)
 
-            # Compact-vocab dense groups are built straight from the
-            # host batches (no sparse stacked upload to discard).  The
-            # chunk runner dispatches on the group layout itself
-            # (fused._compact_dense gathers beta columns and scatters
-            # suff-stats rows per batch).
-            use_wmajor = compact.wmajor
+                    from ..parallel import sharded
+                    from ..parallel.mesh import DATA_AXIS as _DA
+
+                    dense_sh = NamedSharding(
+                        self.mesh,
+                        P(None, None, _DA) if use_wmajor else P(None, _DA),
+                    )
+                    dense_put = lambda x: jax.device_put(x, dense_sh)  # noqa: E731
+                    dense_e_fn = _partial(
+                        sharded.make_data_parallel_dense_e_step(
+                            self.mesh, wmajor=use_wmajor,
+                            precision=cfg.dense_precision,
+                        ),
+                        var_max_iters=cfg.var_max_iters,
+                        var_tol=cfg.var_tol,
+                        interpret=jax.default_backend() != "tpu",
+                    )
+                kibs = [
+                    dense_estep.scoped_vmem_kib(self._local_batch(b),
+                                                self.num_terms, k,
+                                                wmajor=use_wmajor,
+                                                precision=cfg.dense_precision)
+                    for b in batches
+                ]
+            elif (getattr(self._e_base, "_oni_sparse_engine", False)
+                    and jax.default_backend() == "tpu"):
+                from ..ops import sparse_estep
+
+                kibs = [
+                    sparse_estep.scoped_vmem_kib(
+                        b.word_idx.shape[0], b.word_idx.shape[1], k,
+                        getattr(self._e_base, "precision", "f32"),
+                    )
+                    for b in batches
+                ]
+            # XLA drops a Pallas kernel's own scoped-VMEM limit when the
+            # call is fusion-wrapped inside the chunk program (a
+            # stacked-group scan); forward the limit as a program-level
+            # compiler option instead.  The option only exists on the
+            # TPU compiler (CPU interpret runs have no VMEM to limit).
+            if any(kibs) and jax.default_backend() == "tpu":
+                compiler_options = {
+                    "xla_tpu_scoped_vmem_limit_kib": str(
+                        max(filter(None, kibs)))
+                }
+            # Name what will actually run, next to the knobs that chose
+            # it: the kernel behind the engine family.
+            if use_dense and self.vocab_sharded:
+                kernel = "dense_vocab_sharded_xla"
+            elif use_dense:
+                kernel = (
+                    "dense_wmajor" if use_wmajor else "dense_rowmajor"
+                ) + ("_shard_map" if self.mesh is not None else "")
+            elif compact is not None:
+                kernel = "compact_wmajor" if use_wmajor else "compact_rowmajor"
+            elif getattr(self._e_base, "_oni_sparse_engine", False):
+                kernel = "sparse_fused"
+            elif getattr(self._e_base, "_oni_vocab_sharded", False):
+                kernel = "xla_vocab_sharded"
+            elif self._e_base is estep.e_step or getattr(
+                    self._e_base, "_oni_data_parallel", False):
+                # estep.e_step's own preference order, at the shape each
+                # device's call sees (it reports the refusals itself).
+                kernel = "+".join(sorted({
+                    estep.resolve_backend(
+                        "auto", self._local_batch(b), b.word_idx.shape[1],
+                        k, self.num_terms,
+                    )[0]
+                    for b in batches
+                }))
+            else:
+                kernel = "custom"
+            sp.annotate(kernel=kernel)
+
+        # -- placement: the stack (fit.stack), then densify (fit.densify) -
+        if compact is not None:
             groups = fused.compact_stack_batches(
                 batches, np.dtype(cfg.compute_dtype), put, compact,
                 corpus_store=corpus_store,
             )
-            shapes = sorted({b.word_idx.shape for b in batches})
-            kibs = [
-                dense_estep.scoped_vmem_kib(
-                    shape[0], wc, k, wmajor=use_wmajor,
-                    precision=cfg.dense_precision,
-                )
-                for shape, wc in zip(shapes, compact.widths)
-            ]
-            if any(kibs) and jax.default_backend() == "tpu":
-                compiler_options = {
-                    "xla_tpu_scoped_vmem_limit_kib": str(
-                        max(filter(None, kibs))
-                    )
-                }
         else:
             groups = fused.stack_batches(
                 batches, np.dtype(cfg.compute_dtype), put_stacked
             )
-        if use_dense and self.vocab_sharded:
-            from functools import partial as _partial
-
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from ..parallel import sharded
-            from ..parallel.mesh import DATA_AXIS as _DA, MODEL_AXIS as _MA
-
-            # XLA-level vocab-sharded dense plan: stacked dense groups
-            # [NB, B, W] shard docs over `data` and vocab columns over
-            # `model`; width == the (model-divisible) padded vocab, so
-            # suff-stats land exactly in the sparse plan's shard layout
-            # and the vocab-sharded m_step consumes them unchanged.
-            dense_sh = NamedSharding(self.mesh, P(None, _DA, _MA))
-            dense_e_fn = _partial(
-                sharded.make_vocab_sharded_dense_e_step(
-                    self.mesh, precision=cfg.dense_precision
-                ),
-                var_max_iters=cfg.var_max_iters,
-                var_tol=cfg.var_tol,
-            )
-            groups = fused.densify_groups(
-                groups, self.num_terms, wmajor=False,
-                put=lambda x: jax.device_put(x, dense_sh),
-                width=self.num_terms, dtype=corpus_store,
-            )
-        elif use_dense:
-            from functools import partial as _partial
-
-            from ..ops import dense_estep
-
-            # Feasibility checks run against the PER-SHARD batch (each
-            # data shard's kernel sees its local slice).  W-major needs
-            # the doc axis on the 128-lane dimension; fall back to
-            # row-major when any batch shape can't block that way.
-            use_wmajor = cfg.dense_wmajor and all(
-                dense_estep.pick_block_w(self._local_batch(b),
-                                         self.num_terms, k,
-                                         cfg.dense_precision)
-                for b in batches
-            )
-            if self.mesh is not None:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                from ..parallel import sharded
-                from ..parallel.mesh import DATA_AXIS as _DA
-
-                dense_sh = NamedSharding(
-                    self.mesh,
-                    P(None, None, _DA) if use_wmajor else P(None, _DA),
-                )
-                dense_put = lambda x: jax.device_put(x, dense_sh)  # noqa: E731
-                dense_e_fn = _partial(
-                    sharded.make_data_parallel_dense_e_step(
-                        self.mesh, wmajor=use_wmajor,
-                        precision=cfg.dense_precision,
-                    ),
-                    var_max_iters=cfg.var_max_iters,
-                    var_tol=cfg.var_tol,
-                    interpret=jax.default_backend() != "tpu",
-                )
-            else:
-                dense_put = None
+        if use_dense:
             groups = fused.densify_groups(
                 groups, self.num_terms, wmajor=use_wmajor, put=dense_put,
-                dtype=corpus_store,
+                width=dense_width, dtype=corpus_store,
             )
-            # XLA drops the pallas kernel's own scoped-VMEM limit when the
-            # call is fusion-wrapped inside a stacked-group scan; forward
-            # the limit as a program-level compiler option instead.  The
-            # option only exists on the TPU compiler (CPU interpret runs
-            # have no VMEM to limit).
-            kibs = [
-                dense_estep.scoped_vmem_kib(self._local_batch(b),
-                                            self.num_terms, k,
-                                            wmajor=use_wmajor,
-                                            precision=cfg.dense_precision)
-                for b in batches
-            ]
-            if any(kibs) and jax.default_backend() == "tpu":
-                compiler_options = {
-                    "xla_tpu_scoped_vmem_limit_kib": str(max(filter(None, kibs)))
-                }
-        if (
-            not use_dense
-            and compact is None
-            and getattr(self._e_base, "_oni_sparse_engine", False)
-            and jax.default_backend() == "tpu"
-        ):
-            from ..ops import sparse_estep
 
-            # Same scoped-VMEM forwarding the dense kernels need: XLA
-            # drops a fusion-wrapped pallas_call's own CompilerParams
-            # limit inside the chunk program.
-            kibs = [
-                sparse_estep.scoped_vmem_kib(
-                    b.word_idx.shape[0], b.word_idx.shape[1], k,
-                    getattr(self._e_base, "precision", "f32"),
+        with maybe_span("fit.runner"):
+            # The devices that hold corpus shards (on a mesh, one
+            # distinct slice per data shard).
+            corpus = groups.arrays[0][0]
+            self.plan_record["estep_kernel"] = {
+                "value": kernel,
+                "corpus_devices": sorted(
+                    s.device.id for s in corpus.addressable_shards),
+                "corpus_slices": len(
+                    {str(s.index) for s in corpus.addressable_shards}),
+                "platform": jax.default_backend(),
+            }
+            run_chunk = fused.make_chunk_runner(
+                num_docs=num_docs,
+                num_topics=k,
+                num_terms=self.num_terms,
+                chunk=self._em_chunk,
+                var_max_iters=cfg.var_max_iters,
+                var_tol=cfg.var_tol,
+                em_tol=cfg.em_tol,
+                estimate_alpha=cfg.estimate_alpha,
+                e_step_fn=self._e_base,
+                m_step_fn=self._m_base,
+                compiler_options=compiler_options,
+                dense_wmajor=use_wmajor,
+                warm_start=cfg.warm_start_gamma,
+                dense_e_step_fn=dense_e_fn,
+                dense_precision=cfg.dense_precision,
+                alpha_max_iters=cfg.alpha_max_iters,
+                yield_hook=self.yield_hook,
+            )
+            ll_prev_dev = jnp.asarray(
+                np.nan if ll_prev is None else ll_prev, dtype
+            )
+            # Same data-axis commitment as every other device input: on
+            # a multi-host mesh an uncommitted buffer spanning
+            # non-addressable devices fails outright, and even
+            # single-host meshes would pay a reshard on the first chunk
+            # (gamma buffers are [NB, B, K] with B on the data axis,
+            # like the stacked batches).
+            gammas_prev = tuple(
+                put_stacked(g)
+                for g in fused.initial_gammas(
+                    groups.arrays, k, dtype, dense_wmajor=use_wmajor
                 )
-                for b in batches
-            ]
-            if any(kibs):
-                compiler_options = {
-                    "xla_tpu_scoped_vmem_limit_kib": str(
-                        max(filter(None, kibs))
-                    )
-                }
-        # Name what will actually run, next to the knobs that chose it:
-        # the kernel behind the engine family, and the devices that hold
-        # corpus shards (on a mesh, one distinct slice per data shard).
-        if use_dense and self.vocab_sharded:
-            kernel = "dense_vocab_sharded_xla"
-        elif use_dense:
-            kernel = ("dense_wmajor" if use_wmajor else "dense_rowmajor") + (
-                "_shard_map" if self.mesh is not None else "")
-        elif compact is not None:
-            kernel = "compact_wmajor" if use_wmajor else "compact_rowmajor"
-        elif getattr(self._e_base, "_oni_sparse_engine", False):
-            kernel = "sparse_fused"
-        elif getattr(self._e_base, "_oni_vocab_sharded", False):
-            kernel = "xla_vocab_sharded"
-        elif self._e_base is estep.e_step or getattr(
-                self._e_base, "_oni_data_parallel", False):
-            # estep.e_step's own preference order, at the shape each
-            # device's call sees (it reports the refusals itself).
-            kernel = "+".join(sorted({
-                estep.resolve_backend(
-                    "auto", self._local_batch(b), b.word_idx.shape[1], k,
-                    self.num_terms,
-                )[0]
-                for b in batches
-            }))
-        else:
-            kernel = "custom"
-        corpus = groups.arrays[0][0]
-        self.plan_record["estep_kernel"] = {
-            "value": kernel,
-            "corpus_devices": sorted(
-                s.device.id for s in corpus.addressable_shards),
-            "corpus_slices": len(
-                {str(s.index) for s in corpus.addressable_shards}),
-            "platform": jax.default_backend(),
-        }
-        run_chunk = fused.make_chunk_runner(
-            num_docs=num_docs,
-            num_topics=k,
-            num_terms=self.num_terms,
-            chunk=self._em_chunk,
-            var_max_iters=cfg.var_max_iters,
-            var_tol=cfg.var_tol,
-            em_tol=cfg.em_tol,
-            estimate_alpha=cfg.estimate_alpha,
-            e_step_fn=self._e_base,
-            m_step_fn=self._m_base,
-            compiler_options=compiler_options,
-            dense_wmajor=use_wmajor,
-            warm_start=cfg.warm_start_gamma,
-            dense_e_step_fn=dense_e_fn,
-            dense_precision=cfg.dense_precision,
-            alpha_max_iters=cfg.alpha_max_iters,
-            yield_hook=self.yield_hook,
-        )
-
-        ll_prev_dev = jnp.asarray(
-            np.nan if ll_prev is None else ll_prev, dtype
-        )
+            )
+            have_prev = jnp.asarray(False)
         it = start_it
         res = None
-        # Same data-axis commitment as every other device input: on a
-        # multi-host mesh an uncommitted buffer spanning non-addressable
-        # devices fails outright, and even single-host meshes would pay
-        # a reshard on the first chunk (gamma buffers are [NB, B, K]
-        # with B on the data axis, like the stacked batches).
-        gammas_prev = tuple(
-            put_stacked(g)
-            for g in fused.initial_gammas(
-                groups.arrays, k, dtype, dense_wmajor=use_wmajor
-            )
-        )
-        have_prev = jnp.asarray(False)
         # Host-sync cadence: host_sync_every bounds the iterations per
         # dispatch independently of the compiled chunk size, so
         # likelihood.dat streams (and progress fires) at least that
@@ -1354,6 +1384,7 @@ class LDATrainer:
             sync_chunk = min(sync_chunk, self._em_sync)
         t_loop0 = now_ns()
         n_disp = 0
+        doc_sweeps = vi_max = 0
         while it < cfg.em_max_iters:
             stop = min(it + sync_chunk, cfg.em_max_iters)
             if checkpoint_path and cfg.checkpoint_every:
@@ -1378,8 +1409,11 @@ class LDATrainer:
             # enqueue glue vs blocking sync (telemetry/spans.py).
             with maybe_span("em.host_sync", it=it) as sp:
                 steps = int(res.steps_done)
-                if sp is not None and hasattr(sp, "annotate"):
-                    sp.annotate(steps=steps)
+                sweeps = int(np.asarray(res.doc_sweeps)[:steps].sum())
+                vi = int(np.asarray(res.vi_iters)[:steps].max(initial=0))
+                sp.annotate(steps=steps, doc_sweeps=sweeps, vi_max=vi)
+                doc_sweeps += sweeps
+                vi_max = max(vi_max, vi)
                 host_conv = None
                 for ll in np.asarray(res.lls[:steps], np.float64):
                     it += 1
@@ -1407,23 +1441,47 @@ class LDATrainer:
             # monotonic wall — enqueue glue AND blocking host syncs, the
             # whole EM phase.  Journaled as {"kind": "roofline"}; on
             # backends with registered peaks the record carries
-            # mxu_pct/hbm_pct, elsewhere `utilization: null`.
+            # mxu_pct/hbm_pct, elsewhere `utilization: null`.  XLA's
+            # cost analysis counts the chunk's while_loop body once and
+            # a Pallas call as nothing, so mxu_pct is not the EM's
+            # utilization; useful_mxu_pct is: it rests on the work
+            # counted here, every sweep the E-step ran (two [rows, W] x
+            # [W, K] products each) plus one product per row and EM
+            # iteration for the expected counts, at the width the kernel
+            # sweeps.
             from ..telemetry import roofline
 
+            rows = sum(b.word_idx.shape[0] for b in batches)
+            if use_dense:
+                width = dense_width or dense_estep.padded_width(
+                    self.num_terms)
+            elif compact is not None:
+                width = sum(
+                    len(us) * shape[0] * wc
+                    for us, shape, wc in zip(
+                        compact.uniques, shapes, compact.widths)
+                ) / rows
+            else:
+                width = sum(b.word_idx.size for b in batches) / rows
             roofline.emit(
                 "em.run_chunk", (now_ns() - t_loop0) / 1e9,
                 dispatches=n_disp, em_iters=it - start_it,
-                chunk=self._em_chunk,
+                chunk=self._em_chunk, doc_sweeps=doc_sweeps,
+                effective_flops=(
+                    4.0 * doc_sweeps + 2.0 * rows * (it - start_it)
+                ) * width * k,
             )
 
         if res is not None and int(res.steps_done) > 0:
-            for g_arr, slots in zip(res.gammas, groups.batch_slots):
-                g_group = to_host(g_arr, self.mesh)  # one transfer per group
-                for j, bi in enumerate(slots):
-                    b = batches[bi]
-                    sel = b.doc_mask == 1
-                    gamma_out[b.doc_index[sel]] = g_group[j][sel]
-        return log_beta, alpha, it
+            with maybe_span("fit.readback", what="gamma"):
+                for g_arr, slots in zip(res.gammas, groups.batch_slots):
+                    # one transfer per group
+                    g_group = to_host(g_arr, self.mesh)
+                    for j, bi in enumerate(slots):
+                        b = batches[bi]
+                        sel = b.doc_mask == 1
+                        gamma_out[b.doc_index[sel]] = g_group[j][sel]
+        return log_beta, alpha, it, doc_sweeps, vi_max
 
 
 def warm_start_log_beta(
@@ -1790,23 +1848,65 @@ def train_corpus(
     the streaming dataplane demotes those to background checkpoint
     sinks that overlap scoring, so the trainer must not also write
     them inline on the critical path.
+
+    The whole call is the span `fit` (telemetry/spans.py), the root the
+    fit's layer boundaries hang under: fit.engine, fit.batches,
+    fit.init, fit.plan, fit.stack, fit.densify, fit.runner,
+    em.run_chunk / em.host_sync, fit.readback, fit.save.  On its close
+    it counts what the fit ran (`em_iters`, `doc_sweeps`, the engine and
+    kernel) and, while plans.warmup's listener is live, jax's compile
+    counters across the fit.
     """
     if distributed is None:
         distributed = jax.process_count() > 1
-    if distributed:
-        return _train_corpus_distributed(
-            corpus, config, out_dir=out_dir, progress=progress,
-            mesh=mesh, vocab_sharded=vocab_sharded,
-            save_final=save_final, collective=collective,
+    from ..plans import warmup
+
+    with maybe_span(
+        "fit", num_docs=corpus.num_docs, num_terms=corpus.num_terms,
+        k=config.num_topics,
+        mesh=None if mesh is None else str(dict(mesh.shape)),
+    ) as sp:
+        compiles0 = warmup.compile_counts() if warmup.counting() else None
+        train = _train_corpus_distributed if distributed else _train_corpus
+        kw = {"collective": collective} if distributed else {}
+        result = train(
+            corpus, config, out_dir=out_dir, progress=progress, mesh=mesh,
+            vocab_sharded=vocab_sharded, save_final=save_final, **kw,
         )
+        sp.annotate(
+            engine=result.plan["estep_engine"]["value"],
+            kernel=result.plan.get("estep_kernel", {}).get("value"),
+            em_iters=result.em_iters, doc_sweeps=result.doc_sweeps,
+            vi_max=result.vi_max,
+        )
+        if compiles0 is not None:
+            delta = warmup.counts_delta(compiles0)
+            sp.annotate(**{key: delta[key] for key in (
+                "compile_requests", "cache_hits", "trace_s", "compile_s")})
+    return result
+
+
+def _train_corpus(
+    corpus: Corpus,
+    config: LDAConfig,
+    out_dir: str | None = None,
+    progress: Callable[[int, float, float], None] | None = None,
+    mesh=None,
+    vocab_sharded: bool = False,
+    save_final: bool = True,
+) -> LDAResult:
+    """train_corpus in one process (its docstring; the `fit` span is
+    open)."""
     e_fn = m_fn = None
     num_terms = corpus.num_terms
     initial_log_beta = None
     if vocab_sharded and mesh is None:
         raise ValueError("vocab_sharded=True requires a mesh")
-    engine, engine_src = resolve_estep_engine(
-        corpus, config, mesh=mesh, vocab_sharded=vocab_sharded
-    )
+    with maybe_span("fit.engine") as sp:
+        engine, engine_src = resolve_estep_engine(
+            corpus, config, mesh=mesh, vocab_sharded=vocab_sharded
+        )
+        sp.annotate(engine=engine, source=engine_src)
     sparse_layout = None
     sparse_l_record = None
     if engine == "sparse":
@@ -1839,10 +1939,17 @@ def train_corpus(
                 f"{config.dense_precision!r} (K={config.num_topics}); "
                 "use the dense family for this corpus"
             )
-        sparse_layout = corpus.bucketed_layout(
-            min_len=sparse_l, batch_cap=config.batch_size,
-            pad_multiple=pad,
-        )
+        with maybe_span("fit.batches", layout="bucketed") as sp:
+            sparse_layout = corpus.bucketed_layout(
+                min_len=sparse_l, batch_cap=config.batch_size,
+                pad_multiple=pad,
+            )
+            # The sparse engine trains over the bucketed layout's packed
+            # tiles; Batch.doc_index carries the permutation, so fit()'s
+            # gamma scatter restores document order bit-exactly
+            # (layout.inv_perm is the same map, pinned by tests).
+            batches = list(sparse_layout.batches)
+            sp.annotate(**_batch_counts(batches))
         e_fn = sparse_estep.make_e_step_fn(precision=config.dense_precision)
     data_size = 1
     if mesh is not None:
@@ -1850,30 +1957,28 @@ def train_corpus(
             _mesh_trainer_setup(corpus, config, mesh, vocab_sharded)
         )
 
-    if sparse_layout is not None:
-        # The sparse engine trains over the bucketed layout's packed
-        # tiles; Batch.doc_index carries the permutation, so fit()'s
-        # gamma scatter restores document order bit-exactly
-        # (layout.inv_perm is the same map, pinned by tests).
-        batches = list(sparse_layout.batches)
-    else:
-        batches = make_batches(
-            corpus, batch_size=config.batch_size,
-            min_bucket_len=config.min_bucket_len,
-            # Every device's slice of every batch — the tail batches
-            # too — is a multiple of the 8-row sublane tile, or one
-            # ragged tail takes the Pallas kernels away from the whole
-            # run (their doc blocks must divide the per-shard batch).
-            pad_multiple=8 * data_size,
+    if sparse_layout is None:
+        with maybe_span("fit.batches", layout="make_batches") as sp:
+            batches = make_batches(
+                corpus, batch_size=config.batch_size,
+                min_bucket_len=config.min_bucket_len,
+                # Every device's slice of every batch — the tail batches
+                # too — is a multiple of the 8-row sublane tile, or one
+                # ragged tail takes the Pallas kernels away from the
+                # whole run (their doc blocks must divide the per-shard
+                # batch).
+                pad_multiple=8 * data_size,
+            )
+            sp.annotate(**_batch_counts(batches))
+    with maybe_span("fit.init", what="trainer"):
+        trainer = LDATrainer(
+            config,
+            num_terms=num_terms,
+            e_step_fn=e_fn,
+            m_step_fn=m_fn,
+            mesh=mesh,
+            vocab_sharded=vocab_sharded,
         )
-    trainer = LDATrainer(
-        config,
-        num_terms=num_terms,
-        e_step_fn=e_fn,
-        m_step_fn=m_fn,
-        mesh=mesh,
-        vocab_sharded=vocab_sharded,
-    )
     ll_path = os.path.join(out_dir, "likelihood.dat") if out_dir else None
     ckpt_path = (
         os.path.join(out_dir, "checkpoint.npz")
@@ -1899,8 +2004,20 @@ def train_corpus(
         # likelihood.dat was already streamed (crash-safe) during fit;
         # multi-host: the result is identical on every process (to_host
         # gathers collectively) but only the coordinator owns the files.
-        result.save(out_dir, num_terms=corpus.num_terms, include_likelihood=False)
+        with maybe_span("fit.save"):
+            result.save(out_dir, num_terms=corpus.num_terms,
+                        include_likelihood=False)
     return result
+
+
+def _batch_counts(batches) -> dict:
+    """What the `fit.batches` span counts: the batches, their padded
+    rows and their distinct shapes."""
+    return {
+        "batches": len(batches),
+        "rows": sum(b.word_idx.shape[0] for b in batches),
+        "shapes": len({b.word_idx.shape for b in batches}),
+    }
 
 
 def _mesh_trainer_setup(corpus: Corpus, config: LDAConfig, mesh,
@@ -2016,10 +2133,11 @@ def _train_corpus_distributed(
     # cross-rank-count byte-identity contract.
     if coll.rank == 0:
         try:
-            decision = resolve_estep_engine(
-                corpus, config, mesh=mesh, vocab_sharded=vocab_sharded,
-                distributed=True, shard_plan=plan,
-            )
+            with maybe_span("fit.engine"):
+                decision = resolve_estep_engine(
+                    corpus, config, mesh=mesh, vocab_sharded=vocab_sharded,
+                    distributed=True, shard_plan=plan,
+                )
         except BaseException as e:
             # Library-level relay (the runner's stage barrier is not in
             # play for direct train_corpus callers): without this, a
@@ -2083,42 +2201,44 @@ def _train_corpus_distributed(
                 "use the dense family for this corpus"
             )
         e_fn = sparse_estep.make_e_step_fn(precision=config.dense_precision)
-        shard_batches = {
-            s: [
-                Batch(b.word_idx, b.counts,
-                      b.doc_index + plan.bounds[s][0], b.doc_mask)
-                for b in sc.bucketed_layout(
-                    min_len=sparse_l, batch_cap=config.batch_size,
-                    pad_multiple=pad,
-                ).batches
-            ]
-            for s, sc in shard_corpora.items()
-        }
-    else:
-        shard_batches = {
-            s: [
-                Batch(b.word_idx, b.counts,
-                      b.doc_index + plan.bounds[s][0], b.doc_mask)
-                for b in make_batches(
-                    sc, batch_size=config.batch_size,
-                    min_bucket_len=config.min_bucket_len,
-                    pad_multiple=8 * data_size,
-                )
-            ]
-            for s, sc in shard_corpora.items()
-        }
 
-    trainer = LDATrainer(
-        config,
-        num_terms=num_terms,
-        e_step_fn=e_fn,
-        m_step_fn=m_fn,
-        mesh=mesh,
-        vocab_sharded=vocab_sharded,
-        collective=coll,
-        shard_plan=plan,
-        shard_batches=shard_batches,
-    )
+        def shard_layout(sc):
+            return sc.bucketed_layout(
+                min_len=sparse_l, batch_cap=config.batch_size,
+                pad_multiple=pad,
+            ).batches
+    else:
+        def shard_layout(sc):
+            return make_batches(
+                sc, batch_size=config.batch_size,
+                min_bucket_len=config.min_bucket_len,
+                pad_multiple=8 * data_size,
+            )
+
+    with maybe_span("fit.batches", shards=len(shard_corpora)) as sp:
+        shard_batches = {
+            s: [
+                Batch(b.word_idx, b.counts,
+                      b.doc_index + plan.bounds[s][0], b.doc_mask)
+                for b in shard_layout(sc)
+            ]
+            for s, sc in shard_corpora.items()
+        }
+        flat = [b for s in sorted(shard_batches) for b in shard_batches[s]]
+        sp.annotate(**_batch_counts(flat))
+
+    with maybe_span("fit.init", what="trainer"):
+        trainer = LDATrainer(
+            config,
+            num_terms=num_terms,
+            e_step_fn=e_fn,
+            m_step_fn=m_fn,
+            mesh=mesh,
+            vocab_sharded=vocab_sharded,
+            collective=coll,
+            shard_plan=plan,
+            shard_batches=shard_batches,
+        )
     ll_path = os.path.join(out_dir, "likelihood.dat") if out_dir else None
     ckpt_path = (
         os.path.join(out_dir, "checkpoint.npz")
@@ -2131,7 +2251,6 @@ def _train_corpus_distributed(
         # split this run trained under ({"kind": "shard_plan"}).
         rec.journal_record(plan.record(coll.rank))
     ar0 = dict(coll.stats)
-    flat = [b for s in sorted(shard_batches) for b in shard_batches[s]]
     try:
         result = trainer.fit(
             flat,
@@ -2180,6 +2299,7 @@ def _train_corpus_distributed(
     if num_terms != corpus.num_terms:
         result.log_beta = result.log_beta[:, : corpus.num_terms]
     if out_dir and save_final and _is_coordinator():
-        result.save(out_dir, num_terms=corpus.num_terms,
-                    include_likelihood=False)
+        with maybe_span("fit.save"):
+            result.save(out_dir, num_terms=corpus.num_terms,
+                        include_likelihood=False)
     return result

@@ -299,9 +299,10 @@ def densify(word_idx, counts, num_terms: int, width: int | None = None,
     elif width < num_terms:
         raise ValueError(f"width {width} < num_terms {num_terms}")
     b = word_idx.shape[0]
-    dense = jnp.zeros((b, width), counts.dtype)
-    dense = dense.at[jnp.arange(b)[:, None], word_idx].add(counts)
-    return dense if dtype is None else dense.astype(dtype)
+    with jax.named_scope("densify"):
+        dense = jnp.zeros((b, width), counts.dtype)
+        dense = dense.at[jnp.arange(b)[:, None], word_idx].add(counts)
+        return dense if dtype is None else dense.astype(dtype)
 
 
 def _dense_kernel(
@@ -592,6 +593,7 @@ def dense_fixed_point_w(
             vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision)
         ),
         interpret=interpret,
+        name="dense_estep_wmajor",
     )(
         jnp.reshape(jnp.asarray(alpha, dtype), (1, 1)),
         jnp.reshape(warm, (1, 1)),
@@ -600,7 +602,7 @@ def dense_fixed_point_w(
         jnp.reshape(doc_mask, (1, b)),
         gamma_in,
     )
-    return gamma_t.T, t, docll[0], ass[0], iters.max()
+    return gamma_t.T, t, docll[0], ass[0], iters.max(), iters.sum() * bb
 
 
 def dense_fixed_point(
@@ -617,9 +619,11 @@ def dense_fixed_point(
     precision: str = "f32",
 ):
     """Returns (gamma [B, K], T [K, V], docll [B], alpha_ss_part [B],
-    iters scalar) — docll is the full per-doc ELBO minus the alpha-prior
-    constant (token term + gamma-Dirichlet terms, masked), and
-    alpha_ss_part is the per-doc sum_k E[log theta] (masked)."""
+    iters scalar, doc_sweeps scalar) — docll is the full per-doc ELBO
+    minus the alpha-prior constant (token term + gamma-Dirichlet terms,
+    masked), alpha_ss_part is the per-doc sum_k E[log theta] (masked),
+    iters the most sweeps any doc block ran and doc_sweeps the sum over
+    blocks of a block's sweeps x its rows (EStepResult.doc_sweeps)."""
     k_topics, v = exp_beta.shape
     b = dense_counts.shape[0]
     bb = block or pick_block(b, v, k_topics, precision)
@@ -685,6 +689,7 @@ def dense_fixed_point(
             vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision)
         ),
         interpret=interpret,
+        name="dense_estep_rowmajor",
     )(
         jnp.reshape(jnp.asarray(alpha, dtype), (1, 1)),
         jnp.reshape(warm, (1, 1)),
@@ -693,7 +698,7 @@ def dense_fixed_point(
         jnp.reshape(doc_mask, (b, 1)),
         gamma_in,
     )
-    return gamma, t, docll[:, 0], ass[:, 0], iters.max()
+    return gamma, t, docll[:, 0], ass[:, 0], iters.max(), iters.sum() * bb
 
 
 def e_step_dense(
@@ -723,7 +728,7 @@ def e_step_dense(
     if w != v:
         exp_beta = jnp.pad(exp_beta, ((0, 0), (0, w - v)))
     fp = dense_fixed_point_w if wmajor else dense_fixed_point
-    gamma, t, docll, ass, iters = fp(
+    gamma, t, docll, ass, iters, sweeps = fp(
         exp_beta, alpha, dense_counts, doc_mask, var_max_iters, var_tol,
         block=block, interpret=interpret, gamma_prev=gamma_prev, warm=warm,
         precision=precision,
@@ -736,7 +741,7 @@ def e_step_dense(
     alpha_const = gammaln(k_topics * alpha) - k_topics * gammaln(alpha)
     likelihood = docll.sum() + doc_mask.sum() * alpha_const
     alpha_ss = ass.sum()
-    return estep.EStepResult(gamma, suff, alpha_ss, likelihood, iters)
+    return estep.EStepResult(gamma, suff, alpha_ss, likelihood, iters, sweeps)
 
 
 def plan(b: int, v: int, k: int, precision: str = "f32",

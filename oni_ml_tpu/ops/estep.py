@@ -50,18 +50,24 @@ class EStepResult(NamedTuple):
     alpha_ss: jnp.ndarray     # scalar: sum_d sum_k E[log theta_dk]
     likelihood: jnp.ndarray   # scalar: sum over real docs of the ELBO
     vi_iters: jnp.ndarray     # scalar: fixed-point iterations used
+    doc_sweeps: jnp.ndarray   # scalar int32: document-sweeps run — each
+                              # kernel block's sweeps x its rows, padding
+                              # included (a batch that iterates as one
+                              # counts sweeps x its rows): the work run,
+                              # additive across batches and shards
 
 
 # The fields of an EStepResult that are PARTIAL sufficient statistics:
 # additive across document subsets, so per-shard/per-rank results
 # combine into the global result by summation alone (gamma is per-doc
-# state and vi_iters a max — neither reduces by sum).  This is the
+# state and vi_iters a max — neither reduces by sum; doc_sweeps does,
+# but counts work run and is no statistic the M-step reads).  This is the
 # payload contract of the distributed suff-stats allreduce — the named
 # arrays models/lda.py's _distributed_loop hands parallel/allreduce:
 # word-topic counts for the M-step, the ELBO for the convergence
 # check, and the E[log theta] total for the alpha Newton.  The order
 # matches fused.make_partial_runner's return tuple (suff, ll, ass,
-# gammas, vi) with the non-reducible tail dropped.
+# gammas, vi, doc_sweeps) with the tail that is not reduced dropped.
 PARTIAL_STAT_FIELDS = ("suff_stats", "likelihood", "alpha_ss")
 
 
@@ -355,7 +361,7 @@ def e_step(
     phi_c, phinorm = phi_weighted(beta_bt, gamma, counts, doc_mask)
     suff = suff_stats(phi_c, word_idx, V)
     likelihood, alpha_ss = batch_likelihood(gamma, phinorm, counts, alpha, doc_mask)
-    return EStepResult(gamma, suff, alpha_ss, likelihood, iters)
+    return EStepResult(gamma, suff, alpha_ss, likelihood, iters, iters * b)
 
 
 # Lets the fused runner know this callable accepts gamma_prev/warm (a

@@ -211,7 +211,8 @@ def fixed_point(
     gamma_prev=None,         # [B, K] warm start (None = fresh init)
     warm=None,               # traced scalar gating gamma_prev
 ):
-    """Pallas gamma fixed point.  Returns (gamma [B, K], iters scalar)."""
+    """Pallas gamma fixed point.  Returns (gamma [B, K], iters scalar,
+    doc_sweeps scalar: the sum over doc blocks of sweeps x rows)."""
     k_topics, b, l = slab_kbl.shape
     bb = block or pick_block(b, l, k_topics)
     if bb is None:
@@ -256,6 +257,7 @@ def fixed_point(
             jax.ShapeDtypeStruct((grid, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="pallas_estep",
     )(
         jnp.reshape(jnp.asarray(alpha, slab_kbl.dtype), (1, 1)),
         jnp.reshape(warm, (1, 1)),
@@ -264,7 +266,7 @@ def fixed_point(
         jnp.reshape(doc_mask, (b, 1)),
         gamma_in,
     )
-    return gamma, iters.max()
+    return gamma, iters.max(), iters.sum() * bb
 
 
 def e_step(
@@ -287,7 +289,7 @@ def e_step(
     """
     v = log_beta.shape[1]
     slab_kbl = jnp.exp(log_beta)[:, word_idx]           # [K, B, L]
-    gamma, iters = fixed_point(
+    gamma, iters, sweeps = fixed_point(
         slab_kbl, alpha, counts, doc_mask, var_max_iters, var_tol,
         interpret=interpret, gamma_prev=gamma_prev, warm=warm,
     )
@@ -299,4 +301,5 @@ def e_step(
     likelihood, alpha_ss = estep.batch_likelihood(
         gamma, phinorm, counts, alpha, doc_mask
     )
-    return estep.EStepResult(gamma, suff, alpha_ss, likelihood, iters)
+    return estep.EStepResult(gamma, suff, alpha_ss, likelihood, iters,
+                             sweeps)

@@ -305,10 +305,11 @@ def fixed_point_full(
     warm=None,               # traced scalar gating gamma_prev
 ):
     """Fused sparse E-step core.  Returns (gamma [B, K] f32,
-    phi_c [K, B, L] f32, docll [B], alpha_ss_part [B], iters scalar) —
-    docll is the full per-doc ELBO minus the alpha-prior constant,
-    phi_c the per-token phi-weighted counts ready for the [V, K]
-    segment-sum scatter."""
+    phi_c [K, B, L] f32, docll [B], alpha_ss_part [B], iters scalar,
+    doc_sweeps scalar) — docll is the full per-doc ELBO minus the
+    alpha-prior constant, phi_c the per-token phi-weighted counts ready
+    for the [V, K] segment-sum scatter, doc_sweeps the sum over doc
+    blocks of a block's sweeps x its rows."""
     k_topics, b, l = slab_kbl.shape
     precision = "bf16" if slab_kbl.dtype == jnp.bfloat16 else "f32"
     bb = block or pick_block(b, l, k_topics, precision)
@@ -371,6 +372,7 @@ def fixed_point_full(
             vmem_limit_bytes=_vmem_limit(bb, l, k_topics, precision)
         ),
         interpret=interpret,
+        name="sparse_estep",
     )(
         jnp.reshape(jnp.asarray(alpha, jnp.float32), (1, 1)),
         jnp.reshape(warm, (1, 1)),
@@ -379,7 +381,7 @@ def fixed_point_full(
         jnp.reshape(jnp.asarray(doc_mask, jnp.float32), (b, 1)),
         gamma_in,
     )
-    return gamma, phic, docll[:, 0], ass[:, 0], iters.max()
+    return gamma, phic, docll[:, 0], ass[:, 0], iters.max(), iters.sum() * bb
 
 
 def e_step(
@@ -411,7 +413,7 @@ def e_step(
     slab_kbl = jnp.exp(log_beta)[:, word_idx]           # [K, B, L]
     if precision == "bf16":
         slab_kbl = slab_kbl.astype(jnp.bfloat16)
-    gamma, phic, docll, ass, iters = fixed_point_full(
+    gamma, phic, docll, ass, iters, sweeps = fixed_point_full(
         slab_kbl, alpha, counts, doc_mask, var_max_iters, var_tol,
         block=block, interpret=interpret, gamma_prev=gamma_prev, warm=warm,
     )
@@ -423,7 +425,8 @@ def e_step(
     )
     alpha_const = gammaln(k_topics * alpha) - k_topics * gammaln(alpha)
     likelihood = docll.sum() + doc_mask.sum() * alpha_const
-    return estep.EStepResult(gamma, suff, ass.sum(), likelihood, iters)
+    return estep.EStepResult(gamma, suff, ass.sum(), likelihood, iters,
+                             sweeps)
 
 
 def make_e_step_fn(precision: str = "f32", interpret: "bool | None" = None):

@@ -72,6 +72,7 @@ def make_data_parallel_e_step(mesh: Mesh):
             alpha_ss=jax.lax.psum(res.alpha_ss, DATA_AXIS),
             likelihood=jax.lax.psum(res.likelihood, DATA_AXIS),
             vi_iters=jax.lax.pmax(res.vi_iters, DATA_AXIS),
+            doc_sweeps=jax.lax.psum(res.doc_sweeps, DATA_AXIS),
         )
 
     def wrapped(log_beta, alpha, word_idx, counts, doc_mask,
@@ -89,6 +90,7 @@ def make_data_parallel_e_step(mesh: Mesh):
                 alpha_ss=P(),
                 likelihood=P(),
                 vi_iters=P(),
+                doc_sweeps=P(),
             ),
         )
         return fn(log_beta, alpha, word_idx, counts, doc_mask, gamma_prev,
@@ -130,6 +132,7 @@ def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
             alpha_ss=jax.lax.psum(res.alpha_ss, DATA_AXIS),
             likelihood=jax.lax.psum(res.likelihood, DATA_AXIS),
             vi_iters=jax.lax.pmax(res.vi_iters, DATA_AXIS),
+            doc_sweeps=jax.lax.psum(res.doc_sweeps, DATA_AXIS),
         )
 
     dense_spec = (
@@ -155,6 +158,7 @@ def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
                 alpha_ss=P(),
                 likelihood=P(),
                 vi_iters=P(),
+                doc_sweeps=P(),
             ),
             # pallas_call's out_shape carries no varying-mesh-axes info,
             # so shard_map's vma check cannot see through it.
@@ -310,6 +314,8 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
             alpha_ss=jax.lax.psum(ass, DATA_AXIS),
             likelihood=jax.lax.psum(ll, DATA_AXIS),
             vi_iters=jax.lax.pmax(iters, DATA_AXIS),
+            doc_sweeps=jax.lax.psum(
+                iters * doc_mask.shape[0], DATA_AXIS),
         )
 
     def wrapped(log_beta, alpha, dense, doc_mask, gamma_prev, warm,
@@ -340,6 +346,7 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
                 alpha_ss=P(),
                 likelihood=P(),
                 vi_iters=P(),
+                doc_sweeps=P(),
             ),
         )
         return fn(log_beta, alpha, dense, doc_mask, gamma_prev, warm)
@@ -389,6 +396,8 @@ def make_vocab_sharded_fns(mesh: Mesh):
             alpha_ss=jax.lax.psum(alpha_ss, DATA_AXIS),
             likelihood=jax.lax.psum(likelihood, DATA_AXIS),
             vi_iters=jax.lax.pmax(iters, DATA_AXIS),
+            doc_sweeps=jax.lax.psum(
+                iters * doc_mask.shape[0], DATA_AXIS),
         )
 
     def e_step_fn(log_beta, alpha, word_idx, counts, doc_mask,
@@ -410,6 +419,7 @@ def make_vocab_sharded_fns(mesh: Mesh):
                 alpha_ss=P(),
                 likelihood=P(),
                 vi_iters=P(),
+                doc_sweeps=P(),
             ),
         )
         return fn(log_beta, alpha, word_idx, counts, doc_mask, gamma_prev,

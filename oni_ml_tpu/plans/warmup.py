@@ -71,6 +71,13 @@ def _ensure_listener() -> bool:
     return True
 
 
+def counting() -> bool:
+    """Whether jax's compile events are being counted (the listener is
+    registered): only then does a delta of `compile_counts()` mean
+    anything."""
+    return _LISTENING is True
+
+
 def compile_counts() -> dict:
     """Cumulative per-process compile counters.  `traces` is the
     number of compile requests the persistent cache could NOT serve —

@@ -10,10 +10,11 @@ one shared vocabulary:
     with use_recorder(rec):
         with rec.span("stage.lda", fdate="20160122"):
             ...
-            rec.counter("em.chunk_dispatches").add(1)
             rec.histogram("em.host_sync_s").observe(0.012)
 
-Spans nest (per-thread depth tracking), time exclusively on the
+Spans nest (a per-thread stack of span ids: every recorded span carries
+its `id`, its `parent` and the `root` it hangs under, and `depth` is the
+stack's height), time exclusively on the
 MONOTONIC clock (`time.monotonic_ns` — the wall clock can step
 backwards under NTP and is banned for interval timing by the telemetry
 lint in tests/test_telemetry.py), and export as Chrome trace-event JSON
@@ -23,20 +24,32 @@ span also appends a crash-safe `{"kind": "span", ...}` line, so a run
 killed mid-flight still leaves its timeline on disk —
 tools/trace_view.py rebuilds the trace from the journal alone.
 
+A span has a second sink: jax's profiler.  Whenever `jax` is already
+imported (this module never imports it) a span also enters a
+`jax.profiler.TraceAnnotation` of the same name and args, so under a
+profiler session (`ml_ops --profile`, the benchmark's `--trace 1`) the
+program's spans lie in the trace's host plane ON THE DEVICE TRACE'S
+CLOCK, and an idle gap of the device can be put down to the span the
+host was in.  What `annotate()` adds after the work (steps, sweeps,
+bytes) goes to the profiler as one short event `<name>.counts` just
+before the span closes.
+
 Instrumented library code must not pay when nobody is recording:
-`current_recorder()` is a contextvar that defaults to None, and
-`maybe_span(...)` collapses to a no-op context manager when no recorder
-is active, so hot paths (the scoring chunk loop, the fused-EM dispatch)
-carry spans at zero steady-state cost outside an instrumented run.
+`current_recorder()` is a contextvar that defaults to None, and with no
+recorder and no profiler session `maybe_span(...)` hands out one shared
+no-op span, so hot paths (the scoring chunk loop, the fused-EM dispatch)
+carry spans at the cost of a contextvar read and a flag check.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
 import math
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -70,13 +83,42 @@ def use_recorder(recorder):
         _ACTIVE.reset(token)
 
 
+def _trace_annotation():
+    """`jax.profiler.TraceAnnotation` if jax is already imported, else
+    None: no jax, no profiler, and this module stays free of the import."""
+    return getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+
+
+class _NoSpan:
+    """What `maybe_span` hands out when nothing listens: enters, takes
+    `annotate()` and leaves without a trace."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+    def annotate(self, **kw) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
 def maybe_span(name: str, **args):
-    """A span on the active recorder, or a no-op when none is active —
+    """A span on the active recorder; with none active, a span for the
+    profiler alone while a profiler session runs, else the shared no-op —
     what library call sites use so uninstrumented runs pay nothing."""
     rec = _ACTIVE.get()
-    if rec is None:
-        return contextlib.nullcontext()
-    return rec.span(name, **args)
+    if rec is not None:
+        return rec.span(name, **args)
+    annotation = _trace_annotation()
+    if annotation is None or not annotation.is_enabled():
+        return _NO_SPAN
+    return _Span(None, name, args)
 
 
 class Counter:
@@ -236,21 +278,31 @@ class Histogram:
 
 
 class _Span:
-    """One in-flight span; created by Recorder.span()."""
+    """One in-flight span; created by Recorder.span(), or by maybe_span
+    with `rec` None for the profiler alone."""
 
-    __slots__ = ("_rec", "name", "args", "start_ns", "depth", "tid")
+    __slots__ = ("_rec", "name", "args", "start_ns", "tid", "id", "parent",
+                 "root", "depth", "_counts", "_annotation")
 
     def __init__(self, rec, name: str, args: dict) -> None:
         self._rec = rec
         self.name = name
         self.args = args
         self.start_ns = 0
-        self.depth = 0
         self.tid = 0
+        self.id = self.parent = self.root = None
+        self.depth = 0
+        self._counts: dict = {}
+        self._annotation = None
 
     def __enter__(self):
         self.tid = threading.get_ident()
-        self.depth = self._rec._enter_depth()
+        if self._rec is not None:
+            self._rec._enter(self)
+        annotation = _trace_annotation()
+        if annotation is not None:
+            self._annotation = annotation(self.name, **self.args)
+            self._annotation.__enter__()
         self.start_ns = now_ns()
         return self
 
@@ -258,10 +310,19 @@ class _Span:
         """Attach more args mid-span (e.g. a result count discovered
         after the work)."""
         self.args.update(kw)
+        self._counts.update(kw)
 
     def __exit__(self, exc_type, exc, tb):
         dur = now_ns() - self.start_ns
-        self._rec._exit_depth()
+        if self._annotation is not None:
+            if self._counts and self._annotation.is_enabled():
+                with _trace_annotation()(self.name + ".counts",
+                                         **self._counts):
+                    pass
+            self._annotation.__exit__(exc_type, exc, tb)
+        if self._rec is None:
+            return False
+        self._rec._exit(self)
         if exc_type is not None:
             self.args.setdefault("error", repr(exc)[:200])
         self._rec._finish(self, dur)
@@ -285,19 +346,29 @@ class Recorder:
         self._journal = journal
         self._journal_spans = journal_spans and journal is not None
         self._tls = threading.local()
+        self._ids = itertools.count(1)
         self._t0_ns = now_ns()
 
     # -- spans -----------------------------------------------------------
     def span(self, name: str, **args) -> _Span:
         return _Span(self, name, args)
 
-    def _enter_depth(self) -> int:
-        d = getattr(self._tls, "depth", 0)
-        self._tls.depth = d + 1
-        return d
+    def _enter(self, span: _Span) -> None:
+        """Give the span its id and hang it under the span open on this
+        thread (the per-thread stack of ids; `depth` is its height)."""
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        span.id = next(self._ids)
+        span.parent = stack[-1] if stack else None
+        span.root = stack[0] if stack else span.id
+        span.depth = len(stack)
+        stack.append(span.id)
 
-    def _exit_depth(self) -> None:
-        self._tls.depth = max(0, getattr(self._tls, "depth", 1) - 1)
+    def _exit(self, span: _Span) -> None:
+        stack = getattr(self._tls, "stack", ())
+        if span.id in stack:            # also drops spans left open above it
+            del stack[stack.index(span.id):]
 
     def _finish(self, span: _Span, dur_ns: int) -> None:
         ev = {
@@ -305,6 +376,9 @@ class Recorder:
             "start_ns": span.start_ns,
             "dur_ns": dur_ns,
             "tid": span.tid,
+            "id": span.id,
+            "parent": span.parent,
+            "root": span.root,
             "depth": span.depth,
             "args": span.args,
         }
@@ -312,12 +386,17 @@ class Recorder:
             self.events.append(ev)
         self.histogram(f"span.{span.name}_s").observe(dur_ns / 1e9)
         if self._journal_spans:
+            # Spelled out: the journal's schema is read off this literal
+            # (analysis/schema.py).
             self._journal.append({
                 "kind": "span",
                 "name": span.name,
                 "mono_ns": span.start_ns,
                 "dur_ns": dur_ns,
                 "tid": span.tid,
+                "id": span.id,
+                "parent": span.parent,
+                "root": span.root,
                 "depth": span.depth,
                 "args": span.args,
             })

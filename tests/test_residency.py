@@ -823,18 +823,38 @@ def test_paged_fleet_live_stream_from_manifest(tmp_path, capsys):
     mpath = str(tmp_path / "fleet.json")
     with open(mpath, "w") as f:
         json.dump(manifest, f)
-    ipath = str(tmp_path / "events.csv")
-    with open(ipath, "w") as f:
-        for visit in range(2):
-            for lines in input_lines:
-                f.write("\n".join(lines[visit * 6:(visit + 1) * 6]))
-                f.write("\n")
+    # The stream arrives through a FIFO, one tenant's visit at a time
+    # with a pause after it: a LIVE stream.  Read from a file, all 36
+    # events are admitted within a millisecond and the stream closes
+    # before the pager thread has run; close() then resolves the
+    # still-paging tenants through the solo fallback and the ledger
+    # shows one promotion where it should show one per visit (seen on a
+    # loaded machine: the suite's other workers starve the pager).
+    import threading
+    import time
+
+    ipath = str(tmp_path / "events.fifo")
+    os.mkfifo(ipath)
+
+    def feed():
+        with open(ipath, "w") as f:
+            for visit in range(2):
+                for lines in input_lines:
+                    f.write("\n".join(lines[visit * 6:(visit + 1) * 6]))
+                    f.write("\n")
+                    f.flush()
+                    time.sleep(0.25)
+
+    feeder = threading.Thread(target=feed, daemon=True)
+    feeder.start()
     rc = ml_ops.main([
         "serve", "--fleet", mpath, "--input", ipath, "--no-plans",
         "--no-compilation-cache", "--device-score-min", "0",
         "--max-batch", "6", "--hot-tenants", "1",
         "--warm-tenants", "1",
     ])
+    feeder.join(timeout=30)
+    assert not feeder.is_alive()
     out = capsys.readouterr().out
     assert rc == 0
     end = next(json.loads(ln) for ln in out.splitlines()

@@ -304,11 +304,17 @@ def test_native_word_counts_emit_dns(tmp_path):
     _wc_parity(featurize_dns_sources([rows]), tmp_path)
 
 
-def test_native_lib_missing_symbol_degrades(tmp_path):
+def test_native_lib_missing_symbol_degrades(tmp_path, monkeypatch):
     """A prebuilt .so predating a newly added export (no compiler to
     rebuild) must degrade to the Python fallback (load() -> None), not
     crash the caller with AttributeError at symbol-configure time."""
+    from oni_ml_tpu import native_build
     from oni_ml_tpu.native_build import NativeLib
+
+    # A private registry (as tests/test_bringup.py does): the package's
+    # own would keep this throwaway library for every later test of the
+    # worker, and load_all() would name it.
+    monkeypatch.setattr(native_build, "_LIBRARIES", [])
 
     src = tmp_path / "t.cpp"
     src.write_text('extern "C" int foo() { return 1; }\n')
