@@ -26,7 +26,11 @@ data-parallel and vocab-sharded plans unchanged.
 
 from __future__ import annotations
 
+import functools
 import itertools
+import os
+import threading
+from collections import OrderedDict
 from contextlib import nullcontext
 from typing import Callable, NamedTuple, Sequence
 
@@ -39,12 +43,15 @@ from ..ops import estep
 from ..telemetry.spans import current_recorder, maybe_span
 
 
-# Which chunk impl the most recent run_chunk TRACE selected ("fast" |
-# "generic"; None before any trace).  Observability only — the two
-# impls are equivalence-pinned, so without this marker a regression
-# that silently stopped the fast path from ENGAGING (an eligibility
-# check drifting) would pass every correctness test while costing the
-# headline its glue win.  tests/test_fused.py pins engagement.
+# Which chunk impl the most recent run_chunk DISPATCH selected ("fast" |
+# "generic"; None before any dispatch).  Set by the runner wrapper from
+# `_chunk_plan`, the predicate the trace itself branches on, so it is
+# right for a fit that reuses a program and traces nothing.
+# Observability only — the two impls are equivalence-pinned, so without
+# this marker a regression that silently stopped the fast path from
+# ENGAGING (an eligibility check drifting) would pass every correctness
+# test while costing the headline its glue win.  tests/test_fused.py
+# pins engagement.
 LAST_CHUNK_PLAN = None
 
 
@@ -89,6 +96,24 @@ def stack_batches(
     return StackedGroups(tuple(arrays), tuple(slots))
 
 
+@functools.partial(
+    jax.jit, static_argnames=("num_terms", "width", "dtype", "wmajor"))
+def densify_stack(widx, cnts, *, num_terms, width, dtype, wmajor):
+    """One stacked group's token lists [NB,B,L] -> dense counts [NB,B,W]
+    ([NB,W,B] with `wmajor`).  ONE jitted function for the process, the
+    layout its static arguments: a group shape a previous fit densified
+    dispatches the executable jax kept for it, where a fresh `jax.jit`
+    per group and fit was traced, lowered and fetched again every
+    time."""
+    from ..ops import dense_estep
+
+    def one(w, c):
+        d = dense_estep.densify(w, c, num_terms, width=width, dtype=dtype)
+        return d.T if wmajor else d
+
+    return jax.vmap(one)(widx, cnts)
+
+
 def densify_groups(
     groups: StackedGroups, num_terms: int, wmajor: bool = False,
     put: Callable | None = None, width: int | None = None,
@@ -105,21 +130,31 @@ def densify_groups(
     dense path exists to avoid).  `width` overrides the dense width (the
     vocab-sharded XLA path matches it to the sharded beta width);
     `dtype` is the storage dtype (dense_estep.corpus_dtype — bf16 when
-    exact, halving the corpus' HBM footprint and streaming)."""
-    from ..ops import dense_estep
-
-    def one(w, c):
-        d = dense_estep.densify(w, c, num_terms, width=width, dtype=dtype)
-        return d.T if wmajor else d
-
+    exact, halving the corpus' HBM footprint and streaming).  The span
+    says whether any group's program had to be `built` or all were
+    `reused` from an earlier call of this process."""
     arrays = []
     with maybe_span("fit.densify", groups=len(groups.arrays)) as sp:
+        built0 = densify_stack._cache_size()
         for widx, cnts, mask in groups.arrays:
-            dense = jax.jit(jax.vmap(one))(widx, cnts)
+            dense = densify_stack(widx, cnts, num_terms=num_terms,
+                                  width=width, dtype=dtype, wmajor=wmajor)
             if put is not None:  # e.g. shard the doc axis over a mesh
                 dense = put(dense)
             arrays.append((dense, mask))
-        sp.annotate(dense_bytes=sum(d.nbytes for d, _ in arrays))
+        # The span closes when the dense corpus is on the device.  A fit
+        # whose programs are reused enqueues its chunk program
+        # milliseconds later: without this wait that program's buffers
+        # are allocated while the sparse stacks above are still held
+        # (22 MB more at the peak on the v5e, PERF.md), and the device's
+        # densify time passes for the first host sync's.  A fit that
+        # builds its programs waited here anyway, seconds, in its trace.
+        jax.block_until_ready([d for d, _ in arrays])
+        sp.annotate(
+            dense_bytes=sum(d.nbytes for d, _ in arrays),
+            program=("built" if densify_stack._cache_size() > built0
+                     else "reused"),
+        )
     return StackedGroups(tuple(arrays), groups.batch_slots)
 
 
@@ -218,8 +253,6 @@ def compact_stack_batches(
     suff-stats there and the scatter-back adds zeros to word 0.
     Token ids remap via searchsorted into the batch's sorted unique
     set (exact: every token id is a member)."""
-    from ..ops import dense_estep
-
     groups: dict[tuple, list[int]] = {}
     for i, b in enumerate(batches):
         groups.setdefault(b.word_idx.shape, []).append(i)
@@ -243,15 +276,12 @@ def compact_stack_batches(
                 vm[: len(u)] = u
                 vmaps.append(vm)
 
-            def one(w, c):
-                d = dense_estep.densify(w, c, wc, width=wc, dtype=corpus_store)
-                return d.T if plan.wmajor else d
-
             host = (np.stack(local_idx), np.stack(cnts), np.stack(masks),
                     np.stack(vmaps))
             h2d_bytes += sum(a.nbytes for a in host)
-            dense = jax.jit(jax.vmap(one))(
-                jnp.asarray(host[0]), jnp.asarray(host[1])
+            dense = densify_stack(
+                jnp.asarray(host[0]), jnp.asarray(host[1]), num_terms=wc,
+                width=wc, dtype=corpus_store, wmajor=plan.wmajor,
             )
             arrays.append((put(dense), put(host[2]), put(host[3])))
             slots.append(tuple(idxs))
@@ -440,7 +470,116 @@ class ChunkResult(NamedTuple):
                                 # batches of EStepResult.doc_sweeps
 
 
-def make_chunk_runner(
+# -- the chunk program, kept across fits -------------------------------------
+
+# Plan-cache knobs the kernels' block picks look up while the chunk program
+# is traced (dense_estep.pick_block / pick_block_w, sparse_estep.pick_block).
+_TRACED_PLAN_KNOBS = frozenset(
+    ("dense_estep_block", "dense_estep_block_w", "sparse_estep_bb"))
+
+_PROGRAMS_MAX = 8
+_PROGRAMS: "OrderedDict[tuple, Callable]" = OrderedDict()
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def _traced_plan_blocks() -> tuple:
+    """The active plan store's entries for `_TRACED_PLAN_KNOBS`: what the
+    kernels' block picks can read at trace time (a multi-host run reads
+    none: dense_estep._planned_block)."""
+    from .. import plans
+
+    try:
+        store = plans.current_store() if jax.process_count() == 1 else None
+        entries = store.entries() if store is not None else ()
+    except Exception:   # an unreadable store reads as empty: lookup_value
+        entries = ()
+    return tuple(sorted(
+        (e.knob, e.backend, e.shape, e.value) for e in entries
+        if e.knob in _TRACED_PLAN_KNOBS))
+
+
+def _program_key(traced: dict) -> "tuple | None":
+    """The chunk program's identity, from everything its trace reads.
+
+    Closure values (`traced`, the arguments of `_build_chunk_program`):
+    the scalars with their types, `compiler_options` as sorted items, the
+    three step callables by identity (the program closes over them, so an
+    id cannot be reused while its entry lives).  Read at trace time from
+    outside the arguments: `ONI_ML_TPU_ESTEP` (estep.resolve_backend),
+    `jax.default_backend()` (the kernels' interpret flag and the engine
+    gates) and the plan cache's kernel blocks (`_traced_plan_blocks`,
+    which covers `ONI_ML_TPU_PLANS`, `ONI_ML_TPU_PLAN_CACHE` and
+    `plans.use_store`).  jax's own configuration (x64, matmul precision)
+    is `jax.jit`'s to key, inside the kept function; the groups' shapes
+    and dtypes are its operands.  `ONI_ML_TPU_ESTEP_ENGINE` and the
+    engine crossover are read by the driver on the host, before it
+    chooses these arguments.  What a trace only SAYS is not keyed: the
+    `estep_dispatch` log line and journal record (estep._report_dispatch)
+    appear when a program is built, not when it is reused.
+
+    None when a value cannot be hashed: the caller builds a fresh
+    program, as every call did before programs were kept."""
+    fns = ("e_step_fn", "m_step_fn", "dense_e_step_fn")
+    options = traced["compiler_options"]
+    key = (
+        tuple((name, type(val), val) for name, val in sorted(traced.items())
+              if name not in fns and name != "compiler_options"),
+        None if options is None else tuple(sorted(options.items())),
+        tuple(id(traced[name]) for name in fns),
+        os.environ.get("ONI_ML_TPU_ESTEP", "auto"),
+        jax.default_backend(),
+        _traced_plan_blocks(),
+    )
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
+def _chunk_program(traced: dict) -> "tuple[Callable, bool]":
+    """(the jitted chunk program for `traced`, whether this process had
+    built it already).  A small LRU under a lock: fits on threads (the
+    refresh worker, a co-scheduled fit) asking for one program at once
+    build one.  It holds `jax.jit` objects and the Python they close over
+    and nothing of a fit: no array, batch or trainer.  An evicted entry
+    drops its `jax.jit`, and its executables go with it."""
+    key = _program_key(traced)
+    if key is None:
+        return _build_chunk_program(**traced), False
+    with _PROGRAMS_LOCK:
+        jitted = _PROGRAMS.get(key)
+        if jitted is not None:
+            _PROGRAMS.move_to_end(key)
+            return jitted, True
+        jitted = _PROGRAMS[key] = _build_chunk_program(**traced)
+        while len(_PROGRAMS) > _PROGRAMS_MAX:
+            _PROGRAMS.popitem(last=False)
+    return jitted, False
+
+
+def clear_programs() -> None:
+    """Forget every kept chunk program: the next fit builds its own, as a
+    new process would."""
+    with _PROGRAMS_LOCK:
+        _PROGRAMS.clear()
+
+
+def _chunk_plan(groups, m_step_fn, dense_e_step_fn) -> str:
+    """"fast" | "generic": the chunk impl these groups take.  The one
+    predicate both the trace (run_chunk_dispatch) and the runner's
+    LAST_CHUNK_PLAN read; the fast path is described at
+    run_chunk_impl_fast."""
+    single_dense = (
+        m_step_fn is estep.m_step and dense_e_step_fn is None
+        and len(groups) == 1
+        and len(groups[0]) == 2          # (C, mask): full-V dense
+        and groups[0][0].shape[0] == 1   # one stacked batch
+    )
+    return "fast" if single_dense else "generic"
+
+
+def _build_chunk_program(
     *,
     num_docs: int,
     num_topics: int,
@@ -450,31 +589,23 @@ def make_chunk_runner(
     var_tol: float,
     em_tol: float,
     estimate_alpha: bool,
-    e_step_fn: Callable | None = None,
-    m_step_fn: Callable | None = None,
-    compiler_options: dict | None = None,
-    dense_wmajor: bool = False,
-    warm_start: bool = False,
-    dense_e_step_fn: Callable | None = None,
-    dense_precision: str = "f32",
-    alpha_max_iters: int = 100,
-    yield_hook: Callable | None = None,
+    e_step_fn: Callable,
+    m_step_fn: Callable,
+    compiler_options: dict | None,
+    dense_wmajor: bool,
+    warm_start: bool,
+    dense_e_step_fn: Callable | None,
+    dense_precision: str,
+    alpha_max_iters: int,
 ):
-    """Build the jitted `run_chunk(log_beta, alpha, ll_prev, groups,
-    n_steps)` executing up to min(chunk, n_steps) EM iterations on device.
-
-    `n_steps` is a traced scalar, so checkpoint boundaries and the final
-    partial chunk reuse the single compiled program.
-
-    `yield_hook` (a context-manager factory, e.g.
-    `serving.CoScheduler.train_chunk`) makes each chunk dispatch
-    PREEMPTIBLE: the runner enters one hook slot per dispatch, so a
-    co-resident serving plane wins the next dispatch slot at every
-    chunk boundary — the fused chunk is the natural preemption grain.
-    """
+    """The PROGRAM half of `make_chunk_runner`: `jax.jit` of
+    `run_chunk_dispatch(log_beta, alpha, ll_prev, groups, n_steps,
+    gammas_in, have_prev) -> ChunkResult` and everything it closes over.
+    Every argument is read by the trace and is part of `_program_key`; an
+    argument added here is keyed without further ado, a read of anything
+    else at trace time has to be added to the key by hand."""
     from .lda import update_alpha  # local import: lda.py imports this module
 
-    m_fn = m_step_fn or estep.m_step
     k, v = num_topics, num_terms
     # The E-step callable itself now lives inside the accumulator (the
     # shared partial-stats path the distributed driver also jits).
@@ -491,7 +622,7 @@ def make_chunk_runner(
             log_beta, alpha, groups, gammas_prev, warm
         )
         with jax.named_scope("mstep"):
-            new_beta = m_fn(total_ss)
+            new_beta = m_step_fn(total_ss)
         new_alpha = (
             update_alpha(total_ass, alpha, num_docs, k,
                          max_iters=alpha_max_iters)
@@ -586,7 +717,7 @@ def make_chunk_runner(
             log_beta, alpha, ll_prev, lls, step, converged, gammas, vis, sws
         )
 
-    # -- single-dense-group fast path ------------------------------------
+    # -- single-dense-group fast path (`_chunk_plan`) ---------------------
     # The production/bench common case (one full-V dense group, stock
     # M-step, no mesh override) carries exp(beta) in the kernel's padded
     # [K, W] layout across EM iterations instead of log-space [K, V]:
@@ -603,16 +734,6 @@ def make_chunk_runner(
     # clamps to LOG_ZERO — a deliberate floor on probabilities ~1e-44,
     # covered by the 1e-5-rtol equivalence pins (tests/test_fused.py).
     # Entries with exactly zero mass pin to LOG_ZERO in both paths.
-    dense_fast_ok = m_fn is estep.m_step and dense_e_step_fn is None
-
-    def _is_single_dense(groups) -> bool:
-        return (
-            dense_fast_ok
-            and len(groups) == 1
-            and len(groups[0]) == 2          # (C, mask): full-V dense
-            and groups[0][0].shape[0] == 1   # one stacked batch
-        )
-
     def run_chunk_impl_fast(log_beta, alpha, ll_prev, groups, n_steps,
                             gammas_in=None, have_prev=None) -> ChunkResult:
         from jax.scipy.special import gammaln
@@ -679,20 +800,67 @@ def make_chunk_runner(
 
     def run_chunk_dispatch(log_beta, alpha, ll_prev, groups, n_steps,
                            gammas_in=None, have_prev=None) -> ChunkResult:
-        global LAST_CHUNK_PLAN
-        if _is_single_dense(groups):
-            LAST_CHUNK_PLAN = "fast"
-            return run_chunk_impl_fast(
-                log_beta, alpha, ll_prev, groups, n_steps,
-                gammas_in=gammas_in, have_prev=have_prev,
-            )
-        LAST_CHUNK_PLAN = "generic"
-        return run_chunk_impl(
-            log_beta, alpha, ll_prev, groups, n_steps,
-            gammas_in=gammas_in, have_prev=have_prev,
-        )
+        impl = (run_chunk_impl_fast
+                if _chunk_plan(groups, m_step_fn, dense_e_step_fn) == "fast"
+                else run_chunk_impl)
+        return impl(log_beta, alpha, ll_prev, groups, n_steps,
+                    gammas_in=gammas_in, have_prev=have_prev)
 
-    jitted = jax.jit(run_chunk_dispatch, compiler_options=compiler_options)
+    return jax.jit(run_chunk_dispatch, compiler_options=compiler_options)
+
+
+def make_chunk_runner(
+    *,
+    num_docs: int,
+    num_topics: int,
+    num_terms: int,
+    chunk: int,
+    var_max_iters: int,
+    var_tol: float,
+    em_tol: float,
+    estimate_alpha: bool,
+    e_step_fn: Callable | None = None,
+    m_step_fn: Callable | None = None,
+    compiler_options: dict | None = None,
+    dense_wmajor: bool = False,
+    warm_start: bool = False,
+    dense_e_step_fn: Callable | None = None,
+    dense_precision: str = "f32",
+    alpha_max_iters: int = 100,
+    yield_hook: Callable | None = None,
+):
+    """Build `run_chunk(log_beta, alpha, ll_prev, groups, n_steps)`
+    executing up to min(chunk, n_steps) EM iterations on device.
+
+    Two halves.  The PROGRAM (`_build_chunk_program`) is the `jax.jit` of
+    the chunk loop; a process builds it once per distinct program
+    (`_chunk_program`), so a later fit that asks for the same one
+    dispatches the executable the earlier fit traced and compiled, and
+    its first dispatch is an enqueue like the others.  The RUNNER, built
+    here on every call, is the light host wrapper around it: spans, the
+    preemption slot, the roofline harvest.  `runner.program` says which
+    it got: "reused" or "built".
+
+    `n_steps` is a traced scalar, so checkpoint boundaries and the final
+    partial chunk reuse the single compiled program.
+
+    `yield_hook` (a context-manager factory, e.g.
+    `serving.CoScheduler.train_chunk`) makes each chunk dispatch
+    PREEMPTIBLE: the runner enters one hook slot per dispatch, so a
+    co-resident serving plane wins the next dispatch slot at every
+    chunk boundary — the fused chunk is the natural preemption grain.
+    """
+    e_fn = e_step_fn or estep.e_step
+    m_fn = m_step_fn or estep.m_step
+    jitted, reused = _chunk_program(dict(
+        num_docs=num_docs, num_topics=num_topics, num_terms=num_terms,
+        chunk=chunk, var_max_iters=var_max_iters, var_tol=var_tol,
+        em_tol=em_tol, estimate_alpha=estimate_alpha, e_step_fn=e_fn,
+        m_step_fn=m_fn, compiler_options=compiler_options,
+        dense_wmajor=dense_wmajor, warm_start=warm_start,
+        dense_e_step_fn=dense_e_step_fn, dense_precision=dense_precision,
+        alpha_max_iters=alpha_max_iters,
+    ))
     dispatch_no = itertools.count()
 
     def runner(log_beta, alpha, ll_prev, groups, n_steps, *args, **kw):
@@ -700,11 +868,14 @@ def make_chunk_runner(
         `em.run_chunk` span (telemetry/spans.py: recorded under a
         Recorder, in the profiler's trace under a profiler session, a
         no-op otherwise).  JAX dispatch is asynchronous, so the span
-        measures ENQUEUE — the runner's `first` dispatch holds the chunk
-        program's trace, lowering and cache fetch, the later ones the
+        measures ENQUEUE — this runner's `first` dispatch also holds the
+        chunk program's trace, lowering and cache fetch when the program
+        was `built` for it (or meets new group shapes), the others the
         per-dispatch cost the chunked driver exists to amortize — not
         device compute; the driver's host-sync span covers the blocking
         side."""
+        global LAST_CHUNK_PLAN
+        LAST_CHUNK_PLAN = _chunk_plan(groups, m_fn, dense_e_step_fn)
         slot = yield_hook() if yield_hook is not None else nullcontext()
         with slot, maybe_span("em.run_chunk", chunk=chunk,
                               n_steps=int(n_steps)
@@ -738,4 +909,5 @@ def make_chunk_runner(
     runner.alpha_max_iters = alpha_max_iters
     runner.chunk = chunk
     runner.jitted = jitted  # AOT access (tools/config4_hbm_probe.lower)
+    runner.program = "reused" if reused else "built"
     return runner

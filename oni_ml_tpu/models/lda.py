@@ -1321,7 +1321,7 @@ class LDATrainer:
                 width=dense_width, dtype=corpus_store,
             )
 
-        with maybe_span("fit.runner"):
+        with maybe_span("fit.runner") as sp:
             # The devices that hold corpus shards (on a mesh, one
             # distinct slice per data shard).
             corpus = groups.arrays[0][0]
@@ -1352,6 +1352,9 @@ class LDATrainer:
                 alpha_max_iters=cfg.alpha_max_iters,
                 yield_hook=self.yield_hook,
             )
+            # "reused": an earlier fit of this process built the chunk
+            # program, and this fit's first dispatch traces nothing.
+            sp.annotate(program=getattr(run_chunk, "program", None))
             ll_prev_dev = jnp.asarray(
                 np.nan if ll_prev is None else ll_prev, dtype
             )

@@ -144,6 +144,14 @@ def test_day_serve_and_repeat_legs(tmp_path):
     assert last["device_batches"] >= 1
     assert last["stream_end"]["events_scored"] == 512
 
+    # The smoke runs this leg in a new process, which holds no program
+    # of the first day's and has only the compilation cache to find.
+    import jax
+
+    from oni_ml_tpu.models import fused
+
+    fused.clear_programs()
+    jax.clear_caches()
     repeat = chip_smoke.leg_day(work, TINY, name="day_repeat",
                                 tol=TINY.repeat_tol)
     assert repeat["flagged"] > 0                  # the sort check read rows

@@ -326,3 +326,341 @@ def test_host_sync_every_bounds_dispatch_without_changing_results(
         [ll for ll, _ in base.likelihoods], rtol=1e-6,
     )
     np.testing.assert_allclose(synced.log_beta, base.log_beta, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# A process keeps the programs its fits build (fused._chunk_program,
+# fused.densify_stack)
+# ---------------------------------------------------------------------------
+
+DENSE_CFG = dict(num_topics=4, alpha_init=2.5, seed=3, em_max_iters=5,
+                 em_tol=0.0, batch_size=16, min_bucket_len=4,
+                 dense_em="on")
+
+
+def _recorded_fit(corpus, cfg, yield_hook=None):
+    """One fit through the trainer under its own Recorder ->
+    (result, its spans in start order)."""
+    from oni_ml_tpu.telemetry import spans
+
+    batches = make_batches(corpus, batch_size=cfg.batch_size,
+                           min_bucket_len=cfg.min_bucket_len)
+    rec = spans.Recorder()
+    with spans.use_recorder(rec):
+        result = LDATrainer(
+            cfg, num_terms=corpus.num_terms, yield_hook=yield_hook,
+        ).fit(batches, corpus.num_docs)
+    return result, sorted(rec.events, key=lambda e: e["start_ns"])
+
+
+def _span_args(events, name):
+    return [e["args"] for e in events if e["name"] == name]
+
+
+def _assert_same_fit(a, b):
+    np.testing.assert_array_equal(a.log_beta, b.log_beta)
+    np.testing.assert_array_equal(a.gamma, b.gamma)
+    assert a.alpha == b.alpha
+    assert a.likelihoods == b.likelihoods
+
+
+def test_second_fit_reuses_the_programs_of_the_first(problem):
+    """Two fits of one corpus and config in one process: the second asks
+    jax for no executable and traces nothing, says so on its spans, and
+    is the first fit (and a fit with nothing kept) to the bit."""
+    from oni_ml_tpu.models import fused
+    from oni_ml_tpu.plans import warmup
+    from oni_ml_tpu.telemetry import spans
+
+    warmup.setup_compilation_cache()
+    cfg = LDAConfig(**DENSE_CFG)
+    fused.clear_programs()
+    first = train_corpus(problem, cfg)
+
+    fused.LAST_CHUNK_PLAN = None
+    before = warmup.compile_counts()
+    rec = spans.Recorder()
+    with spans.use_recorder(rec):
+        second = train_corpus(problem, cfg)
+    delta = warmup.counts_delta(before)
+    assert delta["compile_requests"] == 0 and delta["traces"] == 0
+    assert delta["trace_s"] == 0.0
+    _assert_same_fit(second, first)
+    events = sorted(rec.events, key=lambda e: e["start_ns"])
+    assert [a["program"] for a in _span_args(events, "fit.runner")] == [
+        "reused"]
+    assert [a["program"] for a in _span_args(events, "fit.densify")] == [
+        "reused"]
+    dispatches = _span_args(events, "em.run_chunk")
+    assert [a["first"] for a in dispatches] == [True] + [False] * (
+        len(dispatches) - 1)
+    # Several dense groups here: the generic impl, known without a trace.
+    assert fused.LAST_CHUNK_PLAN == "generic"
+
+    fused.clear_programs()
+    rec = spans.Recorder()
+    with spans.use_recorder(rec):
+        fresh = train_corpus(problem, cfg)
+    assert [a["program"] for a in _span_args(rec.events, "fit.runner")] == [
+        "built"]
+    _assert_same_fit(fresh, first)
+
+
+def test_fast_path_marker_is_right_after_a_reused_fit(problem, monkeypatch):
+    """LAST_CHUNK_PLAN is set at dispatch: a fit that reuses the fast
+    program says "fast" though nothing was traced, whatever ran between."""
+    from oni_ml_tpu.models import fused
+
+    cfg = LDAConfig(num_topics=4, alpha_init=2.5, seed=3, em_max_iters=2,
+                    em_tol=0.0, fused_em_chunk=2, batch_size=64,
+                    min_bucket_len=64)
+    monkeypatch.setenv("ONI_ML_TPU_ESTEP", "dense")
+    train_corpus(problem, cfg)
+    run(problem, em_max_iters=2, em_tol=0.0, fused_em_chunk=2)
+    assert fused.LAST_CHUNK_PLAN == "generic"
+    train_corpus(problem, cfg)
+    assert fused.LAST_CHUNK_PLAN == "fast"
+
+
+def _sparse_chunk_problem(seed=7, k=3, v=40, b=8, l=6):
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    noise = rng.uniform(size=(k, v)) + 1.0 / v
+    log_beta = jnp.asarray(
+        np.log(noise / noise.sum(-1, keepdims=True)), jnp.float32)
+    groups = ((
+        jnp.asarray(rng.integers(0, v, size=(2, b, l)), jnp.int32),
+        jnp.asarray(rng.integers(1, 4, size=(2, b, l)), jnp.float32),
+        jnp.ones((2, b), jnp.float32),
+    ),)
+    kw = dict(num_docs=2 * b, num_topics=k, num_terms=v, chunk=3,
+              var_max_iters=6, var_tol=1e-6, em_tol=1e-9,
+              estimate_alpha=True, warm_start=True, alpha_max_iters=8)
+    return log_beta, groups, kw
+
+
+def _run_chunk(runner, log_beta, groups):
+    import jax.numpy as jnp
+
+    res = runner(log_beta, jnp.float32(2.5), jnp.float32(np.nan), groups, 3)
+    return [np.asarray(x) for x in (res.log_beta, res.alpha, res.lls,
+                                    res.gammas[0])]
+
+
+def _slow_m_step(ss):
+    from oni_ml_tpu.ops import estep
+
+    return estep.m_step(ss)
+
+
+@pytest.mark.parametrize("change", [
+    dict(num_docs=17), dict(em_tol=1e-3), dict(var_max_iters=5),
+    dict(alpha_max_iters=100), dict(warm_start=False),
+    dict(estimate_alpha=False),
+    dict(compiler_options={"xla_embed_ir_in_executable": True}),
+    dict(m_step_fn=_slow_m_step), dict(env="xla"),
+], ids=lambda c: next(iter(c)))
+def test_program_key_builds_for_anything_the_trace_reads(change, monkeypatch):
+    """Each value the chunk program's trace reads is part of its key: a
+    change builds a new program, and that program computes what a build
+    with nothing kept computes."""
+    from oni_ml_tpu.models import fused
+
+    log_beta, groups, kw = _sparse_chunk_problem()
+    fused.clear_programs()
+    base = fused.make_chunk_runner(**kw)
+    assert base.program == "built"
+    assert fused.make_chunk_runner(**kw).jitted is base.jitted
+
+    change = dict(change)
+    if "env" in change:
+        monkeypatch.setenv("ONI_ML_TPU_ESTEP", change.pop("env"))
+    changed = fused.make_chunk_runner(**dict(kw, **change))
+    assert changed.program == "built"
+    assert changed.jitted is not base.jitted
+    again = fused.make_chunk_runner(**dict(kw, **change))
+    assert again.program == "reused" and again.jitted is changed.jitted
+    got = _run_chunk(changed, log_beta, groups)
+
+    fused.clear_programs()
+    fresh = fused.make_chunk_runner(**dict(kw, **change))
+    assert fresh.program == "built" and fresh.jitted is not changed.jitted
+    for a, b in zip(got, _run_chunk(fresh, log_beta, groups)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("change", [
+    dict(seed=4), dict(em_max_iters=3), dict(host_sync_every=2),
+    dict(yield_hook=True),
+], ids=lambda c: next(iter(c)))
+def test_program_key_reuses_across_what_the_trace_never_sees(problem, change):
+    """The seed, the iteration cap, the sync cadence and the preemption
+    hook are not in the traced program: a fit that differs in one of them
+    reuses it, and still computes its own result."""
+    from contextlib import nullcontext
+
+    from oni_ml_tpu.models import fused
+
+    fused.clear_programs()
+    _, events = _recorded_fit(problem, LDAConfig(**DENSE_CFG))
+    assert _span_args(events, "fit.runner")[0]["program"] == "built"
+
+    change = dict(change)
+    slots = []
+
+    def hook():
+        slots.append(1)
+        return nullcontext()
+
+    yield_hook = hook if change.pop("yield_hook", False) else None
+    cfg = LDAConfig(**dict(DENSE_CFG, **change))
+    got, events = _recorded_fit(problem, cfg, yield_hook=yield_hook)
+    assert _span_args(events, "fit.runner")[0]["program"] == "reused"
+    assert len(slots) == (len(_span_args(events, "em.run_chunk"))
+                          if yield_hook else 0)
+
+    fused.clear_programs()
+    want, events = _recorded_fit(problem, cfg)
+    assert _span_args(events, "fit.runner")[0]["program"] == "built"
+    _assert_same_fit(got, want)
+
+
+def test_unhashable_argument_builds_a_fresh_program_each_time():
+    """A value the key cannot hash is no error: the call builds its own
+    program, as every call once did, and keeps nothing."""
+    import jax.numpy as jnp
+
+    from oni_ml_tpu.models import fused
+
+    log_beta, groups, kw = _sparse_chunk_problem()
+    fused.clear_programs()
+    want = _run_chunk(fused.make_chunk_runner(**kw), log_beta, groups)
+    kept = len(fused._PROGRAMS)
+    kw["em_tol"] = jnp.float32(kw["em_tol"])      # a jax array: no hash
+    a, b = fused.make_chunk_runner(**kw), fused.make_chunk_runner(**kw)
+    assert a.program == b.program == "built" and a.jitted is not b.jitted
+    assert len(fused._PROGRAMS) == kept
+    for x, y in zip(_run_chunk(a, log_beta, groups), want):
+        np.testing.assert_array_equal(x, y)
+
+
+def test_program_table_is_bounded_and_keeps_nothing_of_a_fit(
+        problem, monkeypatch):
+    """More distinct programs than the table holds leave exactly its size,
+    the oldest gone; a fit that has returned leaves no array on the device
+    and no batch or trainer alive; two threads asking for one program at
+    once build one, and both fits are the serial fit."""
+    import gc
+    import threading
+    import weakref
+
+    import jax
+
+    from oni_ml_tpu.models import fused, lda
+
+    _, _, kw = _sparse_chunk_problem()
+    fused.clear_programs()
+    for n in range(fused._PROGRAMS_MAX + 3):
+        assert fused.make_chunk_runner(
+            **dict(kw, num_docs=100 + n)).program == "built"
+    assert len(fused._PROGRAMS) == fused._PROGRAMS_MAX
+    assert fused.make_chunk_runner(
+        **dict(kw, num_docs=100 + n)).program == "reused"
+    assert fused.make_chunk_runner(**dict(kw, num_docs=100)).program == "built"
+    assert len(fused._PROGRAMS) == fused._PROGRAMS_MAX
+
+    # -- retention --------------------------------------------------------
+    cfg = LDAConfig(**DENSE_CFG)
+    fused.clear_programs()
+    serial = train_corpus(problem, cfg)      # builds; its constants stay
+    gc.collect()
+    live0 = len(jax.live_arrays())
+    seen = []
+    real_batches, real_init = lda.make_batches, lda.LDATrainer.__init__
+
+    def spy_batches(*a, **k):
+        out = real_batches(*a, **k)
+        seen.extend(weakref.ref(b) for b in out)
+        return out
+
+    def spy_init(self, *a, **k):
+        seen.append(weakref.ref(self))
+        real_init(self, *a, **k)
+
+    monkeypatch.setattr(lda, "make_batches", spy_batches)
+    monkeypatch.setattr(lda.LDATrainer, "__init__", spy_init)
+    result = train_corpus(problem, cfg)
+    monkeypatch.undo()
+    assert len(seen) > 2 and len(fused._PROGRAMS) == 1
+    _assert_same_fit(result, serial)
+    del result
+    gc.collect()
+    assert len(jax.live_arrays()) == live0
+    assert [r() for r in seen] == [None] * len(seen)
+
+    # -- two threads, one program -----------------------------------------
+    fused.clear_programs()
+    gate = threading.Barrier(2)
+    out = {}
+
+    def fit(i):
+        gate.wait()
+        out[i] = _recorded_fit(problem, cfg)
+
+    threads = [threading.Thread(target=fit, args=(i,)) for i in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    assert len(fused._PROGRAMS) == 1
+    assert sorted(_span_args(out[i][1], "fit.runner")[0]["program"]
+                  for i in range(2)) == ["built", "reused"]
+    for i in range(2):
+        _assert_same_fit(out[i][0], serial)
+
+
+def test_program_table_under_many_threads():
+    """More threads than cores asking for a few programs at once, the
+    interpreter switching as often as it can: each program is built once
+    and every other asker gets that one."""
+    import os
+    import sys
+    import threading
+
+    from oni_ml_tpu.models import fused
+
+    _, _, kw = _sparse_chunk_problem()
+    keys = range(fused._PROGRAMS_MAX // 2)
+    workers = 4 * (os.cpu_count() or 4)
+    fused.clear_programs()
+    gate = threading.Barrier(workers)
+    got = [[] for _ in range(workers)]
+
+    def ask(i):
+        gate.wait()
+        for _ in range(20):
+            for n in keys:
+                r = fused.make_chunk_runner(**dict(kw, num_docs=200 + n))
+                got[i].append((n, r.program, r.jitted))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=ask, args=(i,))
+                   for i in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    asked = [x for per in got for x in per]
+    assert len(asked) == workers * 20 * len(keys)
+    for n in keys:
+        mine = [(tag, jitted) for m, tag, jitted in asked if m == n]
+        assert sum(tag == "built" for tag, _ in mine) == 1
+        assert len({id(jitted) for _, jitted in mine}) == 1
+    assert len(fused._PROGRAMS) == len(keys)
