@@ -149,9 +149,15 @@ class LDAConfig:
     # there emulates the TPU's input truncation instead.  The
     # suff-stats / ELBO tail pass always runs full-width off the
     # converged gamma.  bf16 mode additionally STORES the densified
-    # corpus bf16 whenever every count is <= 256 (exact in bf16's 8
-    # significand bits; ops/dense_estep.corpus_dtype) — halving the
-    # corpus' per-iteration HBM streaming with bit-identical results.
+    # corpus bf16 whenever every densified cell is <= 256 (exact in
+    # bf16's 8 significand bits; ops/dense_estep.corpus_dtype) —
+    # halving the corpus' per-iteration HBM streaming with
+    # bit-identical results.  The gate
+    # (ops/dense_estep.corpus_store_dtype) is exact and reads only what
+    # can change its answer: at "f32" no token; at "bf16" the largest
+    # raw count (over 256: f32), then every document's sum (all
+    # <= 256: bf16), then the per-(doc, word) sums of the documents in
+    # between, batch by batch up to the first cell over 256.
     dense_precision: str = "f32"
     # Store the dense corpus transposed ([W, B]) so the gamma-update
     # matmul's small-K output axis pads to the 8-sublane granularity

@@ -1109,15 +1109,10 @@ class LDATrainer:
         if mode != "on" and jax.default_backend() != "tpu":
             return None
         cfg = self.config
-        cell_max = max(
-            dense_estep.max_dense_cell(b.word_idx, b.counts)
-            for b in batches
-        )
-        # Cache for _fused_loop's corpus_store derivation: this is a
-        # full O(tokens) host pass the compact path must not pay twice.
-        self._compact_cell_max = cell_max
+        # The storage gate; _fused_loop asks it again for the dtype
+        # itself (free at f32: no token is read).
         itemsize = jnp.dtype(
-            dense_estep.corpus_dtype(cell_max, cfg.dense_precision)
+            dense_estep.corpus_store_dtype(batches, cfg.dense_precision)[0]
         ).itemsize
         plan = fused.plan_compact(
             batches, cfg.num_topics, cfg.dense_precision,
@@ -1176,7 +1171,6 @@ class LDATrainer:
         with maybe_span("fit.plan", batches=len(batches)) as sp:
             compiler_options = None
             use_dense = self._use_dense(batches)
-            self._compact_cell_max = None  # set by _plan_compact's scan
             compact = None if use_dense else self._plan_compact(batches)
             use_wmajor = False
             dense_e_fn = None
@@ -1184,6 +1178,7 @@ class LDATrainer:
             dense_mesh = None
             dense_width = None
             corpus_store = None
+            cell_scan, scan_tokens = "none", 0
             kibs = []
             if use_dense or compact is not None:
                 from ..ops import dense_estep
@@ -1193,15 +1188,11 @@ class LDATrainer:
                 # with bit-identical results.  The gate bounds the
                 # DENSIFIED cells (duplicate (doc, word) tokens sum — the
                 # DUPFACTOR feedback path makes ~1000-count cells out of
-                # count-1 tokens), not the raw counts.
-                cell_max = self._compact_cell_max
-                if cell_max is None:
-                    cell_max = max(
-                        dense_estep.max_dense_cell(b.word_idx, b.counts)
-                        for b in batches
-                    )
-                corpus_store = dense_estep.corpus_dtype(
-                    cell_max, cfg.dense_precision)
+                # count-1 tokens), not the raw counts; at f32 it reads
+                # no token.
+                corpus_store, cell_scan, scan_tokens = (
+                    dense_estep.corpus_store_dtype(
+                        batches, cfg.dense_precision))
             if compact is not None:
                 # Compact-vocab dense groups are built straight from the
                 # host batches (no sparse stacked upload to discard).
@@ -1323,7 +1314,8 @@ class LDATrainer:
                 }))
             else:
                 kernel = "custom"
-            sp.annotate(kernel=kernel)
+            sp.annotate(kernel=kernel, cell_scan=cell_scan,
+                        scan_tokens=scan_tokens)
             self.plan_record["exchange"] = self._exchange(batches, num_docs)
 
         # -- placement: the stack (fit.stack), then densify (fit.densify) -

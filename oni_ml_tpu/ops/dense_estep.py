@@ -251,15 +251,27 @@ def max_dense_cell(word_idx, counts) -> float:
     a row DUPFACTOR=1000 times, so a feedback doc holds the same word
     as ~1000 count-1 tokens whose CELL is ~1000 while every raw count
     is 1)."""
-    w = np.asarray(word_idx, np.int64)
+    w = np.asarray(word_idx)
     c = np.asarray(counts, np.float64)
     if w.size == 0:
         return 0.0
-    docs = np.arange(w.shape[0], dtype=np.int64)[:, None]
-    keys = (docs * (int(w.max()) + 1) + w).ravel()
-    _, inv = np.unique(keys, return_inverse=True)
-    sums = np.bincount(inv.ravel(), weights=c.ravel())
-    return float(sums.max()) if sums.size else 0.0
+    # Sort each row by word: a word's tokens become one run, whose sum
+    # is the row's running sum at the run's end less that at the end of
+    # the run before it.
+    order = np.argsort(w, axis=1, kind="stable")
+    ws = np.take_along_axis(w, order, 1)
+    upto = np.cumsum(np.take_along_axis(c, order, 1), axis=1)
+    end = np.ones(w.shape, bool)
+    end[:, :-1] = ws[:, 1:] != ws[:, :-1]
+    rows, cols = np.nonzero(end)
+    ends = upto[rows, cols]
+    before = np.concatenate([[0.0], ends[:-1]])
+    before[np.concatenate([[True], rows[1:] != rows[:-1]])] = 0.0
+    return float((ends - before).max())
+
+
+# bf16's 8 significand bits hold every integer up to here exactly.
+_BF16_EXACT_MAX = 256
 
 
 def corpus_dtype(cell_max: float, precision: str = "f32"):
@@ -274,9 +286,49 @@ def corpus_dtype(cell_max: float, precision: str = "f32"):
     dominant per-iteration memory traffic once the fixed point is
     matmul-bound) halves.  Anything larger — e.g. the DUPFACTOR=1000
     feedback cells — keeps f32."""
-    if precision == "bf16" and cell_max <= 256:
+    if precision == "bf16" and cell_max <= _BF16_EXACT_MAX:
         return jnp.bfloat16
     return jnp.float32
+
+
+def corpus_store_dtype(batches, precision: str = "f32"):
+    """corpus_dtype for a fit's host batches, reading only what can
+    change the answer.  Returns (dtype, cell_scan, scan_tokens): what
+    the gate had to read ("none", "bounds" or "exact") and how many
+    padded tokens its passes went over.
+
+    Anything but bf16 operand mode stores f32 whatever the cells hold:
+    no token is read.  Under bf16 the answer is EXACT — equal to
+    corpus_dtype(max over batches of max_dense_cell, "bf16") — from the
+    cheapest evidence that decides it: a cell is at least its largest
+    token (one raw count over 256: f32) and at most its row's sum
+    (every document's sum <= 256: bf16); only the rows between the two
+    bounds — duplicates may or may not pile up in them, the DUPFACTOR
+    feedback document is 1000 count-1 tokens of one word — pay
+    max_dense_cell's sort, batch by batch, up to the first cell over
+    256."""
+    if precision != "bf16":
+        return jnp.float32, "none", 0
+    counts = [np.asarray(b.counts) for b in batches]
+    read = 0
+    for c in counts:
+        read += c.size
+        if c.size and c.max() > _BF16_EXACT_MAX:
+            return jnp.float32, "bounds", read
+    undecided = []
+    for b, c in zip(batches, counts):
+        read += c.size
+        rows = np.flatnonzero(
+            c.sum(axis=1, dtype=np.float64) > _BF16_EXACT_MAX)
+        if rows.size:
+            undecided.append((b.word_idx, c, rows))
+    if not undecided:
+        return jnp.bfloat16, "bounds", read
+    for w, c, rows in undecided:
+        read += rows.size * c.shape[1]
+        if max_dense_cell(np.asarray(w)[rows], c[rows]) > _BF16_EXACT_MAX:
+            return jnp.float32, "exact", read
+    return jnp.bfloat16, "exact", read
 
 
 def densify(word_idx, counts, num_terms: int, width: int | None = None,

@@ -700,6 +700,120 @@ def test_max_dense_cell_sums_duplicates():
     assert dense.max() == cell_max
 
 
+class _Unreadable:
+    """A host batch whose arrays cannot be read."""
+
+    @property
+    def counts(self):
+        raise AssertionError("the storage gate read a token")
+
+    word_idx = counts
+
+
+def _gate_batch(word_idx, counts):
+    word_idx = np.asarray(word_idx, np.int32)
+    b = word_idx.shape[0]
+    return Batch(word_idx=word_idx, counts=np.asarray(counts, np.float32),
+                 doc_mask=np.ones((b,), np.float32), doc_index=np.arange(b))
+
+
+def _gate_case(name):
+    """(batches, precision) of a named storage-gate case; every case but
+    `f32` has a quiet first batch in front of the one that decides."""
+    quiet = _gate_batch(np.arange(32).reshape(4, 8), np.ones((4, 8)))
+    w = np.tile(np.arange(300), (2, 1))
+    c = np.ones((2, 300))
+    if name == "f32":
+        return [_Unreadable(), _Unreadable()], "f32"
+    if name == "raw_257":
+        c[1, 7] = 257.0
+    elif name == "row_sums_small":
+        w, c = w[:, :256], c[:, :256]       # every row sums to 256
+    elif name == "dupfactor":
+        w = np.zeros((2, 1000), np.int64)   # 1,000 count-1 tokens, one word
+        c = np.ones((2, 1000))
+        w[1] = np.arange(1000)
+    elif name == "row_300_cells_small":
+        w[0, 150:] = w[0, :150]             # every cell of row 0 is 2
+        c[1, 299] = 0.0
+    return [quiet, _gate_batch(w, c)], "bf16"
+
+
+@pytest.mark.parametrize("case,scan,dtype", [
+    ("f32", "none", jnp.float32),
+    ("raw_257", "bounds", jnp.float32),
+    ("row_sums_small", "bounds", jnp.bfloat16),
+    ("dupfactor", "exact", jnp.float32),
+    ("row_300_cells_small", "exact", jnp.bfloat16),
+])
+def test_corpus_store_dtype_reads_only_what_decides(case, scan, dtype,
+                                                    monkeypatch):
+    """The storage gate: f32 reads no token; bf16 is decided by the
+    largest raw count or the largest row sum where one of them can, and
+    only rows between the two bounds reach the exact reader."""
+    batches, precision = _gate_case(case)
+    exact_rows = []
+    reader = dense_estep.max_dense_cell
+
+    def counting_reader(word_idx, counts):
+        exact_rows.append(np.shape(word_idx)[0])
+        return reader(word_idx, counts)
+
+    monkeypatch.setattr(dense_estep, "max_dense_cell", counting_reader)
+    got, cell_scan, scan_tokens = dense_estep.corpus_store_dtype(
+        batches, precision)
+    assert (got, cell_scan) == (dtype, scan)
+    if scan == "none":
+        assert scan_tokens == 0 and not exact_rows
+        return
+    tokens = [b.counts.size for b in batches]
+    assert got == dense_estep.corpus_dtype(
+        max(reader(b.word_idx, b.counts) for b in batches), precision)
+    if case == "raw_257":                   # one pass, up to the culprit
+        assert scan_tokens == sum(tokens) and not exact_rows
+    elif scan == "bounds":                  # the max pass and the row sums
+        assert scan_tokens == 2 * sum(tokens) and not exact_rows
+    else:                                   # plus the undecided rows alone
+        over = [int((b.counts.sum(axis=1) > 256).sum()) for b in batches]
+        assert exact_rows == [n for n in over if n]
+        assert scan_tokens == 2 * sum(tokens) + sum(
+            n * b.counts.shape[1] for n, b in zip(over, batches))
+
+
+@pytest.mark.parametrize("precision", ["f32", "bf16"])
+def test_corpus_store_dtype_is_the_exact_reader_on_random_batches(precision):
+    """200 random batches with duplicates planted around the 256 line:
+    the gate's dtype is corpus_dtype of the exact reader's maximum."""
+    rng = np.random.default_rng(256)
+    seen = set()
+    for _ in range(200):
+        b, l, v = rng.integers(1, 9), rng.integers(1, 40), rng.integers(1, 50)
+        top = int(rng.choice([1, 3, 40, 256, 257, 300]))
+        w = rng.integers(0, v, (b, l))
+        c = rng.integers(0, top + 1, (b, l)).astype(np.float64)
+        for _ in range(rng.integers(0, 4)):     # a word repeated in a row
+            row, n = rng.integers(0, b), rng.integers(1, l + 1)
+            w[row, :n] = w[row, 0]
+            c[row, :n] = rng.choice([1.0, 256.0 / n, 300.0 // n + 1])
+        batches = [_gate_batch(w, np.floor(c))]
+        cells = np.zeros((b, v))
+        np.add.at(cells, (np.arange(b)[:, None], w), batches[0].counts)
+        assert dense_estep.max_dense_cell(w, batches[0].counts) == cells.max()
+        if rng.random() < 0.5:
+            batches.insert(0, _gate_batch(np.zeros((2, 3)), np.ones((2, 3))))
+        want = dense_estep.corpus_dtype(
+            max(dense_estep.max_dense_cell(x.word_idx, x.counts)
+                for x in batches), precision)
+        got, cell_scan, _ = dense_estep.corpus_store_dtype(batches, precision)
+        assert got == want, (w, c)
+        seen.add((cell_scan, got))
+    if precision == "f32":
+        assert seen == {("none", jnp.float32)}
+    else:
+        assert seen == {(scan, dtype) for scan in ("bounds", "exact")
+                        for dtype in (jnp.float32, jnp.bfloat16)}
+
+
 def test_vocab_sharded_dense_bf16_corpus_matches():
     """The XLA-level vocab-sharded dense plan with a bf16-stored corpus
     must match its f32-stored run bitwise (f32-promoting consumers)."""

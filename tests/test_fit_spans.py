@@ -140,6 +140,46 @@ def test_doc_sweeps_lies_between_one_sweep_and_the_cap(recorded_fits, driver):
     assert isinstance(result.doc_sweeps, int)
 
 
+def _corpus_with_a_feedback_doc():
+    """_corpus() plus one document of 300 count-1 tokens of one word:
+    raw counts of 1, a row sum and a cell of 300."""
+    base = _corpus()
+    return Corpus(
+        doc_names=base.doc_names + ["feedback"], vocab=base.vocab,
+        doc_ptr=np.append(base.doc_ptr, base.doc_ptr[-1] + 300),
+        word_idx=np.append(base.word_idx, np.full(300, 5, np.int32)),
+        counts=np.append(base.counts, np.ones(300, np.float32)))
+
+
+@pytest.mark.parametrize("precision,feedback,cell_scan,stored", [
+    ("f32", True, "none", jnp.float32),
+    ("bf16", False, "bounds", jnp.bfloat16),
+    ("bf16", True, "exact", jnp.float32),
+])
+def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
+                                                  cell_scan, stored):
+    corpus = _corpus_with_a_feedback_doc() if feedback else _corpus()
+    cfg = _config(dense_em="on", dense_precision=precision, em_max_iters=1)
+    padded_tokens = sum(b.word_idx.size for b in make_batches(
+        corpus, batch_size=cfg.batch_size,
+        min_bucket_len=cfg.min_bucket_len, pad_multiple=8))
+    rec = spans.Recorder()
+    with spans.use_recorder(rec):
+        train_corpus(corpus, cfg)
+    plan, = [e["args"] for e in rec.events if e["name"] == "fit.plan"]
+    densify, = [e["args"] for e in rec.events if e["name"] == "fit.densify"]
+    assert plan["cell_scan"] == cell_scan
+    assert densify["dense_bytes"] == (
+        _padded_rows(corpus, cfg) * dense_estep.padded_width(corpus.num_terms)
+        * jnp.dtype(stored).itemsize)
+    if cell_scan == "none":
+        assert plan["scan_tokens"] == 0
+    elif cell_scan == "bounds":
+        assert plan["scan_tokens"] == 2 * padded_tokens
+    else:
+        assert 2 * padded_tokens < plan["scan_tokens"] < 3 * padded_tokens
+
+
 def test_fused_and_stepwise_drivers_count_the_same_sweeps():
     """With a cap the fixed point cannot reach, both drivers sweep every
     batch to it; at the stock tolerance their counts differ by a few
@@ -293,6 +333,8 @@ def test_a_fit_under_the_profiler_puts_its_spans_in_the_trace(tmp_path):
     assert by_name["fit.batches.counts"][0][2]["rows"] == _padded_rows(
         corpus, cfg)
     assert by_name["em.host_sync.counts"][0][2]["steps"] == 4
+    plan = by_name["fit.plan.counts"][0][2]
+    assert (plan["cell_scan"], plan["scan_tokens"]) == ("none", 0)
     assert by_name["fit.densify"][0][2]["groups"] >= 1
     assert by_name["fit.densify.counts"][0][2]["dense_bytes"] > 0
     assert by_name["em.run_chunk"][0][2]["first"] in (1, "True", True)

@@ -406,6 +406,50 @@ def test_second_fit_reuses_the_programs_of_the_first(problem):
     _assert_same_fit(fresh, first)
 
 
+@pytest.mark.parametrize("family", ["dense", "compact"])
+def test_f32_fit_stores_what_the_exact_reader_would_without_reading(
+        problem, family, monkeypatch):
+    """Both dense families ask the one storage gate; at f32 it sorts no
+    token (the exact reader raises here) and the fit is, to the bit, the
+    fit whose gate sorted every token as the parent's did."""
+    from oni_ml_tpu.ops import dense_estep
+    from oni_ml_tpu.telemetry import spans
+
+    cfg = LDAConfig(**DENSE_CFG)
+    if family == "compact":
+        cfg = LDAConfig(**dict(DENSE_CFG, dense_em="auto"))
+        monkeypatch.setenv("ONI_ML_TPU_ESTEP", "compact")
+    gate = dense_estep.corpus_store_dtype
+    asked = []
+
+    def every_token_sorted(batches, precision):
+        cell_max = max(dense_estep.max_dense_cell(b.word_idx, b.counts)
+                       for b in batches)
+        return dense_estep.corpus_dtype(cell_max, precision), "exact", 0
+
+    def recording(batches, precision):
+        asked.append(precision)
+        return gate(batches, precision)
+
+    def refuse(word_idx, counts):
+        raise AssertionError("an f32 fit sorted its tokens")
+
+    with monkeypatch.context() as m:
+        m.setattr(dense_estep, "corpus_store_dtype", every_token_sorted)
+        want = train_corpus(problem, cfg)
+    monkeypatch.setattr(dense_estep, "corpus_store_dtype", recording)
+    monkeypatch.setattr(dense_estep, "max_dense_cell", refuse)
+    rec = spans.Recorder()
+    with spans.use_recorder(rec):
+        got = train_corpus(problem, cfg)
+    _assert_same_fit(got, want)
+    # _plan_compact sizes the compact corpus from the same gate
+    assert asked == ["f32"] * (2 if family == "compact" else 1)
+    plan, = _span_args(rec.events, "fit.plan")
+    assert plan["kernel"].startswith(family)
+    assert (plan["cell_scan"], plan["scan_tokens"]) == ("none", 0)
+
+
 def test_fast_path_marker_is_right_after_a_reused_fit(problem, monkeypatch):
     """LAST_CHUNK_PLAN is set at dispatch: a fit that reuses the fast
     program says "fast" though nothing was traced, whatever ran between."""
