@@ -114,7 +114,7 @@ class LDAConfig:
     # budget below; "on"/"off" force it.  When the FULL vocabulary is too
     # wide (config-4 DNS scale), auto/"on" fall through to the
     # compact-vocab dense variant — each batch remapped onto its own
-    # Wc-wide vocabulary slice (models/lda.py _plan_compact) — before
+    # Wc-wide vocabulary slice (models/lda.py _plan_estep) — before
     # giving up on the MXU path.  ONI_ML_TPU_ESTEP=dense/compact/xla/
     # pallas overrides.
     dense_em: str = "auto"

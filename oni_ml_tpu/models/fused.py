@@ -22,6 +22,13 @@ shapes, so the stacking adds no padding.  The E/M-step hooks are the same
 ones the distributed layer substitutes (shard_map over the (data, model)
 mesh, psum'd suff-stats) — the fused loop composes with both the
 data-parallel and vocab-sharded plans unchanged.
+
+There is ONE chunk implementation (`run_chunk_dispatch` in
+`_build_chunk_program`): log-space beta, `m_step_fn`, the accumulator's
+scan over whatever groups it is handed.  A single dense group of one
+batch runs the same code as six groups of thirty-one; which E-step a
+group gets is the accumulator's dispatch on the group's layout, decided
+on the host by the driver's plan (models/lda.py `_plan_estep`).
 """
 
 from __future__ import annotations
@@ -41,18 +48,6 @@ import numpy as np
 from ..io import Batch
 from ..ops import estep
 from ..telemetry.spans import current_recorder, maybe_span
-
-
-# Which chunk impl the most recent run_chunk DISPATCH selected ("fast" |
-# "generic"; None before any dispatch).  Set by the runner wrapper from
-# `_chunk_plan`, the predicate the trace itself branches on, so it is
-# right for a fit that reuses a program and traces nothing.
-# Observability only — the two impls are equivalence-pinned, so without
-# this marker a regression that silently stopped the fast path from
-# ENGAGING (an eligibility check drifting) would pass every correctness
-# test while costing the headline its glue win.  tests/test_fused.py
-# pins engagement.
-LAST_CHUNK_PLAN = None
 
 
 class StackedGroups(NamedTuple):
@@ -605,20 +600,6 @@ def clear_programs() -> None:
         _PROGRAMS.clear()
 
 
-def _chunk_plan(groups, m_step_fn, dense_e_step_fn) -> str:
-    """"fast" | "generic": the chunk impl these groups take.  The one
-    predicate both the trace (run_chunk_dispatch) and the runner's
-    LAST_CHUNK_PLAN read; the fast path is described at
-    run_chunk_impl_fast."""
-    single_dense = (
-        m_step_fn is estep.m_step and dense_e_step_fn is None
-        and len(groups) == 1
-        and len(groups[0]) == 2          # (C, mask): full-V dense
-        and groups[0][0].shape[0] == 1   # one stacked batch
-    )
-    return "fast" if single_dense else "generic"
-
-
 def _build_chunk_program(
     *,
     num_docs: int,
@@ -646,7 +627,7 @@ def _build_chunk_program(
     else at trace time has to be added to the key by hand."""
     from .lda import update_alpha  # local import: lda.py imports this module
 
-    k, v = num_topics, num_terms
+    k = num_topics
     # The E-step callable itself now lives inside the accumulator (the
     # shared partial-stats path the distributed driver also jits).
     accumulate = make_em_accumulator(
@@ -671,31 +652,26 @@ def _build_chunk_program(
         )
         return new_beta, new_alpha, total_ll, tuple(gammas), vi_max, sweeps
 
-    def _resolve_gammas(groups, gammas_in, have_prev, dtype):
-        """Gamma buffers must exist in the carry before the first
-        iteration writes them.  `gammas_in`/`have_prev` carry the
-        PREVIOUS chunk's posteriors across the host boundary so warm
-        start survives chunk boundaries (without them iteration
-        chunk*i+1 restarted fresh); when absent, zeros are never read
-        back (warm gates on step>0)."""
+    def run_chunk_dispatch(log_beta, alpha, ll_prev, groups, n_steps,
+                           gammas_in=None, have_prev=None) -> ChunkResult:
+        """The chunk while-loop: warm gating, the device convergence
+        rule, and the step/ll/vi bookkeeping around `em_iteration`.  The
+        benchmark finds the EM program in a trace by this function's
+        name (`jit_run_chunk_dispatch`), and the name is part of jax's
+        compile-cache key."""
+        dtype = log_beta.dtype
+        # Gamma buffers must exist in the carry before the first
+        # iteration writes them.  `gammas_in`/`have_prev` carry the
+        # PREVIOUS chunk's posteriors across the host boundary so warm
+        # start survives chunk boundaries (without them iteration
+        # chunk*i+1 restarted fresh); when absent, zeros are never read
+        # back (warm gates on step>0).
         if gammas_in is None:
-            return (
-                initial_gammas(groups, k, dtype,
-                               dense_wmajor=dense_wmajor),
-                jnp.asarray(False),
-            )
-        return gammas_in, jnp.asarray(have_prev)
-
-    def _chunk_loop(model0, alpha, ll_prev, gammas0, n_steps, have_prev,
-                    iterate, dtype):
-        """Shared chunk while-loop skeleton — warm gating, the device
-        convergence rule, and step/ll/vi bookkeeping live HERE once,
-        for both the generic impl and the dense fast path (a change to
-        the stop rule or the warm gate must not be able to land in one
-        and not the other).  `iterate(model, alpha, gammas, warm) ->
-        (model', alpha', ll, gammas', vi, doc_sweeps)` supplies the EM
-        iteration body; `model` is whatever beta representation the path carries
-        (log-space [K, V], or padded exp-space [K, W])."""
+            gammas0 = initial_gammas(groups, k, dtype,
+                                     dense_wmajor=dense_wmajor)
+            have_prev = jnp.asarray(False)
+        else:
+            gammas0, have_prev = gammas_in, jnp.asarray(have_prev)
         lls0 = jnp.zeros((chunk,), dtype)
         vi0 = jnp.zeros((chunk,), jnp.int32)
 
@@ -704,7 +680,7 @@ def _build_chunk_program(
             return (step < jnp.minimum(n_steps, chunk)) & ~converged
 
         def body(state):
-            (model, alpha, ll_prev, step, lls, vis, sws, _,
+            (log_beta, alpha, ll_prev, step, lls, vis, sws, _,
              gammas_prev) = state
             # Warm start once ANY gamma exists: produced this chunk
             # (step>0) or carried in from the previous one (have_prev).
@@ -713,8 +689,8 @@ def _build_chunk_program(
                 if warm_start
                 else jnp.asarray(False)
             )
-            model, new_alpha, ll, gammas, vi, sweeps = iterate(
-                model, alpha, gammas_prev, warm
+            log_beta, new_alpha, ll, gammas, vi, sweeps = em_iteration(
+                log_beta, alpha, groups, gammas_prev, warm
             )
             # The first-ever iteration (ll_prev = nan) never stops — the
             # reference's "no previous likelihood" case.  The host
@@ -723,7 +699,7 @@ def _build_chunk_program(
             conv = jnp.abs((ll_prev - ll) / ll_prev)
             converged = ~jnp.isnan(ll_prev) & (conv < em_tol)
             return (
-                model,
+                log_beta,
                 new_alpha,
                 ll,
                 step + 1,
@@ -735,116 +711,15 @@ def _build_chunk_program(
             )
 
         state = (
-            model0, alpha, ll_prev, jnp.asarray(0, jnp.int32),
+            log_beta, alpha, ll_prev, jnp.asarray(0, jnp.int32),
             lls0, vi0, vi0, jnp.asarray(False), gammas0,
         )
-        return jax.lax.while_loop(cond, body, state)
-
-    def run_chunk_impl(log_beta, alpha, ll_prev, groups, n_steps,
-                       gammas_in=None, have_prev=None) -> ChunkResult:
-        dtype = log_beta.dtype
-        gamma0, have_prev = _resolve_gammas(groups, gammas_in, have_prev,
-                                            dtype)
-
-        def iterate(log_beta, alpha, gammas_prev, warm):
-            return em_iteration(log_beta, alpha, groups, gammas_prev, warm)
-
         log_beta, alpha, ll_prev, step, lls, vis, sws, converged, gammas = (
-            _chunk_loop(log_beta, alpha, ll_prev, gamma0, n_steps,
-                        have_prev, iterate, dtype)
+            jax.lax.while_loop(cond, body, state)
         )
         return ChunkResult(
             log_beta, alpha, ll_prev, lls, step, converged, gammas, vis, sws
         )
-
-    # -- single-dense-group fast path (`_chunk_plan`) ---------------------
-    # The production/bench common case (one full-V dense group, stock
-    # M-step, no mesh override) carries exp(beta) in the kernel's padded
-    # [K, W] layout across EM iterations instead of log-space [K, V]:
-    # each iteration is kernel -> elementwise exp-space M-step
-    # (ss / total), eliminating the per-iteration exp(log_beta) pass,
-    # the log() in m_step, the [V, K] transposes, and the EStepResult
-    # assembly.  (An earlier on-chip A/B read this as a wash at the
-    # headline shape — an unverified lead, ROADMAP C2; not measured on
-    # the current machine.)  Log-space beta is reconstructed
-    # ONCE at the chunk boundary; log(ss / total) differs from m_step's
-    # log(ss) - log(total) by at most 1 ulp for quotients down to
-    # exp(-100); BELOW that window (ss/total < ~3.8e-44, where m_step
-    # would emit log values in about (-103, -100]) the reconstruction
-    # clamps to LOG_ZERO — a deliberate floor on probabilities ~1e-44,
-    # covered by the 1e-5-rtol equivalence pins (tests/test_fused.py).
-    # Entries with exactly zero mass pin to LOG_ZERO in both paths.
-    def run_chunk_impl_fast(log_beta, alpha, ll_prev, groups, n_steps,
-                            gammas_in=None, have_prev=None) -> ChunkResult:
-        from jax.scipy.special import gammaln
-
-        from ..ops import dense_estep
-
-        C, mask = (a[0] for a in groups[0])
-        dtype = log_beta.dtype
-        w = C.shape[0] if dense_wmajor else C.shape[1]
-        exp_beta0 = jnp.exp(log_beta)
-        if w != v:
-            exp_beta0 = jnp.pad(exp_beta0, ((0, 0), (0, w - v)))
-        fp = (
-            dense_estep.dense_fixed_point_w
-            if dense_wmajor
-            else dense_estep.dense_fixed_point
-        )
-        interp = jax.default_backend() != "tpu"
-        gamma0, have_prev = _resolve_gammas(groups, gammas_in, have_prev,
-                                            dtype)
-        # exp(LOG_ZERO) — the exact value exp(m_step's floor) produces,
-        # so zero-mass entries round-trip to LOG_ZERO bit-exactly.
-        exp_zero = jnp.asarray(np.exp(np.float64(estep.LOG_ZERO)), dtype)
-
-        def iterate(exp_beta, alpha, g_prev, warm):
-            with jax.named_scope("estep"):
-                gamma, t, docll, ass, iters, sweeps = fp(
-                    exp_beta, alpha, C, mask, var_max_iters, var_tol,
-                    interpret=interp, gamma_prev=g_prev,
-                    warm=jnp.asarray(warm, jnp.int32),
-                    precision=dense_precision,
-                )
-            with jax.named_scope("elbo"):
-                alpha_const = gammaln(k * alpha) - k * gammaln(alpha)
-                ll = docll.sum() + mask.sum() * alpha_const
-            new_alpha = (
-                update_alpha(ass.sum(), alpha, num_docs, k,
-                             max_iters=alpha_max_iters)
-                if estimate_alpha
-                else alpha
-            )
-            with jax.named_scope("mstep"):
-                suff = exp_beta * t                       # [K, W]
-                total = suff.sum(-1, keepdims=True)       # pad cols are 0
-                new_exp = jnp.where(suff > 0, suff / total, exp_zero)
-            return new_exp, new_alpha, ll, gamma, iters, sweeps
-
-        exp_beta, alpha, ll_prev, step, lls, vis, sws, converged, gamma = (
-            _chunk_loop(exp_beta0, alpha, ll_prev, gamma0[0][0], n_steps,
-                        have_prev, iterate, dtype)
-        )
-        # Reconstruct log-space beta once.  A zero-step chunk must
-        # return the INPUT log_beta (log(exp(x)) drifts an ulp).
-        eb = exp_beta[:, :v]
-        new_log = jnp.where(
-            eb > exp_zero, jnp.log(jnp.maximum(eb, 1e-300)),
-            estep.LOG_ZERO
-        )
-        log_out = jnp.where(step > 0, new_log, log_beta)
-        return ChunkResult(
-            log_out, alpha, ll_prev, lls, step, converged,
-            (gamma[None],), vis, sws,
-        )
-
-    def run_chunk_dispatch(log_beta, alpha, ll_prev, groups, n_steps,
-                           gammas_in=None, have_prev=None) -> ChunkResult:
-        impl = (run_chunk_impl_fast
-                if _chunk_plan(groups, m_step_fn, dense_e_step_fn) == "fast"
-                else run_chunk_impl)
-        return impl(log_beta, alpha, ll_prev, groups, n_steps,
-                    gammas_in=gammas_in, have_prev=have_prev)
 
     return jax.jit(run_chunk_dispatch, compiler_options=compiler_options)
 
@@ -873,7 +748,9 @@ def make_chunk_runner(
     executing up to min(chunk, n_steps) EM iterations on device.
 
     Two halves.  The PROGRAM (`_build_chunk_program`) is the `jax.jit` of
-    the chunk loop; a process builds it once per distinct program
+    the one chunk loop, `run_chunk_dispatch`, whatever the groups' layout
+    (dense, compact or token lists; one batch or many); a process builds
+    it once per distinct program
     (`_chunk_program`), so a later fit that asks for the same one
     dispatches the executable the earlier fit traced and compiled, and
     its first dispatch is an enqueue like the others.  The RUNNER, built
@@ -914,8 +791,6 @@ def make_chunk_runner(
         per-dispatch cost the chunked driver exists to amortize — not
         device compute; the driver's host-sync span covers the blocking
         side."""
-        global LAST_CHUNK_PLAN
-        LAST_CHUNK_PLAN = _chunk_plan(groups, m_fn, dense_e_step_fn)
         slot = yield_hook() if yield_hook is not None else nullcontext()
         with slot, maybe_span("em.run_chunk", chunk=chunk,
                               n_steps=int(n_steps)

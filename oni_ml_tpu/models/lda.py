@@ -24,7 +24,7 @@ import os
 from contextlib import nullcontext
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Callable, Sequence
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -236,6 +236,36 @@ def init_log_beta(key: jax.Array, k: int, v: int, dtype=jnp.float32) -> jnp.ndar
     uniform noise + 1/V, log-normalized per topic (lda-c random_initialize_ss)."""
     noise = jax.random.uniform(key, (k, v), dtype=dtype) + 1.0 / v
     return jnp.log(noise / noise.sum(-1, keepdims=True))
+
+
+def _estep_env() -> str:
+    """ONI_ML_TPU_ESTEP, the operator's pin of the E-step ("" when unset):
+    "dense" / "compact" / "sparse" pin a family, "xla" / "pallas" stand the
+    dense family down.  Read here for the engine family
+    (`resolve_estep_engine`) and once a fit for the fused driver's plan
+    (`LDATrainer._plan_estep`); estep.resolve_backend reads it on its own
+    account when a token-list E-step is traced."""
+    return os.environ.get("ONI_ML_TPU_ESTEP", "")
+
+
+class _EStepPlan(NamedTuple):
+    """What `_fused_loop` reads of the E-step it is about to run, decided
+    once on the host by `LDATrainer._plan_estep`."""
+
+    family: str             # "dense" | "dense_vocab_sharded" | "compact"
+                            # | "tokens" (e_step_fn over token lists)
+    kernel: str             # plan_record["estep_kernel"], fit.plan's `kernel`
+    sweep_width: float      # columns a row-sweep reads (the roofline record)
+    wmajor: bool = False    # dense corpus stored [W, B]
+    store: object = None    # the dense corpus' dtype; None: token lists
+    cell_scan: str = "none"     # what dense_estep.corpus_store_dtype read
+    scan_tokens: int = 0
+    compact: "fused.CompactPlan | None" = None
+    dense_e_fn: Callable | None = None      # the sharded dense E-step
+    dense_put: Callable | None = None       # its corpus' device layout
+    dense_mesh: object = None               # densify under this mesh
+    dense_width: int | None = None          # densify to this width
+    compiler_options: dict | None = None    # the scoped-VMEM limit
 
 
 class LDATrainer:
@@ -895,14 +925,6 @@ class LDATrainer:
             )
         return log_beta, alpha, it, doc_sweeps, vi_max
 
-    def _local_batch(self, batch) -> int:
-        """Documents each data shard's kernel sees for one batch."""
-        if self.mesh is None:
-            return batch.word_idx.shape[0]
-        from ..parallel.mesh import DATA_AXIS
-
-        return batch.word_idx.shape[0] // self.mesh.shape[DATA_AXIS]
-
     def _exchange(self, batches, num_docs: int) -> dict:
         """What the mesh adds to a fit, from shapes alone: the shards the
         documents are split into, the real documents each holds (a batch
@@ -930,94 +952,6 @@ class LDATrainer:
                 "allreduce_bytes": len(batches) * per_batch,
                 "rows_per_shard_min": int(rows.min()),
                 "rows_per_shard_max": int(rows.max())}
-
-    def _use_dense(self, batches) -> bool:
-        """Decide whether the fused loop runs the dense-corpus E-step
-        (ops/dense_estep.py).  Auto mode requires: a TPU backend, the
-        stock E-step or this package's own sharded wrappers (a user's
-        custom e_step_fn must not be silently bypassed), VMEM-feasible
-        doc blocks for every PER-SHARD batch, and the densified corpus
-        under the HBM budget.  With a data mesh the Pallas kernel runs
-        under shard_map (parallel.make_data_parallel_dense_e_step),
-        suff-stats psum'd over ICI; with a vocab-sharded trainer the
-        XLA-level make_vocab_sharded_dense_e_step plan applies instead
-        (_use_dense_vocab_sharded)."""
-        from ..ops import dense_estep
-
-        env = os.environ.get("ONI_ML_TPU_ESTEP", "")
-        # "compact" forces the compact-vocab dense variant: full-V dense
-        # off here, then _plan_compact treats the same env as forced-on.
-        # "sparse" forces the fused sparse bucketed engine — the whole
-        # dense family stands down.
-        mode = {"dense": "on", "compact": "off", "xla": "off",
-                "pallas": "off", "sparse": "off"}.get(
-                    env, self.config.dense_em)
-        if mode not in ("auto", "on", "off"):
-            raise ValueError(
-                f"LDAConfig.dense_em={mode!r}: expected 'auto', 'on', or "
-                "'off'"
-            )
-        if mode == "off":
-            return False
-        own_parallel = getattr(self._e_base, "_oni_data_parallel", False)
-        if self.vocab_sharded:
-            return self._use_dense_vocab_sharded(batches, mode)
-        incompatible = (
-            "a custom e_step_fn is installed"
-            if self._e_base is not estep.e_step and not own_parallel
-            else None
-        )
-        if incompatible:
-            if mode == "on":
-                raise ValueError(f"dense E-step forced but {incompatible}")
-            return False
-        k, v = self.config.num_topics, self.num_terms
-        # Feasibility is per data shard: each device's kernel sees its
-        # local slice of the batch.
-        feasible = all(
-            dense_estep.pick_block(self._local_batch(b), v, k,
-                                   self.config.dense_precision)
-            is not None
-            for b in batches
-        )
-        if mode == "on":
-            if not feasible:
-                # Forced dense with an infeasible full-V shape: the
-                # compact-vocab variant is still the dense family —
-                # rescue through it when it can serve (single-process,
-                # per-batch widths blockable), else keep the hard error.
-                if self.mesh is None:
-                    self._compact_rescue = fused.plan_compact(
-                        batches, k, self.config.dense_precision,
-                        wmajor=self.config.dense_wmajor,
-                    )
-                    if self._compact_rescue is not None:
-                        return False
-                raise ValueError(
-                    "dense E-step forced but a batch shape has no "
-                    f"VMEM-feasible doc block (V={v}, K={k}) and the "
-                    "compact-vocab fallback is not feasible either"
-                )
-            return True
-        # Peak device memory during densify_groups holds BOTH the sparse
-        # stacked arrays (scatter inputs) and the dense output, so budget
-        # the sum, not just the dense corpus.  The budget is per DEVICE:
-        # a data mesh shards the doc axis, dividing both terms.
-        if self.mesh is None:
-            shards = 1
-        else:
-            from ..parallel.mesh import DATA_AXIS
-
-            shards = self.mesh.shape[DATA_AXIS]
-        sparse_bytes = sum(
-            b.word_idx.size * 8 for b in batches  # int32 idx + f32 counts
-        ) // shards
-        return (
-            feasible
-            and jax.default_backend() == "tpu"
-            and fused.dense_groups_bytes(batches, v) // shards + sparse_bytes
-            <= self.config.dense_hbm_budget
-        )
 
     def _use_dense_vocab_sharded(self, batches, mode) -> bool:
         """Gate for the vocab-sharded dense plan
@@ -1062,34 +996,113 @@ class LDATrainer:
             <= self.config.dense_hbm_budget
         )
 
-    def _plan_compact(self, batches):
-        """Compact-vocab dense fallback decision (fused.plan_compact).
+    def _plan_estep(self, batches) -> _EStepPlan:
+        """Which E-step the fused driver runs over `batches`, in which
+        layout, stored as what, under which scoped-VMEM limit: every
+        host-side decision between the batches and their placement, made
+        here once and handed to `_fused_loop` as one record.  Nothing is
+        placed and the trainer is left as it was.
 
-        When the FULL vocabulary is too wide to densify — config-4
-        scale, the combinatorial DNS word space of
-        dns_pre_lda.scala:320-326 — each batch still only touches the
-        words its documents contain, so remapping every batch onto its
-        own compacted vocabulary (width Wc << V) recovers the
-        gather/scatter-free MXU kernel at the cost of one beta-column
-        gather and one suff-stats row-scatter per batch per EM
-        iteration.  Gates mirror _use_dense: auto needs the TPU
-        backend, the stock E-step, and the compacted corpus under the
-        HBM budget; ONI_ML_TPU_ESTEP=compact forces it (tests /
-        interpret runs).  Single-process only — the multi-chip huge-V
-        story is the vocab-sharded dense plan (parallel/sharded.py)."""
+        *Full-width dense* (ops/dense_estep.py; `dense_em`, forced by
+        ONI_ML_TPU_ESTEP=dense).  Auto needs a TPU backend, the stock
+        E-step or this package's own sharded wrappers (a user's custom
+        e_step_fn must not be silently bypassed), a VMEM-feasible doc
+        block for every PER-SHARD batch shape, and the densified corpus
+        under the HBM budget.  With a data mesh the Pallas kernel runs
+        under shard_map (parallel.make_data_parallel_dense_e_step),
+        suff-stats psum'd over ICI; a vocab-sharded trainer takes the
+        XLA-level make_vocab_sharded_dense_e_step plan instead
+        (`_use_dense_vocab_sharded`).
+
+        *Compact-vocab dense* (fused.CompactPlan: each batch densified
+        over its own words, when the full vocabulary is too wide).  Auto
+        needs the TPU backend, the stock E-step and the compacted corpus
+        under the HBM budget; ONI_ML_TPU_ESTEP=compact forces it (tests /
+        interpret runs); forced dense with no feasible full-width block
+        is rescued through it.  Single-process only — the multi-chip
+        huge-V story is the vocab-sharded dense plan (parallel/sharded.py).
+
+        *Token lists* otherwise: `e_step_fn` serves the stacked
+        (word_idx, counts, mask) groups (the sparse engine, the sharded
+        wrappers, estep.e_step's own preference order, a custom fn)."""
         from ..ops import dense_estep
 
-        env = os.environ.get("ONI_ML_TPU_ESTEP", "")
-        rescue = getattr(self, "_compact_rescue", None)
-        self._compact_rescue = None
-        if env == "dense":
-            # Forced dense that _use_dense could not serve at full V:
-            # the rescue plan (when one was feasible) IS the
-            # dense-family fallback; no separate compact gating.
-            return rescue
-        if env and env != "compact":
-            return None
-        mode = "on" if env == "compact" else self.config.dense_em
+        cfg = self.config
+        k, v = cfg.num_topics, self.num_terms
+        precision = cfg.dense_precision
+        env = _estep_env()
+        on_tpu = jax.default_backend() == "tpu"
+        shards = 1
+        if self.mesh is not None:
+            from ..parallel.mesh import DATA_AXIS
+
+            shards = self.mesh.shape[DATA_AXIS]
+        # The kernels see each data shard's slice of a batch: feasibility
+        # and VMEM limits are per (rows a shard, L) shape.
+        shapes = sorted({b.word_idx.shape for b in batches})
+        local = [(rows // shards, length) for rows, length in shapes]
+        custom = (self._e_base is not estep.e_step
+                  and not getattr(self._e_base, "_oni_data_parallel", False))
+
+        # "compact" forces the compact-vocab variant (full-width dense
+        # stands down); "sparse" / "xla" / "pallas" stand the whole dense
+        # family down.
+        mode = {"dense": "on", "compact": "off", "xla": "off",
+                "pallas": "off", "sparse": "off"}.get(env, cfg.dense_em)
+        if mode not in ("auto", "on", "off"):
+            raise ValueError(
+                f"LDAConfig.dense_em={mode!r}: expected 'auto', 'on', or "
+                "'off'"
+            )
+        dense, compact = False, None
+        if mode == "off":
+            pass
+        elif self.vocab_sharded:
+            dense = self._use_dense_vocab_sharded(batches, mode)
+        elif custom:
+            if mode == "on":
+                raise ValueError(
+                    "dense E-step forced but a custom e_step_fn is installed")
+        else:
+            feasible = all(
+                dense_estep.pick_block(rows, v, k, precision) is not None
+                for rows, _ in local
+            )
+            if mode == "auto":
+                # Peak device memory during densify_groups holds BOTH the
+                # sparse stacked arrays (scatter inputs; int32 idx + f32
+                # counts) and the dense output, so budget the sum.  The
+                # budget is per DEVICE: a data mesh shards the doc axis,
+                # dividing both terms.
+                sparse_bytes = sum(
+                    b.word_idx.size * 8 for b in batches) // shards
+                dense = (
+                    feasible and on_tpu
+                    and fused.dense_groups_bytes(batches, v) // shards
+                    + sparse_bytes <= cfg.dense_hbm_budget
+                )
+            elif feasible:
+                dense = True
+            else:
+                # Forced dense with an infeasible full-V shape: the
+                # compact-vocab variant is still the dense family —
+                # rescue through it when it can serve (single-process,
+                # per-batch widths blockable), else keep the hard error.
+                if self.mesh is None:
+                    compact = fused.plan_compact(
+                        batches, k, precision, wmajor=cfg.dense_wmajor)
+                if compact is None:
+                    raise ValueError(
+                        "dense E-step forced but a batch shape has no "
+                        f"VMEM-feasible doc block (V={v}, K={k}) and the "
+                        "compact-vocab fallback is not feasible either"
+                    )
+
+        # The compact variant on its own account: forced, or on auto where
+        # full-width dense stood down.  Any other ONI_ML_TPU_ESTEP value
+        # rules it out.
+        compact_mode = ("on" if env == "compact"
+                        else "off" if env else cfg.dense_em)
         blocked = (
             "a mesh is active (the multi-chip huge-V story is the "
             "vocab-sharded dense plan)"
@@ -1098,47 +1111,165 @@ class LDATrainer:
             if self._e_base is not estep.e_step
             else None
         )
-        if mode == "off" or blocked:
-            if env == "compact" and blocked:
-                raise ValueError(
-                    f"compact dense E-step forced but {blocked}"
-                )
-            return None
-        if rescue is not None:  # dense_em="on" rescue from _use_dense
-            return rescue
-        if mode != "on" and jax.default_backend() != "tpu":
-            return None
-        cfg = self.config
-        # The storage gate; _fused_loop asks it again for the dtype
-        # itself (free at f32: no token is read).
-        itemsize = jnp.dtype(
-            dense_estep.corpus_store_dtype(batches, cfg.dense_precision)[0]
-        ).itemsize
-        plan = fused.plan_compact(
-            batches, cfg.num_topics, cfg.dense_precision,
-            wmajor=cfg.dense_wmajor, itemsize=itemsize,
-        )
-        if plan is None:
-            if mode == "on":
+        if env == "compact" and blocked:
+            raise ValueError(f"compact dense E-step forced but {blocked}")
+        try_compact = (
+            not dense and compact is None and not blocked
+            and (compact_mode == "on" or compact_mode == "auto" and on_tpu))
+
+        store, cell_scan, scan_tokens = None, "none", 0
+        if dense or compact is not None or try_compact:
+            # bf16 corpus storage when exact and the run is already in
+            # bf16 operand mode — halves the corpus' HBM streaming with
+            # bit-identical results.  The gate bounds the DENSIFIED cells
+            # (duplicate (doc, word) tokens sum — the DUPFACTOR feedback
+            # path makes ~1000-count cells out of count-1 tokens), not the
+            # raw counts; at f32 it reads no token.
+            store, cell_scan, scan_tokens = dense_estep.corpus_store_dtype(
+                batches, precision)
+        if try_compact:
+            compact = fused.plan_compact(
+                batches, k, precision, wmajor=cfg.dense_wmajor,
+                itemsize=jnp.dtype(store).itemsize,
+            )
+            if compact is None and compact_mode == "on":
                 raise ValueError(
                     "compact dense E-step forced but a batch's compact "
                     "width admits no VMEM-feasible doc block"
                 )
-            return None
-        if mode == "on":
-            return plan
-        # Peak device memory: the whole compacted corpus plus the
-        # largest single group's sparse stacks (compact_stack_batches
-        # uploads sparse arrays one group at a time, unlike
-        # densify_groups which holds them all).
-        groups: dict[tuple, int] = {}
-        for b in batches:
-            groups[b.word_idx.shape] = (
-                groups.get(b.word_idx.shape, 0) + b.word_idx.size * 8
+            if compact is not None and compact_mode == "auto":
+                # Peak device memory: the whole compacted corpus plus the
+                # largest single group's sparse stacks
+                # (compact_stack_batches uploads sparse arrays one group
+                # at a time, unlike densify_groups which holds them all).
+                stacks = max(
+                    sum(b.word_idx.size * 8 for b in batches
+                        if b.word_idx.shape == shape)
+                    for shape in shapes)
+                if compact.corpus_bytes + stacks > cfg.dense_hbm_budget:
+                    compact = None
+
+        # -- the family: its kernel's name and layout, and the (rows, width)
+        # blocks of its Pallas kernel, `kib` their scoped-VMEM need
+        all_rows = sum(b.word_idx.shape[0] for b in batches)
+        plan = dict(store=store, cell_scan=cell_scan,
+                    scan_tokens=scan_tokens)
+        blocks, kib = [], None
+        if dense and self.vocab_sharded:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+
+            from ..parallel import sharded
+            from ..parallel.mesh import DATA_AXIS, MODEL_AXIS
+
+            # XLA-level vocab-sharded dense plan: stacked dense groups
+            # [NB, B, W] shard docs over `data` and vocab columns over
+            # `model`; width == the (model-divisible) padded vocab, so
+            # suff-stats land exactly in the sparse plan's shard layout
+            # and the vocab-sharded m_step consumes them unchanged.  Rows
+            # come out of the scatter sharded over `data` (`dense_mesh`);
+            # `dense_put` only drops the columns other `model` shards own.
+            dense_sh = NamedSharding(self.mesh, P(None, DATA_AXIS, MODEL_AXIS))
+            plan.update(
+                family="dense_vocab_sharded",
+                kernel="dense_vocab_sharded_xla",
+                dense_put=lambda x: jax.device_put(x, dense_sh),
+                dense_mesh=self.mesh, dense_width=v, sweep_width=v,
+                dense_e_fn=sharded.bound(
+                    sharded.make_vocab_sharded_dense_e_step(
+                        self.mesh, precision=precision),
+                    var_max_iters=cfg.var_max_iters, var_tol=cfg.var_tol,
+                ),
             )
-        if plan.corpus_bytes + max(groups.values()) > cfg.dense_hbm_budget:
-            return None
-        return plan
+        elif dense:
+            # W-major needs the doc axis on the 128-lane dimension; fall
+            # back to row-major when any batch shape can't block that way.
+            wmajor = cfg.dense_wmajor and all(
+                dense_estep.pick_block_w(rows, v, k, precision)
+                for rows, _ in local
+            )
+            plan.update(
+                family="dense", wmajor=wmajor,
+                kernel="dense_wmajor" if wmajor else "dense_rowmajor",
+                sweep_width=dense_estep.padded_width(v),
+            )
+            if self.mesh is not None:
+                from ..parallel import sharded
+
+                # Each device densifies its own documents
+                # (fused.densify_stack under the mesh) into the layout
+                # this kernel reads: nothing to put.
+                plan["kernel"] += "_shard_map"
+                plan.update(
+                    dense_mesh=self.mesh,
+                    dense_e_fn=sharded.bound(
+                        sharded.make_data_parallel_dense_e_step(
+                            self.mesh, wmajor=wmajor, precision=precision),
+                        var_max_iters=cfg.var_max_iters,
+                        var_tol=cfg.var_tol,
+                        interpret=not on_tpu,
+                    ),
+                )
+            blocks = [(rows, v) for rows, _ in local]
+            kib = partial(dense_estep.scoped_vmem_kib, wmajor=wmajor,
+                          precision=precision)
+        elif compact is not None:
+            # Compact-vocab dense groups are built straight from the host
+            # batches (no sparse stacked upload to discard).  The chunk
+            # runner dispatches on the group layout itself
+            # (fused._compact_dense gathers beta columns and scatters
+            # suff-stats rows per batch).
+            plan.update(
+                family="compact", compact=compact, wmajor=compact.wmajor,
+                kernel=("compact_wmajor" if compact.wmajor
+                        else "compact_rowmajor"),
+                sweep_width=sum(
+                    len(us) * shape[0] * wc
+                    for us, shape, wc in zip(
+                        compact.uniques, shapes, compact.widths)
+                ) / all_rows,
+            )
+            blocks = [(shape[0], wc)
+                      for shape, wc in zip(shapes, compact.widths)]
+            kib = partial(dense_estep.scoped_vmem_kib,
+                          wmajor=compact.wmajor, precision=precision)
+        else:
+            plan.update(
+                family="tokens",
+                sweep_width=sum(
+                    b.word_idx.size for b in batches) / all_rows,
+            )
+            if getattr(self._e_base, "_oni_sparse_engine", False):
+                from ..ops import sparse_estep
+
+                plan["kernel"] = "sparse_fused"
+                blocks = shapes
+                kib = partial(
+                    sparse_estep.scoped_vmem_kib,
+                    precision=getattr(self._e_base, "precision", "f32"))
+            elif getattr(self._e_base, "_oni_vocab_sharded", False):
+                plan["kernel"] = "xla_vocab_sharded"
+            elif not custom:
+                # estep.e_step's own preference order, at the shape each
+                # device's call sees (it reports the refusals itself).
+                plan["kernel"] = "+".join(sorted({
+                    estep.resolve_backend("auto", rows, length, k, v)[0]
+                    for rows, length in local
+                }))
+            else:
+                plan["kernel"] = "custom"
+        # XLA drops a Pallas kernel's own scoped-VMEM limit when the call
+        # is fusion-wrapped inside the chunk program (a stacked-group
+        # scan); forward the limit as a program-level compiler option
+        # instead.  The option only exists on the TPU compiler (CPU
+        # interpret runs have no VMEM to limit).
+        if on_tpu and blocks:
+            kibs = [kib(rows, width, k) for rows, width in blocks]
+            if any(kibs):
+                plan["compiler_options"] = {
+                    "xla_tpu_scoped_vmem_limit_kib": str(
+                        max(filter(None, kibs)))
+                }
+        return _EStepPlan(**plan)
 
     def _fused_loop(
         self, batches, put, log_beta, alpha, ll_prev, start_it, num_docs,
@@ -1156,6 +1287,14 @@ class LDATrainer:
         k = cfg.num_topics
         dtype = jnp.dtype(cfg.compute_dtype)
 
+        # -- the plan: host-only decisions, before anything is placed ----
+        with maybe_span("fit.plan", batches=len(batches)) as sp:
+            plan = self._plan_estep(batches)
+            sp.annotate(kernel=plan.kernel, cell_scan=plan.cell_scan,
+                        scan_tokens=plan.scan_tokens)
+            self.plan_record["exchange"] = self._exchange(batches, num_docs)
+
+        # -- placement: the stack (fit.stack), then densify (fit.densify) -
         put_stacked = put
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1167,179 +1306,28 @@ class LDATrainer:
             def put_stacked(x):
                 return jax.device_put(jnp.asarray(x), stacked_sh)
 
-        # -- the plan: host-only decisions, before anything is placed ----
-        with maybe_span("fit.plan", batches=len(batches)) as sp:
-            compiler_options = None
-            use_dense = self._use_dense(batches)
-            compact = None if use_dense else self._plan_compact(batches)
-            use_wmajor = False
-            dense_e_fn = None
-            dense_put = None
-            dense_mesh = None
-            dense_width = None
-            corpus_store = None
-            cell_scan, scan_tokens = "none", 0
-            kibs = []
-            if use_dense or compact is not None:
-                from ..ops import dense_estep
-
-                # bf16 corpus storage when exact and the run is already
-                # in bf16 operand mode — halves the corpus' HBM streaming
-                # with bit-identical results.  The gate bounds the
-                # DENSIFIED cells (duplicate (doc, word) tokens sum — the
-                # DUPFACTOR feedback path makes ~1000-count cells out of
-                # count-1 tokens), not the raw counts; at f32 it reads
-                # no token.
-                corpus_store, cell_scan, scan_tokens = (
-                    dense_estep.corpus_store_dtype(
-                        batches, cfg.dense_precision))
-            if compact is not None:
-                # Compact-vocab dense groups are built straight from the
-                # host batches (no sparse stacked upload to discard).
-                # The chunk runner dispatches on the group layout itself
-                # (fused._compact_dense gathers beta columns and scatters
-                # suff-stats rows per batch).
-                use_wmajor = compact.wmajor
-                shapes = sorted({b.word_idx.shape for b in batches})
-                kibs = [
-                    dense_estep.scoped_vmem_kib(
-                        shape[0], wc, k, wmajor=use_wmajor,
-                        precision=cfg.dense_precision,
-                    )
-                    for shape, wc in zip(shapes, compact.widths)
-                ]
-            elif use_dense and self.vocab_sharded:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                from ..parallel import sharded
-                from ..parallel.mesh import (
-                    DATA_AXIS as _DA, MODEL_AXIS as _MA)
-
-                # XLA-level vocab-sharded dense plan: stacked dense
-                # groups [NB, B, W] shard docs over `data` and vocab
-                # columns over `model`; width == the (model-divisible)
-                # padded vocab, so suff-stats land exactly in the sparse
-                # plan's shard layout and the vocab-sharded m_step
-                # consumes them unchanged.
-                dense_sh = NamedSharding(self.mesh, P(None, _DA, _MA))
-                dense_put = lambda x: jax.device_put(x, dense_sh)  # noqa: E731
-                dense_width = self.num_terms
-                # Rows come out of the scatter sharded over `data`; `put`
-                # only drops the columns other `model` shards own.
-                dense_mesh = self.mesh
-                dense_e_fn = sharded.bound(
-                    sharded.make_vocab_sharded_dense_e_step(
-                        self.mesh, precision=cfg.dense_precision),
-                    var_max_iters=cfg.var_max_iters, var_tol=cfg.var_tol,
-                )
-            elif use_dense:
-                # Feasibility checks run against the PER-SHARD batch
-                # (each data shard's kernel sees its local slice).
-                # W-major needs the doc axis on the 128-lane dimension;
-                # fall back to row-major when any batch shape can't
-                # block that way.
-                use_wmajor = cfg.dense_wmajor and all(
-                    dense_estep.pick_block_w(self._local_batch(b),
-                                             self.num_terms, k,
-                                             cfg.dense_precision)
-                    for b in batches
-                )
-                if self.mesh is not None:
-                    from ..parallel import sharded
-
-                    # Each device densifies its own documents
-                    # (fused.densify_stack under the mesh) into the
-                    # layout this kernel reads: nothing to put.
-                    dense_mesh = self.mesh
-                    dense_e_fn = sharded.bound(
-                        sharded.make_data_parallel_dense_e_step(
-                            self.mesh, wmajor=use_wmajor,
-                            precision=cfg.dense_precision),
-                        var_max_iters=cfg.var_max_iters,
-                        var_tol=cfg.var_tol,
-                        interpret=jax.default_backend() != "tpu",
-                    )
-                kibs = [
-                    dense_estep.scoped_vmem_kib(self._local_batch(b),
-                                                self.num_terms, k,
-                                                wmajor=use_wmajor,
-                                                precision=cfg.dense_precision)
-                    for b in batches
-                ]
-            elif (getattr(self._e_base, "_oni_sparse_engine", False)
-                    and jax.default_backend() == "tpu"):
-                from ..ops import sparse_estep
-
-                kibs = [
-                    sparse_estep.scoped_vmem_kib(
-                        b.word_idx.shape[0], b.word_idx.shape[1], k,
-                        getattr(self._e_base, "precision", "f32"),
-                    )
-                    for b in batches
-                ]
-            # XLA drops a Pallas kernel's own scoped-VMEM limit when the
-            # call is fusion-wrapped inside the chunk program (a
-            # stacked-group scan); forward the limit as a program-level
-            # compiler option instead.  The option only exists on the
-            # TPU compiler (CPU interpret runs have no VMEM to limit).
-            if any(kibs) and jax.default_backend() == "tpu":
-                compiler_options = {
-                    "xla_tpu_scoped_vmem_limit_kib": str(
-                        max(filter(None, kibs)))
-                }
-            # Name what will actually run, next to the knobs that chose
-            # it: the kernel behind the engine family.
-            if use_dense and self.vocab_sharded:
-                kernel = "dense_vocab_sharded_xla"
-            elif use_dense:
-                kernel = (
-                    "dense_wmajor" if use_wmajor else "dense_rowmajor"
-                ) + ("_shard_map" if self.mesh is not None else "")
-            elif compact is not None:
-                kernel = "compact_wmajor" if use_wmajor else "compact_rowmajor"
-            elif getattr(self._e_base, "_oni_sparse_engine", False):
-                kernel = "sparse_fused"
-            elif getattr(self._e_base, "_oni_vocab_sharded", False):
-                kernel = "xla_vocab_sharded"
-            elif self._e_base is estep.e_step or getattr(
-                    self._e_base, "_oni_data_parallel", False):
-                # estep.e_step's own preference order, at the shape each
-                # device's call sees (it reports the refusals itself).
-                kernel = "+".join(sorted({
-                    estep.resolve_backend(
-                        "auto", self._local_batch(b), b.word_idx.shape[1],
-                        k, self.num_terms,
-                    )[0]
-                    for b in batches
-                }))
-            else:
-                kernel = "custom"
-            sp.annotate(kernel=kernel, cell_scan=cell_scan,
-                        scan_tokens=scan_tokens)
-            self.plan_record["exchange"] = self._exchange(batches, num_docs)
-
-        # -- placement: the stack (fit.stack), then densify (fit.densify) -
-        if compact is not None:
+        if plan.family == "compact":
             groups = fused.compact_stack_batches(
-                batches, np.dtype(cfg.compute_dtype), put, compact,
-                corpus_store=corpus_store,
+                batches, np.dtype(cfg.compute_dtype), put, plan.compact,
+                corpus_store=plan.store,
             )
         else:
             groups = fused.stack_batches(
                 batches, np.dtype(cfg.compute_dtype), put_stacked
             )
-        if use_dense:
-            groups = fused.densify_groups(
-                groups, self.num_terms, wmajor=use_wmajor, put=dense_put,
-                width=dense_width, dtype=corpus_store, mesh=dense_mesh,
-            )
+            if plan.family != "tokens":
+                groups = fused.densify_groups(
+                    groups, self.num_terms, wmajor=plan.wmajor,
+                    put=plan.dense_put, width=plan.dense_width,
+                    dtype=plan.store, mesh=plan.dense_mesh,
+                )
 
         with maybe_span("fit.runner") as sp:
             # The devices that hold corpus shards (on a mesh, one
             # distinct slice per data shard).
             corpus = groups.arrays[0][0]
             self.plan_record["estep_kernel"] = {
-                "value": kernel,
+                "value": plan.kernel,
                 "corpus_devices": sorted(
                     s.device.id for s in corpus.addressable_shards),
                 "corpus_slices": len(
@@ -1357,10 +1345,10 @@ class LDATrainer:
                 estimate_alpha=cfg.estimate_alpha,
                 e_step_fn=self._e_base,
                 m_step_fn=self._m_base,
-                compiler_options=compiler_options,
-                dense_wmajor=use_wmajor,
+                compiler_options=plan.compiler_options,
+                dense_wmajor=plan.wmajor,
                 warm_start=cfg.warm_start_gamma,
-                dense_e_step_fn=dense_e_fn,
+                dense_e_step_fn=plan.dense_e_fn,
                 dense_precision=cfg.dense_precision,
                 alpha_max_iters=cfg.alpha_max_iters,
                 yield_hook=self.yield_hook,
@@ -1380,7 +1368,7 @@ class LDATrainer:
             gammas_prev = tuple(
                 put_stacked(g)
                 for g in fused.initial_gammas(
-                    groups.arrays, k, dtype, dense_wmajor=use_wmajor
+                    groups.arrays, k, dtype, dense_wmajor=plan.wmajor
                 )
             )
             have_prev = jnp.asarray(False)
@@ -1468,24 +1456,13 @@ class LDATrainer:
             from ..telemetry import roofline
 
             rows = sum(b.word_idx.shape[0] for b in batches)
-            if use_dense:
-                width = dense_width or dense_estep.padded_width(
-                    self.num_terms)
-            elif compact is not None:
-                width = sum(
-                    len(us) * shape[0] * wc
-                    for us, shape, wc in zip(
-                        compact.uniques, shapes, compact.widths)
-                ) / rows
-            else:
-                width = sum(b.word_idx.size for b in batches) / rows
             roofline.emit(
                 "em.run_chunk", (now_ns() - t_loop0) / 1e9,
                 dispatches=n_disp, em_iters=it - start_it,
                 chunk=self._em_chunk, doc_sweeps=doc_sweeps,
                 effective_flops=(
                     4.0 * doc_sweeps + 2.0 * rows * (it - start_it)
-                ) * width * k,
+                ) * plan.sweep_width * k,
             )
 
         if res is not None and int(res.steps_done) > 0:
@@ -1731,8 +1708,8 @@ def resolve_estep_engine(
     "sparse" is the fused bucketed Pallas engine (ops/sparse_estep.py:
     corpus packed by Corpus.bucketed_layout, K×L work per doc);
     "dense" is everything that exists today — the dense/compact/XLA/
-    Pallas family whose internal gates (_use_dense, _plan_compact,
-    estep.e_step auto) are unchanged.  Precedence mirrors the rest of
+    Pallas family, chosen within by LDATrainer._plan_estep and
+    estep.e_step's auto.  Precedence mirrors the rest of
     the plan layer: ONI_ML_TPU_ESTEP env ("env") > an explicit
     LDAConfig.estep_engine ("config") > the MEASURED dense-vs-sparse
     crossover from the plan cache (sparse_estep.engine_crossover —
@@ -1749,7 +1726,7 @@ def resolve_estep_engine(
     crossover is consulted at the dominant LOCAL shard shape — the
     shapes the kernel will actually see, which per-shard batching makes
     smaller than the whole-corpus shapes."""
-    env = os.environ.get("ONI_ML_TPU_ESTEP", "")
+    env = _estep_env()
     choice = config.estep_engine
     if choice not in ("auto", "dense", "sparse"):
         raise ValueError(

@@ -227,7 +227,7 @@ def resolve_backend(backend: str, b: int, l: int, k: int,
     if backend == "auto":
         env = os.environ.get("ONI_ML_TPU_ESTEP", "auto")
         # "dense"/"compact" in the env are DRIVER-level hints (models/lda.py
-        # picks them up in _use_dense/_plan_compact, where the densification
+        # picks them up in LDATrainer._plan_estep, where the densification
         # is amortized across the run).  Honoring them per call here would
         # re-scatter the batch every EM iteration — the exact cost the dense
         # paths exist to avoid — so auto dispatch ignores them; only an

@@ -284,7 +284,7 @@ def test_dense_em_typo_raises():
         doc_index=np.arange(16),
     )
     with pytest.raises(ValueError, match="dense_em"):
-        trainer._use_dense([batch])
+        trainer._plan_estep([batch])
 
 
 def test_forced_dense_with_vocab_sharding_raises():
@@ -306,7 +306,7 @@ def test_forced_dense_with_vocab_sharding_raises():
         doc_index=np.arange(16),
     )
     with pytest.raises(ValueError, match="vocabulary is sharded"):
-        trainer._use_dense([batch])
+        trainer._plan_estep([batch])
 
 
 def test_dense_sharded_matches_single_device():
@@ -452,7 +452,9 @@ def test_use_dense_auto_is_off_on_cpu():
         doc_mask=np.ones((16,), np.float32),
         doc_index=np.arange(16),
     )
-    assert trainer._use_dense([batch]) is False  # CPU backend in tests
+    # CPU backend in tests: the token lists stay, nothing is stored dense
+    plan = trainer._plan_estep([batch])
+    assert (plan.family, plan.kernel, plan.store) == ("tokens", "xla", None)
 
 
 @pytest.mark.parametrize("wmajor", [False, True])
@@ -1038,10 +1040,9 @@ def test_forced_dense_infeasible_rescues_to_compact():
     trainer = LDATrainer(
         LDAConfig(num_topics=4, dense_em="on"), num_terms=v
     )
-    assert trainer._use_dense([batch]) is False
-    plan = trainer._plan_compact([batch])
-    assert plan is not None
-    assert plan.widths[0] <= 512  # 16x16 tokens -> tiny compact width
+    plan = trainer._plan_estep([batch])
+    assert plan.family == "compact" and plan.kernel.startswith("compact_")
+    assert plan.compact.widths[0] <= 512  # 16x16 tokens -> tiny compact width
 
 
 def test_forced_compact_with_mesh_raises(monkeypatch):
@@ -1063,13 +1064,14 @@ def test_forced_compact_with_mesh_raises(monkeypatch):
         doc_index=np.arange(16),
     )
     with pytest.raises(ValueError, match="compact dense E-step forced"):
-        trainer._plan_compact([batch])
+        trainer._plan_estep([batch])
 
 
-def test_env_dense_infeasible_consumes_rescue(monkeypatch):
+def test_env_dense_infeasible_rescue_leaves_nothing_behind(monkeypatch):
     """ONI_ML_TPU_ESTEP=dense with an infeasible full-V shape must
-    route through the compact rescue — and must not leak the rescue
-    into a later decision for different batches."""
+    route through the compact rescue — and the rescue is the plan's
+    return value: the trainer holds nothing of it, so a later decision
+    starts from nothing."""
     from oni_ml_tpu.models.lda import LDATrainer
 
     rng = np.random.default_rng(3)
@@ -1081,7 +1083,12 @@ def test_env_dense_infeasible_consumes_rescue(monkeypatch):
     )
     monkeypatch.setenv("ONI_ML_TPU_ESTEP", "dense")
     trainer = LDATrainer(LDAConfig(num_topics=4), num_terms=v)
-    assert trainer._use_dense([batch]) is False  # rescue cached
-    plan = trainer._plan_compact([batch])
-    assert plan is not None                      # rescue consumed
-    assert trainer._plan_compact([batch]) is None  # not served twice
+    before = dict(vars(trainer))
+    plan = trainer._plan_estep([batch])
+    assert plan.family == "compact" and plan.compact is not None
+    assert vars(trainer) == before               # no stash to consume
+    again = trainer._plan_estep([batch])         # the same answer, afresh
+    assert again.compact.widths == plan.compact.widths
+    # another pin for the same trainer: no rescue left over
+    monkeypatch.setenv("ONI_ML_TPU_ESTEP", "xla")
+    assert trainer._plan_estep([batch]).compact is None

@@ -1,10 +1,10 @@
 """On-chip profiling probes for the fused-EM iteration.  Run them on the
-chip (ROADMAP A1, A6, C2 name what each has to settle):
+chip (ROADMAP A1 and A6 name what each has to settle):
 
-    python tools/tpu_probes.py [cap_sweep] [alpha_ab] [fastpath_ab]
-                               [chunk_sweep] [batch_amort]
+    python tools/tpu_probes.py [cap_sweep] [alpha_ab] [chunk_sweep]
+                               [batch_amort]
 
-(no args = all five).  Each probe prints one JSON line per
+(no args = all four).  Each probe prints one JSON line per
 measurement.  What they answer:
 
 cap_sweep — fixed-cost decomposition of one EM iteration.  docs/s at
@@ -19,9 +19,6 @@ alpha_ab — attribute the alpha-Newton update's cost.  estimate_alpha
   unrolled cap-8 lowering against fixed alpha and the 100-trip
   while_loop.
 
-fastpath_ab — the exp-space single-dense-group fast path
-  (fused.run_chunk_impl_fast) vs the generic chunk impl.
-
 chunk_sweep — host-dispatch amortization: docs/s against EM
   iterations per dispatch.  The per-dispatch cost is not measured on
   the current machine; this is the probe that measures it, and it
@@ -29,8 +26,7 @@ chunk_sweep — host-dispatch amortization: docs/s against EM
 
 batch_amort — per-EM-iteration wall and docs/s vs resident batch count
   (1/2/4 stacked B=4096 batches through the production chunk runner's
-  scan).  The fast path only engages at n_batches=1 — the stacked runs
-  measure the generic impl.
+  scan).
 """
 
 import json
@@ -92,40 +88,6 @@ def alpha_ab():
             print(json.dumps({
                 "probe": "alpha_ab", "alpha": label,
                 "t_iter_ms": round(em["t_iter"] * 1e3, 3),
-                "docs_per_sec": round(em["docs_per_sec"]),
-            }), flush=True)
-    finally:
-        fused.make_chunk_runner = orig
-
-
-def fastpath_ab():
-    """Exp-space single-dense-group fast path (round-4
-    fused.run_chunk_impl_fast) vs the generic chunk impl: measures the
-    per-EM-iteration glue the fast path removes (the exp(log_beta)
-    pass, m_step's log, two [V, K] transposes, EStepResult assembly).
-    The generic impl is summoned by wrapping m_step so the fast path's
-    `is` eligibility check cannot recognize it."""
-    import bench
-    from oni_ml_tpu.models import fused
-    from oni_ml_tpu.ops import estep
-
-    orig = fused.make_chunk_runner
-
-    def stock(**kw):
-        if kw.get("m_step_fn") in (None, estep.m_step):
-            kw["m_step_fn"] = lambda ss: estep.m_step(ss)
-        return orig(**kw)
-
-    try:
-        for label, maker in (("fast", orig), ("stock", stock)):
-            fused.make_chunk_runner = maker
-            em = bench.bench_em(K, V, B, L, chunk=32, rounds=3,
-                                warm_start=True,
-                                precision="bf16")
-            print(json.dumps({
-                "probe": "fastpath_ab", "path": label,
-                "t_iter_ms": round(em["t_iter"] * 1e3, 3),
-                "mean_vi": round(em["mean_vi"], 2),
                 "docs_per_sec": round(em["docs_per_sec"]),
             }), flush=True)
     finally:
@@ -200,8 +162,8 @@ def main() -> int:
         print("tpu_probes: backend is not TPU — these probes measure "
               "device behavior; run on the chip host", file=sys.stderr)
         return 2
-    which = sys.argv[1:] or ["cap_sweep", "alpha_ab", "fastpath_ab",
-                             "chunk_sweep", "batch_amort"]
+    which = sys.argv[1:] or ["cap_sweep", "alpha_ab", "chunk_sweep",
+                             "batch_amort"]
     for name in which:
         fn = globals().get(name)
         if fn is None:
