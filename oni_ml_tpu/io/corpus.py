@@ -389,11 +389,12 @@ class BucketedLayout:
     """Length-sorted, power-of-two-bucketed packing of a corpus — the
     sparse E-step engine's device layout (ops/sparse_estep.py).
 
-    `batches` are ordinary padded `Batch` tiles, but built by ONE
-    vectorized pass (a stable argsort by token count, then CSR gathers)
-    instead of make_batches' per-doc fill loop, and with the bucket
-    floor at the Pallas lane tile (min_len=128 by default) so a
-    [K, BB, L] slab block never pads its lane dimension.
+    `batches` are ordinary padded `Batch` tiles, built like
+    make_batches' with array operations and no per-doc loop (here a
+    stable argsort by token count, then one CSR gather a batch), but
+    ordered by length within a bucket, and with the bucket floor at the
+    Pallas lane tile (min_len=128 by default) so a [K, BB, L] slab
+    block never pads its lane dimension.
 
     `perm[j]` is the ORIGINAL doc id of the j-th real (unmasked) row in
     packed order; `inv_perm` inverts it, so `values[inv_perm]` restores
@@ -420,13 +421,10 @@ class BucketedLayout:
         return packed_rows[self.inv_perm]
 
 
-def _bucket_len(n: int, min_bucket: int) -> int:
-    if min_bucket < 1:
-        raise ValueError(f"min_bucket_len must be >= 1, got {min_bucket}")
-    b = min_bucket
-    while b < n:
-        b *= 2
-    return b
+# Tokens one slab of make_batches' fill holds a destination index for:
+# 2 MiB of int64, so the index and the cells it scatters into stay in
+# cache (the whole day's index at once ran at memory speed, PERF.md PR 32).
+_FILL_SLAB_TOKENS = 1 << 18
 
 
 def make_batches(
@@ -440,6 +438,8 @@ def make_batches(
 
     Returns batches ordered by bucket then position; the union of doc_index
     over all batches (where doc_mask == 1) is exactly range(num_docs).
+    Bucket lengths are min_bucket_len * 2**j; within a bucket documents
+    keep corpus order and are cut every `batch_size`.
 
     With `pad_multiple` set, an under-full bucket pads its batch axis
     to the next multiple of it instead of the full `batch_size` (full
@@ -453,35 +453,94 @@ def make_batches(
     train_corpus_online thread it from their mesh; the None default
     keeps the old full-batch_size padding, so direct callers that
     shard over meshes this module can't see stay correct.
+
+    The fill is array operations over the CSR arrays: one stable sort
+    of the documents by bucket, each document's first padded cell, and
+    per slab of `_FILL_SLAB_TOKENS` tokens one destination index and
+    one scatter each of word ids and counts.  Python loops run once a
+    bucket, a batch and a slab, never once a document or a token.  A
+    pure function: every call batches afresh.  Each field's batches
+    are C-contiguous [B, L] views of one buffer, which lives while any
+    of them does.  tests/test_make_batches.py holds it to the
+    per-document loop it replaced, array for array.
     """
     if pad_multiple is None:
         pad_multiple = batch_size
+    num_docs = corpus.num_docs
+    if num_docs == 0:
+        return []
+    if min_bucket_len < 1:
+        raise ValueError(
+            f"min_bucket_len must be >= 1, got {min_bucket_len}"
+        )
     lengths = corpus.doc_lengths()
-    buckets: dict[int, list[int]] = {}
-    for d in range(corpus.num_docs):
-        # Empty docs (possible only via hand-built corpora) ride the smallest
-        # bucket; their zero counts make them inert anyway.
-        L = _bucket_len(max(int(lengths[d]), 1), min_bucket_len)
-        buckets.setdefault(L, []).append(d)
+    # The bucket lengths up to the longest document, as Python integers,
+    # and each document's bucket by comparison against them: exact for
+    # any min_bucket_len (a document of 32 words rides 32, of 33 rides
+    # 64).  Empty docs (possible only via hand-built corpora) ride the
+    # smallest bucket; their zero counts make them inert anyway.
+    bucket_lens = [min_bucket_len]
+    longest = int(lengths.max())
+    while bucket_lens[-1] < longest:
+        bucket_lens.append(bucket_lens[-1] * 2)
+    bucket = np.searchsorted(
+        np.asarray(bucket_lens, dtype=np.int64), lengths, side="left"
+    ).astype(np.uint8)
+    # Stable: corpus order within a bucket.
+    order = np.argsort(bucket, kind="stable")
+    docs_per_bucket = np.bincount(bucket, minlength=len(bucket_lens))
 
-    batches: list[Batch] = []
-    for L in sorted(buckets):
-        docs = buckets[L]
+    # Lay the batches end to end in one flat run of cells.  shift[d]
+    # takes document d's tokens from their CSR positions to their
+    # cells: token t of the corpus lands in cell t + shift[doc of t].
+    doc_ptr = corpus.doc_ptr
+    shift = np.empty(num_docs, dtype=np.intp)
+    layout: list[tuple[int, int, int, np.ndarray, np.ndarray]] = []
+    cells = 0
+    per_bucket = np.split(order, np.cumsum(docs_per_bucket)[:-1])
+    for L, docs in zip(bucket_lens, per_bucket):
+        if not len(docs):
+            continue
         bucket_b = min(batch_size,
                        -(-len(docs) // pad_multiple) * pad_multiple)
         for start in range(0, len(docs), batch_size):
             chunk = docs[start : start + batch_size]
             B = bucket_b if pad_batch_to_multiple else len(chunk)
-            widx = np.zeros((B, L), dtype=np.int32)
-            cnts = np.zeros((B, L), dtype=np.float32)
+            shift[chunk] = (
+                np.arange(cells, cells + len(chunk) * L, L) - doc_ptr[chunk]
+            )
             didx = np.zeros((B,), dtype=np.int32)
+            didx[: len(chunk)] = chunk
             mask = np.zeros((B,), dtype=np.float32)
-            for i, d in enumerate(chunk):
-                lo, hi = int(corpus.doc_ptr[d]), int(corpus.doc_ptr[d + 1])
-                n = hi - lo
-                widx[i, :n] = corpus.word_idx[lo:hi]
-                cnts[i, :n] = corpus.counts[lo:hi]
-                didx[i] = d
-                mask[i] = 1.0
-            batches.append(Batch(widx, cnts, didx, mask))
-    return batches
+            mask[: len(chunk)] = 1.0
+            layout.append((cells, B, L, didx, mask))
+            cells += B * L
+
+    widx = np.zeros(cells, dtype=np.int32)
+    cnts = np.zeros(cells, dtype=np.float32)
+    tok_lo, tok_hi = int(doc_ptr[0]), int(doc_ptr[-1])
+    cuts = np.unique(np.append(
+        np.searchsorted(
+            doc_ptr, np.arange(tok_lo, tok_hi, _FILL_SLAB_TOKENS),
+            side="left",
+        ),
+        num_docs,
+    )).tolist()
+    for d0, d1 in zip(cuts[:-1], cuts[1:]):
+        t0, t1 = int(doc_ptr[d0]), int(doc_ptr[d1])
+        # intp: numpy converts any other index width before it scatters.
+        cell = np.repeat(shift[d0:d1], lengths[d0:d1])
+        cell += np.arange(t0, t1, dtype=np.intp)
+        # Cast the slab first: a scatter that also casts takes numpy's
+        # buffered path.
+        widx[cell] = corpus.word_idx[t0:t1].astype(np.int32, copy=False)
+        cnts[cell] = corpus.counts[t0:t1].astype(np.float32, copy=False)
+
+    return [
+        Batch(
+            widx[at : at + B * L].reshape(B, L),
+            cnts[at : at + B * L].reshape(B, L),
+            didx, mask,
+        )
+        for at, B, L, didx, mask in layout
+    ]

@@ -1945,7 +1945,7 @@ def _train_corpus(
             # gamma scatter restores document order bit-exactly
             # (layout.inv_perm is the same map, pinned by tests).
             batches = list(sparse_layout.batches)
-            sp.annotate(**_batch_counts(batches))
+            sp.annotate(**_batch_counts(batches, [corpus]))
         e_fn = sparse_estep.make_e_step_fn(precision=config.dense_precision)
     data_size = 1
     if mesh is not None:
@@ -1965,7 +1965,7 @@ def _train_corpus(
                 # batch).
                 pad_multiple=8 * data_size,
             )
-            sp.annotate(**_batch_counts(batches))
+            sp.annotate(**_batch_counts(batches, [corpus]))
     with maybe_span("fit.init", what="trainer"):
         trainer = LDATrainer(
             config,
@@ -2006,13 +2006,18 @@ def _train_corpus(
     return result
 
 
-def _batch_counts(batches) -> dict:
+def _batch_counts(batches, corpora) -> dict:
     """What the `fit.batches` span counts: the batches, their padded
-    rows and their distinct shapes."""
+    rows and their distinct shapes, and the work of the fill: `tokens`,
+    the real (document, word) cells of the corpora that were batched
+    (their CSR length: no pass over the batches), placed into `cells`
+    padded ones (the sum of B * L over the batches' shapes)."""
     return {
         "batches": len(batches),
         "rows": sum(b.word_idx.shape[0] for b in batches),
         "shapes": len({b.word_idx.shape for b in batches}),
+        "tokens": sum(len(c.word_idx) for c in corpora),
+        "cells": sum(b.word_idx.size for b in batches),
     }
 
 
@@ -2221,7 +2226,7 @@ def _train_corpus_distributed(
             for s, sc in shard_corpora.items()
         }
         flat = [b for s in sorted(shard_batches) for b in shard_batches[s]]
-        sp.annotate(**_batch_counts(flat))
+        sp.annotate(**_batch_counts(flat, shard_corpora.values()))
 
     with maybe_span("fit.init", what="trainer"):
         trainer = LDATrainer(

@@ -14,6 +14,7 @@ import pytest
 
 from oni_ml_tpu.config import LDAConfig
 from oni_ml_tpu.io.corpus import Corpus, make_batches
+from oni_ml_tpu.models import lda as lda_mod
 from oni_ml_tpu.models.lda import train_corpus
 from oni_ml_tpu.ops import dense_estep
 from oni_ml_tpu.telemetry import roofline, spans
@@ -51,10 +52,14 @@ def _config(**kw):
     return LDAConfig(**dict(base, **kw))
 
 
-def _padded_rows(corpus, cfg):
-    return sum(b.word_idx.shape[0] for b in make_batches(
+def _batches(corpus, cfg):
+    return make_batches(
         corpus, batch_size=cfg.batch_size,
-        min_bucket_len=cfg.min_bucket_len, pad_multiple=8))
+        min_bucket_len=cfg.min_bucket_len, pad_multiple=8)
+
+
+def _padded_rows(corpus, cfg):
+    return sum(b.word_idx.shape[0] for b in _batches(corpus, cfg))
 
 
 @pytest.fixture(scope="module")
@@ -127,6 +132,48 @@ def test_fit_span_counts_what_the_fit_ran(recorded_fits, driver):
                  if e["name"] == "em.run_chunk" and e["root"] == root["id"]]
         assert first[0] is True and not any(first[1:])
         assert args["kernel"] == result.plan["estep_kernel"]["value"]
+
+
+# site of `fit.batches` -> (config overrides, train_corpus keywords)
+BATCH_SITES = {
+    "make_batches": (dict(), dict()),
+    "bucketed": (dict(estep_engine="sparse", sparse_min_bucket_len=16),
+                 dict()),
+    "distributed": (dict(em_shards=3), dict(distributed=True)),
+    "distributed_bucketed": (
+        dict(em_shards=3, estep_engine="sparse", sparse_min_bucket_len=16),
+        dict(distributed=True)),
+}
+
+
+@pytest.mark.parametrize("site", sorted(BATCH_SITES))
+def test_fit_batches_counts_the_tokens_placed_and_the_padded_cells(site):
+    """`tokens` is the corpus' nnz and `cells` the sum of B * L, beside
+    `batches`, `rows` and `shapes`, at every site that batches a fit."""
+    cfg_kw, train_kw = BATCH_SITES[site]
+    corpus, cfg = _corpus(), _config(em_max_iters=1, **cfg_kw)
+    rec = spans.Recorder()
+    handed = []
+    real_fit = lda_mod.LDATrainer.fit
+
+    def spy_fit(self, batches, *a, **kw):
+        handed.extend(batches)
+        return real_fit(self, batches, *a, **kw)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(lda_mod.LDATrainer, "fit", spy_fit)
+        with spans.use_recorder(rec):
+            train_corpus(corpus, cfg, **train_kw)
+    (span,) = [e for e in rec.events if e["name"] == "fit.batches"]
+    args = span["args"]
+    assert args["tokens"] == len(corpus.word_idx) == int(corpus.doc_ptr[-1])
+    assert args["cells"] == sum(b.word_idx.size for b in handed)
+    assert args["batches"] == len(handed)
+    assert args["rows"] == sum(b.word_idx.shape[0] for b in handed)
+    assert args["shapes"] == len({b.word_idx.shape for b in handed})
+    assert args["tokens"] < args["cells"]
+    # every real token sits in exactly one cell
+    assert sum(int((b.counts > 0).sum()) for b in handed) == args["tokens"]
 
 
 @pytest.mark.parametrize("driver", sorted(DRIVERS))
@@ -332,6 +379,10 @@ def test_a_fit_under_the_profiler_puts_its_spans_in_the_trace(tmp_path):
     assert counts["kernel"] == result.plan["estep_kernel"]["value"]
     assert by_name["fit.batches.counts"][0][2]["rows"] == _padded_rows(
         corpus, cfg)
+    placed = by_name["fit.batches.counts"][0][2]
+    assert placed["tokens"] == len(corpus.word_idx)
+    assert placed["cells"] == sum(
+        b.word_idx.size for b in _batches(corpus, cfg))
     assert by_name["em.host_sync.counts"][0][2]["steps"] == 4
     plan = by_name["fit.plan.counts"][0][2]
     assert (plan["cell_scan"], plan["scan_tokens"]) == ("none", 0)
