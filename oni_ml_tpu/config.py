@@ -118,8 +118,12 @@ class LDAConfig:
     # giving up on the MXU path.  ONI_ML_TPU_ESTEP=dense/compact/xla/
     # pallas overrides.
     dense_em: str = "auto"
-    # Device-byte ceiling for the densified corpus under dense_em="auto".
-    dense_hbm_budget: int = 2 * 1024**3
+    # Device-byte ceiling (per device) for the densified corpus under
+    # dense_em="auto".  None (the default: not stated) follows the device:
+    # three quarters of its own memory limit (`memory_stats()`'s
+    # `bytes_limit`), or 2 GiB where the backend reports none (the CPU) --
+    # models/lda.py dense_budget.  A stated value is obeyed as it is.
+    dense_hbm_budget: "int | None" = None
     # Warm-start each EM iteration's variational fixed point from the
     # previous iteration's gamma instead of the reference's fresh
     # alpha + N_d/K init (every in-package engine: XLA, Pallas, dense,

@@ -149,20 +149,28 @@ class LDAResult:
         directory: str,
         num_terms: int | None = None,
         include_likelihood: bool = True,
-    ) -> None:
+    ) -> dict:
         """Write final.beta / final.gamma / final.other (and, unless the
         trainer already streamed it, likelihood.dat) with the reference
-        formats (README.md:116-119)."""
+        formats (README.md:116-119).  Every file is complete and closed
+        when this returns.  Returns what the `fit.save` span counts: the
+        bytes of each file, and the `rows` and `values` of the two
+        matrices together."""
         k, v = self.log_beta.shape
-        formats.write_beta(os.path.join(directory, "final.beta"), self.log_beta)
-        formats.write_gamma(os.path.join(directory, "final.gamma"), self.gamma)
-        formats.write_other(
-            os.path.join(directory, "final.other"), k, num_terms or v, self.alpha
-        )
+        paths = {name: os.path.join(directory, "final." + name)
+                 for name in ("beta", "gamma", "other")}
+        formats.write_beta(paths["beta"], self.log_beta)
+        formats.write_gamma(paths["gamma"], self.gamma)
+        formats.write_other(paths["other"], k, num_terms or v, self.alpha)
         if include_likelihood:
             with open(os.path.join(directory, "likelihood.dat"), "w") as f:
                 for ll, conv in self.likelihoods:
                     formats.append_likelihood(f, ll, conv)
+        counts = {f"{name}_bytes": os.path.getsize(path)
+                  for name, path in paths.items()}
+        counts["rows"] = k + len(self.gamma)
+        counts["values"] = int(self.log_beta.size + np.size(self.gamma))
+        return counts
 
 
 def to_host(x, mesh=None) -> np.ndarray:
@@ -260,12 +268,36 @@ class _EStepPlan(NamedTuple):
     store: object = None    # the dense corpus' dtype; None: token lists
     cell_scan: str = "none"     # what dense_estep.corpus_store_dtype read
     scan_tokens: int = 0
+    budget: int = 0             # dense_budget(): bytes a device the dense
+    budget_source: str = "stated"   # families were held to, and where from
     compact: "fused.CompactPlan | None" = None
     dense_e_fn: Callable | None = None      # the sharded dense E-step
     dense_put: Callable | None = None       # its corpus' device layout
     dense_mesh: object = None               # densify under this mesh
     dense_width: int | None = None          # densify to this width
     compiler_options: dict | None = None    # the scoped-VMEM limit
+
+
+# What an unstated `dense_hbm_budget` falls back to where the backend
+# reports no memory limit (the CPU).
+FALLBACK_DENSE_BUDGET = 2 * 1024**3
+
+
+def dense_budget(config: LDAConfig, mesh=None) -> tuple[int, str]:
+    """(bytes a device, source) the dense E-step families are held to:
+    `config.dense_hbm_budget` where it is stated ("stated"); else three
+    quarters of the device's own memory limit ("device": 11.81 GiB of a
+    v5e's 15.75 GiB, so an entry point that cannot state a budget -- the
+    lda-c drop-in CLI -- still fills the chip); else 2 GiB ("fallback":
+    the CPU reports no limit)."""
+    if config.dense_hbm_budget is not None:
+        return int(config.dense_hbm_budget), "stated"
+    device = (jax.local_devices()[0] if mesh is None
+              else mesh.devices.flat[0])
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        return int(limit) * 3 // 4, "device"
+    return FALLBACK_DENSE_BUDGET, "fallback"
 
 
 class LDATrainer:
@@ -647,45 +679,46 @@ class LDATrainer:
             ll_prev = ll
 
         if rl is not None:
-            # Harvest the stepwise driver's jitted entry points — the
-            # per-batch E-step and the alpha Newton are the "E-step" and
-            # "alpha update" roofline phases (the fused driver inlines
-            # both into em.run_chunk).  Done post-loop: the programs are
-            # already traced (cache-hit lowering), and with warm starts
-            # the warm variant dominated dispatches (all but the first
-            # iteration), so price against the variant that actually
-            # ran the majority — a mixed run is an approximation the
-            # record's shape suffix names.
-            b0 = batches[0].word_idx.shape[0]
-            widx0, cnts0, mask0 = dev_batches[0]
-            if n_warm_disp * 2 >= n_e_disp and gammas:
-                rl.ensure_harvested(
-                    "em.e_step", self._e_step_warm, log_beta, alpha,
-                    widx0, cnts0, mask0, gammas[0],
-                    jnp.asarray(1, jnp.int32), shape=f"b{b0}.warm",
-                )
-            else:
-                rl.ensure_harvested(
-                    "em.e_step", self._e_step, log_beta, alpha, widx0,
-                    cnts0, mask0, shape=f"b{b0}",
-                )
-            if n_a_disp:
-                rl.ensure_harvested(
-                    "em.update_alpha", update_alpha,
-                    jnp.zeros((), dtype), alpha, num_docs, k,
-                    max_iters=cfg.alpha_max_iters,
-                )
-            # One roofline record per stepwise phase, joined with the
-            # loop wall (the E-step dominates it; the alpha Newton's
-            # record shares the wall and self-describes via
-            # `wall_shared`) — journaled as {"kind": "roofline"} and
-            # published as roofline.* gauges.
-            wall_s = (now_ns() - t_loop0) / 1e9
-            rl.emit("em.e_step", wall_s, dispatches=n_e_disp,
-                    em_iters=it - start_it)
-            if n_a_disp:
-                rl.emit("em.update_alpha", wall_s, dispatches=n_a_disp,
-                        wall_shared="em.e_step")
+            with maybe_span("fit.roofline"):
+                # Harvest the stepwise driver's jitted entry points — the
+                # per-batch E-step and the alpha Newton are the "E-step" and
+                # "alpha update" roofline phases (the fused driver inlines
+                # both into em.run_chunk).  Done post-loop: the programs are
+                # already traced (cache-hit lowering), and with warm starts
+                # the warm variant dominated dispatches (all but the first
+                # iteration), so price against the variant that actually
+                # ran the majority — a mixed run is an approximation the
+                # record's shape suffix names.
+                b0 = batches[0].word_idx.shape[0]
+                widx0, cnts0, mask0 = dev_batches[0]
+                if n_warm_disp * 2 >= n_e_disp and gammas:
+                    rl.ensure_harvested(
+                        "em.e_step", self._e_step_warm, log_beta, alpha,
+                        widx0, cnts0, mask0, gammas[0],
+                        jnp.asarray(1, jnp.int32), shape=f"b{b0}.warm",
+                    )
+                else:
+                    rl.ensure_harvested(
+                        "em.e_step", self._e_step, log_beta, alpha, widx0,
+                        cnts0, mask0, shape=f"b{b0}",
+                    )
+                if n_a_disp:
+                    rl.ensure_harvested(
+                        "em.update_alpha", update_alpha,
+                        jnp.zeros((), dtype), alpha, num_docs, k,
+                        max_iters=cfg.alpha_max_iters,
+                    )
+                # One roofline record per stepwise phase, joined with the
+                # loop wall (the E-step dominates it; the alpha Newton's
+                # record shares the wall and self-describes via
+                # `wall_shared`) — journaled as {"kind": "roofline"} and
+                # published as roofline.* gauges.
+                wall_s = (now_ns() - t_loop0) / 1e9
+                rl.emit("em.e_step", wall_s, dispatches=n_e_disp,
+                        em_iters=it - start_it)
+                if n_a_disp:
+                    rl.emit("em.update_alpha", wall_s, dispatches=n_a_disp,
+                            wall_shared="em.e_step")
 
         with maybe_span("fit.readback", what="gamma"):
             for g, b in zip(gammas, batches):
@@ -953,7 +986,7 @@ class LDATrainer:
                 "rows_per_shard_min": int(rows.min()),
                 "rows_per_shard_max": int(rows.max())}
 
-    def _use_dense_vocab_sharded(self, batches, mode) -> bool:
+    def _use_dense_vocab_sharded(self, batches, mode, budget) -> bool:
         """Gate for the vocab-sharded dense plan
         (parallel.make_vocab_sharded_dense_e_step): an XLA-level matmul
         fixed point with C and beta sharded over `model` — config 4's
@@ -993,7 +1026,7 @@ class LDATrainer:
         resident = total_docs // d * (self.num_terms // m) * 4
         return (
             transient + resident + sparse_bytes
-            <= self.config.dense_hbm_budget
+            <= budget
         )
 
     def _plan_estep(self, batches) -> _EStepPlan:
@@ -1008,7 +1041,9 @@ class LDATrainer:
         E-step or this package's own sharded wrappers (a user's custom
         e_step_fn must not be silently bypassed), a VMEM-feasible doc
         block for every PER-SHARD batch shape, and the densified corpus
-        under the HBM budget.  With a data mesh the Pallas kernel runs
+        under the HBM budget (`dense_budget`: the configuration's where it
+        states one, else three quarters of the device's own memory
+        limit).  With a data mesh the Pallas kernel runs
         under shard_map (parallel.make_data_parallel_dense_e_step),
         suff-stats psum'd over ICI; a vocab-sharded trainer takes the
         XLA-level make_vocab_sharded_dense_e_step plan instead
@@ -1030,6 +1065,7 @@ class LDATrainer:
         cfg = self.config
         k, v = cfg.num_topics, self.num_terms
         precision = cfg.dense_precision
+        budget, budget_source = dense_budget(cfg, self.mesh)
         env = _estep_env()
         on_tpu = jax.default_backend() == "tpu"
         shards = 1
@@ -1058,7 +1094,7 @@ class LDATrainer:
         if mode == "off":
             pass
         elif self.vocab_sharded:
-            dense = self._use_dense_vocab_sharded(batches, mode)
+            dense = self._use_dense_vocab_sharded(batches, mode, budget)
         elif custom:
             if mode == "on":
                 raise ValueError(
@@ -1079,7 +1115,7 @@ class LDATrainer:
                 dense = (
                     feasible and on_tpu
                     and fused.dense_groups_bytes(batches, v) // shards
-                    + sparse_bytes <= cfg.dense_hbm_budget
+                    + sparse_bytes <= budget
                 )
             elif feasible:
                 dense = True
@@ -1146,14 +1182,15 @@ class LDATrainer:
                     sum(b.word_idx.size * 8 for b in batches
                         if b.word_idx.shape == shape)
                     for shape in shapes)
-                if compact.corpus_bytes + stacks > cfg.dense_hbm_budget:
+                if compact.corpus_bytes + stacks > budget:
                     compact = None
 
         # -- the family: its kernel's name and layout, and the (rows, width)
         # blocks of its Pallas kernel, `kib` their scoped-VMEM need
         all_rows = sum(b.word_idx.shape[0] for b in batches)
         plan = dict(store=store, cell_scan=cell_scan,
-                    scan_tokens=scan_tokens)
+                    scan_tokens=scan_tokens, budget=budget,
+                    budget_source=budget_source)
         blocks, kib = [], None
         if dense and self.vocab_sharded:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1291,7 +1328,11 @@ class LDATrainer:
         with maybe_span("fit.plan", batches=len(batches)) as sp:
             plan = self._plan_estep(batches)
             sp.annotate(kernel=plan.kernel, cell_scan=plan.cell_scan,
-                        scan_tokens=plan.scan_tokens)
+                        scan_tokens=plan.scan_tokens,
+                        dense_budget=plan.budget,
+                        dense_budget_source=plan.budget_source)
+            self.plan_record["dense_hbm_budget"] = {
+                "value": plan.budget, "source": plan.budget_source}
             self.plan_record["exchange"] = self._exchange(batches, num_docs)
 
         # -- placement: the stack (fit.stack), then densify (fit.densify) -
@@ -1845,12 +1886,16 @@ def train_corpus(
     The whole call is the span `fit` (telemetry/spans.py), the root the
     fit's layer boundaries hang under: fit.engine, fit.batches,
     fit.init, fit.plan, fit.stack, fit.densify, fit.runner,
-    em.run_chunk / em.host_sync, fit.readback, fit.save.  On its close
-    it counts what the fit ran (`em_iters`, `doc_sweeps`, the engine and
-    kernel), what the mesh added (`data_shards`, `allreduce_bytes` an EM
-    iteration and device, `rows_per_shard_min` / `_max`: LDATrainer.
-    _exchange) and, while plans.warmup's listener is live, jax's compile
-    counters across the fit.
+    em.run_chunk / em.host_sync, fit.readback, fit.save (which counts the
+    bytes of each file it wrote, and the matrices' `rows` and `values`),
+    fit.teardown.  On its close it counts what the fit ran (`em_iters`,
+    `doc_sweeps`, the engine and kernel), which dense budget the plan
+    held and where it came from (`dense_budget`, `dense_budget_source`:
+    stated / device / fallback -- `dense_budget()`), the lines appended
+    to likelihood.dat (`ll_lines`), what the mesh added (`data_shards`,
+    `allreduce_bytes` an EM iteration and device, `rows_per_shard_min` /
+    `_max`: LDATrainer._exchange) and, while plans.warmup's listener is
+    live, jax's compile counters across the fit.
     """
     if distributed is None:
         distributed = jax.process_count() > 1
@@ -1875,6 +1920,14 @@ def train_corpus(
             vi_max=result.vi_max,
         )
         sp.annotate(**result.plan.get("exchange", {}))
+        budget = result.plan.get("dense_hbm_budget")
+        if budget:      # the fused driver's plan: which budget, whose
+            sp.annotate(dense_budget=budget["value"],
+                        dense_budget_source=budget["source"])
+        if out_dir and _is_coordinator():
+            # likelihood.dat: one line an EM iteration, appended as they
+            # came (LDATrainer._log_iteration).
+            sp.annotate(ll_lines=len(result.likelihoods))
         if compiles0 is not None:
             delta = warmup.counts_delta(compiles0)
             sp.annotate(**{key: delta[key] for key in (
@@ -2000,9 +2053,16 @@ def _train_corpus(
         # likelihood.dat was already streamed (crash-safe) during fit;
         # multi-host: the result is identical on every process (to_host
         # gathers collectively) but only the coordinator owns the files.
-        with maybe_span("fit.save"):
-            result.save(out_dir, num_terms=corpus.num_terms,
-                        include_likelihood=False)
+        with maybe_span("fit.save") as sp:
+            sp.annotate(**result.save(out_dir, num_terms=corpus.num_terms,
+                                      include_likelihood=False))
+    with maybe_span("fit.teardown"):
+        # Dropping the trainer drops its jitted entry points and their
+        # executables (the stepwise driver's: 24 ms on the CPU, 5% of a
+        # small fit), and the batches' host buffers go back to malloc
+        # (two munmaps of 50 MB at a flow day's size): time of the fit
+        # that lay under no span while it happened at the return.
+        del trainer, batches, sparse_layout
     return result
 
 
@@ -2300,7 +2360,7 @@ def _train_corpus_distributed(
     if num_terms != corpus.num_terms:
         result.log_beta = result.log_beta[:, : corpus.num_terms]
     if out_dir and save_final and _is_coordinator():
-        with maybe_span("fit.save"):
-            result.save(out_dir, num_terms=corpus.num_terms,
-                        include_likelihood=False)
+        with maybe_span("fit.save") as sp:
+            sp.annotate(**result.save(out_dir, num_terms=corpus.num_terms,
+                                      include_likelihood=False))
     return result

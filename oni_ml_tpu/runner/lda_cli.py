@@ -23,6 +23,21 @@ Differences from the reference, by design:
   were an MPI implementation artifact; `final.*` and `likelihood.dat`
   are the real contract (README.md:116-121).
 
+What holds when `main` returns 0 (the contract `lda_post.py` reads,
+README.md:116-121; benchmarks/configs/flow20_est.json `guarantees`):
+`final.beta` (K rows x V values of log p(word | topic)), `final.gamma`
+(D rows x K, in model.dat's document order), `final.other` (num_topics,
+num_terms, alpha) and `likelihood.dat` (one line an EM iteration:
+likelihood, float64 |dll/ll|) are complete, closed and readable by a
+process that opens them after the return; every value carries ten digits
+after the point (`%5.10f`; `%10.10f\t%5.5e` for likelihood.dat); nothing
+is written after the return.
+
+Spans (telemetry/spans.py): `est.load` (the model.dat parse; counts
+`bytes`, `docs`, `pairs`), a root of its own before the fit's root
+`fit`, whose `fit.save` counts the bytes of each file written and whose
+close counts `ll_lines`.
+
 settings.txt uses Blei lda-c's key-value format:
 
     var max iter 20
@@ -103,9 +118,15 @@ def main(argv: list[str] | None = None) -> int:
 
     from ..io import Corpus
     from ..models import train_corpus
+    from ..telemetry.spans import maybe_span
 
     cfg = config_from_settings(settings_path, float(alpha_s), int(k_s))
-    corpus = Corpus.from_model_dat(corpus_path)
+    # The load is a span of its own, a root BEFORE the fit's root `fit`
+    # (train_corpus opens that one, for this caller as for any other).
+    with maybe_span("est.load", path=os.path.basename(corpus_path)) as sp:
+        corpus = Corpus.from_model_dat(corpus_path)
+        sp.annotate(bytes=os.path.getsize(corpus_path),
+                    docs=corpus.num_docs, pairs=len(corpus.word_idx))
 
     mesh = None
     vocab_sharded = False
@@ -128,6 +149,16 @@ def main(argv: list[str] | None = None) -> int:
         f"em iterations: {result.em_iters}  "
         f"final likelihood: {final_ll:.6f}  "
         f"alpha: {result.alpha:.6f}"
+    )
+    # Which E-step served the day and under which dense budget: this
+    # surface cannot state one, so the budget follows the device
+    # (models/lda.py dense_budget) and the operator reads here what that
+    # came to.
+    budget = result.plan.get("dense_hbm_budget", {})
+    print(
+        f"engine: {result.plan['estep_engine']['value']}  "
+        f"kernel: {result.plan.get('estep_kernel', {}).get('value')}  "
+        f"dense budget: {budget.get('value')} ({budget.get('source')})"
     )
     return 0
 

@@ -25,12 +25,18 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DRIVERS = {
     "fused_dense": (dict(dense_em="on"),
                     ["fit.engine", "fit.batches", "fit.plan", "fit.stack",
-                     "fit.densify", "fit.runner"]),
+                     "fit.densify", "fit.runner", "fit.teardown"]),
     "fused_xla": (dict(),
                   ["fit.engine", "fit.batches", "fit.plan", "fit.stack",
-                   "fit.runner"]),
+                   "fit.runner", "fit.teardown"]),
     "stepwise": (dict(fused_em_chunk=1),
-                 ["fit.engine", "fit.batches", "fit.stack"]),
+                 # fit.roofline: the stepwise driver's cost harvest under a
+                 # Recorder; fit.teardown: dropping the trainer's own jitted
+                 # E-step (24 ms of a 0.4 s fit on the CPU, which lay under
+                 # no span and left the share below one preemption from
+                 # its bound)
+                 ["fit.engine", "fit.batches", "fit.stack", "fit.roofline",
+                  "fit.teardown"]),
 }
 
 
