@@ -1,4 +1,6 @@
-"""ctypes binding for the native corpus ingest (oni_ml_tpu/native_src/corpus_ingest.cpp).
+"""ctypes binding for the native corpus ingest (oni_ml_tpu/native_src/corpus_ingest.cpp):
+two readers in one library, `load_corpus` for word counts and
+`read_model_dat` for the LDA-C corpus file.
 
 The reference's corpus build (lda_pre.py, SURVEY.md §2.4) is three
 sequential Python passes over the day's word counts — its single-node
@@ -6,6 +8,13 @@ bottleneck.  The native path does one buffered C++ pass and hands back
 CSR arrays + id maps with semantics identical to the pure-Python
 ``Corpus.from_word_counts`` (first-seen-order ids, per-doc token
 grouping), so callers can use whichever is available.
+
+``model.dat`` was the fit stage's last text path left in Python: a loop
+a line and a token (io/formats.read_model_dat), 62% of the drop-in CLI's
+``lda est`` call on a 163,840-document day.  The native reader makes two
+passes over the file's bytes into the same three CSR arrays; it decides
+only the plain grammar and hands every other file back to the loop (see
+``read_model_dat`` below).
 
 Loading strategy (oni_ml_tpu/native_build.py, shared with the native flow
 featurizer): use the prebuilt ``_native/liboni_ingest.so`` (built by
@@ -45,6 +54,19 @@ def _configure(lib: ctypes.CDLL) -> None:
     lib.oni_names_bytes.argtypes = [ctypes.c_void_p, ctypes.c_int32]
     lib.oni_fill_names.argtypes = [
         ctypes.c_void_p, ctypes.c_int32, ctypes.c_char_p
+    ]
+    lib.oni_model_open.restype = ctypes.c_void_p
+    lib.oni_model_open.argtypes = [ctypes.c_char_p]
+    lib.oni_model_close.argtypes = [ctypes.c_void_p]
+    for fn in ("oni_model_num_docs", "oni_model_nnz"):
+        getattr(lib, fn).restype = ctypes.c_int64
+        getattr(lib, fn).argtypes = [ctypes.c_void_p]
+    lib.oni_model_fill.restype = ctypes.c_int32
+    lib.oni_model_fill.argtypes = [
+        ctypes.c_void_p,
+        np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS"),
+        np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
+        np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS"),
     ]
 
 
@@ -114,3 +136,35 @@ def load_corpus(paths: str | list[str]):
         )
     finally:
         lib.oni_ingest_destroy(ctypes.c_void_p(h))
+
+
+def read_model_dat(
+    path: str,
+) -> tuple[np.ndarray, np.ndarray, np.ndarray] | None:
+    """Parse an LDA-C ``model.dat`` natively -> (doc_ptr [D+1] int64,
+    word_idx [NNZ] int32, counts [NNZ] int32), or None when the native
+    pass cannot decide the file.
+
+    It decides exactly the plain grammar: ASCII digits, ``:`` and ASCII
+    whitespace, every line's header equal to its count of ``w:c`` fields,
+    every number within int32.  None means "run the Python loop"
+    (io/formats.read_model_dat), which accepts what ``int()`` accepts and
+    raises its own exceptions: the library is unavailable, the file
+    cannot be read, or it holds anything else (a sign, an underscore, a
+    non-ASCII byte, a short or long line, a value past int32)."""
+    lib = _load()
+    if lib is None:
+        return None
+    h = lib.oni_model_open(os.fsencode(path))
+    if not h:
+        return None
+    try:
+        doc_ptr = np.empty(lib.oni_model_num_docs(h) + 1, dtype=np.int64)
+        nnz = lib.oni_model_nnz(h)
+        word_idx = np.empty(nnz, dtype=np.int32)
+        counts = np.empty(nnz, dtype=np.int32)
+        if lib.oni_model_fill(h, doc_ptr, word_idx, counts) != 0:
+            return None
+        return doc_ptr, word_idx, counts
+    finally:
+        lib.oni_model_close(ctypes.c_void_p(h))

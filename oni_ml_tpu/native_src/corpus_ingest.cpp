@@ -4,7 +4,8 @@
 // sequential interpreter passes over doc_wc.dat with per-line dict
 // lookups.  Here it is one buffered pass in C++ with first-seen-order id
 // assignment (the reference's words.dat/doc.dat line-number contract) and
-// CSR output ready for device batching.
+// CSR output ready for device batching.  A second entry below reads the
+// LDA-C model.dat the fit stage starts from (oni_model_*).
 //
 // Exposed as a C ABI for ctypes (no pybind11 in this image).  Semantics
 // match oni_ml_tpu/io/formats.read_word_counts + Corpus.from_word_counts
@@ -18,10 +19,14 @@
 #include <cstdio>
 #include <cstring>
 #include <deque>
+#include <memory>
+#include <new>
 #include <string>
 #include <string_view>
 #include <unordered_map>
 #include <vector>
+
+#include <sys/stat.h>
 
 namespace {
 
@@ -218,6 +223,135 @@ void oni_fill_names(void* h, int32_t which, char* buf) {
     buf += s.size();
     *buf++ = '\n';
   }
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// model.dat (LDA-C corpus, "N w1:c1 ... wN:cN" a document) -> CSR.
+//
+// The native half of io/formats.read_model_dat, whose Python loop is the
+// specification.  This pass decides only the plain grammar: ASCII digits,
+// ':' and the ASCII whitespace that text mode and str.split() treat as
+// such (space, \t, \v, \f inside a line; \n, \r\n or a lone \r ending
+// one; blank lines skipped).  On anything else -- a sign, an underscore,
+// a byte outside that set, a number past int32 or longer than ten digits,
+// a field with no or two colons, a header that is not its line's count of
+// fields, a file it cannot read -- it says "cannot decide" and the caller
+// runs the loop, which accepts what int() accepts or raises its own error.
+//
+// One read of the file, two passes over its bytes: open counts documents
+// and pairs, fill parses into arrays the caller allocated at that size.
+
+namespace {
+
+enum ByteClass : uint8_t { kOther, kDigit, kColon, kSpace, kEol };
+
+struct ByteClasses {
+  uint8_t of[256] = {};  // kOther
+  ByteClasses() {
+    for (int c = '0'; c <= '9'; ++c) of[c] = kDigit;
+    of[':'] = kColon;
+    of[' '] = of['\t'] = of['\v'] = of['\f'] = kSpace;
+    of['\n'] = of['\r'] = kEol;
+  }
+};
+const ByteClasses kClass;
+
+struct ModelDat {
+  // The file's bytes and one '\n' after them: every scan below stops at
+  // a line end, so none needs a bound of its own.
+  std::unique_ptr<uint8_t[]> bytes;
+  size_t size = 0;  // the '\n' included
+  int64_t num_docs = 0;
+  int64_t nnz = 0;
+};
+
+// 1 to 10 digits at p -> v, p past them.  Ten digits hold every int32
+// and fit an int64; a longer run (leading zeros) is the loop's to read.
+inline bool parse_number(const uint8_t*& p, int64_t& v) {
+  const uint8_t* b = p;
+  v = 0;
+  while (kClass.of[*p] == kDigit) v = v * 10 + (*p++ - '0');
+  return p != b && p - b <= 10;
+}
+
+inline bool ends_field(uint8_t c) {
+  return kClass.of[c] == kSpace || kClass.of[c] == kEol;
+}
+
+}  // namespace
+
+extern "C" {
+
+// Read `path` and count its documents and pairs.  nullptr: cannot decide.
+void* oni_model_open(const char* path) {
+  std::unique_ptr<FILE, int (*)(FILE*)> f(fopen(path, "rb"), fclose);
+  struct stat st;
+  if (!f || fstat(fileno(f.get()), &st) != 0 || !S_ISREG(st.st_mode))
+    return nullptr;
+  const size_t n = (size_t)st.st_size;
+  std::unique_ptr<ModelDat> m(new (std::nothrow) ModelDat());
+  if (m) m->bytes.reset(new (std::nothrow) uint8_t[n + 1]);
+  if (!m || !m->bytes || fread(m->bytes.get(), 1, n, f.get()) != n)
+    return nullptr;
+  uint8_t* bytes = m->bytes.get();
+  bytes[n] = '\n';
+  m->size = n + 1;
+  // A pair is a colon; a document is a line that holds more than spaces.
+  // (Whether the bytes are the plain grammar's is fill's to say.)
+  int64_t docs = 0, pairs = 0;
+  for (size_t i = 0, line = 0; i <= n; ++i) {
+    const uint8_t b = bytes[i];
+    pairs += b == ':';
+    if (b == '\n' || b == '\r') {
+      while (kClass.of[bytes[line]] == kSpace) ++line;
+      docs += line < i;
+      line = i + 1;
+    }
+  }
+  m->num_docs = docs;
+  m->nnz = pairs;
+  return m.release();
+}
+
+void oni_model_close(void* h) { delete static_cast<ModelDat*>(h); }
+
+int64_t oni_model_num_docs(void* h) {
+  return static_cast<ModelDat*>(h)->num_docs;
+}
+
+int64_t oni_model_nnz(void* h) { return static_cast<ModelDat*>(h)->nnz; }
+
+// Parse into doc_ptr [num_docs + 1] i64, word_idx [nnz] i32, counts [nnz]
+// i32.  0: filled; -1: cannot decide (the arrays hold nothing of use).
+int32_t oni_model_fill(void* h, int64_t* doc_ptr, int32_t* word_idx,
+                       int32_t* counts) {
+  const ModelDat& m = *static_cast<ModelDat*>(h);
+  const uint8_t* p = m.bytes.get();
+  const uint8_t* end = p + m.size;
+  int64_t d = 0, pos = 0;
+  doc_ptr[0] = 0;
+  for (;;) {
+    while (p < end && ends_field(*p)) ++p;  // blank lines, indentation
+    if (p == end) break;
+    int64_t n, w, c;
+    if (!parse_number(p, n) || !ends_field(*p)) return -1;
+    const int64_t first = pos;
+    for (;;) {
+      while (kClass.of[*p] == kSpace) ++p;
+      if (kClass.of[*p] == kEol) break;
+      if (!parse_number(p, w) || *p++ != ':' || !parse_number(p, c) ||
+          !ends_field(*p) || w > INT32_MAX || c > INT32_MAX || pos == m.nnz)
+        return -1;
+      word_idx[pos] = (int32_t)w;
+      counts[pos] = (int32_t)c;
+      ++pos;
+    }
+    if (pos - first != n || d == m.num_docs) return -1;
+    doc_ptr[++d] = pos;
+  }
+  return d == m.num_docs && pos == m.nnz ? 0 : -1;
 }
 
 }  // extern "C"

@@ -34,8 +34,9 @@ after the point (`%5.10f`; `%10.10f\t%5.5e` for likelihood.dat); nothing
 is written after the return.
 
 Spans (telemetry/spans.py): `est.load` (the model.dat parse; counts
-`bytes`, `docs`, `pairs`), a root of its own before the fit's root
-`fit`, whose `fit.save` counts the bytes of each file written and whose
+`bytes`, `docs`, `pairs`, and says which `reader` parsed it: `native`
+or `python`, io/formats.read_model_dat), a root of its own before the
+fit's root `fit`, whose `fit.save` counts the bytes of each file written and whose
 close counts `ll_lines`.
 
 settings.txt uses Blei lda-c's key-value format:
@@ -116,7 +117,7 @@ def main(argv: list[str] | None = None) -> int:
         print(f"only 'random' init is supported, got {init!r}", file=sys.stderr)
         return 2
 
-    from ..io import Corpus
+    from ..io import Corpus, formats
     from ..models import train_corpus
     from ..telemetry.spans import maybe_span
 
@@ -126,7 +127,8 @@ def main(argv: list[str] | None = None) -> int:
     with maybe_span("est.load", path=os.path.basename(corpus_path)) as sp:
         corpus = Corpus.from_model_dat(corpus_path)
         sp.annotate(bytes=os.path.getsize(corpus_path),
-                    docs=corpus.num_docs, pairs=len(corpus.word_idx))
+                    docs=corpus.num_docs, pairs=len(corpus.word_idx),
+                    reader=formats.model_dat_reader)
 
     mesh = None
     vocab_sharded = False

@@ -240,6 +240,49 @@ def test_cli_spans_the_load_and_counts_the_files(tmp_path):
         assert fit["args"]["dense_budget_source"] == "fallback"
 
 
+def _native_ingest():
+    from oni_ml_tpu.io import native
+
+    if not native.available():
+        pytest.skip("native ingest not built and no g++")
+    return native
+
+
+@pytest.mark.parametrize("reader", ["native", "python"])
+def test_cli_load_span_names_the_reader(tmp_path, monkeypatch, reader):
+    """`est.load` says which reader parsed model.dat: `native`, or `python`
+    where the library is not to be had."""
+    native = _native_ingest()
+    if reader == "python":
+        monkeypatch.setattr(native, "available", lambda: False)
+    day, (ptr, words, _, _) = _seeded_day(tmp_path)
+    rec = spans.Recorder()
+    with spans.use_recorder(rec):
+        assert _est(day, tmp_path / "out") == 0
+    (load,) = [e for e in rec.events if e["name"] == "est.load"]
+    assert load["args"]["reader"] == reader
+    assert load["args"]["docs"] == len(ptr) - 1
+    assert load["args"]["pairs"] == len(words)
+
+
+def test_cli_same_files_and_lines_under_both_readers(tmp_path, monkeypatch,
+                                                     capsys):
+    """The reader is no part of the result: the four files are the same
+    bytes and `main` prints the same lines under either."""
+    native = _native_ingest()
+    day, _ = _seeded_day(tmp_path)
+    assert _est(day, tmp_path / "native") == 0
+    assert formats.model_dat_reader == "native"
+    said_native = capsys.readouterr().out
+    monkeypatch.setattr(native, "available", lambda: False)
+    assert _est(day, tmp_path / "python") == 0
+    assert formats.model_dat_reader == "python"
+    assert capsys.readouterr().out == said_native
+    for name in ldac_files.FILES:
+        assert (tmp_path / "native" / name).read_bytes() == (
+            tmp_path / "python" / name).read_bytes(), name
+
+
 class _Device:
     def __init__(self, stats):
         self._stats = stats

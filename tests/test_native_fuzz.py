@@ -253,3 +253,61 @@ def test_narrow_i32_guards_overflow():
         assert mod._narrow_i32(np.zeros(0, dtype=np.int64)).dtype == np.int32
         with pytest.raises(OverflowError):
             mod._narrow_i32(np.array([1, 2**31], dtype=np.int64))
+
+
+_MODEL_DAT_ALPHABET = (
+    b"0123456789" * 3 + b"::  \n\n\r\t\x0b\x0c" + b"-+_a.,\x00\x1f\x85\xa0\xe9\xc2\xd9"
+)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_fuzz_model_dat_readers_parity(seed, tmp_path, monkeypatch):
+    """Randomized parity for the native model.dat reader: random ragged
+    corpora through write_model_dat come back from both readers as they
+    went in, and a good file with random bytes replaced, inserted and
+    deleted gives the same outcome from both: the same arrays, dtype for
+    dtype, or the same exception with the same message."""
+    from oni_ml_tpu.io import formats, native
+    from test_native_ingest import (
+        _assert_same_outcome, _native_and_loop, _ragged_csr,
+    )
+
+    if not native.available():
+        pytest.skip("native ingest unavailable")
+    rng = np.random.default_rng(2000 + seed)
+    path = str(tmp_path / "model.dat")
+
+    def both():
+        got, reader, want = _native_and_loop(path, monkeypatch)
+        _assert_same_outcome(got, want)
+        if isinstance(want[0], type):   # the loop raised: it alone decided
+            assert reader == "python"
+        return got, reader
+
+    csr = _ragged_csr(rng, int(rng.integers(0, 60)), max_len=15,
+                      max_id=1 << 31, max_count=1 << 31)
+    formats.write_model_dat(path, *csr)
+    got, reader = both()
+    assert reader == "native"
+    _assert_same_outcome(got, csr)
+
+    with open(path, "rb") as f:
+        good = f.read() or b"1 0:1\n"
+    readers = set()
+    for _ in range(150):
+        data = bytearray(good)
+        for _ in range(int(rng.integers(1, 4))):
+            at = int(rng.integers(0, len(data) + 1))
+            byte = _MODEL_DAT_ALPHABET[
+                int(rng.integers(0, len(_MODEL_DAT_ALPHABET)))]
+            kind = rng.integers(0, 3)
+            if kind == 0 and at < len(data):
+                data[at] = byte
+            elif kind == 1:
+                data.insert(at, byte)
+            elif at < len(data):
+                del data[at]
+        with open(path, "wb") as f:
+            f.write(data)
+        readers.add(both()[1])
+    assert readers == {"native", "python"}   # both sides of the border
