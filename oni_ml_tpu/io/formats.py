@@ -205,10 +205,40 @@ def read_model_dat(path: str) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
 # lda_post.py:70) is whitespace-tolerant, so we keep the visual format.
 _FLOAT_FMT = "%5.10f"
 
+# Which writer served this process's last write_beta / write_gamma,
+# "native" or "python" (None before the first), as `model_dat_reader`
+# says of the parse: LDAResult.save reports it on the `fit.save` span.
+matrix_writer: str | None = None
+
+
+def _write_matrix(path: str, a: np.ndarray) -> None:
+    """`a` as float64 lines of "%5.10f" values joined by one space.
+
+    Native fast path: one pass in C++ over the float64 array, written
+    slab by slab, when the emit library is available
+    (native_emit.matrix_emit; 1.50 s -> 0.08 s on a 163,840-document day's
+    final.gamma and final.beta).  np.savetxt is the fallback and the specification: the
+    native pass prints every float64, non-finite ones included, as
+    Python's ``"%5.10f" % x`` does, and hands whatever it cannot write
+    (another rank than 2, a file it cannot open) to np.savetxt, so no
+    input changes its bytes or its exception (parity pinned by
+    tests/test_native_matrix_emit.py).  Either way the file is complete
+    and closed at the return."""
+    global matrix_writer
+    from ..native_emit import matrix_emit
+
+    a = np.asarray(a, dtype=np.float64)
+    if matrix_emit(path, a):
+        matrix_writer = "native"
+        return
+    matrix_writer = "python"
+    np.savetxt(path, a, fmt=_FLOAT_FMT)
+
 
 def write_beta(path: str, log_beta: np.ndarray) -> None:
-    """K x V matrix of log p(word|topic), one topic per row."""
-    np.savetxt(path, np.asarray(log_beta, dtype=np.float64), fmt=_FLOAT_FMT)
+    """K x V matrix of log p(word|topic), one topic per row (native
+    fast path, np.savetxt as fallback: `_write_matrix`)."""
+    _write_matrix(path, log_beta)
 
 
 def read_beta(path: str) -> np.ndarray:
@@ -218,8 +248,9 @@ def read_beta(path: str) -> np.ndarray:
 
 
 def write_gamma(path: str, gamma: np.ndarray) -> None:
-    """D x K matrix of unnormalized doc-topic Dirichlet parameters."""
-    np.savetxt(path, np.asarray(gamma, dtype=np.float64), fmt=_FLOAT_FMT)
+    """D x K matrix of unnormalized doc-topic Dirichlet parameters
+    (native fast path, np.savetxt as fallback: `_write_matrix`)."""
+    _write_matrix(path, gamma)
 
 
 def read_gamma(path: str) -> np.ndarray:

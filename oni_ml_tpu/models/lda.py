@@ -154,8 +154,9 @@ class LDAResult:
         trainer already streamed it, likelihood.dat) with the reference
         formats (README.md:116-119).  Every file is complete and closed
         when this returns.  Returns what the `fit.save` span counts: the
-        bytes of each file, and the `rows` and `values` of the two
-        matrices together."""
+        bytes of each file, the `rows` and `values` of the two matrices
+        together, and which `writer` wrote them: `native` or `python`
+        (io/formats._write_matrix)."""
         k, v = self.log_beta.shape
         paths = {name: os.path.join(directory, "final." + name)
                  for name in ("beta", "gamma", "other")}
@@ -170,6 +171,7 @@ class LDAResult:
                   for name, path in paths.items()}
         counts["rows"] = k + len(self.gamma)
         counts["values"] = int(self.log_beta.size + np.size(self.gamma))
+        counts["writer"] = formats.matrix_writer
         return counts
 
 
@@ -1887,7 +1889,8 @@ def train_corpus(
     fit's layer boundaries hang under: fit.engine, fit.batches,
     fit.init, fit.plan, fit.stack, fit.densify, fit.runner,
     em.run_chunk / em.host_sync, fit.readback, fit.save (which counts the
-    bytes of each file it wrote, and the matrices' `rows` and `values`),
+    bytes of each file it wrote, the matrices' `rows` and `values`, and
+    says their `writer`),
     fit.teardown.  On its close it counts what the fit ran (`em_iters`,
     `doc_sweeps`, the engine and kernel), which dense budget the plan
     held and where it came from (`dense_budget`, `dense_budget_source`:

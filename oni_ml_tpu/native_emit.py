@@ -1,14 +1,17 @@
 """ctypes binding for the native emit/score library
 (oni_ml_tpu/native_src/row_emit.cpp) — package-level because it serves
 three layers: the pre stage's word_counts buffer (runner), the corpus
-stage's model.dat buffer (io.formats), and the score stage's scored-CSV
-assembly + fused gather-dot (scoring).
+stage's model.dat buffer and the LDA stage's final.beta / final.gamma
+files (io.formats), and the score stage's scored-CSV assembly + fused
+gather-dot (scoring).
 
 Each emitter builds its whole output buffer in C++ from the arena
 blobs / numeric columns / CSR arrays the callers already hold, and each
 is byte-identical to its Python fallback loop (pinned by the parity
 tests in tests/test_scoring.py and tests/test_formats.py, plus the
-golden fixture).
+golden fixture).  `matrix_emit` alone writes its file from C, slab by
+slab (a 45 MB final.gamma never exists as one buffer); its fallback and
+specification is np.savetxt (tests/test_native_matrix_emit.py).
 
 The row emitters qualify only for native-backed feature containers —
 the pure-Python DnsFeatures/FlowFeatures keep rows as lists and take
@@ -38,6 +41,10 @@ def _configure(lib: ctypes.CDLL) -> None:
     lib.model_emit.restype = ctypes.c_void_p
     lib.model_emit.argtypes = [
         _I64P, ctypes.c_int64, _I32P, _I64P, _I64P,
+    ]
+    lib.matrix_emit.restype = ctypes.c_int
+    lib.matrix_emit.argtypes = [
+        ctypes.c_char_p, _F64P, ctypes.c_int64, ctypes.c_int64,
     ]
     lib.wc_emit.restype = ctypes.c_void_p
     lib.wc_emit.argtypes = (
@@ -237,6 +244,30 @@ def model_emit(doc_ptr, word_idx, counts) -> bytes | None:
         ctypes.byref(out_len),
     )
     return _collect(lib, ptr, out_len)
+
+
+def matrix_emit(path: str, a: np.ndarray) -> bool:
+    """Write the 2-D float64 `a` to `path` as np.savetxt(path, a,
+    fmt="%5.10f") would, byte for byte, in one native pass: no Python
+    object a row or a value, the file complete and closed at the return.
+    False, with nothing a caller has to undo, where this cannot: the
+    library is unavailable, `a` is not a 2-D float64 array, `path` is not
+    a plain file name (np.savetxt compresses by extension), or the file
+    could not be written (np.savetxt then raises for the caller)."""
+    if not (
+        isinstance(path, str)
+        and isinstance(a, np.ndarray)
+        and a.ndim == 2
+        and a.dtype == np.float64
+        and not path.endswith((".gz", ".bz2", ".xz", ".lzma"))
+        and available()
+    ):
+        return False
+    a = np.ascontiguousarray(a)
+    rows, cols = a.shape
+    return _LIB.load().matrix_emit(
+        os.fsencode(path), a.ctypes.data_as(_F64P), rows, cols
+    ) == 0
 
 
 def word_counts_emit(features) -> bytes | None:

@@ -87,12 +87,21 @@ class FileKVClient:
     def key_value_set(self, key: str, value: str,
                       allow_overwrite: bool = False) -> None:
         path = self._path(key)
-        if not allow_overwrite and os.path.exists(path):
-            raise RuntimeError(f"ALREADY_EXISTS: {key}")
         tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
         with open(tmp, "w") as f:
             f.write(value)
-        os.replace(tmp, path)
+        if allow_overwrite:
+            os.replace(tmp, path)
+            return
+        # Create-if-absent in ONE step: a hard link fails where the name
+        # exists.  (An `exists()` check before a rename let two writers
+        # both pass it and both "win" a first-writer-wins claim.)
+        try:
+            os.link(tmp, path)
+        except FileExistsError:
+            raise RuntimeError(f"ALREADY_EXISTS: {key}") from None
+        finally:
+            os.remove(tmp)
 
     def blocking_key_value_get(self, key: str,
                                timeout_in_ms: int) -> str:

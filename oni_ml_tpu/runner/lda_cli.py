@@ -36,8 +36,9 @@ is written after the return.
 Spans (telemetry/spans.py): `est.load` (the model.dat parse; counts
 `bytes`, `docs`, `pairs`, and says which `reader` parsed it: `native`
 or `python`, io/formats.read_model_dat), a root of its own before the
-fit's root `fit`, whose `fit.save` counts the bytes of each file written and whose
-close counts `ll_lines`.
+fit's root `fit`, whose `fit.save` counts the bytes of each file written
+and says which `writer` wrote the two matrices (`native` or `python`,
+io/formats._write_matrix) and whose close counts `ll_lines`.
 
 settings.txt uses Blei lda-c's key-value format:
 

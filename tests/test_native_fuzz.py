@@ -311,3 +311,46 @@ def test_fuzz_model_dat_readers_parity(seed, tmp_path, monkeypatch):
             f.write(data)
         readers.add(both()[1])
     assert readers == {"native", "python"}   # both sides of the border
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_fuzz_matrix_emit_prints_as_python(seed, tmp_path):
+    """Randomized exactness for the native final.beta / final.gamma
+    writer: every token of the file is Python's ``"%5.10f" % x``, on over
+    10^5 doubles drawn over magnitudes 1e-14 to 1e9, on the exact ties of
+    the tenth place (x * 10^10 = t * 5^10 / 2 ends in one half exactly
+    where x is an odd multiple t of 2^-11: half-even decides them) and on
+    the doubles next to each tie."""
+    from decimal import Decimal
+
+    from oni_ml_tpu import native_emit
+    from oni_ml_tpu.io import formats
+
+    if not native_emit.available():
+        pytest.skip("native emit unavailable")
+    rng = np.random.default_rng(3000 + seed)
+    n = 100_000
+    drawn = rng.uniform(1.0, 10.0, n) * 10.0 ** rng.integers(-14, 9, n)
+    drawn *= rng.choice([-1.0, 1.0], n)
+    odd = 2 * rng.integers(0, 1 << rng.integers(1, 42, 20_000)) + 1
+    ties = odd * 2.0 ** -11 * rng.choice([-1.0, 1.0], len(odd))
+    assert all((Decimal(x) * 10**10) % 1 == Decimal("0.5")
+               for x in np.abs(ties[:2000]).tolist())
+    values = np.concatenate([
+        drawn, ties, np.nextafter(ties, np.inf), np.nextafter(ties, -np.inf),
+        # around the integer path's upper border, and the widest values
+        rng.uniform(-2.0**31, 2.0**31, 2_000),
+        rng.uniform(1.0, 10.0, 1_000) * 10.0 ** rng.integers(9, 308, 1_000),
+        rng.uniform(1.0, 10.0, 1_000) * 10.0 ** rng.integers(-320, -14, 1_000),
+    ])
+    values = rng.permutation(values)[: len(values) // 20 * 20]
+    path = tmp_path / "final.gamma"
+    formats.write_gamma(str(path), values.reshape(-1, 20))
+    assert formats.matrix_writer == "native"
+    lines = path.read_text().split("\n")
+    assert lines.pop() == "" and len(lines) == len(values) // 20
+    got = [tok for line in lines for tok in line.split(" ")]
+    want = ["%5.10f" % x for x in values.tolist()]
+    assert len(got) == len(want)
+    bad = [(x, g, w) for x, g, w in zip(values.tolist(), got, want) if g != w]
+    assert not bad, bad[:5]

@@ -152,6 +152,47 @@ def test_placement_errors_and_shadow_for():
 # ---------------------------------------------------------------------------
 
 
+def test_file_kv_set_without_overwrite_has_one_winner(tmp_path):
+    """`allow_overwrite=False` is an election (claim_promotion): of many
+    writers racing for one key exactly one returns, the others raise
+    ALREADY_EXISTS, and the value is the winner's."""
+    kv_dir = str(tmp_path / "kv")
+    workers, rounds = 16, 40
+    won = [[] for _ in range(rounds)]
+    errors = []
+    barrier = threading.Barrier(workers)
+
+    def race(i):
+        kv = FileKVClient(kv_dir)
+        for r in range(rounds):
+            barrier.wait(timeout=30)
+            try:
+                kv.key_value_set(f"promote/{r}", str(i))
+                won[r].append(i)
+            except RuntimeError as e:
+                if "ALREADY_EXISTS" not in str(e):
+                    errors.append(e)
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=race, args=(i,))
+                   for i in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not errors
+    assert [len(w) for w in won] == [1] * rounds
+    kv = FileKVClient(kv_dir)
+    assert [kv.blocking_key_value_get(f"promote/{r}", 10)
+            for r in range(rounds)] == [str(w[0]) for w in won]
+    assert not [n for n in os.listdir(kv_dir) if ".tmp." in n]
+
+
 def test_file_kv_client(tmp_path):
     kv = FileKVClient(str(tmp_path / "kv"))
     kv.key_value_set("a/b", "one")
