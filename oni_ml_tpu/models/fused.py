@@ -341,6 +341,27 @@ def initial_gammas(groups_arrays, k: int, dtype, dense_wmajor=False):
     )
 
 
+def reads_stack_in_place(group, dense_e_step_fn: Callable | None) -> bool:
+    """Whether the accumulator hands this group's E-step the group's whole
+    stack and a batch index (`dense_estep.e_step_dense(..., batch_index=)`:
+    the kernel's corpus BlockSpec indexes the stack's leading axis, and no
+    batch is copied out of it) where it would scan over the stack's
+    slices.  Decided by what the code can see, no knob: a dense group
+    (`(C, mask)`) of two batches or more, whose dense callable declares
+    `_oni_stack_capable`.  The default (this module's call of
+    `e_step_dense`) and the data-parallel kernel
+    (parallel/sharded.make_data_parallel_dense_e_step) do; the
+    vocab-sharded XLA plan and a user's own `dense_e_step_fn` do not, and
+    keep the per-batch contract.  A single-batch group is called directly
+    on `stack[0]`, a bitcast."""
+    return (
+        len(group) == 2
+        and group[0].shape[0] >= 2
+        and (dense_e_step_fn is None
+             or getattr(dense_e_step_fn, "_oni_stack_capable", False))
+    )
+
+
 def make_em_accumulator(
     *,
     num_topics: int,
@@ -375,7 +396,8 @@ def make_em_accumulator(
     e_warm = warm_start and getattr(e_fn, "_oni_warm_capable", False)
     k, v = num_topics, num_terms
 
-    def _default_dense(log_beta, alpha, dense, m, g_in, warm):
+    def _default_dense(log_beta, alpha, dense, m, g_in, warm,
+                       batch_index=None):
         from ..ops import dense_estep
 
         return dense_estep.e_step_dense(
@@ -384,6 +406,7 @@ def make_em_accumulator(
             interpret=jax.default_backend() != "tpu",
             wmajor=dense_wmajor,
             gamma_prev=g_in, warm=warm, precision=dense_precision,
+            batch_index=batch_index,
         )
 
     dense_fn = dense_e_step_fn or _default_dense
@@ -440,10 +463,8 @@ def make_em_accumulator(
                 var_max_iters=var_max_iters, var_tol=var_tol,
             )
 
-        def scan_body(carry, batch_and_gamma):
+        def add(carry, res):
             ss, ll, ass, vi, sw = carry
-            batch, g_in = batch_and_gamma
-            res = run_batch(batch, g_in)
             return (
                 (ss + res.suff_stats, ll + res.likelihood,
                  ass + res.alpha_ss,
@@ -451,6 +472,10 @@ def make_em_accumulator(
                  sw + jnp.asarray(res.doc_sweeps, jnp.int32)),
                 res.gamma,
             )
+
+        def scan_body(carry, batch_and_gamma):
+            batch, g_in = batch_and_gamma
+            return add(carry, run_batch(batch, g_in))
 
         carry = (total_ss, total_ll, total_ass, vi_max, sweeps)
         with jax.named_scope("estep"):
@@ -466,7 +491,26 @@ def make_em_accumulator(
                     )
                     gammas.append(g[None])
                     continue
-                carry, g = jax.lax.scan(scan_body, carry, (group, g_prev))
+                body, xs = scan_body, (group, g_prev)
+                if reads_stack_in_place(group, dense_e_step_fn):
+                    # The stack stays whole, closed over and invariant in
+                    # the scan, which carries the batch's number: the
+                    # kernel reads batch `n` of the stack in place.
+                    # Scanned over, XLA copied every batch out of the stack
+                    # for the kernel's operand, once an EM iteration
+                    # (`dynamic-slice_bitcast_fusion`: a quarter of the
+                    # device's time, PERF.md PR 37).
+                    stack, masks = group
+
+                    def body(carry, index_mask_gamma):
+                        n, m, g_in = index_mask_gamma
+                        return add(carry, dense_fn(
+                            log_beta, alpha, stack, m, g_in, warm,
+                            batch_index=n))
+
+                    xs = (jnp.arange(stack.shape[0], dtype=jnp.int32),
+                          masks, g_prev)
+                carry, g = jax.lax.scan(body, carry, xs)
                 gammas.append(g)
         total_ss, total_ll, total_ass, vi_max, sweeps = carry
         return total_ss, total_ll, total_ass, tuple(gammas), vi_max, sweeps

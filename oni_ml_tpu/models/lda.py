@@ -1398,7 +1398,17 @@ class LDATrainer:
             )
             # "reused": an earlier fit of this process built the chunk
             # program, and this fit's first dispatch traces nothing.
-            sp.annotate(program=getattr(run_chunk, "program", None))
+            # `stack_indexed_batches`: the batches whose kernel reads them
+            # out of their group's stack in place
+            # (fused.reads_stack_in_place); `sliced_batches`: the rest, a
+            # single-batch group's `stack[0]` or a scan's slice.
+            in_place = sum(
+                g[0].shape[0] for g in groups.arrays
+                if fused.reads_stack_in_place(g, plan.dense_e_fn))
+            sp.annotate(program=getattr(run_chunk, "program", None),
+                        batches=len(batches),
+                        stack_indexed_batches=in_place,
+                        sliced_batches=len(batches) - in_place)
             ll_prev_dev = jnp.asarray(
                 np.nan if ll_prev is None else ll_prev, dtype
             )

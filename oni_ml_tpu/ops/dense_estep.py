@@ -26,7 +26,9 @@ The kernel blocks documents; C_block, q, and ratio live in VMEM for the
 entire per-block fixed point, beta rides along whole (it re-reads HBM
 once per block), and the T accumulator is a revisited output block
 summed across sequential grid steps.  C crosses HBM exactly once per EM
-iteration.
+iteration: a batch that lives in a shape group's stack [NB, B, W] is
+read out of the stack in place (`batch_index`, `_corpus_call`), not from
+a copy of it.
 
 Within the fixed point the [BB, V] ratio divide — not the matmuls —
 was the dominant cost (the VPU's vector divide runs ~1/3 the kernel's
@@ -357,29 +359,36 @@ def densify(word_idx, counts, num_terms: int, width: int | None = None,
         return dense if dtype is None else dense.astype(dtype)
 
 
-def _dense_kernel(
-    alpha_ref, warm_ref, beta_ref, c_ref, mask_ref, gamma_in_ref,
-    gamma_ref, t_ref, docll_ref, ass_ref, iters_ref,
+_BLOCK_STATICS = ("var_max_iters", "var_tol", "precision")
+
+
+@functools.partial(jax.jit, static_argnames=_BLOCK_STATICS)
+def _block_e_step(
+    alpha, warm, beta, c, mask, gamma_in,
     *, var_max_iters: int, var_tol: float, precision: str = "f32",
 ):
-    """One grid step = one block of BB documents; C block, q, and ratio
-    stay in VMEM for the whole fixed point.
+    """One block of BB documents' E-step, as values: beta [K, V]
+    exp(log_beta), c [BB, V] f32 or bf16, mask [BB, 1], gamma_in [BB, K]
+    -> (gamma, T's part [K, V], docll [BB, 1], alpha_ss part [BB, 1],
+    sweeps).  `_dense_kernel` is its refs in and out.
 
-    warm_ref selects the fixed point's start: 0 = the reference's fresh
-    init alpha + N_d/K (lda-c semantics), 1 = resume from gamma_in_ref
+    A `jit` of its own, for its trace cache and nothing else (Mosaic
+    inlines it): every `pallas_call` traces its kernel anew, 0.4 s a
+    kernel on the chip's host, and a fit calls the kernel once a shape
+    group; groups whose batches share a shape share this function's
+    jaxpr (PERF.md, PR 37: read through a scan over slices they shared
+    the scan's body).
+
+    warm selects the fixed point's start: 0 = the reference's fresh
+    init alpha + N_d/K (lda-c semantics), 1 = resume from gamma_in
     (the previous EM iteration's posterior — same fixed point, fewer
     iterations once beta stabilizes; config knob warm_start_gamma)."""
-    k_topics = beta_ref.shape[0]
-    beta = beta_ref[...]                       # [K, V] exp(log_beta)
+    k_topics = beta.shape[0]
     # The corpus block may arrive STORED bf16 (corpus_dtype: exact for
     # counts <= 256, halves its HBM streaming).  It is consumed via
     # f32-promoting elementwise ops — the upcast fuses per use instead
     # of materializing a second full-width copy in VMEM — so the
     # storage dtype changes no results.
-    c = c_ref[...]                             # [BB, V] f32 or bf16
-    mask = mask_ref[...]                       # [BB, 1]
-    alpha = alpha_ref[0, 0]
-    warm = warm_ref[0, 0]
     n_d = jnp.sum(c, axis=1, keepdims=True, dtype=jnp.float32)
     # Relative stop normalizer: mean_k gamma = alpha + N_d/K for every
     # iterate (gamma rows sum to K*alpha + N_d exactly), making var_tol
@@ -424,7 +433,7 @@ def _dense_kernel(
     fresh0 = (alpha + n_d / k_topics) + jnp.zeros(
         (c.shape[0], k_topics), jnp.float32
     )
-    gamma0 = jnp.where(warm != 0, gamma_in_ref[...], fresh0)
+    gamma0 = jnp.where(warm != 0, gamma_in, fresh0)
     gamma, iters, _, _ = jax.lax.while_loop(
         cond,
         body,
@@ -444,17 +453,39 @@ def _dense_kernel(
     exp_et = jnp.exp(e_lt)
     q = qmat(exp_et, beta)
     ratio = (c * _recip(q)) * mask
-    gamma_ref[...] = gamma
     tok = jnp.sum(c * jnp.log(q), axis=1, keepdims=True)
     core = jnp.sum(
         (alpha - gamma) * e_lt + gammaln_pos(gamma), axis=1, keepdims=True
     ) - gammaln_pos(jnp.sum(gamma, axis=1, keepdims=True))
-    docll_ref[...] = (core + tok) * mask
-    ass_ref[...] = jnp.sum(e_lt, axis=1, keepdims=True) * mask
+    docll = (core + tok) * mask
+    ass = jnp.sum(e_lt, axis=1, keepdims=True) * mask
     t_part = jax.lax.dot_general(              # [K, BB] @ [BB, V]
         exp_et * mask, ratio, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+    return gamma, t_part, docll, ass, iters
+
+
+def _dense_kernel(
+    alpha_ref, warm_ref, beta_ref, c_ref, mask_ref, gamma_in_ref,
+    gamma_ref, t_ref, docll_ref, ass_ref, iters_ref, **settings,
+):
+    """One grid step = one block of BB documents; C block, q, and ratio
+    stay in VMEM for the whole fixed point (`_block_e_step`).  The T
+    accumulator is a revisited output block summed over the grid."""
+    gamma, t_part, docll, ass, iters = _block_e_step(
+        alpha_ref[0, 0], warm_ref[0, 0], beta_ref[...], c_ref[...],
+        mask_ref[...], gamma_in_ref[...], **settings)
+    _store_block(gamma_ref, t_ref, docll_ref, ass_ref, iters_ref,
+                 gamma, t_part, docll, ass, iters)
+
+
+def _store_block(gamma_ref, t_ref, docll_ref, ass_ref, iters_ref,
+                 gamma, t_part, docll, ass, iters):
+    """A block's results into the kernel's output refs, either layout."""
+    gamma_ref[...] = gamma
+    docll_ref[...] = docll
+    ass_ref[...] = ass
 
     @pl.when(pl.program_id(0) == 0)
     def _init():
@@ -464,27 +495,22 @@ def _dense_kernel(
     iters_ref[pl.program_id(0), 0] = iters
 
 
-def _dense_kernel_w(
-    alpha_ref, warm_ref, beta_ref, ct_ref, mask_ref, gamma_in_ref,
-    gamma_ref, t_ref, docll_ref, ass_ref, iters_ref,
+@functools.partial(jax.jit, static_argnames=_BLOCK_STATICS)
+def _block_e_step_w(
+    alpha, warm, beta, ct, mask, gamma_in,
     *, var_max_iters: int, var_tol: float, precision: str = "f32",
 ):
-    """W-major variant of _dense_kernel: the corpus block rides as
-    C^T [W, BB] and gamma as gamma^T [K, BB], so the gamma-update
-    contraction s = beta @ ratio^T produces a [K, BB] result whose
-    small-K axis pads to the 8-sublane granularity (20 -> 24) instead
-    of the 128-lane tile (20 -> 128) the row-major layout pays —
-    recovering ~5x of the MXU work on that matmul.  The phinorm matmul
-    contracts over K either way (inherent to LDA's K-mixture).  Math is
-    identical modulo float reassociation."""
-    k_topics = beta_ref.shape[0]
-    beta = beta_ref[...]                       # [K, W] exp(log_beta)
+    """W-major variant of _block_e_step: the corpus block rides as
+    C^T [W, BB] and gamma as gamma^T [K, BB] (mask [1, BB]), so the
+    gamma-update contraction s = beta @ ratio^T produces a [K, BB]
+    result whose small-K axis pads to the 8-sublane granularity
+    (20 -> 24) instead of the 128-lane tile (20 -> 128) the row-major
+    layout pays — recovering ~5x of the MXU work on that matmul.  The
+    phinorm matmul contracts over K either way (inherent to LDA's
+    K-mixture).  Math is identical modulo float reassociation."""
+    k_topics = beta.shape[0]
     # bf16-stored corpus is consumed via f32-promoting ops — exact, no
     # materialized upcast (see _dense_kernel).
-    ct = ct_ref[...]                           # [W, BB] f32 or bf16
-    mask = mask_ref[...]                       # [1, BB]
-    alpha = alpha_ref[0, 0]
-    warm = warm_ref[0, 0]
     n_d = jnp.sum(ct, axis=0, keepdims=True,   # [1, BB]
                   dtype=jnp.float32)
     # Relative stop normalizer (see _dense_kernel / ops/estep.py).
@@ -528,7 +554,7 @@ def _dense_kernel_w(
     fresh0 = (alpha + n_d / k_topics) + jnp.zeros(
         (k_topics, ct.shape[1]), jnp.float32
     )
-    gamma0 = jnp.where(warm != 0, gamma_in_ref[...], fresh0)
+    gamma0 = jnp.where(warm != 0, gamma_in, fresh0)
     gamma_t, iters, _, _ = jax.lax.while_loop(
         cond,
         body,
@@ -544,25 +570,84 @@ def _dense_kernel_w(
     exp_et_t = jnp.exp(e_lt)
     q_t = qmat_t(exp_et_t, beta)
     ratio_t = (ct * _recip(q_t)) * mask
-    gamma_ref[...] = gamma_t
     tok = jnp.sum(ct * jnp.log(q_t), axis=0, keepdims=True)
     core = jnp.sum(
         (alpha - gamma_t) * e_lt + gammaln_pos(gamma_t),
         axis=0, keepdims=True,
     ) - gammaln_pos(jnp.sum(gamma_t, axis=0, keepdims=True))
-    docll_ref[...] = (core + tok) * mask
-    ass_ref[...] = jnp.sum(e_lt, axis=0, keepdims=True) * mask
+    docll = (core + tok) * mask
+    ass = jnp.sum(e_lt, axis=0, keepdims=True) * mask
     t_part = jax.lax.dot_general(                    # [K, BB] x [W, BB]
         exp_et_t * mask, ratio_t, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
+    return gamma_t, t_part, docll, ass, iters
 
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        t_ref[...] = jnp.zeros_like(t_ref)
 
-    t_ref[...] += t_part
-    iters_ref[pl.program_id(0), 0] = iters
+
+
+def _dense_kernel_w(
+    alpha_ref, warm_ref, beta_ref, ct_ref, mask_ref, gamma_in_ref,
+    gamma_ref, t_ref, docll_ref, ass_ref, iters_ref, **settings,
+):
+    """W-major twin of _dense_kernel, around `_block_e_step_w`."""
+    gamma_t, t_part, docll, ass, iters = _block_e_step_w(
+        alpha_ref[0, 0], warm_ref[0, 0], beta_ref[...], ct_ref[...],
+        mask_ref[...], gamma_in_ref[...], **settings)
+    _store_block(gamma_ref, t_ref, docll_ref, ass_ref, iters_ref,
+                 gamma_t, t_part, docll, ass, iters)
+
+
+def _corpus_call(kernel, batch_index, *, grid: int, in_specs, out_specs,
+                 **kw):
+    """The `pallas_call` of `kernel` over `grid` document blocks: ONE
+    kernel, two ways to address its corpus.
+
+    `batch_index` None: the corpus operand is one batch, [B, W] or
+    [W, B].  Else it is a shape group's whole STACK, [NB, B, W] or
+    [NB, W, B], and the traced int32 `batch_index` rides as a
+    scalar-prefetch operand that the corpus BlockSpec's index map reads
+    (`_spec`): the kernel DMAs its [BB, W] blocks straight out of
+    the stack, where a `lax.scan` over the stack has XLA copy each batch
+    out first (a read and a write of the whole batch every EM iteration:
+    PERF.md, PR 37).  Every other operand keeps its per-batch shape and
+    block, and the kernel body never sees the index: same blocks, same
+    grid, same arithmetic, the same numbers."""
+    if batch_index is None:
+        return pl.pallas_call(kernel, grid=(grid,), in_specs=in_specs,
+                              out_specs=out_specs, **kw)
+    call = pl.pallas_call(
+        lambda n_ref, *refs: kernel(*refs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(grid,), in_specs=in_specs,
+            out_specs=out_specs),
+        **kw)
+    return functools.partial(
+        call, jnp.reshape(jnp.asarray(batch_index, jnp.int32), (1,)))
+
+
+def _spec(block: tuple, block_axis: int | None = None, *,
+          space=pltpu.VMEM, stack: bool = False):
+    """BlockSpec of `block`: the grid steps it along `block_axis`, or
+    (None) every step maps to the one block, which is the whole operand
+    or a revisited accumulator.  `stack`: the corpus operand where it is
+    a group's stack (`_corpus_call`), the same block under a squeezed
+    leading axis that the prefetched batch index picks; every other
+    spec's index map is handed that index too and passes it by."""
+    def at(i, *index_ref):
+        here = tuple(i if a == block_axis else 0 for a in range(len(block)))
+        return ((index_ref[0][0],) if stack else ()) + here
+
+    lead = (pl.Squeezed(),) if stack else ()
+    return pl.BlockSpec(lead + block, at, memory_space=space)
+
+
+def _check_corpus_rank(dense, batch_index) -> None:
+    want = 2 if batch_index is None else 3
+    if dense.ndim != want:
+        raise ValueError(
+            f"dense corpus of rank {dense.ndim}: one batch is rank 2, a "
+            "stack of batches (rank 3) comes with its batch_index")
 
 
 def dense_fixed_point_w(
@@ -577,10 +662,12 @@ def dense_fixed_point_w(
     gamma_prev=None,            # [B, K] warm start (None = fresh init)
     warm=None,                  # traced scalar bool/int gating gamma_prev
     precision: str = "f32",
+    batch_index=None,           # dense_counts_t is a stack [NB, W, B]
 ):
     """W-major twin of dense_fixed_point; same returns."""
+    _check_corpus_rank(dense_counts_t, batch_index)
     k_topics, v = exp_beta.shape
-    b = dense_counts_t.shape[1]
+    b = dense_counts_t.shape[-1]
     bb = block or pick_block_w(b, v, k_topics, precision)
     if bb is None:
         raise ValueError(
@@ -608,31 +695,24 @@ def dense_fixed_point_w(
         estep.check_warm_pair(gamma_prev, warm)
         gamma_in = jnp.asarray(gamma_prev, dtype).T
         warm = jnp.asarray(warm, jnp.int32)
-    gamma_t, t, docll, ass, iters = pl.pallas_call(
-        kernel,
-        grid=(grid,),
+    gamma_t, t, docll, ass, iters = _corpus_call(
+        kernel, batch_index,
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec(
-                (k_topics, v), lambda i: (0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec((v, bb), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bb), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec(
-                (k_topics, bb), lambda i: (0, i), memory_space=pltpu.VMEM
-            ),
+            _spec((1, 1), space=pltpu.SMEM),             # alpha
+            _spec((1, 1), space=pltpu.SMEM),             # warm
+            _spec((k_topics, v)),                        # beta, whole
+            _spec((v, bb), 1, stack=batch_index is not None),
+            _spec((1, bb), 1),                           # mask
+            _spec((k_topics, bb), 1),                    # gamma in
         ],
         out_specs=[
-            pl.BlockSpec(
-                (k_topics, bb), lambda i: (0, i), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (k_topics, v), lambda i: (0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec((1, bb), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bb), lambda i: (0, i), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            _spec((k_topics, bb), 1),                    # gamma
+            # Revisited accumulator: every grid step maps to block (0, 0).
+            _spec((k_topics, v)),
+            _spec((1, bb), 1),                           # docll
+            _spec((1, bb), 1),                           # alpha_ss part
+            pl.BlockSpec(memory_space=pltpu.SMEM),       # sweeps a block
         ],
         out_shape=[
             jax.ShapeDtypeStruct((k_topics, b), dtype),
@@ -669,15 +749,21 @@ def dense_fixed_point(
     gamma_prev=None,            # [B, K] warm start (None = fresh init)
     warm=None,                  # traced scalar bool/int gating gamma_prev
     precision: str = "f32",
+    batch_index=None,           # dense_counts is a stack [NB, B, V]
 ):
     """Returns (gamma [B, K], T [K, V], docll [B], alpha_ss_part [B],
     iters scalar, doc_sweeps scalar) — docll is the full per-doc ELBO
     minus the alpha-prior constant (token term + gamma-Dirichlet terms,
     masked), alpha_ss_part is the per-doc sum_k E[log theta] (masked),
     iters the most sweeps any doc block ran and doc_sweeps the sum over
-    blocks of a block's sweeps x its rows (EStepResult.doc_sweeps)."""
+    blocks of a block's sweeps x its rows (EStepResult.doc_sweeps).
+
+    With `batch_index` (a traced int32 scalar) `dense_counts` is a shape
+    group's whole stack and the kernel reads batch `batch_index` of it in
+    place (`_corpus_call`); everything else is that one batch's."""
+    _check_corpus_rank(dense_counts, batch_index)
     k_topics, v = exp_beta.shape
-    b = dense_counts.shape[0]
+    b = dense_counts.shape[-2]
     bb = block or pick_block(b, v, k_topics, precision)
     if bb is None:
         raise ValueError(
@@ -703,32 +789,24 @@ def dense_fixed_point(
         estep.check_warm_pair(gamma_prev, warm)
         gamma_in = jnp.asarray(gamma_prev, dtype)
         warm = jnp.asarray(warm, jnp.int32)
-    gamma, t, docll, ass, iters = pl.pallas_call(
-        kernel,
-        grid=(grid,),
+    gamma, t, docll, ass, iters = _corpus_call(
+        kernel, batch_index,
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec(
-                (k_topics, v), lambda i: (0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec((bb, v), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bb, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(
-                (bb, k_topics), lambda i: (i, 0), memory_space=pltpu.VMEM
-            ),
+            _spec((1, 1), space=pltpu.SMEM),             # alpha
+            _spec((1, 1), space=pltpu.SMEM),             # warm
+            _spec((k_topics, v)),                        # beta, whole
+            _spec((bb, v), 0, stack=batch_index is not None),
+            _spec((bb, 1), 0),                           # mask
+            _spec((bb, k_topics), 0),                    # gamma in
         ],
         out_specs=[
-            pl.BlockSpec(
-                (bb, k_topics), lambda i: (i, 0), memory_space=pltpu.VMEM
-            ),
+            _spec((bb, k_topics), 0),                    # gamma
             # Revisited accumulator: every grid step maps to block (0, 0).
-            pl.BlockSpec(
-                (k_topics, v), lambda i: (0, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec((bb, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((bb, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
+            _spec((k_topics, v)),
+            _spec((bb, 1), 0),                           # docll
+            _spec((bb, 1), 0),                           # alpha_ss part
+            pl.BlockSpec(memory_space=pltpu.SMEM),       # sweeps a block
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, k_topics), dtype),
@@ -766,8 +844,12 @@ def e_step_dense(
     gamma_prev=None,            # [B, K] warm start (None = fresh init)
     warm=None,                  # traced scalar gating gamma_prev
     precision: str = "f32",     # "bf16": half-precision MXU iterations
+    batch_index=None,           # traced int32: dense_counts is a STACK
 ) -> estep.EStepResult:
-    """estep.e_step semantics over a pre-densified batch.
+    """estep.e_step semantics over a pre-densified batch: `dense_counts`
+    itself, or with `batch_index` batch `batch_index` of the stack
+    `dense_counts` ([NB, B, W], W-major [NB, W, B]), read in place and to
+    the same numbers as `dense_counts[batch_index]` (`_corpus_call`).
 
     The padded columns are inert: C is zero there (densify allocates
     them zeroed), beta is zero-padded here, so q = 1e-30 and ratio = 0
@@ -775,7 +857,7 @@ def e_step_dense(
     """
     _check_precision(precision)
     v = log_beta.shape[1]
-    w = dense_counts.shape[0] if wmajor else dense_counts.shape[1]
+    w = dense_counts.shape[-2] if wmajor else dense_counts.shape[-1]
     exp_beta = jnp.exp(log_beta)
     if w != v:
         exp_beta = jnp.pad(exp_beta, ((0, 0), (0, w - v)))
@@ -783,7 +865,7 @@ def e_step_dense(
     gamma, t, docll, ass, iters, sweeps = fp(
         exp_beta, alpha, dense_counts, doc_mask, var_max_iters, var_tol,
         block=block, interpret=interpret, gamma_prev=gamma_prev, warm=warm,
-        precision=precision,
+        precision=precision, batch_index=batch_index,
     )
     suff = (exp_beta * t)[:, :v].T             # [V, K]
     # The kernel emits the per-doc ELBO terms (token + gamma-Dirichlet)

@@ -124,7 +124,12 @@ def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
     W-major); the local batch is B / data_size, so dense feasibility
     (pick_block / pick_block_w) must be checked against the PER-SHARD
     batch by the caller.  gamma_prev/warm thread the warm-start state
-    exactly as in the single-device path."""
+    exactly as in the single-device path.  With `batch_index` (a traced
+    int32 scalar, replicated) `dense` is a shape group's whole stack
+    ([NB, B, W] / [NB, W, B], documents over `data` as before) and every
+    shard's kernel reads batch `batch_index` of its slice of the stack in
+    place (dense_estep._corpus_call); the callable says so with
+    `_oni_stack_capable` (models/fused.reads_stack_in_place)."""
     from ..ops import dense_estep
 
     batch_axis = 1 if wmajor else 0
@@ -143,18 +148,19 @@ def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
     @partial(jax.jit,
              static_argnames=("var_max_iters", "var_tol", "interpret"))
     def tpu_custom_call(log_beta, alpha, dense, doc_mask, gamma_prev, warm,
-                        *, var_max_iters, var_tol, interpret):
+                        batch_index, *, var_max_iters, var_tol, interpret):
         return dense_estep.e_step_dense(
             log_beta, alpha, dense, doc_mask,
             var_max_iters=var_max_iters, var_tol=var_tol,
             interpret=interpret, wmajor=wmajor,
             gamma_prev=gamma_prev, warm=warm, precision=precision,
+            batch_index=batch_index,
         )
 
     def local(log_beta, alpha, dense, doc_mask, gamma_prev, warm,
-              var_max_iters, var_tol, interpret):
+              batch_index, var_max_iters, var_tol, interpret):
         res = tpu_custom_call(
-            log_beta, alpha, dense, doc_mask, gamma_prev, warm,
+            log_beta, alpha, dense, doc_mask, gamma_prev, warm, batch_index,
             var_max_iters=var_max_iters, var_tol=var_tol,
             interpret=interpret,
         )
@@ -172,18 +178,22 @@ def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
     )
 
     def wrapped(log_beta, alpha, dense, doc_mask, gamma_prev, warm,
-                var_max_iters, var_tol, interpret=False):
-        if dense.shape[batch_axis] % mesh.shape[DATA_AXIS]:
+                var_max_iters, var_tol, interpret=False, batch_index=None):
+        rows = dense.shape[batch_axis + (batch_index is not None)]
+        if rows % mesh.shape[DATA_AXIS]:
             raise ValueError(
-                f"batch {dense.shape[batch_axis]} not divisible by data "
+                f"batch {rows} not divisible by data "
                 f"axis {mesh.shape[DATA_AXIS]}"
             )
         fn = shard_map(
             partial(local, var_max_iters=var_max_iters, var_tol=var_tol,
                     interpret=interpret),
             mesh=mesh,
-            in_specs=(P(), P(), dense_spec, P(DATA_AXIS), P(DATA_AXIS),
-                      P()),
+            in_specs=(P(), P(),
+                      dense_spec if batch_index is None
+                      else P(None, *dense_spec),
+                      P(DATA_AXIS), P(DATA_AXIS), P(),
+                      None if batch_index is None else P()),
             out_specs=estep.EStepResult(
                 gamma=P(DATA_AXIS),
                 suff_stats=P(),
@@ -196,8 +206,10 @@ def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
             # so shard_map's vma check cannot see through it.
             check_vma=False,
         )
-        return fn(log_beta, alpha, dense, doc_mask, gamma_prev, warm)
+        return fn(log_beta, alpha, dense, doc_mask, gamma_prev, warm,
+                  batch_index)
 
+    wrapped._oni_stack_capable = True
     return wrapped
 
 
@@ -489,8 +501,12 @@ def bound(fn, **settings):
     """`partial(fn, **settings)`, the same object for the same `fn` and
     settings: with a memoised maker's `fn`, what a mesh fit hands its
     chunk program (models/lda.py `_fused_loop`) is what the process's
-    earlier fit on that mesh handed it, and the program is found again."""
-    return partial(fn, **settings)
+    earlier fit on that mesh handed it, and the program is found again.
+    What `fn` declares of itself (`_oni_stack_capable`) the bound callable
+    declares too."""
+    out = partial(fn, **settings)
+    vars(out).update(vars(fn))
+    return out
 
 
 def pad_vocab(v: int, model_size: int) -> int:

@@ -400,13 +400,18 @@ HARVEST_COVERAGE: "dict[str, str]" = {
         "harvested at first dispatch per padded shape via "
         "roofline.ensure_harvested in lut_rows/fused_scores"
     ),
-    # ops/dense_estep.py holds kernel BODIES inlined into the jitted
-    # chunk/E-step programs (no jax.jit site of its own) — cost is
-    # harvested at the callers' entries (em.run_chunk, em.e_step).
-    # plans/warmup.py is likewise the AOT harvest hook itself, not an
-    # entry point: _aot() reads cost_analysis off every program it
-    # compiles.  Neither belongs in the registry: the harvest-coverage
-    # lint keys entries to real jax.jit AST nodes.
+    "ops/dense_estep.py": (
+        "exempt: _block_e_step / _block_e_step_w are the kernel BODIES' "
+        "arithmetic, jitted only for the trace cache (every pallas_call "
+        "traces its kernel anew; shape groups of one batch shape share "
+        "the jaxpr) and inlined by Mosaic into the kernels of the jitted "
+        "chunk/E-step programs — cost is harvested at the callers' "
+        "entries (em.run_chunk, em.e_step)"
+    ),
+    # plans/warmup.py is the AOT harvest hook itself, not an entry
+    # point: _aot() reads cost_analysis off every program it compiles.
+    # It does not belong in the registry: the harvest-coverage lint keys
+    # entries to real jax.jit AST nodes.
     "parallel/allreduce.py": (
         "exempt: _psum_gather's jitted resharding identity is the "
         "control-plane collective transport (the explicit suff-stats "

@@ -7,6 +7,8 @@ suff-stats semantics.  Runs in Pallas interpret mode on the CPU backend
 sparse kernel.
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -1092,3 +1094,75 @@ def test_env_dense_infeasible_rescue_leaves_nothing_behind(monkeypatch):
     # another pin for the same trainer: no rescue left over
     monkeypatch.setenv("ONI_ML_TPU_ESTEP", "xla")
     assert trainer._plan_estep([batch]).compact is None
+
+
+# -- a batch read out of its group's stack, in place --------------------------
+
+_STACK_NB, _STACK_B, _STACK_V, _STACK_K = 3, 256, 200, 4
+
+
+@functools.lru_cache(maxsize=None)
+def _stack_problem(wmajor, store):
+    """A group of three batches [3, 256, W] (two doc blocks a batch in
+    either layout), the last rows of each masked and the batches unlike
+    each other; `store` bf16 keeps the corpus half-width (counts <= 4:
+    exact) and runs the bf16 operand mode that such a store comes with."""
+    rng = np.random.default_rng(37)
+    nb, b, v, k = _STACK_NB, _STACK_B, _STACK_V, _STACK_K
+    dense, masks = [], []
+    for n in range(nb):
+        w, c, m = _random_batch(rng, b, 12 + 4 * n, v, n_masked=3 + n)
+        dense.append(dense_estep.densify(w, c, v, dtype=store))
+        masks.append(m)
+    stack = jnp.stack(dense)
+    if wmajor:
+        stack = jnp.transpose(stack, (0, 2, 1))
+    gamma_prev = jnp.asarray(
+        rng.uniform(0.5, 3.0, size=(nb, b, k)), jnp.float32)
+    return (_log_beta(rng, k, v), stack, jnp.stack(masks), gamma_prev,
+            "bf16" if store == jnp.bfloat16 else "f32")
+
+
+@pytest.mark.parametrize("n", range(_STACK_NB))
+@pytest.mark.parametrize("warm", [False, True], ids=["fresh", "warm"])
+@pytest.mark.parametrize("store", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16_stored"])
+@pytest.mark.parametrize("wmajor", [False, True],
+                         ids=["rowmajor", "wmajor"])
+def test_batch_read_from_the_stack_is_the_batch_sliced_out_bit_for_bit(
+        wmajor, store, warm, n):
+    """`e_step_dense(stack, batch_index=n)` against `e_step_dense(stack[n])`:
+    one kernel, the same blocks of the same bytes from another address
+    (dense_estep._corpus_call), so every field is the same numbers.  Both
+    run under `jit` with `n` traced, as the accumulator's scan calls them."""
+    log_beta, stack, masks, gamma_prev, precision = _stack_problem(
+        wmajor, store)
+
+    def e_step(corpus, m, g, index):
+        return dense_estep.e_step_dense(
+            log_beta, jnp.float32(2.5), corpus, m, var_max_iters=12,
+            var_tol=1e-5, interpret=True, wmajor=wmajor, gamma_prev=g,
+            warm=jnp.asarray(warm), precision=precision, batch_index=index)
+
+    sliced = jax.jit(lambda c, m, g: e_step(c, m, g, None))(
+        stack[n], masks[n], gamma_prev[n])
+    in_place = jax.jit(lambda i, m, g: e_step(stack, m, g, i))(
+        jnp.asarray(n, jnp.int32), masks[n], gamma_prev[n])
+    assert int(in_place.vi_iters) >= 2      # a fixed point ran
+    for field in estep.EStepResult._fields:
+        np.testing.assert_array_equal(
+            np.asarray(getattr(in_place, field)),
+            np.asarray(getattr(sliced, field)), err_msg=field)
+
+
+@pytest.mark.parametrize("wmajor", [False, True],
+                         ids=["rowmajor", "wmajor"])
+def test_a_stack_and_its_index_come_together(wmajor):
+    log_beta, stack, masks, _, _ = _stack_problem(wmajor, jnp.float32)
+    kw = dict(var_max_iters=4, var_tol=1e-5, interpret=True, wmajor=wmajor)
+    with pytest.raises(ValueError, match="rank 3"):
+        dense_estep.e_step_dense(log_beta, jnp.float32(2.5), stack,
+                                 masks[0], **kw)
+    with pytest.raises(ValueError, match="rank 2"):
+        dense_estep.e_step_dense(log_beta, jnp.float32(2.5), stack[0],
+                                 masks[0], batch_index=0, **kw)

@@ -140,6 +140,31 @@ def test_fit_span_counts_what_the_fit_ran(recorded_fits, driver):
         assert args["kernel"] == result.plan["estep_kernel"]["value"]
 
 
+@pytest.mark.parametrize("driver", ["fused_dense", "fused_xla"])
+def test_fit_runner_counts_the_batches_read_from_their_stack_in_place(
+        recorded_fits, driver):
+    """Every fit through the chunk runner says how its batches reach their
+    E-step: `stack_indexed_batches` (the kernel reads them out of their
+    group's stack in place: dense groups of two batches or more) and
+    `sliced_batches` (the rest) add up to `batches`."""
+    _, events = recorded_fits[driver]
+    by_shape = {}
+    for b in _batches(_corpus(), _config()):
+        by_shape[b.word_idx.shape] = by_shape.get(b.word_idx.shape, 0) + 1
+    stacked = sum(n for n in by_shape.values() if n >= 2)
+    # two groups of three here; a fit with single-batch groups beside a
+    # stacked one is counted in tests/test_sharded.py
+    assert stacked == 6
+    runners = [e["args"] for e in events if e["name"] == "fit.runner"]
+    assert len(runners) == 2
+    for args in runners:
+        assert args["batches"] == sum(by_shape.values())
+        assert args["stack_indexed_batches"] == (
+            stacked if driver == "fused_dense" else 0)
+        assert (args["stack_indexed_batches"] + args["sliced_batches"]
+                == args["batches"])
+
+
 # site of `fit.batches` -> (config overrides, train_corpus keywords)
 BATCH_SITES = {
     "make_batches": (dict(), dict()),
@@ -395,6 +420,10 @@ def test_a_fit_under_the_profiler_puts_its_spans_in_the_trace(tmp_path):
     assert by_name["fit.densify"][0][2]["groups"] >= 1
     assert by_name["fit.densify.counts"][0][2]["dense_bytes"] > 0
     assert by_name["em.run_chunk"][0][2]["first"] in (1, "True", True)
+    runner = by_name["fit.runner.counts"][0][2]
+    assert runner["batches"] == len(_batches(corpus, cfg))
+    assert (runner["stack_indexed_batches"] + runner["sliced_batches"]
+            == runner["batches"])
 
 
 def test_em_roofline_record_rests_on_the_counted_sweeps():

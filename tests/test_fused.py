@@ -819,3 +819,195 @@ def test_a_mesh_fit_keeps_nothing_of_itself(problem, monkeypatch):
     mesh alone."""
     _assert_a_fit_keeps_nothing_of_itself(
         problem, LDAConfig(**MESH_CFG), monkeypatch, make_mesh=_mesh)
+
+
+# -- a group's batches read out of its stack, in place ------------------------
+
+def _three_batch_group(wmajor, k=4, v=96, b=16, l=8):
+    """-> (log_beta, the dense group [3, B, W] with its masks, gammas_prev):
+    three unlike batches, some documents masked."""
+    import jax.numpy as jnp
+
+    from oni_ml_tpu.ops import dense_estep
+
+    rng = np.random.default_rng(11)
+    noise = rng.uniform(size=(k, v)) + 1.0 / v
+    log_beta = jnp.asarray(
+        np.log(noise / noise.sum(-1, keepdims=True)), jnp.float32)
+    dense = jnp.stack([
+        dense_estep.densify(
+            jnp.asarray(rng.integers(0, v, size=(b, l)), jnp.int32),
+            jnp.asarray(rng.integers(1, 5, size=(b, l)), jnp.float32), v)
+        for _ in range(3)])
+    if wmajor:
+        dense = jnp.transpose(dense, (0, 2, 1))
+    masks = np.ones((3, b), np.float32)
+    masks[1, -3:] = masks[2, -5:] = 0.0
+    gammas = jnp.asarray(rng.uniform(0.5, 3.0, size=(3, b, k)), jnp.float32)
+    return log_beta, (dense, jnp.asarray(masks)), gammas
+
+
+def _accumulate(wmajor, groups, gammas, log_beta, warm, **kw):
+    import jax
+    import jax.numpy as jnp
+
+    from oni_ml_tpu.models import fused
+
+    acc = fused.make_em_accumulator(
+        num_topics=log_beta.shape[0], num_terms=log_beta.shape[1],
+        var_max_iters=8, var_tol=1e-6, dense_wmajor=wmajor,
+        warm_start=True, **kw)
+    return jax.jit(acc)(log_beta, jnp.float32(2.5), groups, gammas,
+                        jnp.asarray(warm))
+
+
+def _own_dense_e_step(capable, seen=None):
+    """A `dense_e_step_fn` of the caller's own around the dense kernel,
+    which declares `_oni_stack_capable` or does not; `seen` collects the
+    corpus shape and the keywords of every call."""
+    from oni_ml_tpu.ops import dense_estep
+
+    def own(lb, alpha, corpus, m, g_in, warm, **kw):
+        if seen is not None:
+            seen.append((corpus.shape, sorted(kw)))
+        return dense_estep.e_step_dense(
+            lb, alpha, corpus, m, var_max_iters=8, var_tol=1e-6,
+            interpret=True, gamma_prev=g_in, warm=warm, **kw)
+
+    if capable:
+        own._oni_stack_capable = True
+    return own
+
+
+@pytest.mark.parametrize("warm", [False, True], ids=["fresh", "warm"])
+@pytest.mark.parametrize("wmajor", [False, True], ids=["rowmajor", "wmajor"])
+def test_group_of_three_batches_is_three_groups_of_one(wmajor, warm):
+    """The accumulator over ONE dense group of three batches (the kernel
+    reads each batch out of the stack in place) gives what it gives over
+    the same batches as three single-batch groups (the direct call on
+    `stack[0]`): the same statistics summed in the same order, to what
+    two XLA programs of one arithmetic differ by on the CPU (the kernel
+    itself is bit for bit: tests/test_dense_estep.py)."""
+    from oni_ml_tpu.models import fused
+
+    log_beta, (dense, masks), gammas = _three_batch_group(wmajor)
+    assert fused.reads_stack_in_place((dense, masks), None)
+    stacked = _accumulate(wmajor, ((dense, masks),), (gammas,), log_beta,
+                          warm)
+    singles = tuple((dense[n:n + 1], masks[n:n + 1]) for n in range(3))
+    assert not any(fused.reads_stack_in_place(g, None) for g in singles)
+    apart = _accumulate(wmajor, singles,
+                        tuple(gammas[n:n + 1] for n in range(3)), log_beta,
+                        warm)
+    for got, want in zip(stacked[:3], apart[:3]):
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-6)
+    np.testing.assert_allclose(
+        stacked[3][0], np.concatenate([np.asarray(g) for g in apart[3]]),
+        rtol=2e-6)
+    assert [int(x) for x in stacked[4:]] == [int(x) for x in apart[4:]]
+
+
+@pytest.mark.parametrize("capable", [False, True],
+                         ids=["per_batch_contract", "declares_stack"])
+def test_only_a_callable_that_says_so_is_handed_the_stack(capable):
+    """A user's own `dense_e_step_fn` (and the vocab-sharded XLA plan)
+    keeps the per-batch contract: `[B, W]` slices and no `batch_index`.
+    One that declares `_oni_stack_capable` gets the whole stack and the
+    index; both come to the same numbers."""
+    import jax
+
+    from oni_ml_tpu.models import fused
+
+    log_beta, (dense, masks), gammas = _three_batch_group(False)
+    seen = []
+    own = _own_dense_e_step(capable, seen)
+    assert fused.reads_stack_in_place((dense, masks), own) is capable
+    got = _accumulate(False, ((dense, masks),), (gammas,), log_beta, True,
+                      dense_e_step_fn=own)
+    assert seen == [(dense.shape, ["batch_index"]) if capable
+                    else (dense.shape[1:], [])]
+    want = _accumulate(False, ((dense, masks),), (gammas,), log_beta, True)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("capable", [True, False],
+                         ids=["in_place", "per_batch_contract"])
+def test_chunk_program_slices_no_batch_out_of_a_stack_it_reads_in_place(
+        capable):
+    """Structure, no chip: the lowered chunk program of a two-batch dense
+    group holds no `dynamic_slice` that yields a whole `[B, W]` batch (on
+    the chip XLA made each one a copy of the batch, every EM iteration: a
+    quarter of the device's time, PERF.md PR 37).  The kernel's own blocks
+    are `[8, W]` here, a third of a batch.  A callable under the per-batch
+    contract shows that this looks where the slice would be."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from oni_ml_tpu.models import fused
+    from oni_ml_tpu.ops import dense_estep, estep
+
+    k, v, b, w = 4, 96, 24, 128
+    assert dense_estep.pick_block(b, v, k) == 8
+    program = fused._build_chunk_program(
+        num_docs=2 * b, num_topics=k, num_terms=v, chunk=4, var_max_iters=8,
+        var_tol=1e-6, em_tol=0.0, estimate_alpha=True,
+        e_step_fn=estep.e_step, m_step_fn=estep.m_step,
+        compiler_options=None, dense_wmajor=False, warm_start=True,
+        dense_e_step_fn=_own_dense_e_step(capable), dense_precision="f32",
+        alpha_max_iters=100)
+    f32 = jnp.float32
+    groups = ((jax.ShapeDtypeStruct((2, b, w), f32),
+               jax.ShapeDtypeStruct((2, b), f32)),)
+    text = program.lower(
+        jax.ShapeDtypeStruct((k, v), f32), jax.ShapeDtypeStruct((), f32),
+        jax.ShapeDtypeStruct((), f32), groups,
+        jax.ShapeDtypeStruct((), jnp.int32),
+        (jax.ShapeDtypeStruct((2, b, k), f32),),
+        jax.ShapeDtypeStruct((), jnp.bool_)).as_text()
+    assert "stablehlo.while" in text
+    whole_batch = re.findall(
+        rf"dynamic_slice.*-> tensor<(?:1x)?{b}x{w}xf32>", text)
+    assert bool(whole_batch) is not capable, whole_batch
+    # the kernel's own block reads are there either way
+    assert re.search(rf"dynamic_slice.*-> tensor<(?:1x)?8x{w}xf32>", text)
+
+
+def test_groups_of_one_batch_shape_trace_the_kernels_arithmetic_once(
+        monkeypatch):
+    """Every `pallas_call` traces its kernel anew, and a stack-indexed
+    call's operand has its group's NB in its shape, so two groups cannot
+    share a scan body's jaxpr as sliced batches did.  What they share is
+    `dense_estep._block_e_step`, the kernel's arithmetic under a `jit` of
+    its own: one trace for every group whose batches have one shape (on
+    the chip's host a kernel's trace is 0.4 s of every process's first
+    fit, `setup_s`: PERF.md PR 37)."""
+    import jax
+    import jax.numpy as jnp
+
+    from oni_ml_tpu.models import fused
+    from oni_ml_tpu.ops import dense_estep
+
+    traces = []
+    real = dense_estep._cast_for          # called once a trace of the body
+
+    def counting(precision):
+        traces.append(precision)
+        return real(precision)
+
+    monkeypatch.setattr(dense_estep, "_cast_for", counting)
+    k, v, b, w = 3, 100, 40, 128          # a shape no other test traces
+    accumulate = fused.make_em_accumulator(
+        num_topics=k, num_terms=v, var_max_iters=4, var_tol=1e-6,
+        warm_start=True)
+    f32 = jnp.float32
+    groups = tuple((jax.ShapeDtypeStruct((nb, b, w), f32),
+                    jax.ShapeDtypeStruct((nb, b), f32)) for nb in (3, 2, 1))
+    gammas = tuple(jax.ShapeDtypeStruct((nb, b, k), f32) for nb in (3, 2, 1))
+    jax.jit(accumulate).lower(
+        jax.ShapeDtypeStruct((k, v), f32), jax.ShapeDtypeStruct((), f32),
+        groups, gammas, jax.ShapeDtypeStruct((), jnp.bool_))
+    assert traces == ["f32"]

@@ -319,9 +319,19 @@ def test_four_shard_fit_is_the_one_device_fit_and_the_plain_reference(day):
     Newton, so 2e-3 of gamma, 1e-3 of alpha, 1e-5 of the ELBO)."""
     from benchmarks.reference import lda_plain
 
+    from oni_ml_tpu.telemetry import spans
+
     corpus, lda = day
     mesh = make_mesh(data=4, model=1, devices=jax.devices()[:4])
-    four, one = _fit(corpus, lda, mesh), _fit(corpus, lda)
+    rec = spans.Recorder()
+    with spans.use_recorder(rec):
+        four, one = _fit(corpus, lda, mesh), _fit(corpus, lda)
+    # Both fits hold a group of two batches, which every shard's kernel
+    # reads out of its slice of the group's stack in place; the three
+    # single-batch groups are called directly.
+    runners = [e["args"] for e in rec.events if e["name"] == "fit.runner"]
+    assert [(r["batches"], r["stack_indexed_batches"], r["sliced_batches"])
+            for r in runners] == [(5, 2, 3)] * 2
     assert four.plan["estep_kernel"]["value"].endswith("_shard_map")
     assert four.plan["estep_kernel"]["corpus_slices"] == 4
     assert four.em_iters == one.em_iters == lda["em_max_iters"]
@@ -341,6 +351,55 @@ def test_four_shard_fit_is_the_one_device_fit_and_the_plain_reference(day):
     np.testing.assert_allclose(four.alpha, plain.alpha, rtol=1e-3)
     np.testing.assert_allclose([ll for ll, _ in four.likelihoods],
                                plain.likelihoods, rtol=1e-5)
+
+
+@pytest.mark.parametrize("n", range(3))
+@pytest.mark.parametrize("wmajor", [False, True],
+                         ids=["rowmajor", "wmajor"])
+def test_four_shards_read_a_batch_out_of_their_slices_of_the_stack(
+        problem, wmajor, n):
+    """Under the mesh the stack is `P(None, data, None)` (W-major
+    `P(None, None, data)`) and the index replicated: every shard's kernel
+    reads batch `n` of its own rows in place, and computes what it
+    computes from that batch handed over alone."""
+    from oni_ml_tpu.ops import dense_estep
+    from oni_ml_tpu.parallel.sharded import (
+        bound, make_data_parallel_dense_e_step)
+
+    corpus, K, log_beta = problem
+    rng = np.random.default_rng(5)
+    stack = jnp.stack([
+        dense_estep.densify(
+            jnp.asarray(rng.integers(0, corpus.num_terms, (64, 6 + 2 * i)),
+                        jnp.int32),
+            jnp.asarray(rng.integers(1, 4, (64, 6 + 2 * i)), jnp.float32),
+            corpus.num_terms)
+        for i in range(3)])
+    if wmajor:
+        stack = jnp.transpose(stack, (0, 2, 1))
+    mask = np.ones((64,), np.float32)
+    mask[-5:] = 0.0
+    gamma_prev = jnp.asarray(rng.uniform(0.5, 3.0, (64, K)), jnp.float32)
+    mesh = make_mesh(data=4, model=1, devices=jax.devices()[:4])
+    fn = bound(make_data_parallel_dense_e_step(mesh, wmajor=wmajor),
+               var_max_iters=20, var_tol=1e-6, interpret=True)
+    assert fn._oni_stack_capable
+    args = (jnp.asarray(log_beta, jnp.float32), jnp.float32(2.5))
+    rest = (jnp.asarray(mask), gamma_prev, jnp.asarray(1, jnp.int32))
+    alone = jax.jit(fn)(*args, stack[n], *rest)
+    in_place = jax.jit(
+        lambda whole, i: fn(*args, whole, *rest, batch_index=i))(
+        stack, jnp.asarray(n, jnp.int32))
+    # (Bit for bit on one device, tests/test_dense_estep.py; the two
+    # `shard_map` programs the CPU compiles here differ in the last bit.)
+    for field in alone._fields:
+        np.testing.assert_allclose(
+            np.asarray(getattr(in_place, field)),
+            np.asarray(getattr(alone, field)), rtol=2e-6, err_msg=field)
+    assert int(in_place.doc_sweeps) == int(alone.doc_sweeps) > 64
+    with pytest.raises(ValueError, match="not divisible"):
+        fn(*args, stack[:, :62] if not wmajor else stack[:, :, :62],
+           *rest, batch_index=0)
 
 
 @pytest.mark.parametrize("wmajor", [False, True],
