@@ -71,7 +71,10 @@ def stack_batches(
     axis.  `put` commits the stacked [NB, ...] arrays to device (on a
     mesh: shard the batch axis, axis 1).  The span's `h2d_bytes` is the
     host arrays' size: on a mesh, the sum over the devices, each of which
-    receives its own rows."""
+    receives its own rows.  Per group the span holds `fit.stack.copy`
+    (the host's fresh stack) and `fit.stack.put` (handing it to the
+    runtime; `shards`: the devices it went to), each counting its
+    `bytes`."""
     groups: dict[tuple, list[int]] = {}
     for i, b in enumerate(batches):
         groups.setdefault(b.word_idx.shape, []).append(i)
@@ -81,13 +84,22 @@ def stack_batches(
         h2d_bytes = 0
         for shape in sorted(groups):
             idxs = groups[shape]
-            host = (
-                np.stack([batches[i].word_idx for i in idxs]),
-                np.stack([batches[i].counts for i in idxs]).astype(dtype),
-                np.stack([batches[i].doc_mask for i in idxs]).astype(dtype),
-            )
-            h2d_bytes += sum(a.nbytes for a in host)
-            arrays.append(tuple(put(a) for a in host))
+            with maybe_span("fit.stack.copy") as sub:
+                host = (
+                    np.stack([batches[i].word_idx for i in idxs]),
+                    np.stack([batches[i].counts for i in idxs]).astype(dtype),
+                    np.stack(
+                        [batches[i].doc_mask for i in idxs]).astype(dtype),
+                )
+                nbytes = sum(a.nbytes for a in host)
+                sub.annotate(bytes=nbytes)
+            h2d_bytes += nbytes
+            with maybe_span("fit.stack.put") as sub:
+                arrays.append(tuple(put(a) for a in host))
+                if sub.live:
+                    sub.annotate(
+                        bytes=nbytes,
+                        shards=len(arrays[-1][0].sharding.device_set))
             slots.append(tuple(idxs))
         sp.annotate(h2d_bytes=h2d_bytes)
     return StackedGroups(tuple(arrays), tuple(slots))
@@ -284,7 +296,10 @@ def compact_stack_batches(
     because their local counts are zero, so the kernel produces zero
     suff-stats there and the scatter-back adds zeros to word 0.
     Token ids remap via searchsorted into the batch's sorted unique
-    set (exact: every token id is a member)."""
+    set (exact: every token id is a member).  Under `fit.stack`, per
+    group, `fit.stack.copy` is the host's remap and stacks and
+    `fit.stack.put` the three `put`s; the device's densify lies between
+    them, under neither."""
     groups: dict[tuple, list[int]] = {}
     for i, b in enumerate(batches):
         groups.setdefault(b.word_idx.shape, []).append(i)
@@ -296,26 +311,35 @@ def compact_stack_batches(
             idxs = groups[shape]
             wc = plan.widths[g]
 
-            local_idx, cnts, masks, vmaps = [], [], [], []
-            for j, i in enumerate(idxs):
-                u = plan.uniques[g][j]
-                local_idx.append(
-                    np.searchsorted(u, batches[i].word_idx).astype(np.int32)
-                )
-                cnts.append(batches[i].counts.astype(dtype))
-                masks.append(batches[i].doc_mask.astype(dtype))
-                vm = np.zeros(wc, np.int32)
-                vm[: len(u)] = u
-                vmaps.append(vm)
+            with maybe_span("fit.stack.copy") as sub:
+                local_idx, cnts, masks, vmaps = [], [], [], []
+                for j, i in enumerate(idxs):
+                    u = plan.uniques[g][j]
+                    local_idx.append(
+                        np.searchsorted(
+                            u, batches[i].word_idx).astype(np.int32)
+                    )
+                    cnts.append(batches[i].counts.astype(dtype))
+                    masks.append(batches[i].doc_mask.astype(dtype))
+                    vm = np.zeros(wc, np.int32)
+                    vm[: len(u)] = u
+                    vmaps.append(vm)
 
-            host = (np.stack(local_idx), np.stack(cnts), np.stack(masks),
-                    np.stack(vmaps))
-            h2d_bytes += sum(a.nbytes for a in host)
+                host = (np.stack(local_idx), np.stack(cnts), np.stack(masks),
+                        np.stack(vmaps))
+                nbytes = sum(a.nbytes for a in host)
+                sub.annotate(bytes=nbytes)
+            h2d_bytes += nbytes
             dense = densify_stack(
                 jnp.asarray(host[0]), jnp.asarray(host[1]), num_terms=wc,
                 width=wc, dtype=corpus_store, wmajor=plan.wmajor,
             )
-            arrays.append((put(dense), put(host[2]), put(host[3])))
+            with maybe_span("fit.stack.put") as sub:
+                arrays.append((put(dense), put(host[2]), put(host[3])))
+                if sub.live:
+                    sub.annotate(
+                        bytes=dense.nbytes + host[2].nbytes + host[3].nbytes,
+                        shards=len(arrays[-1][0].sharding.device_set))
             slots.append(tuple(idxs))
         sp.annotate(h2d_bytes=h2d_bytes)
     return StackedGroups(tuple(arrays), tuple(slots))

@@ -490,7 +490,7 @@ class LDATrainer:
             os.remove(checkpoint_path)  # run completed; day dir stays clean
 
         with maybe_span("fit.readback", what="log_beta"):
-            beta_host = to_host(log_beta, self.mesh)
+            beta_host = self._to_host(log_beta)
             alpha = float(alpha)
         return LDAResult(
             log_beta=beta_host,
@@ -502,6 +502,34 @@ class LDATrainer:
             doc_sweeps=doc_sweeps,
             vi_max=vi_max,
         )
+
+    def _to_host(self, x) -> np.ndarray:
+        """`to_host` under the span `fit.readback.d2h`: `bytes` as they
+        left the device, `shards` the devices that held them."""
+        with maybe_span("fit.readback.d2h") as sp:
+            host = to_host(x, self.mesh)
+            if sp.live:
+                sp.annotate(bytes=x.nbytes,
+                            shards=len(x.sharding.device_set))
+        return host
+
+    def _read_back_gamma(self, g_arr, batches, gamma_out) -> None:
+        """The final posteriors of `batches`, held by ONE device array (a
+        batch's [B, K], or a shape group's [NB, B, K] in the batches'
+        order), into their documents' rows of `gamma_out`: one transfer
+        (`fit.readback.d2h`), then a masked store per batch
+        (`fit.readback.scatter`: `rows` and float64 `bytes` written).
+        The one read-back of all three drivers."""
+        k = gamma_out.shape[1]
+        g_host = self._to_host(g_arr).reshape(len(batches), -1, k)
+        with maybe_span("fit.readback.scatter") as sp:
+            rows = 0
+            for g, b in zip(g_host, batches):
+                sel = b.doc_mask == 1
+                idx = b.doc_index[sel]
+                gamma_out[idx] = g[sel]
+                rows += len(idx)
+            sp.annotate(rows=rows, bytes=rows * k * gamma_out.itemsize)
 
     def _resolve_em_plan(self, batches) -> tuple[int, int]:
         """Resolve the fused driver's dispatch knobs through the plan
@@ -724,9 +752,7 @@ class LDATrainer:
 
         with maybe_span("fit.readback", what="gamma"):
             for g, b in zip(gammas, batches):
-                g = to_host(g, self.mesh)
-                sel = b.doc_mask == 1
-                gamma_out[b.doc_index[sel]] = g[sel]
+                self._read_back_gamma(g, [b], gamma_out)
         return log_beta, alpha, it, doc_sweeps, vi_max
 
     def _distributed_loop(
@@ -921,11 +947,8 @@ class LDATrainer:
             for si, sg, gms in zip(owned, shard_groups, gammas_prev):
                 bs = self._shard_batches[si]
                 for g_arr, slots in zip(gms, sg.batch_slots):
-                    g_group = to_host(g_arr, self.mesh)
-                    for j, bi in enumerate(slots):
-                        b = bs[bi]
-                        sel = b.doc_mask == 1
-                        gamma_out[b.doc_index[sel]] = g_group[j][sel]
+                    self._read_back_gamma(
+                        g_arr, [bs[bi] for bi in slots], gamma_out)
         if coll.num_processes > 1:
             # Ship only the OWNED contiguous row blocks (a rank owns
             # 1/P of the documents; gathering the full mostly-zero
@@ -1330,9 +1353,7 @@ class LDATrainer:
         with maybe_span("fit.plan", batches=len(batches)) as sp:
             plan = self._plan_estep(batches)
             sp.annotate(kernel=plan.kernel, cell_scan=plan.cell_scan,
-                        scan_tokens=plan.scan_tokens,
-                        dense_budget=plan.budget,
-                        dense_budget_source=plan.budget_source)
+                        scan_tokens=plan.scan_tokens)
             self.plan_record["dense_hbm_budget"] = {
                 "value": plan.budget, "source": plan.budget_source}
             self.plan_record["exchange"] = self._exchange(batches, num_docs)
@@ -1521,12 +1542,8 @@ class LDATrainer:
         if res is not None and int(res.steps_done) > 0:
             with maybe_span("fit.readback", what="gamma"):
                 for g_arr, slots in zip(res.gammas, groups.batch_slots):
-                    # one transfer per group
-                    g_group = to_host(g_arr, self.mesh)
-                    for j, bi in enumerate(slots):
-                        b = batches[bi]
-                        sel = b.doc_mask == 1
-                        gamma_out[b.doc_index[sel]] = g_group[j][sel]
+                    self._read_back_gamma(
+                        g_arr, [batches[bi] for bi in slots], gamma_out)
         return log_beta, alpha, it, doc_sweeps, vi_max
 
 
@@ -1897,10 +1914,14 @@ def train_corpus(
 
     The whole call is the span `fit` (telemetry/spans.py), the root the
     fit's layer boundaries hang under: fit.engine, fit.batches,
-    fit.init, fit.plan, fit.stack, fit.densify, fit.runner,
-    em.run_chunk / em.host_sync, fit.readback, fit.save (which counts the
-    bytes of each file it wrote, the matrices' `rows` and `values`, and
-    says their `writer`),
+    fit.init, fit.plan, fit.stack (per shape group fit.stack.copy, the
+    host's fresh stack, and fit.stack.put, handing it to the runtime:
+    `bytes` each), fit.densify, fit.runner, em.run_chunk / em.host_sync,
+    fit.readback (per device array fit.readback.d2h, `to_host`: `bytes`
+    as they left the device, `shards`; for gamma also
+    fit.readback.scatter, the masked stores into the result: `rows`,
+    `bytes`), fit.save (which counts the bytes of each file it wrote, the
+    matrices' `rows` and `values`, and says their `writer`),
     fit.teardown.  On its close it counts what the fit ran (`em_iters`,
     `doc_sweeps`, the engine and kernel), which dense budget the plan
     held and where it came from (`dense_budget`, `dense_budget_source`:
@@ -1964,11 +1985,10 @@ def _train_corpus(
     initial_log_beta = None
     if vocab_sharded and mesh is None:
         raise ValueError("vocab_sharded=True requires a mesh")
-    with maybe_span("fit.engine") as sp:
+    with maybe_span("fit.engine"):
         engine, engine_src = resolve_estep_engine(
             corpus, config, mesh=mesh, vocab_sharded=vocab_sharded
         )
-        sp.annotate(engine=engine, source=engine_src)
     sparse_layout = None
     sparse_l_record = None
     if engine == "sparse":

@@ -91,9 +91,12 @@ def _trace_annotation():
 
 class _NoSpan:
     """What `maybe_span` hands out when nothing listens: enters, takes
-    `annotate()` and leaves without a trace."""
+    `annotate()` and leaves without a trace.  `live` is False here and
+    True on a span somebody reads: a call site asks it before it works
+    out counts that cost more than a keyword."""
 
     __slots__ = ()
+    live = False
 
     def __enter__(self):
         return self
@@ -283,6 +286,7 @@ class _Span:
 
     __slots__ = ("_rec", "name", "args", "start_ns", "tid", "id", "parent",
                  "root", "depth", "_counts", "_annotation")
+    live = True
 
     def __init__(self, rec, name: str, args: dict) -> None:
         self._rec = rec

@@ -427,6 +427,16 @@ def test_f32_fit_stores_what_the_exact_reader_would_without_reading(
     plan, = _span_args(rec.events, "fit.plan")
     assert plan["kernel"].startswith(family)
     assert (plan["cell_scan"], plan["scan_tokens"]) == ("none", 0)
+    # a copy and a put a shape group, in both families; the compact
+    # family's device densify lies between the two, under neither
+    (stack,) = [e for e in rec.events if e["name"] == "fit.stack"]
+    inside = [e for e in rec.events if e["parent"] == stack["id"]]
+    assert [e["name"] for e in inside] == (
+        ["fit.stack.copy", "fit.stack.put"] * stack["args"]["groups"])
+    copied = sum(e["args"]["bytes"] for e in inside[0::2])
+    put = sum(e["args"]["bytes"] for e in inside[1::2])
+    assert copied == stack["args"]["h2d_bytes"]
+    assert (put == copied) == (family == "dense")
 
 
 def _sparse_chunk_problem(seed=7, k=3, v=40, b=8, l=6):
@@ -783,11 +793,20 @@ def test_second_mesh_fit_reuses_the_programs_of_the_first(problem):
                            pad_multiple=32)
     assert root["allreduce_bytes"] == len(batches) * (
         problem.num_terms * cfg.num_topics * 4 + 16)
+    # every stack goes to, and every gamma comes from, the four shards;
+    # beta is replicated over them
+    puts = _span_args(rec.events, "fit.stack.put")
+    d2h = _span_args(rec.events, "fit.readback.d2h")
+    assert len(puts) + 1 == len(d2h) and {a["shards"] for a in puts} == {4}
+    assert [a["shards"] for a in d2h] == [4] * len(d2h)
+    assert d2h[-1]["bytes"] == cfg.num_topics * problem.num_terms * 4
 
     # without a mesh the same spans say so
     rec = spans.Recorder()
     with spans.use_recorder(rec):
         train_corpus(problem, cfg)
+    assert {a["shards"]
+            for a in _span_args(rec.events, "fit.stack.put")} == {1}
     assert _span_args(rec.events, "fit.densify")[0]["sharded"] is False
     root, = _span_args(rec.events, "fit")
     assert (root["data_shards"], root["allreduce_bytes"]) == (1, 0)

@@ -7,6 +7,7 @@ Everything here is the fast tier-1 smoke — no device, no subprocesses
 (the SIGKILL crash-recovery path lives in tests/test_journal_crash.py).
 """
 
+import contextlib
 import json
 import os
 import threading
@@ -192,6 +193,20 @@ def test_maybe_span_is_noop_without_recorder():
     assert current_recorder() is None
     with maybe_span("nothing", a=1):
         pass  # no recorder: must not raise, must not record anywhere
+
+
+@pytest.mark.parametrize("listening", [False, True])
+def test_a_span_says_whether_anybody_reads_it(listening):
+    """`live`: what a call site asks before it works out counts that cost
+    more than a keyword (a sharding's device set, say)."""
+    rec = Recorder()
+    with use_recorder(rec) if listening else contextlib.nullcontext():
+        with maybe_span("asked") as sp:
+            if sp.live:
+                sp.annotate(costly=1)
+    assert sp.live is listening
+    assert [e["args"] for e in rec.events] == (
+        [{"costly": 1}] if listening else [])
 
 
 def test_use_recorder_binds_and_restores():
