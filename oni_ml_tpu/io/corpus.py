@@ -460,9 +460,14 @@ def make_batches(
     one scatter each of word ids and counts.  Python loops run once a
     bucket, a batch and a slab, never once a document or a token.  A
     pure function: every call batches afresh.  Each field's batches
-    are C-contiguous [B, L] views of one buffer, which lives while any
-    of them does.  tests/test_make_batches.py holds it to the
-    per-document loop it replaced, array for array.
+    are C-contiguous [B, L] views of one buffer, laid bucket by bucket,
+    batch after batch, so a bucket's batches are one contiguous
+    [NB, B, L] run of it: `fused.stack_batches` hands that run to the
+    device as it lies.  The buffer lives while any batch, or any such
+    view of a run, does: through the fit's puts, which may still be
+    reading it after they return.  Nobody writes to it.
+    tests/test_make_batches.py holds it to the per-document loop it
+    replaced, array for array.
     """
     if pad_multiple is None:
         pad_multiple = batch_size

@@ -62,6 +62,50 @@ class StackedGroups(NamedTuple):
     batch_slots: tuple
 
 
+def _run_view(arrays: Sequence[np.ndarray]) -> np.ndarray | None:
+    """The [G, ...] stack of `arrays` as a view, where it already exists
+    in memory: every array C-contiguous, of one dtype and shape, each one
+    starting where the one before it ends, all of them views of one
+    C-contiguous owner (`.base`).  That is what `make_batches` returns for
+    the batches of a bucket.  A single C-contiguous array is a run of
+    one, whoever owns it.  None wherever any of it cannot be seen."""
+    first = arrays[0]
+    if not first.flags.c_contiguous:
+        return None
+    if len(arrays) == 1:
+        return first.reshape(1, *first.shape)
+    owner = first.base
+    if not (isinstance(owner, np.ndarray) and owner.flags.c_contiguous):
+        return None
+    at = first.ctypes.data
+    for a in arrays:
+        if not (a.base is owner and a.flags.c_contiguous
+                and a.dtype == first.dtype and a.shape == first.shape
+                and a.ctypes.data == at):
+            return None
+        at += a.nbytes
+    return np.ndarray(
+        (len(arrays), *first.shape), first.dtype, buffer=owner,
+        offset=first.ctypes.data - owner.ctypes.data)
+
+
+def stack_run(arrays: Sequence[np.ndarray], dtype=None):
+    """`np.stack(arrays)` cast to `dtype` (None: as they are), and the
+    bytes the host wrote to make it.  Arrays that lie end to end in one
+    buffer (`_run_view`) are handed on as a view of it: nothing written,
+    nothing allocated, and the view keeps the buffer alive.  Anything else
+    is stacked, and cast, in ONE pass into a fresh array.  The same values
+    either way."""
+    view = _run_view(arrays)
+    if view is None:
+        out = np.stack(arrays, dtype=dtype, casting="unsafe")
+    elif dtype is None or view.dtype == dtype:
+        return view, 0
+    else:
+        out = view.astype(dtype)
+    return out, out.nbytes
+
+
 def stack_batches(
     batches: Sequence[Batch],
     dtype,
@@ -71,29 +115,44 @@ def stack_batches(
     axis.  `put` commits the stacked [NB, ...] arrays to device (on a
     mesh: shard the batch axis, axis 1).  The span's `h2d_bytes` is the
     host arrays' size: on a mesh, the sum over the devices, each of which
-    receives its own rows.  Per group the span holds `fit.stack.copy`
-    (the host's fresh stack) and `fit.stack.put` (handing it to the
-    runtime; `shards`: the devices it went to), each counting its
-    `bytes`."""
+    receives its own rows.
+
+    A group's word ids and counts are VIEWS of the batches' own buffer
+    wherever the batches lie in it end to end (`stack_run`: every group
+    of `make_batches`' list, whose buffers therefore have to live, and
+    stay unwritten, until the puts have been consumed: the fit holds the
+    batches to its end); batches some caller copied, reordered or built
+    one by one are `np.stack`ed as before.  Which of the two a group took
+    is `copied_bytes`, what the host wrote for it: the masks alone (one
+    small array a batch, allocated apart, always stacked) on views, all
+    of `bytes` otherwise.
+
+    Per group the span holds `fit.stack.copy` (assembling the host stack:
+    views, or the copy) and `fit.stack.put` (handing it to the runtime;
+    `shards`: the devices it went to), each counting its `bytes`; the
+    copy and `fit.stack` also count `copied_bytes`."""
     groups: dict[tuple, list[int]] = {}
     for i, b in enumerate(batches):
         groups.setdefault(b.word_idx.shape, []).append(i)
     arrays = []
     slots = []
     with maybe_span("fit.stack", groups=len(groups)) as sp:
-        h2d_bytes = 0
+        h2d_bytes = copied_bytes = 0
         for shape in sorted(groups):
             idxs = groups[shape]
             with maybe_span("fit.stack.copy") as sub:
-                host = (
-                    np.stack([batches[i].word_idx for i in idxs]),
-                    np.stack([batches[i].counts for i in idxs]).astype(dtype),
-                    np.stack(
-                        [batches[i].doc_mask for i in idxs]).astype(dtype),
-                )
+                widx, wrote_w = stack_run(
+                    [batches[i].word_idx for i in idxs])
+                cnts, wrote_c = stack_run(
+                    [batches[i].counts for i in idxs], dtype)
+                mask = np.stack([batches[i].doc_mask for i in idxs],
+                                dtype=dtype, casting="unsafe")
+                host = (widx, cnts, mask)
                 nbytes = sum(a.nbytes for a in host)
-                sub.annotate(bytes=nbytes)
+                wrote = wrote_w + wrote_c + mask.nbytes
+                sub.annotate(bytes=nbytes, copied_bytes=wrote)
             h2d_bytes += nbytes
+            copied_bytes += wrote
             with maybe_span("fit.stack.put") as sub:
                 arrays.append(tuple(put(a) for a in host))
                 if sub.live:
@@ -101,7 +160,7 @@ def stack_batches(
                         bytes=nbytes,
                         shards=len(arrays[-1][0].sharding.device_set))
             slots.append(tuple(idxs))
-        sp.annotate(h2d_bytes=h2d_bytes)
+        sp.annotate(h2d_bytes=h2d_bytes, copied_bytes=copied_bytes)
     return StackedGroups(tuple(arrays), tuple(slots))
 
 
@@ -297,9 +356,10 @@ def compact_stack_batches(
     suff-stats there and the scatter-back adds zeros to word 0.
     Token ids remap via searchsorted into the batch's sorted unique
     set (exact: every token id is a member).  Under `fit.stack`, per
-    group, `fit.stack.copy` is the host's remap and stacks and
-    `fit.stack.put` the three `put`s; the device's densify lies between
-    them, under neither."""
+    group, `fit.stack.copy` is the host's remap and stacks (all of
+    them written afresh: `copied_bytes` = `bytes`) and `fit.stack.put`
+    the three `put`s; the device's densify lies between them, under
+    neither."""
     groups: dict[tuple, list[int]] = {}
     for i, b in enumerate(batches):
         groups.setdefault(b.word_idx.shape, []).append(i)
@@ -328,7 +388,7 @@ def compact_stack_batches(
                 host = (np.stack(local_idx), np.stack(cnts), np.stack(masks),
                         np.stack(vmaps))
                 nbytes = sum(a.nbytes for a in host)
-                sub.annotate(bytes=nbytes)
+                sub.annotate(bytes=nbytes, copied_bytes=nbytes)
             h2d_bytes += nbytes
             dense = densify_stack(
                 jnp.asarray(host[0]), jnp.asarray(host[1]), num_terms=wc,
@@ -341,7 +401,7 @@ def compact_stack_batches(
                         bytes=dense.nbytes + host[2].nbytes + host[3].nbytes,
                         shards=len(arrays[-1][0].sharding.device_set))
             slots.append(tuple(idxs))
-        sp.annotate(h2d_bytes=h2d_bytes)
+        sp.annotate(h2d_bytes=h2d_bytes, copied_bytes=h2d_bytes)
     return StackedGroups(tuple(arrays), tuple(slots))
 
 

@@ -1915,10 +1915,11 @@ def train_corpus(
     The whole call is the span `fit` (telemetry/spans.py), the root the
     fit's layer boundaries hang under: fit.engine, fit.batches,
     fit.init, fit.plan, fit.stack (per shape group fit.stack.copy, the
-    host's fresh stack, and fit.stack.put, handing it to the runtime:
-    `bytes` each), fit.densify, fit.runner, em.run_chunk / em.host_sync,
-    fit.readback (per device array fit.readback.d2h, `to_host`: `bytes`
-    as they left the device, `shards`; for gamma also
+    group's host stack: a view of the batches' buffer or a copy,
+    `copied_bytes` says which; and fit.stack.put, handing it to the
+    runtime: `bytes` each), fit.densify, fit.runner, em.run_chunk /
+    em.host_sync, fit.readback (per device array fit.readback.d2h,
+    `to_host`: `bytes` as they left the device, `shards`; for gamma also
     fit.readback.scatter, the masked stores into the result: `rows`,
     `bytes`), fit.save (which counts the bytes of each file it wrote, the
     matrices' `rows` and `values`, and says their `writer`),

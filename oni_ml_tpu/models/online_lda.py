@@ -38,6 +38,7 @@ from ..config import OnlineLDAConfig
 from ..io import Batch
 from ..ops import estep
 from ..ops.estep import e_log_dirichlet as expected_log_beta
+from . import fused
 from .lda import LDAResult
 
 
@@ -624,9 +625,9 @@ class OnlineLDATrainer:
         """Device placement for a stacked [N, B, ...] run of same-shape
         micro-batches (docs axis 1 sharded over `data` on a mesh)."""
         dtype = jnp.dtype(self.config.compute_dtype)
-        w = np.stack([b.word_idx for b in run])
-        c = np.stack([b.counts for b in run]).astype(dtype)
-        m = np.stack([b.doc_mask for b in run]).astype(dtype)
+        w, _ = fused.stack_run([b.word_idx for b in run])
+        c, _ = fused.stack_run([b.counts for b in run], dtype)
+        m, _ = fused.stack_run([b.doc_mask for b in run], dtype)
         if self.mesh is None:
             return jnp.asarray(w), jnp.asarray(c), jnp.asarray(m)
         from ..parallel.mesh import stacked_batch_sharding

@@ -179,6 +179,14 @@ def test_stack_and_readback_are_tiled_by_their_sub_spans(recorded_fits,
                        + b.doc_mask.astype(np.float32).nbytes
                        for b in batches))
         assert {e["args"]["shards"] for e in puts} == {1}
+        # make_batches' groups are views of its buffers: the host wrote
+        # the masks and nothing else
+        masks = [sum(b.doc_mask.nbytes for b in batches
+                     if b.word_idx.shape == shape)
+                 for shape in sorted({b.word_idx.shape for b in batches})]
+        assert [e["args"]["copied_bytes"] for e in copies] == masks
+        assert stack["args"]["copied_bytes"] == sum(masks) == (
+            _padded_rows(corpus, cfg) * 4)
 
     gamma, beta = of("fit.readback")
     assert (gamma["args"]["what"], beta["args"]["what"]) == (
@@ -220,6 +228,15 @@ def test_the_distributed_driver_reads_back_through_the_same_sub_spans():
         inside = [e["name"] for e in rec.events if e["parent"] == stack["id"]]
         assert inside == ["fit.stack.copy", "fit.stack.put"] * (
             stack["args"]["groups"])
+        copies = [e["args"] for e in rec.events
+                  if e["parent"] == stack["id"]
+                  and e["name"] == "fit.stack.copy"]
+        assert (sum(a["bytes"] for a in copies)
+                == stack["args"]["h2d_bytes"])
+        # a shard's batches are make_batches' own: views, masks written
+        assert (sum(a["copied_bytes"] for a in copies)
+                == stack["args"]["copied_bytes"]
+                < 0.1 * stack["args"]["h2d_bytes"])
 
 
 @pytest.mark.parametrize("driver", sorted(DRIVERS))
@@ -547,6 +564,11 @@ def test_a_fit_under_the_profiler_puts_its_spans_in_the_trace(tmp_path):
         assert stack[0] <= start and start + dur <= stack[0] + stack[1]
     assert sum(c[2]["bytes"] for c in tail["fit.stack.put.counts"]) == (
         tail["fit.stack.counts"][0][2]["h2d_bytes"])
+    # the same counters under the profiler: the masks, and nothing else
+    assert sum(c[2]["copied_bytes"]
+               for c in tail["fit.stack.copy.counts"]) == (
+        tail["fit.stack.counts"][0][2]["copied_bytes"]) == (
+        _padded_rows(corpus, cfg) * 4)
     assert sum(c[2]["rows"] for c in tail["fit.readback.scatter.counts"]
                ) == corpus.num_docs
     assert {c[2]["shards"] for c in tail["fit.readback.d2h.counts"]} == {1}

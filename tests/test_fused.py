@@ -437,6 +437,15 @@ def test_f32_fit_stores_what_the_exact_reader_would_without_reading(
     put = sum(e["args"]["bytes"] for e in inside[1::2])
     assert copied == stack["args"]["h2d_bytes"]
     assert (put == copied) == (family == "dense")
+    # what the host wrote: everything it remapped on the compact path,
+    # the masks alone where a dense group is a view of the batches' buffer
+    wrote = sum(e["args"]["copied_bytes"] for e in inside[0::2])
+    assert wrote == stack["args"]["copied_bytes"]
+    batches = make_batches(problem, batch_size=cfg.batch_size,
+                           min_bucket_len=cfg.min_bucket_len,
+                           pad_multiple=8)
+    assert wrote == (copied if family == "compact" else
+                     sum(b.doc_mask.nbytes for b in batches))
 
 
 def _sparse_chunk_problem(seed=7, k=3, v=40, b=8, l=6):
@@ -798,6 +807,13 @@ def test_second_mesh_fit_reuses_the_programs_of_the_first(problem):
     puts = _span_args(rec.events, "fit.stack.put")
     d2h = _span_args(rec.events, "fit.readback.d2h")
     assert len(puts) + 1 == len(d2h) and {a["shards"] for a in puts} == {4}
+    # the sharded put is handed views of the batches' buffer too
+    stack, = _span_args(rec.events, "fit.stack")
+    copies = _span_args(rec.events, "fit.stack.copy")
+    assert [a["bytes"] for a in copies] == [a["bytes"] for a in puts]
+    assert sum(a["bytes"] for a in copies) == stack["h2d_bytes"]
+    assert (sum(a["copied_bytes"] for a in copies) == stack["copied_bytes"]
+            == sum(b.doc_mask.nbytes for b in batches))
     assert [a["shards"] for a in d2h] == [4] * len(d2h)
     assert d2h[-1]["bytes"] == cfg.num_topics * problem.num_terms * 4
 
