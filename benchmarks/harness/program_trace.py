@@ -22,9 +22,11 @@ own spans and what the profiler knows of every device operation's origin.
           (`pallas_call`, `while`), so no reader may lean on the path
           (PERF.md, PR 27).
 
-The readers' `ctx` does not carry the trace's directory: `newest()` takes the
-newest `*.xplane.pb` under `<checkout>/.bench_trace/*/`, which the run has
-just written.
+`run.py` hands the readers the run's own trace directory (`ctx["trace_dir"]`,
+`<checkout>/.bench_trace/<cell>`): `newest(trace_dir=...)` takes the newest
+`*.xplane.pb` under it.  Without one it takes the newest under
+`<checkout>/.bench_trace/*/`, which is another run's where two cells are
+traced in one checkout at once (the test suite's workers).
 """
 
 from __future__ import annotations
@@ -40,10 +42,11 @@ from benchmarks.harness import cells, xplane
 SCOPE_STAT = "tf_op"
 
 
-def newest(root: str | None = None) -> str | None:
+def newest(root: str | None = None,
+           trace_dir: str | None = None) -> str | None:
+    under = trace_dir or os.path.join(root or cells.ROOT, ".bench_trace", "*")
     files = glob.glob(os.path.join(
-        root or cells.ROOT, ".bench_trace", "*", "plugins", "profile", "*",
-        "*.xplane.pb"))
+        under, "plugins", "profile", "*", "*.xplane.pb"))
     return max(files, key=os.path.getmtime) if files else None
 
 

@@ -7,7 +7,9 @@ have, as ShapeDtypeStructs on the described devices.  The program asks
 jax.default_backend() to choose between its kernels and their interpreter;
 here that answer is steered to "tpu" for the length of the lowering (a
 rehearsal's business, not an option of the program).  The dense layout is
-chosen as models/lda.py `_fused_loop` chooses it.  Nothing runs: this says
+chosen as models/lda.py `_fused_loop` chooses it, and the placement's
+densify is the program's own `fused.densify_stack`, under the cell's mesh
+where it has one (`densify_peak_bytes`).  Nothing runs: this says
 what the chip's compiler accepts and how many bytes the program holds, not
 how fast it is.
 """
@@ -15,7 +17,9 @@ how fast it is.
 from __future__ import annotations
 
 import os
+from collections import Counter
 from functools import partial
+from types import SimpleNamespace
 from unittest import mock
 
 import numpy as np
@@ -38,98 +42,139 @@ def batch_shapes(traffic: dict, num_terms: int, data: int) -> list:
         corpus, batch_size=traffic["batch_size"], pad_multiple=8 * data)]
 
 
-def compile_cell(name: str) -> dict:
+def describe_topology():
+    """A v5e 2x2 that is described and not attached."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
+
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+def cell_layout(name: str, topo) -> SimpleNamespace | None:
+    """What the placement and the chunk program of a fit cell see of it on
+    the described devices: the batches by shape, the dense layout chosen as
+    models/lda.py `_fused_loop` chooses it, the mesh and the shardings.
+    Nothing for a cell whose job is not `fit`."""
     from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                               SingleDeviceSharding)
+
+    from oni_ml_tpu.ops import dense_estep
+
+    found = cells.resolve(name)
+    traffic, lda = found["traffic"], found["config"]["lda"]
+    if traffic["job"] != "fit":
+        return None
+    k, v = lda["num_topics"], found["config"]["num_terms"]
+    data = traffic["mesh"][0] if traffic.get("mesh") else 1
+    shapes = batch_shapes(traffic, v, data)
+    local = sorted({b // data for b, _ in shapes})
+    wmajor = all(dense_estep.pick_block_w(b, v, k, "f32") for b in local)
+    by_shape = dict(Counter(shapes))
+    if data > 1:
+        mesh = Mesh(np.array(topo.devices[:data]).reshape(data, 1),
+                    ("data", "model"))
+        rep = NamedSharding(mesh, P())
+        docs = NamedSharding(mesh, P(None, None, "data") if wmajor
+                             else P(None, "data"))
+        rows = NamedSharding(mesh, P(None, "data"))
+    else:
+        mesh = None
+        rep = docs = rows = SingleDeviceSharding(topo.devices[0])
+    return SimpleNamespace(
+        traffic=traffic, lda=lda, k=k, v=v, data=data, local=local,
+        wmajor=wmajor, by_shape=by_shape, mesh=mesh, rep=rep, docs=docs,
+        rows=rows)
+
+
+def densify_peak_bytes(layout) -> int:
+    """The most bytes a device holds while the placement densifies one shape
+    group: arguments, output and scratch of `fused.densify_stack`, the
+    program's own jit, under the cell's mesh where it has one (every device
+    scatters its own rows; a jit of this file's own let XLA gather the whole
+    dense stack onto every device, the failure PR 29 repaired in the
+    program: 19.38 GB of 15.75 GB for `flow20_fit_dp4`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from oni_ml_tpu.models import fused
+
+    peak = 0
+    for (b, length), nb in sorted(layout.by_shape.items()):
+        sparse = [jax.ShapeDtypeStruct((nb, b, length), dt,
+                                       sharding=layout.rows)
+                  for dt in (jnp.int32, jnp.float32)]
+        placed = fused.densify_stack.lower(
+            *sparse, num_terms=layout.v, width=None, dtype=jnp.float32,
+            wmajor=layout.wmajor, mesh=layout.mesh,
+        ).compile().memory_analysis()
+        peak = max(peak, placed.output_size_in_bytes
+                   + placed.temp_size_in_bytes
+                   + placed.argument_size_in_bytes)
+    return peak
+
+
+def compile_cell(name: str) -> dict:
+    import jax
+    import jax.numpy as jnp
 
     from oni_ml_tpu.models import fused
     from oni_ml_tpu.ops import dense_estep
     from oni_ml_tpu.parallel import sharded
 
-    found = cells.resolve(name)
-    traffic, lda = found["traffic"], found["config"]["lda"]
-    if traffic["job"] != "fit":
-        print(f"{name}: no compile rehearsal for job {traffic['job']!r}")
+    layout = cell_layout(name, describe_topology())
+    if layout is None:
+        print(f"{name}: no compile rehearsal for a job that is not 'fit'")
         return {}
-    k, v = lda["num_topics"], found["config"]["num_terms"]
+    lda, k, v, wmajor = layout.lda, layout.k, layout.v, layout.wmajor
     width = dense_estep.padded_width(v)
-    data = traffic["mesh"][0] if traffic.get("mesh") else 1
-    shapes = batch_shapes(traffic, v, data)
-    local = sorted({b // data for b, _ in shapes})
-    wmajor = all(dense_estep.pick_block_w(b, v, k, "f32") for b in local)
-    blocks = {b: dense_estep.pick_block(b, v, k, "f32") for b in local}
+    blocks = {b: dense_estep.pick_block(b, v, k, "f32") for b in layout.local}
     kib = max(filter(None, (
         dense_estep.scoped_vmem_kib(b, v, k, wmajor=wmajor, precision="f32")
-        for b in local)), default=None)
-
-    topo = topologies.get_topology_desc(platform="tpu",
-                                        topology_name="v5e:2x2")
-    if data > 1:
-        mesh = Mesh(np.array(topo.devices[:data]).reshape(data, 1),
-                    ("data", "model"))
-        rep = NamedSharding(mesh, P())
-        doc_axis = NamedSharding(mesh, P(None, None, "data") if wmajor
-                                 else P(None, "data"))
-        row_axis = NamedSharding(mesh, P(None, "data"))
+        for b in layout.local)), default=None)
+    dense_fn = None
+    if layout.mesh is not None:
         dense_fn = partial(
             sharded.make_data_parallel_dense_e_step(
-                mesh, wmajor=wmajor, precision="f32"),
+                layout.mesh, wmajor=wmajor, precision="f32"),
             var_max_iters=lda["var_max_iters"], var_tol=lda["var_tol"],
             interpret=False)
-    else:
-        rep = doc_axis = row_axis = SingleDeviceSharding(topo.devices[0])
-        dense_fn = None
 
     def shape(dims, dtype, sharding):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
-    by_shape: dict = {}
-    for s in shapes:
-        by_shape[s] = by_shape.get(s, 0) + 1
     groups, gammas = [], []
-    for (b, _), nb in sorted(by_shape.items()):
+    for (b, _), nb in sorted(layout.by_shape.items()):
         dims = (nb, width, b) if wmajor else (nb, b, width)
-        groups.append((shape(dims, jnp.float32, doc_axis),
-                       shape((nb, b), jnp.float32, row_axis)))
-        gammas.append(shape((nb, b, k), jnp.float32, row_axis))
+        groups.append((shape(dims, jnp.float32, layout.docs),
+                       shape((nb, b), jnp.float32, layout.rows)))
+        gammas.append(shape((nb, b, k), jnp.float32, layout.rows))
     runner = fused.make_chunk_runner(
-        num_docs=traffic["num_docs"], num_topics=k, num_terms=v, chunk=128,
-        var_max_iters=lda["var_max_iters"], var_tol=lda["var_tol"],
-        em_tol=lda["em_tol"], estimate_alpha=lda["estimate_alpha"],
-        dense_wmajor=wmajor, warm_start=lda["warm_start"],
-        dense_e_step_fn=dense_fn, dense_precision="f32",
-        alpha_max_iters=lda["alpha_max_iters"],
+        num_docs=layout.traffic["num_docs"], num_topics=k, num_terms=v,
+        chunk=128, var_max_iters=lda["var_max_iters"],
+        var_tol=lda["var_tol"], em_tol=lda["em_tol"],
+        estimate_alpha=lda["estimate_alpha"], dense_wmajor=wmajor,
+        warm_start=lda["warm_start"], dense_e_step_fn=dense_fn,
+        dense_precision="f32", alpha_max_iters=lda["alpha_max_iters"],
         compiler_options={"xla_tpu_scoped_vmem_limit_kib": str(kib)}
         if kib else None)
     # Placement: the program densifies each sparse stack in a jit of its own
-    # (fused.densify_groups) before the chunk program ever runs.
-    place_bytes = 0
-    for (b, length), nb in sorted(by_shape.items()):
-        sparse = [shape((nb, b, length), dt, row_axis)
-                  for dt in (jnp.int32, jnp.float32)]
-        placed = jax.jit(jax.vmap(
-            lambda w, c: dense_estep.densify(w, c, v, dtype=jnp.float32))
-        ).lower(*sparse).compile().memory_analysis()
-        place_bytes = max(place_bytes, placed.output_size_in_bytes
-                          + placed.temp_size_in_bytes
-                          + placed.argument_size_in_bytes)
-    scalar = partial(shape, (), sharding=rep)
+    # (fused.densify_stack, dispatched by fused.densify_groups) before the
+    # chunk program ever runs.
+    place_bytes = densify_peak_bytes(layout)
+    scalar = partial(shape, (), sharding=layout.rep)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         compiled = runner.jitted.lower(
-            shape((k, v), jnp.float32, rep), scalar(jnp.float32),
+            shape((k, v), jnp.float32, layout.rep), scalar(jnp.float32),
             scalar(jnp.float32), tuple(groups), scalar(jnp.int32),
             tuple(gammas), scalar(jnp.bool_)).compile()
     mem = compiled.memory_analysis()
     text = compiled.as_text()
     out = {
-        "cell": name, "batches": {f"{b}x{l}": n
-                                  for (b, l), n in sorted(by_shape.items())},
+        "cell": name, "batches": {f"{b}x{l}": n for (b, l), n
+                                  in sorted(layout.by_shape.items())},
         "wmajor": wmajor, "doc_blocks": blocks,
-        "padded_docs": sum(b for b, _ in shapes),
+        "padded_docs": sum(b * n for (b, _), n in layout.by_shape.items()),
         "argument_bytes_per_device": mem.argument_size_in_bytes,
         "temp_bytes_per_device": mem.temp_size_in_bytes,
         "output_bytes_per_device": mem.output_size_in_bytes,

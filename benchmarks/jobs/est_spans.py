@@ -25,12 +25,12 @@ _loaded: dict = {}
 
 def spans(ctx: dict) -> list:
     """The spans named in SPANS: what a test put under
-    `ctx["program_trace"]["spans"]`, else those of the newest `.xplane.pb`
-    of the checkout (read once per file)."""
+    `ctx["program_trace"]["spans"]`, else those of the run's newest
+    `.xplane.pb` (read once per file)."""
     if "program_trace" in ctx:
         return [e for e in ctx["program_trace"]["spans"]
                 if program_trace.is_span(e[0], SPANS)]
-    path = program_trace.newest()
+    path = program_trace.newest(trace_dir=ctx.get("trace_dir"))
     if path is None:
         return []
     key = (path, os.path.getmtime(path))

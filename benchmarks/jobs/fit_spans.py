@@ -49,10 +49,10 @@ _loaded: dict = {}
 def spans(ctx: dict) -> list:
     """The program's spans in the run's trace (`program_trace.load_spans`):
     what a test put under `ctx["program_trace"]["spans"]`, else those of the
-    newest `.xplane.pb` of the checkout (read once per file)."""
+    run's newest `.xplane.pb` (read once per file)."""
     if "program_trace" in ctx:
         return ctx["program_trace"]["spans"]
-    path = program_trace.newest()
+    path = program_trace.newest(trace_dir=ctx.get("trace_dir"))
     if path is None:
         return []
     key = (path, os.path.getmtime(path))

@@ -17,7 +17,11 @@ own from the same trace, as `jobs/est_spans.py` does):
   fit.save              the result's files
   fit.teardown          dropping the trainer and the batches' host buffers
   fit.stack             inside it, per shape group:
-    fit.stack.copy        `np.stack` and `astype` into a fresh host stack
+    fit.stack.copy        assembling the group's host stack: since PR 39 a
+                          view of `make_batches`' buffer where the batches
+                          allow it (then the masks alone are copied), one
+                          `np.stack` pass where they do not (`.counts`:
+                          `bytes`, `copied_bytes`)
     fit.stack.put         handing the stack to the runtime (`.counts`:
                           `bytes`, `shards`)
 
@@ -30,14 +34,6 @@ program's split of it and sum to it; the two `place_stack_*` split
 
 A program without these spans (the parent of the PR that added them) gives
 nothing, and every reader returns nothing.
-
-The seven readers (`metrics/readback_*.py`, `metrics/place_stack_*.py`) have
-no entry in BENCHMARK.json yet, so `run.py` does not call them: a new entry
-goes at the END of `per_layer`, `tests/perfbench/test_perfbench_est.py` holds
-that list's last two to be `est_load_s`, `est_save_s`, and only a `benchmark`
-PR may edit that line.  PERF.md section 7 has the entries to append then;
-until then a builder reads them on the chip by appending those entries to
-the BENCHMARK.json of a git-ignored `git archive` copy of the tree.
 """
 
 from __future__ import annotations
@@ -66,12 +62,12 @@ _loaded: dict = {}
 
 def spans(ctx: dict) -> list:
     """The spans named in SPANS: what a test put under
-    `ctx["program_trace"]["spans"]`, else those of the newest `.xplane.pb`
-    of the checkout (read once per file)."""
+    `ctx["program_trace"]["spans"]`, else those of the run's newest
+    `.xplane.pb` (read once per file)."""
     if "program_trace" in ctx:
         return [e for e in ctx["program_trace"]["spans"]
                 if program_trace.is_span(e[0], SPANS)]
-    path = program_trace.newest()
+    path = program_trace.newest(trace_dir=ctx.get("trace_dir"))
     if path is None:
         return []
     key = (path, os.path.getmtime(path))

@@ -85,6 +85,7 @@ def run_cell(args, stamp: dict, found: dict, program=None) -> dict:
         line["device"].update(busy_s=trace["busy_s"],
                               window_s=trace["window_s"])
         reader_ctx = dict(out["observed"], trace=trace, chips=chips,
+                          trace_dir=trace_dir,
                           peaks=device.peaks_for(stamp["kind"])
                           if stamp["platform"] == "tpu" else None,
                           end_to_end=measured)

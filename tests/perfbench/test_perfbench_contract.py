@@ -1,125 +1,107 @@
-"""BENCHMARK.json against the contract's own limits, the peaks table and
-the rule that a run off the chip gives no result."""
+"""BENCHMARK.json against the contract's own limits (the rules themselves are
+`entry_rules.py`'s), the guard that every rule of this directory stands an
+appended cell, configuration and metric, the peaks table and the rule that a
+run off the chip gives no result."""
 
 import json
 import os
-import re
+import shutil
 import subprocess
 import sys
 
 import pytest
 
+import entry_rules
 from benchmarks.harness import cells, device
-
-NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}\Z")
-UNIT = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}\Z")
 
 
 @pytest.fixture(scope="module")
 def bench():
-    with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+    return entry_rules.load()
 
 
 def test_top_level_keys_and_command(bench):
-    assert set(bench) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert 1 <= bench["run_seconds"] <= 51
-    # 2 + 14 x cells runs, run_seconds + 60 each, 180 s a cell to compile,
-    # 1200 s spare, for the full 24 cells, inside 43200 s
-    runs = 2 + 14 * 24
-    assert runs * (bench["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
-    for word in bench["command"]:
-        assert not word.startswith("/") and ".." not in word
-    assert os.path.exists(os.path.join(cells.ROOT, bench["command"][1]))
-    assert os.path.getsize(
-        os.path.join(cells.ROOT, "BENCHMARK.json")) <= 64 * 1024
+    entry_rules.top_level_keys_and_command(bench)
 
 
 def test_names_units_and_lines(bench):
-    for group in ("configs", "workloads", "end_to_end", "per_layer"):
-        names = [entry["name"] for entry in bench[group]]
-        assert all(NAME.match(n) for n in names), names
-        assert len(names) == len(set(names)), names
-        for entry in bench[group]:
-            for key in ("why", "layer"):
-                text = entry.get(key, "x")
-                assert 1 <= len(text) <= 200, (entry["name"], key)
-                assert "\n" not in text and "\t" not in text
-    for config in bench["configs"]:
-        assert 1 <= len(config["source"]) <= 200
-    metrics = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
-    assert len(metrics) == len(set(metrics))
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
-        assert m["source"] in ("device_trace", "program_span",
-                               "program_counter", "host_clock")
+    entry_rules.names_units_and_lines(bench)
 
 
 def test_entries_have_just_the_contract_keys(bench):
-    for c in bench["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-    for w in bench["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-    for m in bench["end_to_end"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "bound",
-                                          "source"}
-        assert m["source"] in ("host_clock", "device_trace")
-        assert 0.01 <= m["bound"] <= 0.1
-    for m in bench["per_layer"]:
-        assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
-                                          "layer", "moves"}
+    entry_rules.entries_have_just_the_contract_keys(bench)
 
 
 def test_cells_configs_and_metrics_hang_together(bench):
-    configs = {c["name"]: c for c in bench["configs"]}
-    cell_names = {w["name"] for w in bench["workloads"]}
-    used = set()
-    pairs = set()
-    for w in bench["workloads"]:
-        assert w["chips"] in (1, 4) and w["config"] in configs
-        assert (w["config"], w["traffic"]) not in pairs
-        pairs.add((w["config"], w["traffic"]))
-        used.add(w["config"])
-        assert os.path.exists(os.path.join(
-            cells.BENCH_DIR, "traffic", w["traffic"] + ".json"))
-    assert used == set(configs)
-    four = sum(w["chips"] == 4 for w in bench["workloads"])
-    assert four <= max(1, len(bench["workloads"]) // 4)
-    files = [c["file"] for c in bench["configs"]]
-    assert len(files) == len(set(files))
-    for c in bench["configs"]:
-        assert any(c["file"].startswith(p + "/") for p in bench["paths"])
-        with open(os.path.join(cells.ROOT, c["file"])) as f:
-            body = json.load(f)
-        assert body["reduced"] == c["reduced"]
-        for key in c["reduced"]:
-            assert not re.search(r"(_dim|_rank|hidden|width|topics|terms)",
-                                 key), "a width may never be reduced"
-    e2e = {m["name"] for m in bench["end_to_end"]}
-    assert "setup_s" in e2e
-    for m in bench["per_layer"]:
-        assert m["moves"] in e2e and m["moves"] != "setup_s"
-        assert set(m.get("workloads", [])) <= cell_names
-        assert os.path.exists(os.path.join(
-            cells.BENCH_DIR, "metrics", m["name"] + ".py"))
-        if m["name"].endswith("_roofline") or "mfu" in m["name"]:
-            assert m["unit"] == "%"
-    for w in bench["workloads"]:
-        found = cells.resolve(w["name"])
-        assert {"setup_s"} < {m["name"] for m in found["end_to_end"]}
-        assert found["per_layer"], w["name"]
-        assert os.path.exists(os.path.join(
-            cells.BENCH_DIR, "jobs", found["traffic"]["job"] + ".py"))
-        limits = found["traffic"]["limits"]
-        from benchmarks.harness import fit_check
-
-        assert set(limits) == set(fit_check.NUMBERS), w["name"]
+    entry_rules.cells_configs_and_metrics_hang_together(bench)
 
 
 def test_one_layer_one_spelling(bench):
-    layers = {m["layer"] for m in bench["per_layer"]}
-    assert len({name.lower() for name in layers}) == len(layers)
+    entry_rules.one_layer_one_spelling(bench)
+
+
+# -- the guard: a later PR appends, and no rule may mind -------------------
+
+GUARD_METRIC = '''"""docs_per_fit: a metric a later PR adds as a file."""
+
+
+def read(ctx):
+    return None
+'''
+# What a later PR's metric may say of its cells: only the cell it came
+# with, or (no `workloads` key) every cell that reports what it moves.
+APPENDED = {"a_metric_of_the_new_cell": {"workloads": ["flow20_guard_fit"]},
+            "a_metric_of_every_cell": {}}
+
+
+@pytest.fixture(scope="module", params=sorted(APPENDED))
+def appended(request, tmp_path_factory):
+    """A checkout's BENCHMARK.json and benchmarks/ (the recorded traces
+    left out) with one more configuration, one more one-chip cell and one
+    more per-layer metric APPENDED, each with the file its name leads to:
+    all that a `model_config`, `perf_opt` or `tracing` PR may do there."""
+    root = tmp_path_factory.mktemp("appended")
+    shutil.copytree(cells.BENCH_DIR, root / "benchmarks",
+                    ignore=shutil.ignore_patterns("__pycache__", "data"))
+    bench = entry_rules.load()
+    config = cells.load_json(cells.BENCH_DIR, "configs", "flow20.json")
+    config.update(name="flow20_guard", source="a later PR's")
+    (root / "benchmarks/configs/flow20_guard.json").write_text(
+        json.dumps(config))
+    shutil.copy(root / "benchmarks/traffic/resident_163840.json",
+                root / "benchmarks/traffic/resident_guard.json")
+    (root / "benchmarks/metrics/docs_per_fit.py").write_text(GUARD_METRIC)
+    bench["configs"].append({
+        "name": "flow20_guard", "source": "a later PR's", "reduced": [],
+        "file": "benchmarks/configs/flow20_guard.json",
+        "why": "appended by the guard"})
+    bench["workloads"].append({
+        "name": "flow20_guard_fit", "config": "flow20_guard",
+        "traffic": "resident_guard", "chips": 1,
+        "why": "appended by the guard"})
+    bench["per_layer"].append({
+        "name": "docs_per_fit", "unit": "docs", "better": "higher",
+        "source": "program_counter", "layer": "convergence",
+        "moves": "fit_s", **APPENDED[request.param]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
+    return str(root)
+
+
+@pytest.mark.parametrize("rule", entry_rules.RULES,
+                         ids=lambda rule: rule.__name__)
+def test_no_rule_minds_an_appended_cell_configuration_and_metric(
+        appended, rule, monkeypatch):
+    """Every rule the tests of this directory hold BENCHMARK.json's entries
+    to, the same functions, on the appended copy: one that pins a list's
+    end or its length (PR 33's `bench["workloads"][-1]`, `configs[-1]`,
+    `per_layer[-2:]`, PR 29's `four == [CELL]`) fails here, in the PR that
+    writes it, not in the next PR that appends."""
+    monkeypatch.setattr(cells, "ROOT", appended)
+    monkeypatch.setattr(cells, "BENCH_DIR",
+                        os.path.join(appended, "benchmarks"))
+    bench = entry_rules.load()
+    assert [w["name"] for w in bench["workloads"]][-1] == "flow20_guard_fit"
+    rule(bench)
 
 
 def test_peaks_table_knows_the_v5e_and_nothing_by_default():

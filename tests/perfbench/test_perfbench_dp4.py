@@ -12,6 +12,7 @@ import sys
 
 import pytest
 
+import entry_rules
 from benchmarks import control, rehearse, run as bench_run
 from benchmarks.harness import cells, device, fit_check, xplane
 from benchmarks.jobs import fit as fit_job
@@ -26,37 +27,17 @@ STAMP = {"platform": "cpu", "kind": "rehearsal", "count": 4}
 
 @pytest.fixture(scope="module")
 def bench():
-    return cells.load_json(cells.ROOT, "BENCHMARK.json")
+    return entry_rules.load()
 
 
 # -- the files -------------------------------------------------------------
 
-def test_the_cell_is_the_benchmarks_one_four_chip_cell(bench):
-    four = [w["name"] for w in bench["workloads"] if w["chips"] == 4]
-    assert four == [CELL]
-    assert len(four) <= max(1, len(bench["workloads"]) // 4)
-    cell = cells.resolve(CELL)["cell"]
-    assert (cell["config"], cell["traffic"]) == ("flow20_dp4",
-                                                 "resident_655360_dp4")
-    assert "one host process feeds 4 chips" in cell["why"]
+def test_the_cell_is_a_four_chip_cell_inside_the_allowance(bench):
+    entry_rules.dp4_is_a_four_chip_cell_inside_the_allowance(bench)
 
 
 def test_the_configuration_is_flow20_at_four_shards(bench):
-    entry = {c["name"]: c for c in bench["configs"]}["flow20_dp4"]
-    assert entry["reduced"] == ["ranks"] and len(entry["source"]) <= 200
-    assert "mpiexec -n 20" in entry["source"]
-    new = cells.load_json(cells.ROOT, entry["file"])
-    old = cells.load_json(cells.BENCH_DIR, "configs", "flow20.json")
-    assert new["source"] == entry["source"]
-    for key in ("num_terms", "lda", "precision"):   # letter for letter
-        assert new[key] == old[key], key
-    assert new["program"] == {"estep_engine": "auto",
-                              "dense_hbm_budget": 12 * 2**30}
-    assert new["ranks"] == 4 and new["reduced"] == ["ranks"]
-    assert new["cut"]["ranks"].startswith("20 -> 4")
-    assert new["deployment"]["mesh"] == {"data": 4, "model": 1}
-    for word in ("EVERY EM iteration", "synchronously", "float32"):
-        assert word in new["deployment"]["exchange"]
+    entry_rules.dp4s_configuration_is_flow20_at_four_shards(bench)
 
 
 def test_the_traffic_is_the_accepted_days_law_at_four_times_its_documents():
@@ -73,24 +54,7 @@ def test_the_traffic_is_the_accepted_days_law_at_four_times_its_documents():
 
 
 def test_both_new_metrics_are_the_cells_alone(bench):
-    found = cells.resolve(CELL)
-    names = {m["name"] for m in found["per_layer"]}
-    old = {m["name"] for m in cells.resolve("flow20_fit")["per_layer"]}
-    assert names - old == {"collective_exposed_pct", "shard_busy_skew_pct"}
-    # Every accepted metric applies through `moves`, its entry untouched:
-    # the program calls its kernels under a mesh what it calls them on one
-    # device, so `estep_roofline` and `estep_glue_pct` read them here too.
-    assert old - names == set()
-    assert not [m for m in bench["per_layer"]
-                if m["name"] in old and "workloads" in m]
-    assert {m["name"] for m in found["end_to_end"]} == {
-        "em_docs_per_s", "fit_s", "setup_s"}
-    for m in bench["per_layer"]:
-        if m["name"] in names - old:
-            assert (m["unit"], m["better"], m["source"], m["layer"],
-                    m["moves"], m["workloads"]) == (
-                "%", "lower", "device_trace", "exchange", "em_docs_per_s",
-                [CELL])
+    entry_rules.dp4s_two_metrics_are_the_cells_alone(bench)
 
 
 def test_allreduce_bytes_by_hand():
@@ -100,6 +64,47 @@ def test_allreduce_bytes_by_hand():
     assert fit_exchange.allreduce_bytes_per_iter(42, 8192, 20) == 27525792
     assert fit_exchange.allreduce_bytes_per_iter(3, 512, 20, 2) == 3 * (
         512 * 20 * 2 + 4 + 8)
+
+
+# -- the third rehearsal: the placement at the real size -------------------
+
+@pytest.fixture
+def topo():
+    """A described v5e 2x2, by the worker that is given this file; an
+    executable compiled for it is written to the persistent cache and cannot
+    be read back without a chip, so the cache is off around the test."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from benchmarks.harness import rehearse_compile
+
+    try:
+        described = rehearse_compile.describe_topology()
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield described
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def test_the_rehearsed_densify_of_the_cell_fits_a_chip(topo):
+    """`rehearse.py compile --workload flow20_fit_dp4` densifies through the
+    program's own `fused.densify_stack(..., mesh=)`: every device scatters
+    its own rows.  With a jit of the rehearsal's own it reproduced the
+    failure PR 29 repaired in the program (19.38 GB of 15.75 GB)."""
+    from benchmarks.harness import rehearse_compile
+
+    layout = rehearse_compile.cell_layout(CELL, topo)
+    assert layout.data == 4 and layout.mesh.shape == {"data": 4, "model": 1}
+    assert layout.by_shape[(16384, 32)] == 31 and not layout.wmajor
+    peak = rehearse_compile.densify_peak_bytes(layout)
+    # the largest group's dense rows of one device, their scatter's scratch
+    # (one more copy of them) and the sparse stack's shard
+    own = 31 * 4096 * 8192 * 4
+    assert 2 * own <= peak < 2.05 * own
+    assert peak < device.peaks_for("TPU v5 lite")["hbm_bytes"]
 
 
 # -- the rehearsal and `correct` -------------------------------------------

@@ -11,6 +11,7 @@ import os
 import numpy as np
 import pytest
 
+import entry_rules
 from benchmarks import control_est, rehearse, run as bench_run
 from benchmarks.harness import cells, fit_check
 from benchmarks.jobs import est_files, est_spans
@@ -333,37 +334,4 @@ def test_a_program_without_the_spans_gives_nothing():
 # -- the entries ---------------------------------------------------------
 
 def test_the_cell_its_configuration_and_its_metrics_as_the_issue_names_them():
-    with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    found = cells.resolve(CELL)
-    assert found["cell"] == bench["workloads"][-1]
-    assert (found["cell"]["config"], found["cell"]["traffic"],
-            found["cell"]["chips"]) == ("flow20_est", "est_files_163840", 1)
-    config, traffic = found["config"], found["traffic"]
-    assert bench["configs"][-1]["reduced"] == config["reduced"] == ["ranks"]
-    assert bench["configs"][-1]["source"] == config["source"]
-    assert config["program"] == {} and config["architecture"] is None
-    flow20 = cells.resolve("flow20_fit")
-    assert config["num_terms"] == flow20["config"]["num_terms"]
-    assert dict(flow20["config"]["lda"], warm_start=False,
-                alpha_max_iters=100, seed=0) == config["lda"]
-    assert set(config["guarantees"]) >= {"complete", "shape", "format"}
-    # flow20_fit's day, letter for letter
-    assert traffic["corpus"] == flow20["traffic"]["corpus"]
-    assert traffic["num_docs"] == flow20["traffic"]["num_docs"]
-    assert (traffic["job"], traffic["mesh"], traffic["trace_fits"],
-            traffic["files_limit"]) == ("est_files", None, 1, 0.0)
-    assert "files" not in traffic["limits"]
-    by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name, layer in (("est_load_s", "corpus ingest"),
-                        ("est_save_s", "model files")):
-        assert by_name[name] == {
-            "name": name, "unit": "s", "better": "lower",
-            "source": "program_span", "layer": layer, "moves": "fit_s",
-            "workloads": [CELL]}
-    assert [m["name"] for m in bench["per_layer"][-2:]] == [
-        "est_load_s", "est_save_s"]
-    reported = {m["name"] for m in found["per_layer"]}
-    assert {"est_load_s", "est_save_s", "fit_place_s",
-            "estep_sweeps_per_doc_iter", "estep_roofline"} <= reported
-    assert not {"collective_exposed_pct", "shard_busy_skew_pct"} & reported
+    entry_rules.est_files_cell_configuration_and_metrics(entry_rules.load())

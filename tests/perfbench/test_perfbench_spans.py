@@ -8,6 +8,7 @@ import os
 
 import pytest
 
+import entry_rules
 from benchmarks.harness import cells, program_trace, xplane
 from benchmarks.jobs import fit_spans
 from benchmarks.metrics import estep_glue_pct
@@ -15,20 +16,7 @@ from benchmarks.metrics import estep_glue_pct
 DATA = os.path.join(cells.BENCH_DIR, "data", "flow20_fit_spans.json.gz")
 
 # name -> (unit, source, layer, moves): the issue's table.
-NEW = {
-    "place_batches_s": ("s", "program_span", "corpus placement", "fit_s"),
-    "place_plan_s": ("s", "program_span", "corpus placement", "fit_s"),
-    "place_transfer_s": ("s", "program_span", "corpus placement", "fit_s"),
-    "place_densify_s": ("s", "program_span", "corpus placement", "fit_s"),
-    "place_first_dispatch_s": ("s", "program_span", "EM driver", "fit_s"),
-    "place_unattributed_s": ("s", "program_span", "corpus placement",
-                             "fit_s"),
-    "fit_compile_requests": ("count", "program_counter", "EM driver",
-                             "fit_s"),
-    "estep_sweeps_per_doc_iter": ("sweeps", "program_counter",
-                                  "E-step kernels", "em_docs_per_s"),
-    "estep_glue_pct": ("%", "device_trace", "whole EM step", "em_docs_per_s"),
-}
+NEW = entry_rules.SPAN_METRICS
 PLACE = [n for n in NEW if n.startswith("place_")]
 
 
@@ -217,19 +205,7 @@ def test_every_idle_gap_over_10_ms_in_a_recorded_fit_lies_in_a_span(recorded):
 
 @pytest.mark.parametrize("name", sorted(NEW))
 def test_benchmark_json_holds_the_entry_and_its_reader_file(name):
-    with open(os.path.join(cells.ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    entries = {m["name"]: m for m in bench["per_layer"]}
-    unit, source, layer, moves = NEW[name]
-    assert entries[name] == {"name": name, "unit": unit, "better": "lower",
-                             "source": source, "layer": layer, "moves": moves}
-    assert os.path.exists(os.path.join(cells.BENCH_DIR, "metrics",
-                                       name + ".py"))
-    # the six the benchmark had stay first, as they were
-    assert [m["name"] for m in bench["per_layer"]][:6] == [
-        "em_mfu", "estep_roofline", "device_idle_pct", "fit_readback_s",
-        "fit_place_s", "em_iters_per_fit"]
-    assert name in {m["name"] for m in cells.resolve("flow20_fit")["per_layer"]}
+    entry_rules.span_metric_entry(entry_rules.load(), name)
 
 
 def test_newest_takes_the_newest_trace_of_the_checkout(tmp_path):
@@ -241,6 +217,12 @@ def test_newest_takes_the_newest_trace_of_the_checkout(tmp_path):
         os.utime(d / "x.xplane.pb", (100 + i, 100 + i))
     assert program_trace.newest(str(tmp_path)).endswith(
         os.path.join("b", "plugins", "profile", "t", "x.xplane.pb"))
+    # a run's readers are handed its own directory: another cell's newer
+    # trace (a second worker of the test suite) is not theirs
+    assert program_trace.newest(
+        trace_dir=str(tmp_path / ".bench_trace" / "a")).endswith(
+        os.path.join("a", "plugins", "profile", "t", "x.xplane.pb"))
+    assert program_trace.newest(trace_dir=str(tmp_path / "none")) is None
 
 
 def test_span_names_and_counts_events():

@@ -1,7 +1,8 @@
 """The readers of the fit's tail and of `fit.stack`'s two halves (PR 38):
 their arithmetic on a hand-made trace, the sum rules, nothing without the
-sub-spans, and a recorded chip trace.  BENCHMARK.json has no entry for them
-yet (`jobs/fit_tail.py` says why)."""
+sub-spans, a recorded chip trace, and (since PR 40) their entries in
+BENCHMARK.json.  `fit.stack.copy` is the assembling of a group's host stack:
+since PR 39 a view of `make_batches`' buffer where the batches allow it."""
 
 import gzip
 import json
@@ -9,14 +10,13 @@ import os
 
 import pytest
 
+import entry_rules
 from benchmarks.harness import cells, program_trace, xplane
 from benchmarks.jobs import fit_spans, fit_tail
 
 DATA = os.path.join(cells.BENCH_DIR, "data", "flow20_fit_tail_spans.json.gz")
 
-NEW = ("readback_sync_s", "readback_d2h_s", "readback_scatter_s",
-       "readback_teardown_s", "readback_unattributed_s",
-       "place_stack_copy_s", "place_stack_put_s")
+NEW = tuple(entry_rules.TAIL_METRICS)
 READBACK = [n for n in NEW if n.startswith("readback_")]
 
 
@@ -237,3 +237,14 @@ def test_span_names_of_the_tail_and_their_counts_events():
     assert not program_trace.is_span("fit.plan", names)
     # fit_spans loads none of the new names, so its children stay direct
     assert not set(fit_tail.SUBSPANS) & set(fit_spans.SPANS)
+
+
+# -- the entries (PR 40) ---------------------------------------------------
+
+@pytest.mark.parametrize("name", sorted(NEW))
+def test_benchmark_json_holds_the_entry_and_its_reader_file(name):
+    entry_rules.tail_metric_entry(entry_rules.load(), name)
+
+
+def test_the_seven_entries_follow_what_the_benchmark_had_in_the_issues_order():
+    entry_rules.tail_metric_entries(entry_rules.load())
