@@ -160,14 +160,16 @@ class LDAConfig:
     # (ops/dense_estep.corpus_store_dtype, span `fit.plan.gate`) is
     # exact and reads only what can change its answer: at "f32" no
     # token; at "bf16" the largest raw count (over 256: f32), then
-    # every document's sum (all <= 256: bf16), then the per-(doc, word)
-    # sums of the documents in between, batch by batch up to the first
-    # cell over 256.  The plan runs the gate BEFORE its full-width
-    # budget test and budgets the dense corpus at the store's itemsize,
-    # so a day that fits the budget only at 2 B a cell is full-width
-    # dense; the densify then fills the bf16 stack a batch at a time
-    # (models/fused.densify_stack), holding one batch's float32 block,
-    # never a float32 copy of a group (PERF.md, PR 41).
+    # every document's sum (all <= 256: bf16), then, of the documents in
+    # between, the per-(doc, word) sums of those where a word repeats
+    # (one where every word is distinct has cells equal to its tokens),
+    # batch by batch up to the first cell over 256.  The plan runs the
+    # gate BEFORE its full-width budget test and budgets the dense
+    # corpus at the store's itemsize, so a day that fits the budget only
+    # at 2 B a cell is full-width dense; the densify then fills the bf16
+    # stack a batch at a time (models/fused.densify_stack), holding one
+    # batch's float32 block, never a float32 copy of a group (PERF.md,
+    # PR 41).
     dense_precision: str = "f32"
     # Store the dense corpus transposed ([W, B]) so the gamma-update
     # matmul's small-K output axis pads to the 8-sublane granularity

@@ -1107,9 +1107,12 @@ class LDATrainer:
             # itemsize is what the corpus costs.  The gate bounds the
             # DENSIFIED cells (duplicate (doc, word) tokens sum: the
             # DUPFACTOR feedback path makes ~1000-count cells out of
-            # count-1 tokens), not the raw counts; at f32 it reads no token.
+            # count-1 tokens), not the raw counts, and sums cells only in
+            # the rows over 256 where a word repeats (the span counts
+            # `undecided_rows` and `repeat_rows`); at f32 it reads no token.
             with maybe_span("fit.plan.gate") as sp:
-                gate = dense_estep.corpus_store_dtype(batches, precision)
+                gate = dense_estep.corpus_store_dtype(batches, precision,
+                                                      span=sp)
                 sp.annotate(store=jnp.dtype(gate[0]).name,
                             cell_scan=gate[1], scan_tokens=gate[2])
             return gate

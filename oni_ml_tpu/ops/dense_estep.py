@@ -293,30 +293,60 @@ def corpus_dtype(cell_max: float, precision: str = "f32"):
     return jnp.float32
 
 
-def corpus_store_dtype(batches, precision: str = "f32"):
+def _repeat_rows(word_idx, counts):
+    """Indices of the rows of [n, L] tokens in which one word holds two
+    tokens of count > 0: the only rows where a densified cell can exceed
+    the row's largest token.  One sort of int keys a row, no sums: a
+    token of count > 0 keys as its word, any other as -1 - its column,
+    unique and below every word id, so the zero-count word-0 cells
+    make_batches pads with never read as repeats (a zero-count token
+    adds nothing to its cell)."""
+    w = np.asarray(word_idx)
+    pad = -1 - np.arange(w.shape[1], dtype=w.dtype)
+    keys = np.where(np.asarray(counts) > 0, w, pad)
+    keys.sort(axis=1)
+    return np.flatnonzero((keys[:, 1:] == keys[:, :-1]).any(axis=1))
+
+
+def corpus_store_dtype(batches, precision: str = "f32", span=None):
     """corpus_dtype for a fit's host batches, reading only what can
     change the answer.  Returns (dtype, cell_scan, scan_tokens): what
     the gate had to read ("none", "bounds" or "exact") and how many
-    padded tokens its passes went over.
+    padded tokens its passes went over.  `span`, where given, is
+    annotated with `undecided_rows`, the rows between the two bounds
+    below, and `repeat_rows`, those of them that paid the summed-cell
+    scan (0 and 0 where the bounds decided).
 
     Anything but bf16 operand mode stores f32 whatever the cells hold:
     no token is read.  Under bf16 the answer is EXACT — equal to
     corpus_dtype(max over batches of max_dense_cell, "bf16") — from the
     cheapest evidence that decides it: a cell is at least its largest
     token (one raw count over 256: f32) and at most its row's sum
-    (every document's sum <= 256: bf16); only the rows between the two
-    bounds — duplicates may or may not pile up in them, the DUPFACTOR
-    feedback document is 1000 count-1 tokens of one word — pay
-    max_dense_cell's sort, batch by batch, up to the first cell over
-    256."""
+    (every document's sum <= 256: bf16).  A row between the two bounds
+    whose words are distinct has cells equal to its tokens, all <= 256
+    by the first bound; only rows where a word repeats (the DUPFACTOR
+    feedback document is 1000 count-1 tokens of one word) pay
+    max_dense_cell's sums, batch by batch, up to the first cell over
+    256.  So `scan_tokens` counts the two passes, the undecided rows'
+    tokens once for the repeat test and the repeat rows' for the sums."""
     if precision != "bf16":
-        return jnp.float32, "none", 0
+        dtype, cell_scan, read, undecided, repeats = (
+            jnp.float32, "none", 0, 0, 0)
+    else:
+        dtype, cell_scan, read, undecided, repeats = _bf16_gate(batches)
+    if span is not None:
+        span.annotate(undecided_rows=undecided, repeat_rows=repeats)
+    return dtype, cell_scan, read
+
+
+def _bf16_gate(batches):
+    """corpus_store_dtype under bf16, and its two row counts."""
     counts = [np.asarray(b.counts) for b in batches]
     read = 0
     for c in counts:
         read += c.size
         if c.size and c.max() > _BF16_EXACT_MAX:
-            return jnp.float32, "bounds", read
+            return jnp.float32, "bounds", read, 0, 0
     undecided = []
     for b, c in zip(batches, counts):
         read += c.size
@@ -325,12 +355,18 @@ def corpus_store_dtype(batches, precision: str = "f32"):
         if rows.size:
             undecided.append((b.word_idx, c, rows))
     if not undecided:
-        return jnp.bfloat16, "bounds", read
+        return jnp.bfloat16, "bounds", read, 0, 0
+    n_undecided = sum(rows.size for _, _, rows in undecided)
+    repeats = 0
     for w, c, rows in undecided:
-        read += rows.size * c.shape[1]
-        if max_dense_cell(np.asarray(w)[rows], c[rows]) > _BF16_EXACT_MAX:
-            return jnp.float32, "exact", read
-    return jnp.bfloat16, "exact", read
+        w, c = np.asarray(w)[rows], c[rows]
+        read += c.size
+        rep = _repeat_rows(w, c)
+        repeats += rep.size
+        read += rep.size * c.shape[1]
+        if rep.size and max_dense_cell(w[rep], c[rep]) > _BF16_EXACT_MAX:
+            return jnp.float32, "exact", read, n_undecided, repeats
+    return jnp.bfloat16, "exact", read, n_undecided, repeats
 
 
 def densify(word_idx, counts, num_terms: int, width: int | None = None,

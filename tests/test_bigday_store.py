@@ -74,6 +74,7 @@ def test_a_day_that_fits_only_at_2_bytes_a_cell_is_dense_and_stored_bf16():
     assert gate["store"] == "bfloat16"
     assert (gate["cell_scan"], gate["scan_tokens"]) == ("bounds",
                                                         plan.scan_tokens)
+    assert (gate["undecided_rows"], gate["repeat_rows"]) == (0, 0)
 
 
 def test_a_cell_over_256_keeps_float32_and_the_day_is_not_dense():
@@ -84,6 +85,8 @@ def test_a_cell_over_256_keeps_float32_and_the_day_is_not_dense():
     assert plan.store == jnp.float32
     gate, = gates
     assert (gate["store"], gate["cell_scan"]) == ("float32", "exact")
+    # document 0, one word L times, is the one row over 256 and repeats
+    assert (gate["undecided_rows"], gate["repeat_rows"]) == (1, 1)
 
 
 # budget -> (family, kernel, store, cell_scan, scan_tokens), as the parent

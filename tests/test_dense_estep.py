@@ -740,7 +740,29 @@ def _gate_case(name):
     elif name == "row_300_cells_small":
         w[0, 150:] = w[0, :150]             # every cell of row 0 is 2
         c[1, 299] = 0.0
+    elif name == "padded_tail":             # make_batches' zero-count
+        w = np.concatenate([w, np.zeros((2, 100), int)], axis=1)  # word 0
+        c = np.concatenate([c, np.zeros((2, 100))], axis=1)
+    elif name == "zero_count_repeat":       # word 7 again, at count 0
+        w = np.concatenate([w, np.full((2, 1), 7)], axis=1)
+        c = np.concatenate([c, np.zeros((2, 1))], axis=1)
     return [quiet, _gate_batch(w, c)], "bf16"
+
+
+def _repeat_rows(batch):
+    """Rows over the row-sum bound where a word holds two tokens of count
+    > 0, found one row at a time."""
+    w, c = np.asarray(batch.word_idx), np.asarray(batch.counts)
+    rows = [r for r in range(len(c)) if c[r].sum() > 256]
+    return [r for r in rows if len(set(w[r][c[r] > 0]))
+            < int((c[r] > 0).sum())]
+
+
+class _Counts:
+    """A span stand-in that keeps what it is annotated with."""
+
+    def annotate(self, **kw):
+        self.__dict__.update(kw)
 
 
 @pytest.mark.parametrize("case,scan,dtype", [
@@ -749,12 +771,17 @@ def _gate_case(name):
     ("row_sums_small", "bounds", jnp.bfloat16),
     ("dupfactor", "exact", jnp.float32),
     ("row_300_cells_small", "exact", jnp.bfloat16),
+    ("row_300_distinct", "exact", jnp.bfloat16),
+    ("padded_tail", "exact", jnp.bfloat16),
+    ("zero_count_repeat", "exact", jnp.bfloat16),
 ])
 def test_corpus_store_dtype_reads_only_what_decides(case, scan, dtype,
                                                     monkeypatch):
     """The storage gate: f32 reads no token; bf16 is decided by the
     largest raw count or the largest row sum where one of them can, and
-    only rows between the two bounds reach the exact reader."""
+    of the rows between the two bounds only those where a word repeats
+    (at count > 0: padding and zero-count tokens are no repeat) reach
+    the exact reader."""
     batches, precision = _gate_case(case)
     exact_rows = []
     reader = dense_estep.max_dense_cell
@@ -764,11 +791,13 @@ def test_corpus_store_dtype_reads_only_what_decides(case, scan, dtype,
         return reader(word_idx, counts)
 
     monkeypatch.setattr(dense_estep, "max_dense_cell", counting_reader)
+    span = _Counts()
     got, cell_scan, scan_tokens = dense_estep.corpus_store_dtype(
-        batches, precision)
+        batches, precision, span=span)
     assert (got, cell_scan) == (dtype, scan)
     if scan == "none":
         assert scan_tokens == 0 and not exact_rows
+        assert (span.undecided_rows, span.repeat_rows) == (0, 0)
         return
     tokens = [b.counts.size for b in batches]
     assert got == dense_estep.corpus_dtype(
@@ -777,28 +806,43 @@ def test_corpus_store_dtype_reads_only_what_decides(case, scan, dtype,
         assert scan_tokens == sum(tokens) and not exact_rows
     elif scan == "bounds":                  # the max pass and the row sums
         assert scan_tokens == 2 * sum(tokens) and not exact_rows
-    else:                                   # plus the undecided rows alone
+    else:          # plus the undecided rows, and again the repeat rows
         over = [int((b.counts.sum(axis=1) > 256).sum()) for b in batches]
-        assert exact_rows == [n for n in over if n]
+        repeats = [len(_repeat_rows(b)) for b in batches]
+        assert exact_rows == [n for n in repeats if n]
         assert scan_tokens == 2 * sum(tokens) + sum(
-            n * b.counts.shape[1] for n, b in zip(over, batches))
+            (n + r) * b.counts.shape[1]
+            for n, r, b in zip(over, repeats, batches))
+        assert (span.undecided_rows, span.repeat_rows) == (sum(over),
+                                                           sum(repeats))
+        assert sum(over) == 2
+        assert sum(repeats) == {"dupfactor": 1,
+                                "row_300_cells_small": 1}.get(case, 0)
+        return
+    assert (span.undecided_rows, span.repeat_rows) == (0, 0)
 
 
 @pytest.mark.parametrize("precision", ["f32", "bf16"])
 def test_corpus_store_dtype_is_the_exact_reader_on_random_batches(precision):
-    """200 random batches with duplicates planted around the 256 line:
-    the gate's dtype is corpus_dtype of the exact reader's maximum."""
+    """200 random batches with duplicates planted around the 256 line, rows
+    of distinct words and zero-count padding tails (word 0, as make_batches
+    pads): the gate's dtype is corpus_dtype of the exact reader's maximum."""
     rng = np.random.default_rng(256)
-    seen = set()
+    seen, repeated = set(), set()
     for _ in range(200):
         b, l, v = rng.integers(1, 9), rng.integers(1, 40), rng.integers(1, 50)
         top = int(rng.choice([1, 3, 40, 256, 257, 300]))
         w = rng.integers(0, v, (b, l))
+        if l <= v and rng.random() < 0.5:       # every row's words distinct
+            w = np.argsort(rng.random((b, v)), axis=1)[:, :l]
         c = rng.integers(0, top + 1, (b, l)).astype(np.float64)
         for _ in range(rng.integers(0, 4)):     # a word repeated in a row
             row, n = rng.integers(0, b), rng.integers(1, l + 1)
             w[row, :n] = w[row, 0]
             c[row, :n] = rng.choice([1.0, 256.0 / n, 300.0 // n + 1])
+        pad = rng.integers(0, 9)                # the padded tail
+        w = np.concatenate([w, np.zeros((b, pad), int)], axis=1)
+        c = np.concatenate([c, np.zeros((b, pad))], axis=1)
         batches = [_gate_batch(w, np.floor(c))]
         cells = np.zeros((b, v))
         np.add.at(cells, (np.arange(b)[:, None], w), batches[0].counts)
@@ -811,11 +855,16 @@ def test_corpus_store_dtype_is_the_exact_reader_on_random_batches(precision):
         got, cell_scan, _ = dense_estep.corpus_store_dtype(batches, precision)
         assert got == want, (w, c)
         seen.add((cell_scan, got))
+        if cell_scan == "exact":
+            repeated.add((any(_repeat_rows(x) for x in batches), got))
     if precision == "f32":
         assert seen == {("none", jnp.float32)}
     else:
         assert seen == {(scan, dtype) for scan in ("bounds", "exact")
                         for dtype in (jnp.float32, jnp.bfloat16)}
+        # decided with and without the summed cells
+        assert repeated == {(True, jnp.float32), (True, jnp.bfloat16),
+                            (False, jnp.bfloat16)}
 
 
 def test_vocab_sharded_dense_bf16_corpus_matches():

@@ -364,13 +364,13 @@ def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
                                                   cell_scan, stored):
     corpus = _corpus_with_a_feedback_doc() if feedback else _corpus()
     cfg = _config(dense_em="on", dense_precision=precision, em_max_iters=1)
-    padded_tokens = sum(b.word_idx.size for b in make_batches(
-        corpus, batch_size=cfg.batch_size,
-        min_bucket_len=cfg.min_bucket_len, pad_multiple=8))
+    batches = _batches(corpus, cfg)
+    padded_tokens = sum(b.word_idx.size for b in batches)
     rec = spans.Recorder()
     with spans.use_recorder(rec):
         train_corpus(corpus, cfg)
     plan, = [e["args"] for e in rec.events if e["name"] == "fit.plan"]
+    gate, = [e["args"] for e in rec.events if e["name"] == "fit.plan.gate"]
     densify, = [e["args"] for e in rec.events if e["name"] == "fit.densify"]
     assert plan["cell_scan"] == cell_scan
     assert densify["dense_bytes"] == (
@@ -381,7 +381,69 @@ def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
     elif cell_scan == "bounds":
         assert plan["scan_tokens"] == 2 * padded_tokens
     else:
+        # the feedback row, the only one over 256, read twice more: once
+        # for the repeat test, once for its summed cells
+        row_len, = [b.word_idx.shape[1] for b in batches
+                    if (b.counts.sum(axis=1) > 256).any()]
+        assert plan["scan_tokens"] == 2 * padded_tokens + 2 * row_len
         assert 2 * padded_tokens < plan["scan_tokens"] < 3 * padded_tokens
+    assert (gate["undecided_rows"], gate["repeat_rows"]) == (
+        (1, 1) if cell_scan == "exact" else (0, 0))
+
+
+def _corpus_with_hub_docs(hubs=3, feedback=False, num_terms=320, seed=1):
+    """_corpus() at `num_terms` words plus `hubs` documents of 300
+    DISTINCT words at count 1 (row sums of 300, every cell 1), as a day
+    aggregated per (IP, word) gives them; with `feedback`, one document
+    of 300 count-1 tokens of one word besides."""
+    base = _corpus(num_terms=num_terms)
+    rng = np.random.default_rng(seed)
+    docs = [rng.choice(num_terms, 300, replace=False) for _ in range(hubs)]
+    if feedback:
+        docs.append(np.full(300, 5))
+    lens = np.cumsum([len(d) for d in docs])
+    return Corpus(
+        doc_names=base.doc_names + [f"hub{i}" for i in range(len(docs))],
+        vocab=base.vocab,
+        doc_ptr=np.append(base.doc_ptr, base.doc_ptr[-1] + lens),
+        word_idx=np.concatenate([base.word_idx] + docs).astype(np.int32),
+        counts=np.append(base.counts, np.ones(int(lens[-1]), np.float32)))
+
+
+@pytest.mark.parametrize("feedback,stored,repeats", [
+    (False, jnp.bfloat16, 0),
+    (True, jnp.float32, 1),
+])
+def test_the_gate_sums_cells_only_in_rows_where_a_word_repeats(
+        feedback, stored, repeats, monkeypatch):
+    """Hub documents of distinct words sum over 256 yet are decided
+    without one summed cell; the feedback document, the one row where a
+    word repeats, is the only row the summed-cell reader sees."""
+    corpus = _corpus_with_hub_docs(feedback=feedback)
+    cfg = _config(dense_em="on", dense_precision="bf16", em_max_iters=1)
+    reader = dense_estep.max_dense_cell
+    summed = []
+
+    def reader_of_repeats(word_idx, counts):
+        if not feedback:
+            raise AssertionError("rows of distinct words were summed")
+        summed.append(np.shape(word_idx)[0])
+        return reader(word_idx, counts)
+
+    monkeypatch.setattr(dense_estep, "max_dense_cell", reader_of_repeats)
+    rec = spans.Recorder()
+    with spans.use_recorder(rec):
+        train_corpus(corpus, cfg)
+    gate, = [e["args"] for e in rec.events if e["name"] == "fit.plan.gate"]
+    densify, = [e["args"] for e in rec.events if e["name"] == "fit.densify"]
+    assert (gate["store"], gate["cell_scan"]) == (jnp.dtype(stored).name,
+                                                  "exact")
+    assert gate["undecided_rows"] == 3 + feedback
+    assert gate["repeat_rows"] == repeats
+    assert summed == [repeats] * bool(repeats)
+    assert densify["dense_bytes"] == (
+        _padded_rows(corpus, cfg) * dense_estep.padded_width(corpus.num_terms)
+        * jnp.dtype(stored).itemsize)
 
 
 def test_fused_and_stepwise_drivers_count_the_same_sweeps():

@@ -401,14 +401,14 @@ def test_f32_fit_stores_what_the_exact_reader_would_without_reading(
     gate = dense_estep.corpus_store_dtype
     asked = []
 
-    def every_token_sorted(batches, precision):
+    def every_token_sorted(batches, precision, span=None):
         cell_max = max(dense_estep.max_dense_cell(b.word_idx, b.counts)
                        for b in batches)
         return dense_estep.corpus_dtype(cell_max, precision), "exact", 0
 
-    def recording(batches, precision):
+    def recording(batches, precision, span=None):
         asked.append(precision)
-        return gate(batches, precision)
+        return gate(batches, precision, span)
 
     def refuse(word_idx, counts):
         raise AssertionError("an f32 fit sorted its tokens")
