@@ -153,23 +153,30 @@ class LDAConfig:
     # there emulates the TPU's input truncation instead.  The
     # suff-stats / ELBO tail pass always runs full-width off the
     # converged gamma.  bf16 mode additionally STORES the densified
-    # corpus bf16 whenever every densified cell is <= 256 (exact in
-    # bf16's 8 significand bits; ops/dense_estep.corpus_dtype) —
-    # halving the corpus' device memory and per-iteration HBM
-    # streaming with bit-identical results.  The gate
-    # (ops/dense_estep.corpus_store_dtype, span `fit.plan.gate`) is
-    # exact and reads only what can change its answer: at "f32" no
-    # token; at "bf16" the largest raw count (over 256: f32), then
-    # every document's sum (all <= 256: bf16), then, of the documents in
-    # between, the per-(doc, word) sums of those where a word repeats
-    # (one where every word is distinct has cells equal to its tokens),
-    # batch by batch up to the first cell over 256.  The plan runs the
-    # gate BEFORE its full-width budget test and budgets the dense
-    # corpus at the store's itemsize, so a day that fits the budget only
-    # at 2 B a cell is full-width dense; the densify then fills the bf16
-    # stack a batch at a time (models/fused.densify_stack), holding one
-    # batch's float32 block, never a float32 copy of a group (PERF.md,
-    # PR 41).
+    # corpus in the narrowest exact rung of a ladder
+    # (ops/dense_estep.corpus_dtype): bf16 where every densified cell
+    # is <= 256 (bf16's 8 significand bits), int16 where every cell is
+    # <= 32,767 (the day after analyst feedback: a DUPFACTOR=1000 row
+    # makes cells of 1,000 and more), f32 otherwise — both 2-byte
+    # stores halving the corpus' device memory and per-iteration HBM
+    # streaming, the count promoted to f32 where the kernels use it:
+    # the bf16 store with bit-identical results, the int16 store with
+    # its products at f32 (a document that one word of 1,000 counts
+    # dominates amplifies a bf16 rounding in the products by the
+    # sweeps; ops/dense_estep._products).  The gate
+    # (ops/dense_estep.corpus_store_dtype, span `fit.plan.gate`, which
+    # says the `rung`) is exact and reads only what can change its
+    # answer: at "f32" no token; at "bf16" the largest raw count (over
+    # 32,767: f32; over 256: the int16 rung at best), then every
+    # document's sum (all within the rung's maximum: decided), then, of
+    # the documents over it, the per-(doc, word) sums of those where a
+    # word repeats (one where every word is distinct has cells equal to
+    # its tokens).  The plan runs the gate BEFORE its full-width budget
+    # test and budgets the dense corpus at the store's itemsize, so a
+    # day that fits the budget only at 2 B a cell is full-width dense;
+    # the densify then fills the 2-byte stack a batch at a time
+    # (models/fused.densify_stack), holding one batch's float32 block,
+    # never a float32 copy of a group (PERF.md).
     dense_precision: str = "f32"
     # Store the dense corpus transposed ([W, B]) so the gamma-update
     # matmul's small-K output axis pads to the 8-sublane granularity

@@ -184,9 +184,10 @@ def densify_stack(widx, cnts, *, num_terms, width, dtype, wmajor,
     move.  Left to itself XLA gathered the whole dense stack onto every
     device first (PERF.md, PR 29).
 
-    A store narrower than the counts (bf16 under float32 counts) is
-    densified a batch at a time (`lax.map`): each batch's float32 block
-    is converted into its slot of the stored stack, so the program holds
+    A store narrower than the counts (bf16 or int16 under float32
+    counts, dense_estep.corpus_dtype) is densified a batch at a time
+    (`lax.map`): each batch's float32 block of summed cells is cast,
+    exactly, into its slot of the stored stack, so the program holds
     one batch's float32 block and not a float32 copy of the whole group,
     which the chip cannot hold beside a day stored at 2 B a cell (PERF.md,
     PR 41).  A store as wide as the counts is one scatter over the group
@@ -230,8 +231,8 @@ def densify_groups(
     amortization is the whole point (a per-iteration scatter is what the
     dense path exists to avoid).  `width` overrides the dense width (the
     vocab-sharded XLA path matches it to the sharded beta width);
-    `dtype` is the storage dtype (dense_estep.corpus_dtype — bf16 when
-    exact, halving the corpus' HBM footprint and streaming).
+    `dtype` is the storage dtype (dense_estep.corpus_dtype — bf16 or
+    int16 where exact, halving the corpus' HBM footprint and streaming).
 
     `mesh`: the stacks are sharded over its `data` axis and each device
     densifies its own documents (`densify_stack`); the dense groups come
@@ -241,8 +242,8 @@ def densify_groups(
 
     The span says whether any group's program had to be `built` or all
     were `reused` from an earlier call of this process, whether the
-    scatter ran `sharded`, the store's `itemsize`, and the dense corpus'
-    bytes: `dense_bytes` over all devices, `dense_bytes_device` on each
+    scatter ran `sharded`, the `store` and its `itemsize`, and the dense
+    corpus' bytes: `dense_bytes` over all devices, `dense_bytes_device` on each
     one.  Where a program is built, `temp_bytes` is the most scratch any
     group's program asks for (its compiled memory analysis): a float32
     copy of a group under a narrower store would show here."""
@@ -282,7 +283,8 @@ def densify_groups(
             program="built" if built else "reused",
         )
         if arrays:
-            sp.annotate(itemsize=arrays[0][0].dtype.itemsize)
+            sp.annotate(store=arrays[0][0].dtype.name,
+                        itemsize=arrays[0][0].dtype.itemsize)
         if temp_bytes is not None:
             sp.annotate(temp_bytes=temp_bytes)
     return StackedGroups(tuple(arrays), groups.batch_slots)
@@ -441,15 +443,24 @@ def initial_gammas(groups_arrays, k: int, dtype, dense_wmajor=False):
         # Dense [NB,B,W] / compact-dense [NB,B,Wc] groups put docs on
         # axis 1 like sparse [NB,B,L]; the W-major layouts transpose
         # docs onto the last axis.  Compact groups are len 3 like
-        # sparse but lead with the floating dense corpus (sparse leads
-        # with integer word_idx) — same rule run_batch dispatches on.
-        is_dense = len(g) == 2 or jnp.issubdtype(g[0].dtype, jnp.floating)
+        # sparse but end with the integer vocab_map (sparse ends with
+        # the floating mask) — same rule run_batch dispatches on.
+        is_dense = len(g) == 2 or is_compact_group(g)
         return g[0].shape[2] if is_dense and dense_wmajor else g[0].shape[1]
 
     return tuple(
         jnp.zeros((g[0].shape[0], batch_dim(g), k), dtype)
         for g in groups_arrays
     )
+
+
+def is_compact_group(group) -> bool:
+    """A compact-dense group (C_local, mask, vocab_map), told from a
+    sparse one (word_idx, counts, mask) by its last array: the integer
+    vocab_map, where sparse ends with its floating mask.  Not by the
+    first: C_local may be stored int16 (dense_estep.corpus_dtype), an
+    integer like word_idx."""
+    return len(group) == 3 and jnp.issubdtype(group[2].dtype, jnp.integer)
 
 
 def reads_stack_in_place(group, dense_e_step_fn: Callable | None) -> bool:
@@ -557,10 +568,10 @@ def make_em_accumulator(
         def run_batch(batch, g_in):
             if len(batch) == 2:                # dense group: (C [B,V], mask)
                 return dense_fn(log_beta, alpha, *batch, g_in, warm)
-            if jnp.issubdtype(batch[0].dtype, jnp.floating):
+            if is_compact_group(batch):
                 # compact-dense group: (C_local, mask, vocab_map) —
-                # disjoint from sparse, whose leading word_idx is
-                # integer (dtype is static at trace time).
+                # disjoint from sparse (word_idx, counts, mask), whose
+                # mask is floating (dtype is static at trace time).
                 return _compact_dense(log_beta, alpha, *batch, g_in, warm)
             w, c, m = batch                    # sparse group: (w, c, mask)
             if e_warm:

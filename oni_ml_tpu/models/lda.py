@@ -1101,15 +1101,16 @@ class LDATrainer:
 
         @cache
         def store_gate():
-            # What the dense corpus is stored as (bf16 where exact and the
-            # run is in bf16 operand mode): decided once a plan, and under
-            # bf16 BEFORE the full-width budget test, since the store's
-            # itemsize is what the corpus costs.  The gate bounds the
-            # DENSIFIED cells (duplicate (doc, word) tokens sum: the
-            # DUPFACTOR feedback path makes ~1000-count cells out of
-            # count-1 tokens), not the raw counts, and sums cells only in
-            # the rows over 256 where a word repeats (the span counts
-            # `undecided_rows` and `repeat_rows`); at f32 it reads no token.
+            # What the dense corpus is stored as: in bf16 operand mode the
+            # narrowest exact rung of the ladder bf16 / int16 / f32
+            # (dense_estep.corpus_dtype), decided once a plan, BEFORE the
+            # full-width budget test, since the store's itemsize is what
+            # the corpus costs.  The gate bounds the DENSIFIED cells
+            # (duplicate (doc, word) tokens sum), not the raw counts, and
+            # sums cells only in the undecided rows where a word repeats;
+            # the span says the `rung` and counts `over_bf16_tokens`,
+            # `undecided_rows` and `repeat_rows`.  At f32 it reads no
+            # token.
             with maybe_span("fit.plan.gate") as sp:
                 gate = dense_estep.corpus_store_dtype(batches, precision,
                                                       span=sp)
@@ -1203,8 +1204,8 @@ class LDATrainer:
 
         store, cell_scan, scan_tokens = None, "none", 0
         if dense or compact is not None or try_compact:
-            # bf16 corpus storage halves the corpus' HBM footprint and
-            # streaming with bit-identical results.
+            # A 2-byte store (bf16 or int16) halves the corpus' HBM
+            # footprint and streaming; the counts it holds are exact.
             store, cell_scan, scan_tokens = store_gate()
         if try_compact:
             compact = fused.plan_compact(
@@ -1368,6 +1369,8 @@ class LDATrainer:
         dtype = jnp.dtype(cfg.compute_dtype)
 
         # -- the plan: host-only decisions, before anything is placed ----
+        from ..ops import dense_estep
+
         with maybe_span("fit.plan", batches=len(batches)) as sp:
             plan = self._plan_estep(batches)
             sp.annotate(kernel=plan.kernel, cell_scan=plan.cell_scan,
@@ -1375,10 +1378,13 @@ class LDATrainer:
             self.plan_record["dense_hbm_budget"] = {
                 "value": plan.budget, "source": plan.budget_source}
             self.plan_record["exchange"] = self._exchange(batches, num_docs)
-            # what the dense corpus is stored as (None: token lists)
+            # what the dense corpus is stored as (None: token lists), and
+            # the store ladder's rung
             self.plan_record["corpus_store"] = {
                 "value": (None if plan.store is None
                           else jnp.dtype(plan.store).name),
+                "rung": (None if plan.store is None
+                         else dense_estep.store_rung(plan.store)),
                 "cell_scan": plan.cell_scan}
 
         # -- placement: the stack (fit.stack), then densify (fit.densify) -

@@ -121,7 +121,33 @@ def _cast_for(precision: str):
     return (lambda x: x.astype(dt)) if dt else (lambda x: x)
 
 
+def _products(corpus_dtype, precision: str):
+    """(operand cast, dot precision) of the kernels' matrix products over
+    a corpus block stored as `corpus_dtype`.
+
+    An int16 block (corpus_dtype's integer rung) comes from a day whose
+    cells pass 256, the day after analyst feedback, whose marked rows put
+    1,000 and more counts of ONE word into a document.  That document's
+    fixed point is a power iteration over the topics: each sweep
+    multiplies gamma_k by about beta_kw, so a bfloat16 rounding in the
+    products grows with the sweeps and decides where the document ends.
+    On the v5e, with bfloat16 operands the fit read `gammaN_max` 1.93
+    against the float32 reference (limit 0.05), and with only the fixed
+    point's phinorm left in bfloat16 one seed in eight still did (1.90;
+    PERF.md).  So every product over an int16 block runs at HIGHEST,
+    float32 on the MXU, with no operand cast; every other store keeps
+    `_cast_for(precision)` under XLA's DEFAULT, as before."""
+    if jnp.issubdtype(corpus_dtype, jnp.integer):
+        return (lambda x: x), jax.lax.Precision.HIGHEST
+    return _cast_for(precision), None
+
+
 def _vmem_estimate(bb: int, v: int, k: int, precision: str = "f32") -> int:
+    """Modelled VMEM bytes of one grid step at doc block `bb`.  One
+    formula for every store: a 2-byte corpus block (bfloat16 or int16,
+    corpus_dtype) is double-buffered at 2 B a cell and promoted to
+    float32 where it is used, 2 x 2 + 4 bytes a cell, which is what the
+    float32 block's double buffer counts."""
     est = (4 * bb * v + 2 * k * v) * 4
     if precision == "bf16":
         # bf16 copies of the ratio block, exp_et, and beta live alongside
@@ -246,13 +272,11 @@ def max_dense_cell(word_idx, counts) -> float:
     """Largest value any densified cell will hold: the max over
     (doc, word) of the SUMMED counts of duplicate tokens.
 
-    This — not the max raw per-token count — is what the bf16-exactness
-    gate must bound: duplicate (doc, word) tokens sum in densify(), and
-    the corpus deliberately contains them (the ingest keeps duplicate
-    pairs as separate tokens, and the analyst-feedback path replicates
-    a row DUPFACTOR=1000 times, so a feedback doc holds the same word
-    as ~1000 count-1 tokens whose CELL is ~1000 while every raw count
-    is 1)."""
+    This — not the max raw per-token count — is what the storage gate
+    must bound: duplicate (doc, word) tokens sum in densify(), and the
+    corpus may contain them (the ingest keeps duplicate pairs as separate
+    tokens; a feedback row replicated DUPFACTOR=1000 times as separate
+    count-1 tokens makes a CELL of ~1000 while every raw count is 1)."""
     w = np.asarray(word_idx)
     c = np.asarray(counts, np.float64)
     if w.size == 0:
@@ -272,25 +296,44 @@ def max_dense_cell(word_idx, counts) -> float:
     return float((ends - before).max())
 
 
-# bf16's 8 significand bits hold every integer up to here exactly.
+# The largest integer each 2-byte store holds exactly, and every integer
+# below it: bfloat16's 8 significand bits, int16's 15 value bits.  Mosaic
+# lowers no uint16 or uint8 block to float32 on the v5e (PERF.md),
+# so int16 is the integer rung.
 _BF16_EXACT_MAX = 256
+_INT16_EXACT_MAX = 32767
+
+# The store ladder under bf16 operand mode, narrowest first: (largest
+# densified cell the rung holds exactly, its dtype, its name in spans).
+_RUNGS = ((_BF16_EXACT_MAX, jnp.bfloat16, "bf16"),
+          (_INT16_EXACT_MAX, jnp.int16, "int16"))
 
 
 def corpus_dtype(cell_max: float, precision: str = "f32"):
     """Storage dtype for the densified corpus.
 
-    bf16 when the dense path runs in bf16 operand mode AND every
+    Under bf16 operand mode, the narrowest store that holds every
     DENSIFIED CELL (per-(doc, word) summed count — see max_dense_cell;
-    raw per-token counts undercount duplicates) is <= 256: bf16's 8
-    significand bits represent integers exactly up to 256, so the
-    f32-promoting consumers in the kernels see the exact counts —
-    bit-identical results — while the corpus' HBM streaming (the
-    dominant per-iteration memory traffic once the fixed point is
-    matmul-bound) halves.  Anything larger — e.g. the DUPFACTOR=1000
-    feedback cells — keeps f32."""
-    if precision == "bf16" and cell_max <= _BF16_EXACT_MAX:
-        return jnp.bfloat16
+    raw per-token counts undercount duplicates) exactly: bfloat16 where
+    every cell is <= 256, int16 where every cell is <= 32,767 (the
+    analyst-feedback day: a DUPFACTOR=1000 row makes cells of 1,000 and
+    more), float32 otherwise.  Both 2-byte stores are promoted to float32
+    where the kernels use them, so the counts they see are exact, while
+    the corpus' device memory and HBM streaming halve: the bfloat16
+    store's results are a float32 store's bit for bit, and the int16
+    store's kernels take their products at float32 (`_products`).  At
+    f32: float32."""
+    if precision == "bf16":
+        for top, dtype, _ in _RUNGS:
+            if cell_max <= top:
+                return dtype
     return jnp.float32
+
+
+def store_rung(dtype) -> str:
+    """The ladder's name for a store dtype: "bf16", "int16" or "f32"."""
+    return {jnp.dtype(d): name for _, d, name in _RUNGS}.get(
+        jnp.dtype(dtype), "f32")
 
 
 def _repeat_rows(word_idx, counts):
@@ -313,49 +356,62 @@ def corpus_store_dtype(batches, precision: str = "f32", span=None):
     change the answer.  Returns (dtype, cell_scan, scan_tokens): what
     the gate had to read ("none", "bounds" or "exact") and how many
     padded tokens its passes went over.  `span`, where given, is
-    annotated with `undecided_rows`, the rows between the two bounds
-    below, and `repeat_rows`, those of them that paid the summed-cell
-    scan (0 and 0 where the bounds decided).
+    annotated with the `rung` ("bf16", "int16", "f32"),
+    `over_bf16_tokens`, the raw counts over 256 the first pass saw,
+    `undecided_rows`, the rows between the two bounds below, and
+    `repeat_rows`, those of them that paid the summed-cell scan (0 and
+    0 where the bounds decided).
 
     Anything but bf16 operand mode stores f32 whatever the cells hold:
     no token is read.  Under bf16 the answer is EXACT — equal to
     corpus_dtype(max over batches of max_dense_cell, "bf16") — from the
     cheapest evidence that decides it: a cell is at least its largest
-    token (one raw count over 256: f32) and at most its row's sum
-    (every document's sum <= 256: bf16).  A row between the two bounds
-    whose words are distinct has cells equal to its tokens, all <= 256
-    by the first bound; only rows where a word repeats (the DUPFACTOR
-    feedback document is 1000 count-1 tokens of one word) pay
-    max_dense_cell's sums, batch by batch, up to the first cell over
-    256.  So `scan_tokens` counts the two passes, the undecided rows'
-    tokens once for the repeat test and the repeat rows' for the sums."""
+    token and at most its row's sum.  The first pass finds the largest
+    raw count, which names the lowest rung left (over 32,767: f32, and
+    the pass stops there); the second sums every document and leaves
+    undecided only the rows over that rung's maximum.  A row between
+    the two bounds whose words are distinct has cells equal to its
+    tokens, all within the rung by the first bound; only rows where a
+    word repeats pay max_dense_cell's sums, which may lift the store a
+    rung (up to the first cell over 32,767: f32).  So `scan_tokens`
+    counts the two passes, the undecided rows' tokens once for the
+    repeat test and the repeat rows' for the sums."""
     if precision != "bf16":
-        dtype, cell_scan, read, undecided, repeats = (
-            jnp.float32, "none", 0, 0, 0)
+        dtype, cell_scan, read, over, undecided, repeats = (
+            jnp.float32, "none", 0, 0, 0, 0)
     else:
-        dtype, cell_scan, read, undecided, repeats = _bf16_gate(batches)
+        dtype, cell_scan, read, over, undecided, repeats = _ladder_gate(
+            batches)
     if span is not None:
-        span.annotate(undecided_rows=undecided, repeat_rows=repeats)
+        span.annotate(rung=store_rung(dtype), over_bf16_tokens=over,
+                      undecided_rows=undecided, repeat_rows=repeats)
     return dtype, cell_scan, read
 
 
-def _bf16_gate(batches):
-    """corpus_store_dtype under bf16, and its two row counts."""
+def _ladder_gate(batches):
+    """corpus_store_dtype under bf16, with its three counts: (dtype,
+    cell_scan, scan_tokens, over_bf16_tokens, undecided, repeats)."""
     counts = [np.asarray(b.counts) for b in batches]
-    read = 0
+    read, top, over = 0, 0.0, 0
     for c in counts:
         read += c.size
-        if c.size and c.max() > _BF16_EXACT_MAX:
-            return jnp.float32, "bounds", read, 0, 0
+        if not c.size:
+            continue
+        most = float(c.max())
+        if most > _BF16_EXACT_MAX:
+            over += int(np.count_nonzero(c > _BF16_EXACT_MAX))
+        top = max(top, most)
+        if top > _INT16_EXACT_MAX:
+            return jnp.float32, "bounds", read, over, 0, 0
+    bound = _BF16_EXACT_MAX if top <= _BF16_EXACT_MAX else _INT16_EXACT_MAX
     undecided = []
     for b, c in zip(batches, counts):
         read += c.size
-        rows = np.flatnonzero(
-            c.sum(axis=1, dtype=np.float64) > _BF16_EXACT_MAX)
+        rows = np.flatnonzero(c.sum(axis=1, dtype=np.float64) > bound)
         if rows.size:
             undecided.append((b.word_idx, c, rows))
     if not undecided:
-        return jnp.bfloat16, "bounds", read, 0, 0
+        return corpus_dtype(top, "bf16"), "bounds", read, over, 0, 0
     n_undecided = sum(rows.size for _, _, rows in undecided)
     repeats = 0
     for w, c, rows in undecided:
@@ -364,9 +420,13 @@ def _bf16_gate(batches):
         rep = _repeat_rows(w, c)
         repeats += rep.size
         read += rep.size * c.shape[1]
-        if rep.size and max_dense_cell(w[rep], c[rep]) > _BF16_EXACT_MAX:
-            return jnp.float32, "exact", read, n_undecided, repeats
-    return jnp.bfloat16, "exact", read, n_undecided, repeats
+        if rep.size:
+            top = max(top, max_dense_cell(w[rep], c[rep]))
+            if top > _INT16_EXACT_MAX:
+                return (jnp.float32, "exact", read, over, n_undecided,
+                        repeats)
+    return (corpus_dtype(top, "bf16"), "exact", read, over, n_undecided,
+            repeats)
 
 
 def densify(word_idx, counts, num_terms: int, width: int | None = None,
@@ -383,7 +443,8 @@ def densify(word_idx, counts, num_terms: int, width: int | None = None,
 
     `dtype` is the STORAGE dtype (see corpus_dtype); the scatter
     accumulates in the counts dtype and converts once at the end, so a
-    bf16 store is an exact conversion, never a bf16 accumulation.  The
+    bf16 or int16 store is an exact conversion of every summed cell,
+    never a 2-byte accumulation.  The
     float32 block this leaves is ONE call's: `fused.densify_stack` calls
     this a batch at a time wherever the store is narrower than the
     counts, so a group's float32 stack is never held (PERF.md, PR 41)."""
@@ -407,7 +468,8 @@ def _block_e_step(
     *, var_max_iters: int, var_tol: float, precision: str = "f32",
 ):
     """One block of BB documents' E-step, as values: beta [K, V]
-    exp(log_beta), c [BB, V] f32 or bf16, mask [BB, 1], gamma_in [BB, K]
+    exp(log_beta), c [BB, V] f32, bf16 or int16, mask [BB, 1], gamma_in
+    [BB, K]
     -> (gamma, T's part [K, V], docll [BB, 1], alpha_ss part [BB, 1],
     sweeps).  `_dense_kernel` is its refs in and out.
 
@@ -423,17 +485,18 @@ def _block_e_step(
     (the previous EM iteration's posterior — same fixed point, fewer
     iterations once beta stabilizes; config knob warm_start_gamma)."""
     k_topics = beta.shape[0]
-    # The corpus block may arrive STORED bf16 (corpus_dtype: exact for
-    # counts <= 256, halves its HBM streaming).  It is consumed via
-    # f32-promoting elementwise ops — the upcast fuses per use instead
-    # of materializing a second full-width copy in VMEM — so the
-    # storage dtype changes no results.
+    # The corpus block may arrive STORED in 2 bytes (corpus_dtype:
+    # bfloat16 for cells <= 256, int16 for cells <= 32,767, both exact;
+    # halves its HBM streaming).  It is consumed via f32-promoting
+    # elementwise ops, the count promoted where it is used (n_d, ratio,
+    # the token ELBO): a bf16 store changes no results, and an int16 one
+    # takes its products at float32 (`_products`).
     n_d = jnp.sum(c, axis=1, keepdims=True, dtype=jnp.float32)
     # Relative stop normalizer: mean_k gamma = alpha + N_d/K for every
     # iterate (gamma rows sum to K*alpha + N_d exactly), making var_tol
     # a relative tolerance — reachable in f32 (see ops/estep.py).
     inv_scale = 1.0 / (alpha + n_d / k_topics)   # [BB, 1]
-    cast = _cast_for(precision)
+    cast, dot_precision = _products(c.dtype, precision)
     beta_m = cast(beta)
 
     def e_log_theta(gamma):
@@ -445,7 +508,7 @@ def _block_e_step(
         # [BB, K] @ [K, V]; matches the sparse path's phinorm + 1e-30.
         return jax.lax.dot_general(
             exp_et, b, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=dot_precision,
         ) + 1e-30
 
     def body(state):
@@ -455,7 +518,7 @@ def _block_e_step(
         ratio = c * _recip(q)
         s = jax.lax.dot_general(               # [BB, V] @ [V, K]^T contraction
             cast(ratio), beta_m, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=dot_precision,
         )
         gamma_new = alpha + exp_et * s
         delta = jnp.max(
@@ -500,7 +563,7 @@ def _block_e_step(
     ass = jnp.sum(e_lt, axis=1, keepdims=True) * mask
     t_part = jax.lax.dot_general(              # [K, BB] @ [BB, V]
         exp_et * mask, ratio, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=dot_precision,
     )
     return gamma, t_part, docll, ass, iters
 
@@ -548,13 +611,14 @@ def _block_e_step_w(
     phinorm matmul contracts over K either way (inherent to LDA's
     K-mixture).  Math is identical modulo float reassociation."""
     k_topics = beta.shape[0]
-    # bf16-stored corpus is consumed via f32-promoting ops — exact, no
-    # materialized upcast (see _dense_kernel).
+    # A 2-byte store (bf16 or int16) is consumed via f32-promoting ops,
+    # exact; an int16 one takes its products at float32 (see
+    # _block_e_step, `_products`).
     n_d = jnp.sum(ct, axis=0, keepdims=True,   # [1, BB]
                   dtype=jnp.float32)
     # Relative stop normalizer (see _dense_kernel / ops/estep.py).
     inv_scale = 1.0 / (alpha + n_d / k_topics)  # [1, BB]
-    cast = _cast_for(precision)
+    cast, dot_precision = _products(ct.dtype, precision)
     beta_m = cast(beta)
 
     def e_log_theta_t(gamma_t):
@@ -566,7 +630,7 @@ def _block_e_step_w(
         # [K, W] x [K, BB] contracting K -> [W, BB] phinorm.
         return jax.lax.dot_general(
             b, exp_et_t, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=dot_precision,
         ) + 1e-30
 
     def body(state):
@@ -576,7 +640,7 @@ def _block_e_step_w(
         ratio_t = ct * _recip(q_t)
         s_t = jax.lax.dot_general(                   # [K, W] x [W, BB]
             beta_m, cast(ratio_t), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=dot_precision,
         )
         gamma_new = alpha + exp_et_t * s_t
         delta = jnp.max(
@@ -618,7 +682,7 @@ def _block_e_step_w(
     ass = jnp.sum(e_lt, axis=0, keepdims=True) * mask
     t_part = jax.lax.dot_general(                    # [K, BB] x [W, BB]
         exp_et_t * mask, ratio_t, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=dot_precision,
     )
     return gamma_t, t_part, docll, ass, iters
 
@@ -724,8 +788,8 @@ def dense_fixed_point_w(
         _dense_kernel_w, var_max_iters=var_max_iters, var_tol=var_tol,
         precision=precision,
     )
-    # Outputs/state stay f32 even when the corpus is STORED bf16
-    # (corpus_dtype); the kernel upcasts the block on entry.
+    # Outputs/state stay f32 whatever the corpus is STORED as
+    # (corpus_dtype); the kernel promotes the block where it uses it.
     dtype = jnp.promote_types(dense_counts_t.dtype, jnp.float32)
     if gamma_prev is None:
         gamma_in = jnp.zeros((k_topics, b), dtype)
@@ -818,8 +882,8 @@ def dense_fixed_point(
         _dense_kernel, var_max_iters=var_max_iters, var_tol=var_tol,
         precision=precision,
     )
-    # Outputs/state stay f32 even when the corpus is STORED bf16
-    # (corpus_dtype); the kernel upcasts the block on entry.
+    # Outputs/state stay f32 whatever the corpus is STORED as
+    # (corpus_dtype); the kernel promotes the block where it uses it.
     dtype = jnp.promote_types(dense_counts.dtype, jnp.float32)
     if gamma_prev is None:
         gamma_in = jnp.zeros((b, k_topics), dtype)

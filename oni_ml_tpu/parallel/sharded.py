@@ -257,15 +257,17 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
 
     d_sz, m_sz = mesh.shape[DATA_AXIS], mesh.shape[MODEL_AXIS]
     dense_estep._check_precision(precision)
-    cast = dense_estep._cast_for(precision)
 
     def local(log_beta_l, alpha, c_l, doc_mask, gamma_prev, warm,
               var_max_iters, var_tol):
         k = log_beta_l.shape[0]
+        # The kernel's products (dense_estep._products): bf16 operands
+        # under "bf16", float32 ones at HIGHEST on an int16 store.
+        cast, dot_precision = dense_estep._products(c_l.dtype, precision)
         beta_l = jnp.exp(log_beta_l)               # [K, W_l]
         beta_m = cast(beta_l)
         mask_col = doc_mask[:, None]
-        # f32 accumulation: the corpus may be STORED bf16
+        # f32 accumulation: the corpus may be STORED bf16 or int16
         # (dense_estep.corpus_dtype) and is consumed via f32-promoting
         # ops throughout.
         n_d = jax.lax.psum(
@@ -282,6 +284,7 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
             return jax.lax.dot_general(
                 exp_et, b, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
+                precision=dot_precision,
             ) + 1e-30
 
         def body(state):
@@ -293,6 +296,7 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
                 jax.lax.dot_general(
                     cast(ratio), beta_m, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
+                    precision=dot_precision,
                 ),
                 MODEL_AXIS,
             )
@@ -334,6 +338,7 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
         t_l = jax.lax.dot_general(                 # [K, W_l]
             exp_et * mask_col, ratio, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=dot_precision,
         )
         suff_l = (beta_l * t_l).T                  # [W_l, K]
         # Token ELBO term spans the sharded vocab axis: psum over model.

@@ -1638,7 +1638,10 @@ def build_parser() -> argparse.ArgumentParser:
         "under XLA's DEFAULT matmul precision on current TPUs (measured "
         "on v5e; that default already truncates MXU inputs — refused if "
         "a jax.default_matmul_precision override is active) and ~10%% "
-        "faster",
+        "faster.  bf16 also stores the dense corpus at 2 B a cell where "
+        "that is exact: bfloat16 for counts up to 256, int16 up to "
+        "32,767 (DUPFACTOR feedback cells, whose products then run at "
+        "float32), else float32",
     )
     p.add_argument(
         "--online", action="store_true",

@@ -1,8 +1,10 @@
-"""A day that one chip holds only at 2 B a cell (PR 41, the benchmark's
-`flow20_bigday_fit`): the plan budgets the full-width corpus at the store's
-itemsize, decided by the gate BEFORE the budget test; the densify holds one
-batch's float32 block, never a float32 copy of a group.  At f32 the plan is
-what it was.
+"""A day that one chip holds only at 2 B a cell (the benchmark's
+`flow20_bigday_fit`, and `flow20_feedback_fit` with the analyst feedback's
+cells of 1,000 and more): the plan budgets the full-width corpus at the
+store's itemsize, decided by the gate's ladder (bfloat16 to 256, int16 to
+32,767, float32) BEFORE the budget test; the densify holds one batch's
+float32 block, never a float32 copy of a group.  At f32 the plan is what it
+was.
 
 The plan is driven with `on_tpu` stood in for (`jax.default_backend`
 answers "tpu" for the length of the call): that is what lets `auto` take a
@@ -78,15 +80,40 @@ def test_a_day_that_fits_only_at_2_bytes_a_cell_is_dense_and_stored_bf16():
 
 
 def test_a_cell_over_256_keeps_float32_and_the_day_is_not_dense():
-    batches = _batches(dup_cell=320)     # every raw count 40, the cell 320
+    """A cell over 256 that no 2-byte store holds exactly: 40,000, over
+    int16's 32,767."""
+    batches = _batches(dup_cell=40000)   # every raw count 5,000
     two, four = _sizes(batches)
     plan, gates = _plan(batches, "bf16", (two + four) // 2)
     assert plan.family != "dense"
     assert plan.store == jnp.float32
     gate, = gates
-    assert (gate["store"], gate["cell_scan"]) == ("float32", "exact")
-    # document 0, one word L times, is the one row over 256 and repeats
+    assert (gate["store"], gate["cell_scan"], gate["rung"]) == (
+        "float32", "exact", "f32")
+    # document 0, one word L times, is the one row over 32,767 and repeats
     assert (gate["undecided_rows"], gate["repeat_rows"]) == (1, 1)
+    assert gate["over_bf16_tokens"] == L
+
+
+@pytest.mark.parametrize("cell, over", [(257, 0), (2048, 0), (32767, L)])
+def test_a_feedback_cell_a_2_byte_int16_store_holds_keeps_the_day_dense(
+        cell, over):
+    """The analyst-feedback day: a cell over 256 that int16 holds exactly
+    (as summed count-1 tokens, or as raw counts over 256) is stored int16,
+    and the day that fits only at 2 B a cell stays full-width dense."""
+    batches = _batches(dup_cell=cell)     # L raw counts of cell / L
+    assert int((batches[0].counts > 256).sum()) == over
+    two, four = _sizes(batches)
+    plan, gates = _plan(batches, "bf16", (two + four) // 2)
+    assert (plan.family, plan.kernel) == ("dense", "dense_wmajor")
+    assert plan.store == jnp.int16
+    gate, = gates
+    assert (gate["store"], gate["rung"]) == ("int16", "int16")
+    assert gate["over_bf16_tokens"] == over
+    # under a raw count over 256 the bound is int16's, which no row passes
+    assert gate["cell_scan"] == ("bounds" if over else "exact")
+    assert (gate["undecided_rows"], gate["repeat_rows"]) == (
+        (0, 0) if over else (1, 1))
 
 
 # budget -> (family, kernel, store, cell_scan, scan_tokens), as the parent

@@ -182,3 +182,44 @@ def test_the_big_days_largest_group_densifies_into_bf16_on_one_chip(topo):
         wmajor=True).compile().memory_analysis()
     assert mem.output_size_in_bytes == 87 * ROWS * V * 2
     assert mem.temp_size_in_bytes < 2 * ROWS * V * 4
+
+
+def test_the_feedback_days_largest_group_densifies_into_int16_on_one_chip(
+        topo):
+    """Group (87, 4096, 32) of `flow20_feedback_fit` into the integer rung
+    of the store ladder: int16, 2 B a cell, a batch's float32 block of
+    summed cells cast exactly into its slot, as the bfloat16 store is."""
+    one = SingleDeviceSharding(topo.devices[0])
+    sparse = [jax.ShapeDtypeStruct((87, ROWS, 32), dtype, sharding=one)
+              for dtype in (jnp.int32, jnp.float32)]
+    mem = fused.densify_stack.lower(
+        *sparse, num_terms=V, width=None, dtype=jnp.int16,
+        wmajor=True).compile().memory_analysis()
+    assert mem.output_size_in_bytes == 87 * ROWS * V * 2
+    assert mem.temp_size_in_bytes < 2 * ROWS * V * 4
+
+
+def test_the_wmajor_kernel_reads_an_int16_stack_in_place_on_one_chip(topo):
+    """The W-major kernel over the feedback day's int16 stack [87, 8192,
+    4096], a batch read in place by its index: Mosaic lowers the 16-bit
+    integer block and its promote to float32 (it lowers no uint16 and no
+    uint8 one), and the program holds no float32 copy of a batch."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def e_step(lb, a, stack, m, g, w, n):
+        return dense_estep.e_step_dense(
+            lb, a, stack, m, var_max_iters=20, var_tol=1e-6, wmajor=True,
+            gamma_prev=g, warm=w, precision="bf16", batch_index=n)
+
+    compiled = jax.jit(e_step).lower(
+        shape((K, V)), shape(()), shape((87, V, ROWS), jnp.int16),
+        shape((ROWS,)), shape((ROWS, K)), shape((), jnp.int32),
+        shape((), jnp.int32)).compile()
+    assert _kernels(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < ROWS * V * 4
+    assert not [rest[:60] for _, rest in _instructions(compiled)
+                if re.match(rf"f32\[{V},{ROWS}\]", rest)]
+

@@ -677,16 +677,30 @@ def test_bf16_corpus_storage_is_bit_identical(wmajor, precision):
 
 
 def test_corpus_dtype_decision():
+    """The store ladder under bf16 at its edges: bfloat16 to 256, int16 to
+    32,767, float32 beyond; at f32 always float32."""
     assert dense_estep.corpus_dtype(4.0, "bf16") == jnp.bfloat16
     assert dense_estep.corpus_dtype(256.0, "bf16") == jnp.bfloat16
-    assert dense_estep.corpus_dtype(257.0, "bf16") == jnp.float32
+    assert dense_estep.corpus_dtype(257.0, "bf16") == jnp.int16
+    assert dense_estep.corpus_dtype(32767.0, "bf16") == jnp.int16
+    assert dense_estep.corpus_dtype(32768.0, "bf16") == jnp.float32
     assert dense_estep.corpus_dtype(4.0, "f32") == jnp.float32
+    assert dense_estep.corpus_dtype(1000.0, "f32") == jnp.float32
+    assert [dense_estep.store_rung(d) for d in (
+        jnp.bfloat16, jnp.int16, jnp.float32)] == ["bf16", "int16", "f32"]
+    # every integer of a rung's range survives its store exactly
+    for top, dtype in ((256, jnp.bfloat16), (32767, jnp.int16)):
+        every = np.arange(top + 1, dtype=np.float32)
+        np.testing.assert_array_equal(
+            np.asarray(jnp.asarray(every).astype(dtype), np.float32), every)
+        assert float(jnp.asarray(top + 1.0).astype(dtype)
+                     .astype(jnp.float32)) != top + 1
 
 
 def test_max_dense_cell_sums_duplicates():
-    """The bf16 gate must bound DENSIFIED cells: duplicate (doc, word)
-    tokens sum in densify — the DUPFACTOR=1000 feedback path makes a
-    ~1000-count cell out of count-1 tokens, which a max over raw counts
+    """The storage gate must bound DENSIFIED cells: duplicate (doc, word)
+    tokens sum in densify — a DUPFACTOR=1000 feedback row replicated as
+    count-1 tokens makes a ~1000-count cell, which a max over raw counts
     never sees."""
     word_idx = np.zeros((2, 300), np.int32)
     counts = np.ones((2, 300), np.float32)
@@ -694,8 +708,8 @@ def test_max_dense_cell_sums_duplicates():
     cell_max = dense_estep.max_dense_cell(word_idx, counts)
     assert cell_max == 300.0                # doc 0: one word, 300 tokens
     assert float(np.max(counts)) == 1.0     # the raw-count max is blind
-    # ...and the decision falls back to exact f32 storage.
-    assert dense_estep.corpus_dtype(cell_max, "bf16") == jnp.float32
+    # ...and the decision climbs to the exact int16 rung.
+    assert dense_estep.corpus_dtype(cell_max, "bf16") == jnp.int16
     # Consistency with the real scatter:
     dense = np.asarray(
         dense_estep.densify(jnp.asarray(word_idx), jnp.asarray(counts), 300),
@@ -731,12 +745,28 @@ def _gate_case(name):
         return [_Unreadable(), _Unreadable()], "f32"
     if name == "raw_257":
         c[1, 7] = 257.0
+    elif name == "raw_32767":               # row 1 sums to it: decided
+        c[1] = 0.0
+        c[1, 7] = 32767.0
+    elif name == "raw_32768":
+        c[1, 7] = 32768.0
     elif name == "row_sums_small":
         w, c = w[:, :256], c[:, :256]       # every row sums to 256
     elif name == "dupfactor":
         w = np.zeros((2, 1000), np.int64)   # 1,000 count-1 tokens, one word
         c = np.ones((2, 1000))
         w[1] = np.arange(1000)
+    elif name == "dupfactor_32768":         # 1,024 tokens of 32: one cell
+        w = np.zeros((2, 1024), np.int64)   # of 32,768, every token small
+        c = np.full((2, 1024), 32.0)
+        w[1] = np.arange(1024)
+    elif name == "raw_1000_row_over_int16":
+        c[1, :] = 200.0                     # row 1 sums to 60,800, distinct
+        c[1, 7] = 1000.0
+    elif name == "raw_1000_repeat_over_int16":
+        c[1, :] = 200.0                     # ...and word 7 again: 1,200
+        c[1, 7] = 1000.0
+        w[1, 8] = 7
     elif name == "row_300_cells_small":
         w[0, 150:] = w[0, :150]             # every cell of row 0 is 2
         c[1, 299] = 0.0
@@ -749,11 +779,18 @@ def _gate_case(name):
     return [quiet, _gate_batch(w, c)], "bf16"
 
 
-def _repeat_rows(batch):
+def _bound(batches) -> int:
+    """The row-sum bound the gate's second pass holds rows to: the maximum
+    of the lowest rung the largest raw count leaves."""
+    top = max(float(np.max(b.counts)) for b in batches)
+    return 256 if top <= 256 else 32767
+
+
+def _repeat_rows(batch, bound=256):
     """Rows over the row-sum bound where a word holds two tokens of count
     > 0, found one row at a time."""
     w, c = np.asarray(batch.word_idx), np.asarray(batch.counts)
-    rows = [r for r in range(len(c)) if c[r].sum() > 256]
+    rows = [r for r in range(len(c)) if c[r].sum() > bound]
     return [r for r in rows if len(set(w[r][c[r] > 0]))
             < int((c[r] > 0).sum())]
 
@@ -767,9 +804,14 @@ class _Counts:
 
 @pytest.mark.parametrize("case,scan,dtype", [
     ("f32", "none", jnp.float32),
-    ("raw_257", "bounds", jnp.float32),
+    ("raw_257", "bounds", jnp.int16),
+    ("raw_32767", "bounds", jnp.int16),
+    ("raw_32768", "bounds", jnp.float32),
     ("row_sums_small", "bounds", jnp.bfloat16),
-    ("dupfactor", "exact", jnp.float32),
+    ("dupfactor", "exact", jnp.int16),
+    ("dupfactor_32768", "exact", jnp.float32),
+    ("raw_1000_row_over_int16", "exact", jnp.int16),
+    ("raw_1000_repeat_over_int16", "exact", jnp.int16),
     ("row_300_cells_small", "exact", jnp.bfloat16),
     ("row_300_distinct", "exact", jnp.bfloat16),
     ("padded_tail", "exact", jnp.bfloat16),
@@ -777,11 +819,12 @@ class _Counts:
 ])
 def test_corpus_store_dtype_reads_only_what_decides(case, scan, dtype,
                                                     monkeypatch):
-    """The storage gate: f32 reads no token; bf16 is decided by the
-    largest raw count or the largest row sum where one of them can, and
-    of the rows between the two bounds only those where a word repeats
-    (at count > 0: padding and zero-count tokens are no repeat) reach
-    the exact reader."""
+    """The storage gate: f32 reads no token; bf16 climbs the ladder
+    bfloat16 / int16 / float32, decided by the largest raw count or the
+    largest row sum where one of them can, and of the rows over the
+    bound of the lowest rung left (256, or 32,767 once a raw count is
+    over 256) only those where a word repeats (at count > 0: padding and
+    zero-count tokens are no repeat) reach the exact reader."""
     batches, precision = _gate_case(case)
     exact_rows = []
     reader = dense_estep.max_dense_cell
@@ -795,28 +838,35 @@ def test_corpus_store_dtype_reads_only_what_decides(case, scan, dtype,
     got, cell_scan, scan_tokens = dense_estep.corpus_store_dtype(
         batches, precision, span=span)
     assert (got, cell_scan) == (dtype, scan)
+    assert span.rung == dense_estep.store_rung(dtype)
     if scan == "none":
         assert scan_tokens == 0 and not exact_rows
-        assert (span.undecided_rows, span.repeat_rows) == (0, 0)
+        assert (span.undecided_rows, span.repeat_rows,
+                span.over_bf16_tokens) == (0, 0, 0)
         return
     tokens = [b.counts.size for b in batches]
     assert got == dense_estep.corpus_dtype(
         max(reader(b.word_idx, b.counts) for b in batches), precision)
-    if case == "raw_257":                   # one pass, up to the culprit
+    # the raw counts over 256 that the first pass saw
+    assert span.over_bf16_tokens == sum(
+        int((b.counts > 256).sum()) for b in batches)
+    bound = _bound(batches)
+    if case == "raw_32768":                 # one pass, up to the culprit
         assert scan_tokens == sum(tokens) and not exact_rows
     elif scan == "bounds":                  # the max pass and the row sums
         assert scan_tokens == 2 * sum(tokens) and not exact_rows
     else:          # plus the undecided rows, and again the repeat rows
-        over = [int((b.counts.sum(axis=1) > 256).sum()) for b in batches]
-        repeats = [len(_repeat_rows(b)) for b in batches]
+        over = [int((b.counts.sum(axis=1) > bound).sum()) for b in batches]
+        repeats = [len(_repeat_rows(b, bound)) for b in batches]
         assert exact_rows == [n for n in repeats if n]
         assert scan_tokens == 2 * sum(tokens) + sum(
             (n + r) * b.counts.shape[1]
             for n, r, b in zip(over, repeats, batches))
         assert (span.undecided_rows, span.repeat_rows) == (sum(over),
                                                            sum(repeats))
-        assert sum(over) == 2
-        assert sum(repeats) == {"dupfactor": 1,
+        assert sum(over) == (1 if "over_int16" in case else 2)
+        assert sum(repeats) == {"dupfactor": 1, "dupfactor_32768": 1,
+                                "raw_1000_repeat_over_int16": 1,
                                 "row_300_cells_small": 1}.get(case, 0)
         return
     assert (span.undecided_rows, span.repeat_rows) == (0, 0)
@@ -824,14 +874,15 @@ def test_corpus_store_dtype_reads_only_what_decides(case, scan, dtype,
 
 @pytest.mark.parametrize("precision", ["f32", "bf16"])
 def test_corpus_store_dtype_is_the_exact_reader_on_random_batches(precision):
-    """200 random batches with duplicates planted around the 256 line, rows
-    of distinct words and zero-count padding tails (word 0, as make_batches
-    pads): the gate's dtype is corpus_dtype of the exact reader's maximum."""
+    """Random batches with duplicates planted around the 256 and 32,767
+    lines, rows of distinct words and zero-count padding tails (word 0, as
+    make_batches pads): the gate's dtype is corpus_dtype of the exact
+    reader's maximum, on every rung."""
     rng = np.random.default_rng(256)
     seen, repeated = set(), set()
-    for _ in range(200):
+    for _ in range(400):
         b, l, v = rng.integers(1, 9), rng.integers(1, 40), rng.integers(1, 50)
-        top = int(rng.choice([1, 3, 40, 256, 257, 300]))
+        top = int(rng.choice([1, 3, 40, 256, 257, 300, 32767, 32768]))
         w = rng.integers(0, v, (b, l))
         if l <= v and rng.random() < 0.5:       # every row's words distinct
             w = np.argsort(rng.random((b, v)), axis=1)[:, :l]
@@ -839,7 +890,8 @@ def test_corpus_store_dtype_is_the_exact_reader_on_random_batches(precision):
         for _ in range(rng.integers(0, 4)):     # a word repeated in a row
             row, n = rng.integers(0, b), rng.integers(1, l + 1)
             w[row, :n] = w[row, 0]
-            c[row, :n] = rng.choice([1.0, 256.0 / n, 300.0 // n + 1])
+            c[row, :n] = rng.choice([1.0, 256.0 / n, 300.0 // n + 1,
+                                     32767.0 / n, 32768.0 // n + 1])
         pad = rng.integers(0, 9)                # the padded tail
         w = np.concatenate([w, np.zeros((b, pad), int)], axis=1)
         c = np.concatenate([c, np.zeros((b, pad))], axis=1)
@@ -856,14 +908,17 @@ def test_corpus_store_dtype_is_the_exact_reader_on_random_batches(precision):
         assert got == want, (w, c)
         seen.add((cell_scan, got))
         if cell_scan == "exact":
-            repeated.add((any(_repeat_rows(x) for x in batches), got))
+            bound = _bound(batches)
+            repeated.add((any(_repeat_rows(x, bound) for x in batches), got))
     if precision == "f32":
         assert seen == {("none", jnp.float32)}
     else:
         assert seen == {(scan, dtype) for scan in ("bounds", "exact")
-                        for dtype in (jnp.float32, jnp.bfloat16)}
-        # decided with and without the summed cells
-        assert repeated == {(True, jnp.float32), (True, jnp.bfloat16),
+                        for dtype in (jnp.float32, jnp.int16, jnp.bfloat16)}
+        # decided with and without the summed cells; a day with no repeat
+        # over its bound never climbs past the rung of its largest token
+        assert repeated == {(True, jnp.float32), (True, jnp.int16),
+                            (True, jnp.bfloat16), (False, jnp.int16),
                             (False, jnp.bfloat16)}
 
 

@@ -358,7 +358,7 @@ def _corpus_with_a_feedback_doc():
 @pytest.mark.parametrize("precision,feedback,cell_scan,stored", [
     ("f32", True, "none", jnp.float32),
     ("bf16", False, "bounds", jnp.bfloat16),
-    ("bf16", True, "exact", jnp.float32),
+    ("bf16", True, "exact", jnp.int16),
 ])
 def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
                                                   cell_scan, stored):
@@ -389,6 +389,12 @@ def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
         assert 2 * padded_tokens < plan["scan_tokens"] < 3 * padded_tokens
     assert (gate["undecided_rows"], gate["repeat_rows"]) == (
         (1, 1) if cell_scan == "exact" else (0, 0))
+    # the ladder's rung, and no raw count over 256: the feedback cell is
+    # 300 count-1 tokens
+    assert (gate["rung"], gate["over_bf16_tokens"]) == (
+        dense_estep.store_rung(stored), 0)
+    assert (densify["store"], densify["itemsize"]) == (
+        jnp.dtype(stored).name, jnp.dtype(stored).itemsize)
 
 
 def _corpus_with_hub_docs(hubs=3, feedback=False, num_terms=320, seed=1):
@@ -412,7 +418,7 @@ def _corpus_with_hub_docs(hubs=3, feedback=False, num_terms=320, seed=1):
 
 @pytest.mark.parametrize("feedback,stored,repeats", [
     (False, jnp.bfloat16, 0),
-    (True, jnp.float32, 1),
+    (True, jnp.int16, 1),
 ])
 def test_the_gate_sums_cells_only_in_rows_where_a_word_repeats(
         feedback, stored, repeats, monkeypatch):
