@@ -161,9 +161,11 @@ class LDAConfig:
     # stores halving the corpus' device memory and per-iteration HBM
     # streaming, the count promoted to f32 where the kernels use it:
     # the bf16 store with bit-identical results, the int16 store with
-    # its products at f32 (a document that one word of 1,000 counts
-    # dominates amplifies a bf16 rounding in the products by the
-    # sweeps; ops/dense_estep._products).  The gate
+    # its products at f32 accuracy (a document that one word of 1,000
+    # counts dominates amplifies a bf16 rounding in the products by the
+    # sweeps): the kernels' fixed point from exact bf16 pieces packed
+    # into the MXU's contraction depth, the rest at HIGHEST
+    # (ops/dense_estep._sweep_products, _products).  The gate
     # (ops/dense_estep.corpus_store_dtype, span `fit.plan.gate`, which
     # says the `rung`) is exact and reads only what can change its
     # answer: at "f32" no token; at "bf16" the largest raw count (over

@@ -333,13 +333,16 @@ def plan_compact(
     wmajor: bool = True,
     itemsize: int = 4,
     local_div: int = 1,
+    store=None,
 ) -> CompactPlan | None:
     """Build a CompactPlan, or None when some group's compact width
     admits no VMEM-feasible doc block (then the sparse path is the
     only option).  Pure host-side: np.unique over each batch's token
     ids (the corpus is static, so the per-batch vocabulary is fixed
     for the whole run).  `local_div` divides the per-kernel doc count
-    (data-mesh shard factor); callers gate mesh support themselves."""
+    (data-mesh shard factor); callers gate mesh support themselves.
+    `store`: the corpus' store dtype where known (the kernels' VMEM
+    need depends on it: dense_estep.pick_block)."""
     from ..ops import dense_estep
 
     groups: dict[tuple, list[int]] = {}
@@ -355,10 +358,11 @@ def plan_compact(
         wc = -(-wc // 128) * 128  # lane tile, like padded_width()
         b_local = shape[0] // local_div
         if dense_estep.pick_block(b_local, wc, num_topics,
-                                  precision) is None:
+                                  precision, store) is None:
             return None
         use_wmajor = use_wmajor and (
-            dense_estep.pick_block_w(b_local, wc, num_topics, precision)
+            dense_estep.pick_block_w(b_local, wc, num_topics, precision,
+                                     store)
             is not None
         )
         uniques.append(us)

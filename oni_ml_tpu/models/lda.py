@@ -1210,7 +1210,7 @@ class LDATrainer:
         if try_compact:
             compact = fused.plan_compact(
                 batches, k, precision, wmajor=cfg.dense_wmajor,
-                itemsize=jnp.dtype(store).itemsize,
+                itemsize=jnp.dtype(store).itemsize, store=store,
             )
             if compact is None and compact_mode == "on":
                 raise ValueError(
@@ -1265,7 +1265,7 @@ class LDATrainer:
             # W-major needs the doc axis on the 128-lane dimension; fall
             # back to row-major when any batch shape can't block that way.
             wmajor = cfg.dense_wmajor and all(
-                dense_estep.pick_block_w(rows, v, k, precision)
+                dense_estep.pick_block_w(rows, v, k, precision, store)
                 for rows, _ in local
             )
             plan.update(
@@ -1292,7 +1292,7 @@ class LDATrainer:
                 )
             blocks = [(rows, v) for rows, _ in local]
             kib = partial(dense_estep.scoped_vmem_kib, wmajor=wmajor,
-                          precision=precision)
+                          precision=precision, store=store)
         elif compact is not None:
             # Compact-vocab dense groups are built straight from the host
             # batches (no sparse stacked upload to discard).  The chunk
@@ -1312,7 +1312,8 @@ class LDATrainer:
             blocks = [(shape[0], wc)
                       for shape, wc in zip(shapes, compact.widths)]
             kib = partial(dense_estep.scoped_vmem_kib,
-                          wmajor=compact.wmajor, precision=precision)
+                          wmajor=compact.wmajor, precision=precision,
+                          store=store)
         else:
             plan.update(
                 family="tokens",
@@ -1386,6 +1387,19 @@ class LDATrainer:
                 "rung": (None if plan.store is None
                          else dense_estep.store_rung(plan.store)),
                 "cell_scan": plan.cell_scan}
+            # the products the fixed point takes over that store and the
+            # phinorm's MXU passes a sweep (dense_estep.kernel_products;
+            # the vocab-sharded XLA plan packs nothing)
+            products = passes = None
+            if plan.store is not None:
+                products = dense_estep.kernel_products(plan.store, k)
+                if plan.family == "dense_vocab_sharded" and (
+                        products != "default"):
+                    products = "highest"
+                passes = dense_estep.phinorm_passes(products, k)
+                sp.annotate(products=products, phinorm_passes=passes)
+            self.plan_record["products"] = {
+                "value": products, "phinorm_passes": passes}
 
         # -- placement: the stack (fit.stack), then densify (fit.densify) -
         put_stacked = put

@@ -122,8 +122,9 @@ def _cast_for(precision: str):
 
 
 def _products(corpus_dtype, precision: str):
-    """(operand cast, dot precision) of the kernels' matrix products over
-    a corpus block stored as `corpus_dtype`.
+    """(operand cast, dot precision) of the matrix products over a corpus
+    block stored as `corpus_dtype`, where they are not packed
+    (`kernel_products`).
 
     An int16 block (corpus_dtype's integer rung) comes from a day whose
     cells pass 256, the day after analyst feedback, whose marked rows put
@@ -134,38 +135,237 @@ def _products(corpus_dtype, precision: str):
     On the v5e, with bfloat16 operands the fit read `gammaN_max` 1.93
     against the float32 reference (limit 0.05), and with only the fixed
     point's phinorm left in bfloat16 one seed in eight still did (1.90;
-    PERF.md).  So every product over an int16 block runs at HIGHEST,
-    float32 on the MXU, with no operand cast; every other store keeps
-    `_cast_for(precision)` under XLA's DEFAULT, as before."""
+    PERF.md).  So a product over an int16 block keeps float32 accuracy:
+    the kernels' fixed point packs it (`_packed_products`) where K lets
+    it, and everything else over an int16 block, the kernels' tail pass
+    and the vocab-sharded XLA plan, runs at HIGHEST with no operand
+    cast; every other store keeps `_cast_for(precision)` under XLA's
+    DEFAULT, as before."""
     if jnp.issubdtype(corpus_dtype, jnp.integer):
-        return (lambda x: x), jax.lax.Precision.HIGHEST
+        return (lambda x: x), _dot_precision(corpus_dtype)
     return _cast_for(precision), None
 
 
-def _vmem_estimate(bb: int, v: int, k: int, precision: str = "f32") -> int:
-    """Modelled VMEM bytes of one grid step at doc block `bb`.  One
-    formula for every store: a 2-byte corpus block (bfloat16 or int16,
+def _dot_precision(corpus_dtype):
+    """HIGHEST over an int16 block, else XLA's DEFAULT (None): the
+    precision of every product `_products` and the packing leave."""
+    if jnp.issubdtype(corpus_dtype, jnp.integer):
+        return jax.lax.Precision.HIGHEST
+    return None
+
+
+# The fixed point's products over an int16 block, at float32 accuracy and
+# packed into the MXU's contraction depth.  A float32 product at HIGHEST
+# is six bfloat16 passes over the MXU; at K = 20 the phinorm product
+# contracts 20 of the v5e MXU's 128 rows a pass, so five sixths of what it
+# pays is padding (PERF.md).  Here the bfloat16 partial products that such
+# a product is made of are stacked into that idle depth instead: each
+# operand splits into three bfloat16 pieces that sum back to it exactly
+# (`split3`), and each bf16 x bf16 partial product is exact in float32.
+_MXU_DEPTH = 128        # contraction rows of one v5e MXU pass
+_HIGHEST_PASSES = 6     # bfloat16 passes of one product at HIGHEST
+# (i, j): operand A's piece i times operand B's piece j (0 hi, 1 mid,
+# 2 lo).  phinorm takes HIGHEST's six, the smallest first along the
+# contraction, so that the float32 accumulation adds them before hi x hi:
+# on the v5e this read closer to a float64 product than HIGHEST itself,
+# and than all nine (hi x hi first read 1.7x HIGHEST's error; PERF.md).
+_SPLIT6 = ((0, 2), (1, 1), (2, 0), (0, 1), (1, 0), (0, 0))
+
+
+def split3(x):
+    """float32 -> (hi, mid, lo), three bfloat16 arrays whose float32 sum is
+    `x` exactly: each piece is the bfloat16 rounding of what the pieces
+    before it leave, and float32's 24 significand bits fit in three of
+    bfloat16's 8 (wherever the pieces stay above float32's smallest
+    normal: beta's 1e-12 and up leave 1e-19 and up)."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _packed_passes(k: int) -> int:
+    """MXU passes of the packed phinorm product a sweep: its contraction
+    is the six terms' K rows each."""
+    return -(-len(_SPLIT6) * k // _MXU_DEPTH)
+
+
+def kernel_products(corpus_dtype, k: int) -> str:
+    """The kernels' fixed-point products over a block stored as
+    `corpus_dtype` at K topics: "default" (XLA's DEFAULT, the operand
+    cast of `_cast_for`) on a floating store; on the int16 store
+    "split6_packed" (`_packed_products`) where the packed phinorm takes
+    fewer MXU passes than HIGHEST's six (K <= 106), else "highest"."""
+    if not jnp.issubdtype(corpus_dtype, jnp.integer):
+        return "default"
+    if _packed_passes(k) < _HIGHEST_PASSES:
+        return "split6_packed"
+    return "highest"
+
+
+def phinorm_passes(products: str, k: int) -> int:
+    """MXU passes of the fixed point's phinorm product a sweep (it
+    contracts over the K topics) under `products` (`kernel_products`)."""
+    depth = -(-k // _MXU_DEPTH)
+    return {"default": depth, "highest": _HIGHEST_PASSES * depth,
+            "split6_packed": _packed_passes(k)}[products]
+
+
+def _placement(rows: int, cols: int, offsets, transpose: bool = False):
+    """bfloat16 0/1 [rows, cols] that puts a [cols, N] operand's rows at
+    each row offset of `offsets` (transpose: [cols, rows], putting a
+    [N, cols] operand's columns).  A product with it copies bfloat16
+    values exactly: every output sums one of them and zeros."""
+    shape = (cols, rows) if transpose else (rows, cols)
+    r = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transpose else 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if transpose else 1)
+    hit = functools.reduce(jnp.logical_or, [r - c == o for o in offsets])
+    return jnp.where(hit, 1.0, 0.0).astype(jnp.bfloat16)
+
+
+def _mm(a, b, contract=((1,), (0,))):
+    """bfloat16 operands, float32 accumulation, one DEFAULT product."""
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _total(xs):
+    return functools.reduce(jnp.add, xs)
+
+
+def _sweep_products(beta, corpus_dtype, precision: str, wmajor: bool):
+    """(phinorm, gamma_update, tail_phinorm) of the kernels over a block
+    stored as `corpus_dtype`: the packed products where `kernel_products`
+    says so (the tail's phinorm too), else beta cast once and each
+    product one dot under `_products`, the tail's phinorm uncast.  The
+    one helper both kernel bodies call (and so the data-parallel kernel
+    under `shard_map`); the signatures are `_packed_products`'."""
+    if kernel_products(corpus_dtype, beta.shape[0]) == "split6_packed":
+        phinorm, gamma_update = _packed_products(beta, wmajor)
+        return phinorm, gamma_update, phinorm
+    cast, dot_precision = _products(corpus_dtype, precision)
+    beta_m = cast(beta)
+    # phinorm contracts K; + 1e-30 matches the sparse path's phinorm
+    dims = ((((0,), (0,)), ((1,), (0,))) if wmajor
+            else (((1,), (0,)), ((1,), (1,))))
+
+    def product(a, b, contract):
+        return jax.lax.dot_general(
+            a, b, (contract, ((), ())),
+            preferred_element_type=jnp.float32, precision=dot_precision)
+
+    def phinorm(exp_et, cast=cast, beta_m=beta_m):
+        a, b = (beta_m, cast(exp_et)) if wmajor else (cast(exp_et), beta_m)
+        return product(a, b, dims[0]) + 1e-30
+
+    def gamma_update(ratio):
+        a, b = (beta_m, cast(ratio)) if wmajor else (cast(ratio), beta_m)
+        return product(a, b, dims[1])
+
+    return (phinorm, gamma_update,
+            functools.partial(phinorm, cast=lambda x: x, beta_m=beta))
+
+
+def _packed_products(beta, wmajor: bool, terms=_SPLIT6):
+    """(phinorm, gamma_update) of the fixed point over an int16 block from
+    the bfloat16 pieces of its float32 operands (`split3`).
+
+    phinorm contracts over K: both operands' pieces are stacked along
+    that axis, block t of beta's holding piece terms[t][0] and block t of
+    exp_et's piece terms[t][1], so ONE product of contraction 6K sums the
+    six terms (K = 20: one MXU pass where HIGHEST takes six).  The gamma
+    update contracts over W: beta's three pieces are stacked along its K
+    output rows (blocks of Kp, K rounded up to the 8-row sublane tile),
+    one product a piece of the ratio, and the blocks summed: all nine
+    terms in three passes over the ratio where HIGHEST takes six.
+    beta's stacks are built once, before the fixed point's loop, by exact
+    0/1 placement products (`_placement`); exp_et's and the ratio's
+    pieces once a sweep.  W-major: exp_et [K, BB] -> q [W, BB], ratio
+    [W, BB] -> s [K, BB]; row-major: exp_et [BB, K] -> q [BB, W], ratio
+    [BB, W] -> s [BB, K]."""
+    k = beta.shape[0]
+    kp = -(-k // 8) * 8
+    n = len(terms) * k
+    b = split3(beta)
+    at = {p: [t * k for t, pair in enumerate(terms) if pair[0] == p]
+          for p in range(3)}
+    e_at = {p: [t * k for t, pair in enumerate(terms) if pair[1] == p]
+            for p in range(3)}
+    # beta's pieces along the phinorm's contraction, and along the gamma
+    # update's output rows: exact copies, so one cast to bfloat16 each.
+    if wmajor:
+        bq = _total(_mm(b[p], _placement(n, k, at[p], transpose=True),
+                        ((0,), (0,))) for p in range(3))    # [W, 6K]
+    else:
+        bq = _total(_mm(_placement(n, k, at[p]), b[p])
+                    for p in range(3))                      # [6K, W]
+    bq = bq.astype(jnp.bfloat16)
+    bs = _total(_mm(_placement(3 * kp, k, [p * kp]), b[p])
+                for p in range(3)).astype(jnp.bfloat16)     # [3Kp, W]
+    # the gamma update's terms summed smallest first too: the ratio's lo
+    # piece, then mid, then hi; beta's lo rows, then mid, then hi
+    if wmajor:
+        put = [_placement(n, k, e_at[p]) for p in range(3)]
+
+        def phinorm(exp_et_t):
+            e = split3(exp_et_t)
+            eq = _total(_mm(put[p], e[p]) for p in range(3))  # [6K, BB]
+            return _mm(bq, eq.astype(jnp.bfloat16)) + 1e-30
+
+        def gamma_update(ratio_t):
+            s = _total(_mm(bs, r)
+                       for r in split3(ratio_t)[::-1])      # [3Kp, BB]
+            return s[2 * kp:2 * kp + k] + s[kp:kp + k] + s[:k]
+    else:
+        put = [_placement(n, k, e_at[p], transpose=True) for p in range(3)]
+
+        def phinorm(exp_et):
+            e = split3(exp_et)
+            eq = _total(_mm(e[p], put[p]) for p in range(3))  # [BB, 6K]
+            return _mm(eq.astype(jnp.bfloat16), bq) + 1e-30
+
+        def gamma_update(ratio):
+            s = _total(_mm(r, bs, ((1,), (1,)))
+                       for r in split3(ratio)[::-1])        # [BB, 3Kp]
+            return s[:, 2 * kp:2 * kp + k] + s[:, kp:kp + k] + s[:, :k]
+    return phinorm, gamma_update
+
+
+def _vmem_estimate(bb: int, v: int, k: int, precision: str = "f32",
+                   store=None) -> int:
+    """Modelled VMEM bytes of one grid step at doc block `bb` over a block
+    stored as `store` (None: a floating store).  One formula for every
+    store's block: a 2-byte corpus block (bfloat16 or int16,
     corpus_dtype) is double-buffered at 2 B a cell and promoted to
     float32 where it is used, 2 x 2 + 4 bytes a cell, which is what the
     float32 block's double buffer counts."""
     est = (4 * bb * v + 2 * k * v) * 4
-    if precision == "bf16":
+    if store is not None and kernel_products(store, k) == "split6_packed":
+        # the packed products' bfloat16 operands (`_packed_products`):
+        # the ratio's three pieces, and beta's pieces stacked for phinorm
+        # (6K of them, lanes padded to the tile) and the gamma update (3Kp)
+        est += (3 * bb * v
+                + (padded_width(len(_SPLIT6) * k) + 3 * (-(-k // 8) * 8))
+                * v) * 2
+    elif precision == "bf16":
         # bf16 copies of the ratio block, exp_et, and beta live alongside
         # their f32 originals during the fixed point.
         est += (bb * v + bb * k + k * v) * 2
     return est
 
 
-def _vmem_limit(bb: int, v: int, k: int, precision: str = "f32") -> int:
+def _vmem_limit(bb: int, v: int, k: int, precision: str = "f32",
+                store=None) -> int:
     # Mosaic's real stack allocation runs ~1.6x the modeled working set
     # (measured: 56.2MB actual vs 34.9MB modeled at BB=256, V=8192, K=20);
     # 2x keeps headroom without hitting the 128MB physical VMEM.
-    est = _vmem_estimate(bb, v, k, precision)
+    est = _vmem_estimate(bb, v, k, precision, store)
     return min(max(32 * 1024 * 1024, est * 2), 128 * 1024 * 1024)
 
 
 def scoped_vmem_kib(b: int, v: int, k: int, wmajor: bool = False,
-                    precision: str = "f32") -> int | None:
+                    precision: str = "f32", store=None) -> int | None:
     """Scoped-VMEM KiB the dense kernel needs at pick_block's block size —
     for drivers to pass as the xla_tpu_scoped_vmem_limit_kib compiler
     option.  Needed because XLA drops the pallas_call's own
@@ -173,10 +373,10 @@ def scoped_vmem_kib(b: int, v: int, k: int, wmajor: bool = False,
     multi-batch lax.scan (observed: a [NB>=2] stacked group compiles the
     kernel as kCustom fusion with the default 16MB scoped limit)."""
     pick = pick_block_w if wmajor else pick_block
-    bb = pick(b, v, k, precision)
+    bb = pick(b, v, k, precision, store)
     if bb is None:
         return None
-    return _vmem_limit(bb, padded_width(v), k, precision) // 1024
+    return _vmem_limit(bb, padded_width(v), k, precision, store) // 1024
 
 
 def _planned_block(knob: str, b: int, v: int, k: int,
@@ -201,11 +401,13 @@ def _planned_block(knob: str, b: int, v: int, k: int,
         return None
 
 
-def pick_block(b: int, v: int, k: int, precision: str = "f32") -> int | None:
+def pick_block(b: int, v: int, k: int, precision: str = "f32",
+               store=None) -> int | None:
     """Largest power-of-two doc block (<= 256) dividing `b` whose
-    estimated working set fits the VMEM ceiling — or the plan cache's
-    measured block for this shape when one exists and passes the same
-    feasibility checks.  None = infeasible."""
+    estimated working set fits the VMEM ceiling (over a block stored as
+    `store`, None a floating one) — or the plan cache's measured block
+    for this shape when one exists and passes the same feasibility
+    checks.  None = infeasible."""
     w = padded_width(v)
     planned = _planned_block("dense_estep_block", b, v, k, precision)
     if (
@@ -216,21 +418,21 @@ def pick_block(b: int, v: int, k: int, precision: str = "f32") -> int | None:
         # analytic space only ever emits multiples of 8, and a
         # hand-edited entry must not hand Mosaic an unaligned tile.
         and planned % 8 == 0
-        and _vmem_estimate(planned, w, k, precision) <= _VMEM_CEILING
+        and _vmem_estimate(planned, w, k, precision, store) <= _VMEM_CEILING
     ):
         return planned
     bb = 8
     best = None
     while bb <= min(b, 256) and b % bb == 0:
-        if _vmem_estimate(bb, w, k, precision) > _VMEM_CEILING:
+        if _vmem_estimate(bb, w, k, precision, store) > _VMEM_CEILING:
             break
         best = bb
         bb *= 2
     return best
 
 
-def pick_block_w(b: int, v: int, k: int,
-                 precision: str = "f32") -> int | None:
+def pick_block_w(b: int, v: int, k: int, precision: str = "f32",
+                 store=None) -> int | None:
     """Doc block for the W-major layout.  The doc axis is the LANE
     dimension of the C^T block there, so Mosaic requires it divisible by
     128 — or equal to the full batch (single-block grid).  None =
@@ -242,18 +444,18 @@ def pick_block_w(b: int, v: int, k: int,
         and planned <= b
         and b % planned == 0
         and (planned % 128 == 0 or planned == b)
-        and _vmem_estimate(planned, w, k, precision) <= _VMEM_CEILING
+        and _vmem_estimate(planned, w, k, precision, store) <= _VMEM_CEILING
     ):
         return planned
     best = None
     bb = 128
     while bb <= min(b, 256) and b % bb == 0:
-        if _vmem_estimate(bb, w, k, precision) > _VMEM_CEILING:
+        if _vmem_estimate(bb, w, k, precision, store) > _VMEM_CEILING:
             break
         best = bb
         bb *= 2
     if best is None and b <= 256 and (
-        _vmem_estimate(b, w, k, precision) <= _VMEM_CEILING
+        _vmem_estimate(b, w, k, precision, store) <= _VMEM_CEILING
     ):
         best = b  # block == full array: any lane extent is legal
     return best
@@ -321,8 +523,8 @@ def corpus_dtype(cell_max: float, precision: str = "f32"):
     where the kernels use them, so the counts they see are exact, while
     the corpus' device memory and HBM streaming halve: the bfloat16
     store's results are a float32 store's bit for bit, and the int16
-    store's kernels take their products at float32 (`_products`).  At
-    f32: float32."""
+    store's kernels take their products at float32 accuracy
+    (`_sweep_products`).  At f32: float32."""
     if precision == "bf16":
         for top, dtype, _ in _RUNGS:
             if cell_max <= top:
@@ -490,36 +692,27 @@ def _block_e_step(
     # halves its HBM streaming).  It is consumed via f32-promoting
     # elementwise ops, the count promoted where it is used (n_d, ratio,
     # the token ELBO): a bf16 store changes no results, and an int16 one
-    # takes its products at float32 (`_products`).
+    # takes its products at float32 accuracy (`_sweep_products`).
     n_d = jnp.sum(c, axis=1, keepdims=True, dtype=jnp.float32)
     # Relative stop normalizer: mean_k gamma = alpha + N_d/K for every
     # iterate (gamma rows sum to K*alpha + N_d exactly), making var_tol
     # a relative tolerance — reachable in f32 (see ops/estep.py).
     inv_scale = 1.0 / (alpha + n_d / k_topics)   # [BB, 1]
-    cast, dot_precision = _products(c.dtype, precision)
-    beta_m = cast(beta)
+    dot_precision = _dot_precision(c.dtype)   # the tail's statistics
+    phinorm, gamma_update, tail_phinorm = _sweep_products(
+        beta, c.dtype, precision, wmajor=False)
 
     def e_log_theta(gamma):
         return digamma_pos(gamma) - digamma_pos(
             jnp.sum(gamma, axis=1, keepdims=True)
         )
 
-    def qmat(exp_et, b):
-        # [BB, K] @ [K, V]; matches the sparse path's phinorm + 1e-30.
-        return jax.lax.dot_general(
-            exp_et, b, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=dot_precision,
-        ) + 1e-30
-
     def body(state):
         gamma, it, delta_old, _ = state
         exp_et = jnp.exp(e_log_theta(gamma))   # [BB, K]
-        q = qmat(cast(exp_et), beta_m)
+        q = phinorm(exp_et)                    # [BB, K] @ [K, V]
         ratio = c * _recip(q)
-        s = jax.lax.dot_general(               # [BB, V] @ [V, K]^T contraction
-            cast(ratio), beta_m, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=dot_precision,
-        )
+        s = gamma_update(ratio)                # [BB, V] @ [V, K]^T contraction
         gamma_new = alpha + exp_et * s
         delta = jnp.max(
             jnp.mean(jnp.abs(gamma_new - gamma), axis=1, keepdims=True)
@@ -553,7 +746,7 @@ def _block_e_step(
     # f32 off the converged gamma, whatever the iteration precision was.
     e_lt = e_log_theta(gamma)
     exp_et = jnp.exp(e_lt)
-    q = qmat(exp_et, beta)
+    q = tail_phinorm(exp_et)                   # [BB, K] @ [K, V]
     ratio = (c * _recip(q)) * mask
     tok = jnp.sum(c * jnp.log(q), axis=1, keepdims=True)
     core = jnp.sum(
@@ -612,36 +805,27 @@ def _block_e_step_w(
     K-mixture).  Math is identical modulo float reassociation."""
     k_topics = beta.shape[0]
     # A 2-byte store (bf16 or int16) is consumed via f32-promoting ops,
-    # exact; an int16 one takes its products at float32 (see
-    # _block_e_step, `_products`).
+    # exact; an int16 one takes its products at float32 accuracy (see
+    # _block_e_step, `_sweep_products`).
     n_d = jnp.sum(ct, axis=0, keepdims=True,   # [1, BB]
                   dtype=jnp.float32)
     # Relative stop normalizer (see _dense_kernel / ops/estep.py).
     inv_scale = 1.0 / (alpha + n_d / k_topics)  # [1, BB]
-    cast, dot_precision = _products(ct.dtype, precision)
-    beta_m = cast(beta)
+    dot_precision = _dot_precision(ct.dtype)  # the tail's statistics
+    phinorm, gamma_update, tail_phinorm = _sweep_products(
+        beta, ct.dtype, precision, wmajor=True)
 
     def e_log_theta_t(gamma_t):
         return digamma_pos(gamma_t) - digamma_pos(
             jnp.sum(gamma_t, axis=0, keepdims=True)
         )
 
-    def qmat_t(exp_et_t, b):
-        # [K, W] x [K, BB] contracting K -> [W, BB] phinorm.
-        return jax.lax.dot_general(
-            b, exp_et_t, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=dot_precision,
-        ) + 1e-30
-
     def body(state):
         gamma_t, it, delta_old, _ = state
         exp_et_t = jnp.exp(e_log_theta_t(gamma_t))   # [K, BB]
-        q_t = qmat_t(cast(exp_et_t), beta_m)
+        q_t = phinorm(exp_et_t)                      # [K, W] x [K, BB]
         ratio_t = ct * _recip(q_t)
-        s_t = jax.lax.dot_general(                   # [K, W] x [W, BB]
-            beta_m, cast(ratio_t), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=dot_precision,
-        )
+        s_t = gamma_update(ratio_t)                  # [K, W] x [W, BB]
         gamma_new = alpha + exp_et_t * s_t
         delta = jnp.max(
             jnp.mean(jnp.abs(gamma_new - gamma_t), axis=0, keepdims=True)
@@ -671,7 +855,7 @@ def _block_e_step_w(
     # _dense_kernel).
     e_lt = e_log_theta_t(gamma_t)
     exp_et_t = jnp.exp(e_lt)
-    q_t = qmat_t(exp_et_t, beta)
+    q_t = tail_phinorm(exp_et_t)                     # [K, W] x [K, BB]
     ratio_t = (ct * _recip(q_t)) * mask
     tok = jnp.sum(ct * jnp.log(q_t), axis=0, keepdims=True)
     core = jnp.sum(
@@ -771,7 +955,8 @@ def dense_fixed_point_w(
     _check_corpus_rank(dense_counts_t, batch_index)
     k_topics, v = exp_beta.shape
     b = dense_counts_t.shape[-1]
-    bb = block or pick_block_w(b, v, k_topics, precision)
+    bb = block or pick_block_w(b, v, k_topics, precision,
+                               dense_counts_t.dtype)
     if bb is None:
         raise ValueError(
             f"no W-major-feasible doc block for B={b}, V={v}, K={k_topics} "
@@ -825,7 +1010,8 @@ def dense_fixed_point_w(
             jax.ShapeDtypeStruct((grid, 1), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision)
+            vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision,
+                                         dense_counts_t.dtype)
         ),
         interpret=interpret,
         name="dense_estep_wmajor",
@@ -867,7 +1053,7 @@ def dense_fixed_point(
     _check_corpus_rank(dense_counts, batch_index)
     k_topics, v = exp_beta.shape
     b = dense_counts.shape[-2]
-    bb = block or pick_block(b, v, k_topics, precision)
+    bb = block or pick_block(b, v, k_topics, precision, dense_counts.dtype)
     if bb is None:
         raise ValueError(
             f"no VMEM-feasible doc block for B={b}, V={v}, K={k_topics}"
@@ -919,7 +1105,8 @@ def dense_fixed_point(
             jax.ShapeDtypeStruct((grid, 1), jnp.int32),
         ],
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision)
+            vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision,
+                                         dense_counts.dtype)
         ),
         interpret=interpret,
         name="dense_estep_rowmajor",

@@ -261,8 +261,9 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
     def local(log_beta_l, alpha, c_l, doc_mask, gamma_prev, warm,
               var_max_iters, var_tol):
         k = log_beta_l.shape[0]
-        # The kernel's products (dense_estep._products): bf16 operands
-        # under "bf16", float32 ones at HIGHEST on an int16 store.
+        # The products of dense_estep._products: bf16 operands under
+        # "bf16", float32 ones at HIGHEST on an int16 store (XLA here: the
+        # kernels' packed products are Mosaic's, dense_estep._sweep_products).
         cast, dot_precision = dense_estep._products(c_l.dtype, precision)
         beta_l = jnp.exp(log_beta_l)               # [K, W_l]
         beta_m = cast(beta_l)

@@ -223,3 +223,25 @@ def test_the_wmajor_kernel_reads_an_int16_stack_in_place_on_one_chip(topo):
     assert not [rest[:60] for _, rest in _instructions(compiled)
                 if re.match(rf"f32\[{V},{ROWS}\]", rest)]
 
+
+
+def test_the_rowmajor_kernel_packs_the_int16_products_on_one_chip(topo):
+    """The row-major kernel over an int16 batch [4096, 8192]: Mosaic lowers
+    the packed products' lane-wise stacks, the exact placement products
+    and the gamma update's lane slices at offsets of 24 (the W-major
+    kernel's are sublane-wise: the test above)."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def e_step(lb, a, c, m, g, w):
+        return dense_estep.e_step_dense(
+            lb, a, c, m, var_max_iters=20, var_tol=1e-6, gamma_prev=g,
+            warm=w, precision="bf16")
+
+    compiled = jax.jit(e_step).lower(
+        shape((K, V)), shape(()), shape((ROWS, V), jnp.int16),
+        shape((ROWS,)), shape((ROWS, K)), shape((), jnp.int32)).compile()
+    assert _kernels(compiled)
+    assert dense_estep.kernel_products(jnp.int16, K) == "split6_packed"

@@ -368,11 +368,18 @@ def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
     padded_tokens = sum(b.word_idx.size for b in batches)
     rec = spans.Recorder()
     with spans.use_recorder(rec):
-        train_corpus(corpus, cfg)
+        result = train_corpus(corpus, cfg)
     plan, = [e["args"] for e in rec.events if e["name"] == "fit.plan"]
     gate, = [e["args"] for e in rec.events if e["name"] == "fit.plan.gate"]
     densify, = [e["args"] for e in rec.events if e["name"] == "fit.densify"]
     assert plan["cell_scan"] == cell_scan
+    # the products the kernels take over the store, and the phinorm's MXU
+    # passes a sweep: packed on int16 (K = 4: 24 rows of contraction, one
+    # pass), XLA's DEFAULT on a floating store
+    products = "split6_packed" if stored == jnp.int16 else "default"
+    assert (plan["products"], plan["phinorm_passes"]) == (products, 1)
+    assert result.plan["products"] == {"value": products,
+                                       "phinorm_passes": 1}
     assert densify["dense_bytes"] == (
         _padded_rows(corpus, cfg) * dense_estep.padded_width(corpus.num_terms)
         * jnp.dtype(stored).itemsize)
@@ -611,6 +618,7 @@ def test_a_fit_under_the_profiler_puts_its_spans_in_the_trace(tmp_path):
     assert by_name["em.host_sync.counts"][0][2]["steps"] == 4
     plan = by_name["fit.plan.counts"][0][2]
     assert (plan["cell_scan"], plan["scan_tokens"]) == ("none", 0)
+    assert (plan["products"], plan["phinorm_passes"]) == ("default", 1)
     assert by_name["fit.densify"][0][2]["groups"] >= 1
     assert by_name["fit.densify.counts"][0][2]["dense_bytes"] > 0
     assert by_name["em.run_chunk"][0][2]["first"] in (1, "True", True)
