@@ -523,7 +523,7 @@ def bench_online_svi(k=20, v=8192, b=4096, l=128, steps=64, chunk=64):
     truly runs as one.  The stream's host->device transfer stays in the
     timed region — arriving micro-batch data is real steady-state
     cost.  One warm chunk absorbs compile + densify warmup;
-    dense_em='auto' picks the dense MXU E-step on TPU."""
+    OnlineLDAConfig.dense_em='auto' picks the dense MXU E-step on TPU."""
     from oni_ml_tpu.config import OnlineLDAConfig
     from oni_ml_tpu.io import Batch
     from oni_ml_tpu.models import OnlineLDATrainer
@@ -2104,7 +2104,7 @@ def _spawn_dist_workers(workdir, nprocs, mode, timeout=300.0,
     env = {
         k: v for k, v in os.environ.items()
         if k not in ("XLA_FLAGS", "JAX_PLATFORMS",
-                     "ONI_ML_TPU_ESTEP", "ONI_ML_TPU_ALLREDUCE_PRECISION")
+                     "ONI_ML_TPU_ALLREDUCE_PRECISION")
     }
     env["JAX_PLATFORMS"] = "cpu"
     if precision:

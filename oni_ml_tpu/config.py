@@ -14,6 +14,9 @@ import dataclasses
 import os
 from dataclasses import dataclass, field
 
+# The values of LDAConfig.estep_engine.
+ESTEP_ENGINES = ("auto", "dense", "compact", "sparse", "xla", "pallas")
+
 
 @dataclass(frozen=True)
 class LDAConfig:
@@ -108,19 +111,9 @@ class LDAConfig:
     # fused_em_chunk iterations (maximum throughput, coarsest
     # observability — a whole fit can be one dispatch).
     host_sync_every: int = 16
-    # Dense-corpus E-step (ops/dense_estep.py): "auto" densifies the corpus
-    # once and runs the gather/scatter-free MXU kernel when the device is a
-    # TPU, the doc blocks fit VMEM, and the dense corpus fits the HBM
-    # budget below; "on"/"off" force it.  When the FULL vocabulary is too
-    # wide (config-4 DNS scale), auto/"on" fall through to the
-    # compact-vocab dense variant — each batch remapped onto its own
-    # Wc-wide vocabulary slice (models/lda.py _plan_estep) — before
-    # giving up on the MXU path.  ONI_ML_TPU_ESTEP=dense/compact/xla/
-    # pallas overrides.
-    dense_em: str = "auto"
     # Device-byte ceiling (per device) for the densified corpus under
-    # dense_em="auto".  None (the default: not stated) follows the device:
-    # three quarters of its own memory limit (`memory_stats()`'s
+    # estep_engine="auto".  None (the default: not stated) follows the
+    # device: three quarters of its own memory limit (`memory_stats()`'s
     # `bytes_limit`), or 2 GiB where the backend reports none (the CPU) --
     # models/lda.py dense_budget.  A stated value is obeyed as it is.
     dense_hbm_budget: "int | None" = None
@@ -180,24 +173,30 @@ class LDAConfig:
     # (models/fused.densify_stack), holding one batch's float32 block,
     # never a float32 copy of a group (PERF.md).
     dense_precision: str = "f32"
-    # Store the dense corpus transposed ([W, B]) so the gamma-update
-    # matmul's small-K output axis pads to the 8-sublane granularity
-    # instead of the 128-lane tile (measured ~1.2x on the EM iteration;
-    # ops/dense_estep._dense_kernel_w).  False = row-major [B, W].
-    dense_wmajor: bool = True
-    # EM E-step engine family (single-process batch training):
-    # "dense" = today's dense-corpus family (full-V dense, compact-vocab
-    # fallback, XLA/Pallas sparse groups — everything gated by dense_em
-    # above); "sparse" = the fused sparse bucketed Pallas engine
-    # (ops/sparse_estep.py: corpus packed by Corpus.bucketed_layout,
-    # K×L work per doc instead of K×V); "auto" consults the MEASURED
-    # dense-vs-sparse crossover persisted in the plan cache
-    # (sparse_estep.engine_crossover — the dispatch_calibration pattern:
-    # measured once per backend+shape, source "plan" on run 2) on TPU
-    # and stays with the dense family elsewhere.  The sparse engine is
-    # single-process only; meshes keep the sharded dense/sparse plans.
-    # ONI_ML_TPU_ESTEP=sparse forces it; ONI_ML_TPU_ESTEP_ENGINE pins
-    # the crossover's answer without forcing infeasible shapes.
+    # Which E-step the batch trainer runs: the one selector of kernel and
+    # family, read by models/lda.py `estep_engine` (ESTEP_ENGINES above).
+    #   "auto": the measured choice.  On a TPU the dense-vs-sparse
+    #     crossover persisted in the plan cache (sparse_estep.
+    #     engine_crossover: measured once per backend+shape, source "plan"
+    #     on run 2) picks the family; within the dense family
+    #     LDATrainer._plan_estep takes full-width dense under the HBM
+    #     budget and the VMEM gates, else compact-vocab dense (each batch
+    #     remapped onto its own Wc-wide vocabulary slice), else token
+    #     lists through estep.e_step's own preference order.  Off the TPU:
+    #     token lists (XLA on the CPU).
+    #   "dense": full-width dense forced; a width with no VMEM-feasible
+    #     doc block is rescued through compact-vocab dense, else refused.
+    #   "compact": compact-vocab dense forced (single-process, stock
+    #     E-step).
+    #   "sparse": the fused sparse bucketed Pallas engine forced
+    #     (ops/sparse_estep.py: corpus packed by Corpus.bucketed_layout,
+    #     K×L work per doc instead of K×V); single-process per rank, so a
+    #     mesh refuses it.
+    #   "xla" / "pallas": token lists through that estep.e_step backend.
+    # The dense layout is no option: W-major ([W, B], the small-K output
+    # axis padding to 8 sublanes, not 128 lanes) wherever
+    # dense_estep.pick_block_w admits every per-shard batch shape, else
+    # row-major [B, W].
     estep_engine: str = "auto"
     # Minimum packed tile length for the sparse engine's bucketed
     # layout (Corpus.bucketed_layout min_len): buckets pad up to

@@ -35,7 +35,6 @@ from __future__ import annotations
 
 import functools
 import itertools
-import os
 import threading
 from collections import OrderedDict
 from contextlib import nullcontext
@@ -709,14 +708,14 @@ def _program_key(traced: dict) -> "tuple | None":
     id cannot be reused while its entry lives).  That tells meshes apart
     too: the makers of parallel/sharded.py hand out one object per mesh
     and settings, so two meshes are two programs and a second fit on a
-    mesh reuses the first one's.  Read at trace time from
-    outside the arguments: `ONI_ML_TPU_ESTEP` (estep.resolve_backend),
-    `jax.default_backend()` (the kernels' interpret flag and the engine
-    gates) and the plan cache's kernel blocks (`_traced_plan_blocks`,
-    which covers `ONI_ML_TPU_PLANS`, `ONI_ML_TPU_PLAN_CACHE` and
-    `plans.use_store`).  jax's own configuration (x64, matmul precision)
+    mesh reuses the first one's; so does a forced token backend, which
+    the trainer binds into `e_step_fn` (one object a backend).  Read at
+    trace time from outside the arguments: `jax.default_backend()` (the
+    kernels' interpret flag and the engine gates) and the plan cache's
+    kernel blocks (`_traced_plan_blocks`, which covers
+    `ONI_ML_TPU_PLANS`, `ONI_ML_TPU_PLAN_CACHE` and `plans.use_store`).  jax's own configuration (x64, matmul precision)
     is `jax.jit`'s to key, inside the kept function; the groups' shapes
-    and dtypes are its operands.  `ONI_ML_TPU_ESTEP_ENGINE` and the
+    and dtypes are its operands.  `LDAConfig.estep_engine` and the
     engine crossover are read by the driver on the host, before it
     chooses these arguments.  What a trace only SAYS is not keyed: the
     `estep_dispatch` log line and journal record (estep._report_dispatch)
@@ -731,7 +730,6 @@ def _program_key(traced: dict) -> "tuple | None":
               if name not in fns and name != "compiler_options"),
         None if options is None else tuple(sorted(options.items())),
         tuple(id(traced[name]) for name in fns),
-        os.environ.get("ONI_ML_TPU_ESTEP", "auto"),
         jax.default_backend(),
         _traced_plan_blocks(),
     )

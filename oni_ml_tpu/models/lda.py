@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.scipy.special import digamma, polygamma
 
-from ..config import LDAConfig
+from ..config import ESTEP_ENGINES, LDAConfig
 from ..io import Batch, Corpus, formats, make_batches
 from ..ops import estep
 from ..telemetry.spans import current_recorder, maybe_span, now_ns
@@ -248,14 +248,26 @@ def init_log_beta(key: jax.Array, k: int, v: int, dtype=jnp.float32) -> jnp.ndar
     return jnp.log(noise / noise.sum(-1, keepdims=True))
 
 
-def _estep_env() -> str:
-    """ONI_ML_TPU_ESTEP, the operator's pin of the E-step ("" when unset):
-    "dense" / "compact" / "sparse" pin a family, "xla" / "pallas" stand the
-    dense family down.  Read here for the engine family
-    (`resolve_estep_engine`) and once a fit for the fused driver's plan
-    (`LDATrainer._plan_estep`); estep.resolve_backend reads it on its own
-    account when a token-list E-step is traced."""
-    return os.environ.get("ONI_ML_TPU_ESTEP", "")
+def _estep_engine(config: LDAConfig) -> str:
+    """`config.estep_engine`, the one selector of the batch trainer's
+    E-step, checked against config.ESTEP_ENGINES.  Read for the engine
+    family (`resolve_estep_engine`), the backend a stock token-list
+    E-step is bound to (`_token_backend`) and the fused driver's kernel
+    and layout (`LDATrainer._plan_estep`)."""
+    engine = config.estep_engine
+    if engine not in ESTEP_ENGINES:
+        raise ValueError(
+            f"LDAConfig.estep_engine={engine!r}: expected one of "
+            + ", ".join(ESTEP_ENGINES)
+        )
+    return engine
+
+
+def _token_backend(config: LDAConfig) -> str:
+    """The estep.e_step backend the engine forces over token lists
+    ("xla", "pallas" or "sparse"), else "auto"."""
+    engine = _estep_engine(config)
+    return engine if engine in ("xla", "pallas", "sparse") else "auto"
 
 
 class _EStepPlan(NamedTuple):
@@ -348,7 +360,15 @@ class LDATrainer:
         self._shard_batches = shard_batches
         self.yield_hook = yield_hook
         self._partial_runner = None  # distributed-loop jit, fit-reused
+        # The stock E-step carries the backend the engine forces, bound
+        # once (one object a backend: the chunk program is found again).
+        self._stock_e = e_step_fn is None
         base = e_step_fn or estep.e_step
+        backend = _token_backend(config)
+        if self._stock_e and backend != "auto":
+            from ..parallel import sharded
+
+            base = sharded.bound(estep.e_step, backend=backend)
         self._e_base = base
         self._m_base = m_step_fn or estep.m_step
         self._e_step = jax.jit(
@@ -1011,7 +1031,7 @@ class LDATrainer:
                 "rows_per_shard_min": int(rows.min()),
                 "rows_per_shard_max": int(rows.max())}
 
-    def _use_dense_vocab_sharded(self, batches, mode, budget) -> bool:
+    def _use_dense_vocab_sharded(self, batches, forced, budget) -> bool:
         """Gate for the vocab-sharded dense plan
         (parallel.make_vocab_sharded_dense_e_step): an XLA-level matmul
         fixed point with C and beta sharded over `model` — config 4's
@@ -1035,10 +1055,10 @@ class LDATrainer:
             else None
         )
         if incompatible:
-            if mode == "on":
+            if forced:
                 raise ValueError(f"dense E-step forced but {incompatible}")
             return False
-        if mode == "on":
+        if forced:
             return True
         if jax.default_backend() != "tpu":
             return False
@@ -1061,14 +1081,16 @@ class LDATrainer:
         here once and handed to `_fused_loop` as one record.  Nothing is
         placed and the trainer is left as it was.
 
-        *Full-width dense* (ops/dense_estep.py; `dense_em`, forced by
-        ONI_ML_TPU_ESTEP=dense).  Auto needs a TPU backend, the stock
-        E-step or this package's own sharded wrappers (a user's custom
-        e_step_fn must not be silently bypassed), a VMEM-feasible doc
-        block for every PER-SHARD batch shape, and the densified corpus
-        under the HBM budget (`dense_budget`: the configuration's where it
-        states one, else three quarters of the device's own memory
-        limit).  With a data mesh the Pallas kernel runs
+        What runs follows from `LDAConfig.estep_engine` and the gates.
+
+        *Full-width dense* (ops/dense_estep.py; forced by "dense").  Auto
+        needs a TPU backend, the stock E-step or this package's own
+        sharded wrappers (a user's custom e_step_fn must not be silently
+        bypassed), a VMEM-feasible doc block for every PER-SHARD batch
+        shape, and the densified corpus under the HBM budget
+        (`dense_budget`: the configuration's where it states one, else
+        three quarters of the device's own memory limit).  With a data
+        mesh the Pallas kernel runs
         under shard_map (parallel.make_data_parallel_dense_e_step),
         suff-stats psum'd over ICI; a vocab-sharded trainer takes the
         XLA-level make_vocab_sharded_dense_e_step plan instead
@@ -1077,21 +1099,24 @@ class LDATrainer:
         *Compact-vocab dense* (fused.CompactPlan: each batch densified
         over its own words, when the full vocabulary is too wide).  Auto
         needs the TPU backend, the stock E-step and the compacted corpus
-        under the HBM budget; ONI_ML_TPU_ESTEP=compact forces it (tests /
-        interpret runs); forced dense with no feasible full-width block
-        is rescued through it.  Single-process only — the multi-chip
-        huge-V story is the vocab-sharded dense plan (parallel/sharded.py).
+        under the HBM budget; "compact" forces it (tests / interpret
+        runs); forced dense with no feasible full-width block is rescued
+        through it.  Single-process only — the multi-chip huge-V story is
+        the vocab-sharded dense plan (parallel/sharded.py).  Either dense
+        family is W-major wherever dense_estep.pick_block_w admits every
+        per-shard shape, else row-major.
 
         *Token lists* otherwise: `e_step_fn` serves the stacked
         (word_idx, counts, mask) groups (the sparse engine, the sharded
-        wrappers, estep.e_step's own preference order, a custom fn)."""
+        wrappers, estep.e_step's own preference order or the backend the
+        engine forces, a custom fn)."""
         from ..ops import dense_estep
 
         cfg = self.config
         k, v = cfg.num_topics, self.num_terms
         precision = cfg.dense_precision
         budget, budget_source = dense_budget(cfg, self.mesh)
-        env = _estep_env()
+        engine = _estep_engine(cfg)
         on_tpu = jax.default_backend() == "tpu"
         shards = 1
         if self.mesh is not None:
@@ -1122,26 +1147,20 @@ class LDATrainer:
         # and VMEM limits are per (rows a shard, L) shape.
         shapes = sorted({b.word_idx.shape for b in batches})
         local = [(rows // shards, length) for rows, length in shapes]
-        custom = (self._e_base is not estep.e_step
+        custom = (not self._stock_e
                   and not getattr(self._e_base, "_oni_data_parallel", False))
 
-        # "compact" forces the compact-vocab variant (full-width dense
-        # stands down); "sparse" / "xla" / "pallas" stand the whole dense
-        # family down.
-        mode = {"dense": "on", "compact": "off", "xla": "off",
-                "pallas": "off", "sparse": "off"}.get(env, cfg.dense_em)
-        if mode not in ("auto", "on", "off"):
-            raise ValueError(
-                f"LDAConfig.dense_em={mode!r}: expected 'auto', 'on', or "
-                "'off'"
-            )
+        # Full-width dense is weighed on "auto" and forced by "dense"; every
+        # other engine stands it down ("compact" takes the compact-vocab
+        # variant below, the rest token lists).
         dense, compact = False, None
-        if mode == "off":
+        if engine not in ("auto", "dense"):
             pass
         elif self.vocab_sharded:
-            dense = self._use_dense_vocab_sharded(batches, mode, budget)
+            dense = self._use_dense_vocab_sharded(
+                batches, engine == "dense", budget)
         elif custom:
-            if mode == "on":
+            if engine == "dense":
                 raise ValueError(
                     "dense E-step forced but a custom e_step_fn is installed")
         else:
@@ -1149,7 +1168,7 @@ class LDATrainer:
                 dense_estep.pick_block(rows, v, k, precision) is not None
                 for rows, _ in local
             )
-            if mode == "auto":
+            if engine == "auto":
                 # Peak device memory during densify_groups holds BOTH the
                 # sparse stacked arrays (scatter inputs; int32 idx + f32
                 # counts) and the dense output at the store's itemsize
@@ -1174,8 +1193,7 @@ class LDATrainer:
                 # rescue through it when it can serve (single-process,
                 # per-batch widths blockable), else keep the hard error.
                 if self.mesh is None:
-                    compact = fused.plan_compact(
-                        batches, k, precision, wmajor=cfg.dense_wmajor)
+                    compact = fused.plan_compact(batches, k, precision)
                 if compact is None:
                     raise ValueError(
                         "dense E-step forced but a batch shape has no "
@@ -1184,23 +1202,20 @@ class LDATrainer:
                     )
 
         # The compact variant on its own account: forced, or on auto where
-        # full-width dense stood down.  Any other ONI_ML_TPU_ESTEP value
-        # rules it out.
-        compact_mode = ("on" if env == "compact"
-                        else "off" if env else cfg.dense_em)
+        # full-width dense stood down.
         blocked = (
             "a mesh is active (the multi-chip huge-V story is the "
             "vocab-sharded dense plan)"
             if self.mesh is not None or self.vocab_sharded
             else "a custom e_step_fn is installed"
-            if self._e_base is not estep.e_step
+            if not self._stock_e
             else None
         )
-        if env == "compact" and blocked:
+        if engine == "compact" and blocked:
             raise ValueError(f"compact dense E-step forced but {blocked}")
         try_compact = (
             not dense and compact is None and not blocked
-            and (compact_mode == "on" or compact_mode == "auto" and on_tpu))
+            and (engine == "compact" or engine == "auto" and on_tpu))
 
         store, cell_scan, scan_tokens = None, "none", 0
         if dense or compact is not None or try_compact:
@@ -1209,15 +1224,15 @@ class LDATrainer:
             store, cell_scan, scan_tokens = store_gate()
         if try_compact:
             compact = fused.plan_compact(
-                batches, k, precision, wmajor=cfg.dense_wmajor,
+                batches, k, precision,
                 itemsize=jnp.dtype(store).itemsize, store=store,
             )
-            if compact is None and compact_mode == "on":
+            if compact is None and engine == "compact":
                 raise ValueError(
                     "compact dense E-step forced but a batch's compact "
                     "width admits no VMEM-feasible doc block"
                 )
-            if compact is not None and compact_mode == "auto":
+            if compact is not None and engine == "auto":
                 # Peak device memory: the whole compacted corpus plus the
                 # largest single group's sparse stacks
                 # (compact_stack_batches uploads sparse arrays one group
@@ -1264,7 +1279,7 @@ class LDATrainer:
         elif dense:
             # W-major needs the doc axis on the 128-lane dimension; fall
             # back to row-major when any batch shape can't block that way.
-            wmajor = cfg.dense_wmajor and all(
+            wmajor = all(
                 dense_estep.pick_block_w(rows, v, k, precision, store)
                 for rows, _ in local
             )
@@ -1331,10 +1346,12 @@ class LDATrainer:
             elif getattr(self._e_base, "_oni_vocab_sharded", False):
                 plan["kernel"] = "xla_vocab_sharded"
             elif not custom:
-                # estep.e_step's own preference order, at the shape each
-                # device's call sees (it reports the refusals itself).
+                # estep.e_step's own preference order or the backend the
+                # engine forces, at the shape each device's call sees (it
+                # reports the refusals itself).
+                backend = _token_backend(cfg)
                 plan["kernel"] = "+".join(sorted({
-                    estep.resolve_backend("auto", rows, length, k, v)[0]
+                    estep.resolve_backend(backend, rows, length, k, v)[0]
                     for rows, length in local
                 }))
             else:
@@ -1820,14 +1837,13 @@ def resolve_estep_engine(
 
     "sparse" is the fused bucketed Pallas engine (ops/sparse_estep.py:
     corpus packed by Corpus.bucketed_layout, K×L work per doc);
-    "dense" is everything that exists today — the dense/compact/XLA/
-    Pallas family, chosen within by LDATrainer._plan_estep and
-    estep.e_step's auto.  Precedence mirrors the rest of
-    the plan layer: ONI_ML_TPU_ESTEP env ("env") > an explicit
-    LDAConfig.estep_engine ("config") > the MEASURED dense-vs-sparse
-    crossover from the plan cache (sparse_estep.engine_crossover —
-    source "plan" when a persisted entry serves, "measured" when this
-    run sweeps it once) on TPU, else the dense family ("default").
+    "dense" is the rest — the dense/compact/XLA/Pallas family, chosen
+    within by LDATrainer._plan_estep and estep.e_step's auto.  Any
+    LDAConfig.estep_engine but "auto" names its family ("config");
+    "auto" takes the MEASURED dense-vs-sparse crossover from the plan
+    cache (sparse_estep.engine_crossover — source "plan" when a
+    persisted entry serves, "measured" when this run sweeps it once) on
+    TPU, else the dense family ("default").
 
     The sparse engine is single-process PER RANK — a mesh whose data
     axis would shard its layout still takes the dense family, and
@@ -1839,38 +1855,23 @@ def resolve_estep_engine(
     crossover is consulted at the dominant LOCAL shard shape — the
     shapes the kernel will actually see, which per-shard batching makes
     smaller than the whole-corpus shapes."""
-    env = _estep_env()
-    choice = config.estep_engine
-    if choice not in ("auto", "dense", "sparse"):
-        raise ValueError(
-            f"LDAConfig.estep_engine={choice!r}: expected 'auto', "
-            "'dense', or 'sparse'"
-        )
-    forced_sparse = env == "sparse" or (not env and choice == "sparse")
+    engine = _estep_engine(config)
     if mesh is not None or vocab_sharded:
-        if forced_sparse:
+        if engine == "sparse":
             raise ValueError(
                 "the sparse bucketed E-step engine is single-process; "
                 "meshes keep the sharded dense/sparse plans "
-                "(unset ONI_ML_TPU_ESTEP=sparse / estep_engine='sparse'"
+                "(choose another estep_engine"
                 + (" or drop the local mesh — distributed EM runs the "
                    "sparse engine host-locally without one)"
                    if distributed else ")")
             )
         return "dense", "default"
-    if forced_sparse and config.dense_em == "on":
-        raise ValueError(
-            "estep_engine='sparse' conflicts with dense_em='on' — pin "
-            "one engine family, not both"
-        )
-    if env:
-        return ("sparse", "env") if env == "sparse" else ("dense", "env")
-    if choice != "auto":
-        return choice, "config"
-    if jax.default_backend() != "tpu" or config.dense_em == "on":
-        # CPU/interpret runs keep today's paths (the dense family's
-        # auto already resolves to XLA there); dense_em="on" is an
-        # explicit family pin.
+    if engine != "auto":
+        return ("sparse" if engine == "sparse" else "dense"), "config"
+    if jax.default_backend() != "tpu":
+        # CPU/interpret runs keep the dense family (whose auto resolves
+        # to XLA token lists there).
         return "dense", "default"
     from ..ops import sparse_estep
 
@@ -2208,7 +2209,8 @@ def _mesh_trainer_setup(corpus: Corpus, config: LDAConfig, mesh,
             corpus, config, mesh
         )
     else:
-        e_fn = sharded.make_data_parallel_e_step(mesh)
+        e_fn = sharded.make_data_parallel_e_step(
+            mesh, _token_backend(config))
         m_fn = None
         num_terms = corpus.num_terms
         initial_log_beta = None

@@ -221,25 +221,12 @@ def resolve_backend(backend: str, b: int, l: int, k: int,
     "auto" prefers the fused sparse Pallas kernel, then the
     fixed-point-only Pallas kernel, then plain XLA; both kernels need a
     TPU backend and a VMEM-feasible doc block.  A forced engine whose
-    shape gate says no raises — it never falls through."""
-    import os
-
-    if backend == "auto":
-        env = os.environ.get("ONI_ML_TPU_ESTEP", "auto")
-        # "dense"/"compact" in the env are DRIVER-level hints (models/lda.py
-        # picks them up in LDATrainer._plan_estep, where the densification
-        # is amortized across the run).  Honoring them per call here would
-        # re-scatter the batch every EM iteration — the exact cost the dense
-        # paths exist to avoid — so auto dispatch ignores them; only an
-        # explicit backend="dense" argument densifies inline.  "sparse"
-        # passes through: the fused sparse kernel has no per-call setup
-        # to amortize, so forcing it per call is well-defined.
-        backend = "auto" if env in ("dense", "compact") else env
+    shape gate says no raises — it never falls through.  Reads only its
+    arguments and `jax.default_backend()`."""
     if backend not in _BACKENDS:
         raise ValueError(
-            f"unknown E-step backend {backend!r} (set via ONI_ML_TPU_ESTEP "
-            "or the backend= argument); expected auto, xla, pallas, "
-            "sparse, or dense"
+            f"unknown E-step backend {backend!r}; expected auto, xla, "
+            "pallas, sparse, or dense"
         )
     if backend == "xla":
         return "xla", {}
@@ -256,8 +243,7 @@ def resolve_backend(backend: str, b: int, l: int, k: int,
                      else f"B={b}, L={l}, K={k}")
             raise ValueError(
                 f"{backend} E-step forced but {shape} has no "
-                "VMEM-feasible doc block (unset "
-                f"ONI_ML_TPU_ESTEP={backend} or reduce the batch"
+                "VMEM-feasible doc block (reduce the batch"
                 + ("/vocab size)" if backend == "dense" else ")")
             )
         return backend, {}
@@ -319,9 +305,10 @@ def e_step(
     shapes admit it (ops/sparse_estep.py — fixed point AND suff-stats/
     ELBO tail in one VMEM residency), else the fixed-point-only Pallas
     kernel (ops/pallas_estep.py), else pure XLA; "xla" / "pallas" /
-    "sparse" / "dense" force a path (ONI_ML_TPU_ESTEP env var overrides
-    "auto").  "dense" densifies the batch per call — drivers that own the
-    batches amortize the densification instead (models/fused.py).  The
+    "sparse" / "dense" force a path (the batch trainer binds the one
+    LDAConfig.estep_engine names).  "dense" densifies the batch per call
+    — drivers that own the batches amortize the densification instead
+    (models/fused.py).  The
     choice is `resolve_backend`'s, and every call reports it.
     """
     b, l = word_idx.shape

@@ -49,7 +49,6 @@ oni-lda-c's per-document inner loop (SURVEY.md §2.8, §3.3).
 from __future__ import annotations
 
 import functools
-import os
 import time
 
 import jax
@@ -628,22 +627,10 @@ def engine_crossover(k: int, v: int, b: int, l: int, *,
     (knob `estep_engine`, exact shape beating the density band —
     source "plan", so run 2 re-measures nothing), else a fresh
     measurement persisted under BOTH keys with its timings as
-    provenance.  ONI_ML_TPU_ESTEP_ENGINE=sparse|dense overrides with a
-    pin (source "env").  Every resolution journals a
+    provenance.  Every resolution journals a
     {"kind": "estep_crossover"} record under an active recorder."""
     _check_precision(precision)
     exact, band = crossover_shapes(k, v, b, l, precision)
-    env = os.environ.get("ONI_ML_TPU_ESTEP_ENGINE", "")
-    if env:
-        if env not in ("sparse", "dense"):
-            raise ValueError(
-                f"ONI_ML_TPU_ESTEP_ENGINE={env!r}: expected sparse or "
-                "dense"
-            )
-        rec = {"engine": env, "dense_s": None, "sparse_s": None,
-               "source": "env", "shape": exact}
-        _journal_crossover(rec)
-        return rec
     if not force and exact in _CROSSOVER_CACHE:
         return _CROSSOVER_CACHE[exact]
     if not force:

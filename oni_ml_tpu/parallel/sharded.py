@@ -65,16 +65,17 @@ def _fresh_warm_fill(log_beta, word_idx):
 
 
 @_memoised
-def make_data_parallel_e_step(mesh: Mesh):
+def make_data_parallel_e_step(mesh: Mesh, backend: str = "auto"):
     """e_step-compatible callable: inputs batch-sharded over `data`,
-    outputs gamma sharded / reductions replicated.  The same object for
-    the same mesh."""
+    outputs gamma sharded / reductions replicated; each shard runs
+    estep.e_step with `backend`.  The same object for the same mesh and
+    backend."""
 
     def local(log_beta, alpha, word_idx, counts, doc_mask, gamma_prev,
               warm, var_max_iters, var_tol):
         res = estep.e_step(
             log_beta, alpha, word_idx, counts, doc_mask, var_max_iters,
-            var_tol, gamma_prev=gamma_prev, warm=warm,
+            var_tol, backend=backend, gamma_prev=gamma_prev, warm=warm,
         )
         return estep.EStepResult(
             gamma=res.gamma,
@@ -237,8 +238,8 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
 
     The batch trainer selects this plan automatically
     (models/lda.py _use_dense_vocab_sharded) when the trainer is
-    vocab-sharded, dense_em allows it, and the per-device corpus slices
-    fit the HBM budget; the per-EM-iteration semantics are pinned to the
+    vocab-sharded, LDAConfig.estep_engine is "auto" or "dense", and the
+    per-device corpus slices fit the HBM budget; the per-EM-iteration semantics are pinned to the
     unwrapped dense kernel by
     tests/test_sharded.py::test_vocab_sharded_DENSE_e_step_parity and
     end-to-end by test_full_training_parity_vocab_sharded_dense.

@@ -7,6 +7,7 @@ suff-stats semantics.  Runs in Pallas interpret mode on the CPU backend
 sparse kernel.
 """
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -228,7 +229,8 @@ def test_fused_runner_dense_groups_match_sparse():
 
 
 def test_trainer_dense_mode_matches_sparse():
-    """LDATrainer end-to-end with dense_em='on' vs 'off' on a tiny corpus."""
+    """LDATrainer end-to-end with estep_engine='dense' vs 'xla' on a tiny
+    corpus."""
     from oni_ml_tpu.models.lda import LDATrainer
 
     rng = np.random.default_rng(5)
@@ -241,10 +243,10 @@ def test_trainer_dense_mode_matches_sparse():
         doc_index=np.arange(b),
     )
     results = {}
-    for mode in ("on", "off"):
+    for mode in ("dense", "xla"):
         cfg = LDAConfig(
             num_topics=4, em_max_iters=6, em_tol=0.0,
-            var_max_iters=20, fused_em_chunk=3, seed=1, dense_em=mode,
+            var_max_iters=20, fused_em_chunk=3, seed=1, estep_engine=mode,
             # This test pins dense-vs-sparse NUMERICS; warm start (dense
             # only) would make the trajectories differ by design.
             warm_start_gamma=False,
@@ -252,7 +254,7 @@ def test_trainer_dense_mode_matches_sparse():
         trainer = LDATrainer(cfg, num_terms=v)
         results[mode] = trainer.fit([batch], num_docs=b - 2)
 
-    on, off = results["on"], results["off"]
+    on, off = results["dense"], results["xla"]
     np.testing.assert_allclose(on.log_beta, off.log_beta, rtol=5e-3, atol=5e-3)
     np.testing.assert_allclose(
         [ll for ll, _ in on.likelihoods],
@@ -273,20 +275,13 @@ def test_explicit_block_must_divide_batch():
         )
 
 
-def test_dense_em_typo_raises():
+def test_estep_engine_typo_raises():
     from oni_ml_tpu.models.lda import LDATrainer
 
-    trainer = LDATrainer(
-        LDAConfig(num_topics=4, dense_em="true"), num_terms=200
-    )
-    batch = Batch(
-        word_idx=np.zeros((16, 8), np.int32),
-        counts=np.zeros((16, 8), np.float32),
-        doc_mask=np.ones((16,), np.float32),
-        doc_index=np.arange(16),
-    )
-    with pytest.raises(ValueError, match="dense_em"):
-        trainer._plan_estep([batch])
+    with pytest.raises(ValueError, match="estep_engine='true': expected "
+                       "one of auto, dense, compact, sparse, xla, pallas"):
+        LDATrainer(LDAConfig(num_topics=4, estep_engine="true"),
+                   num_terms=200)
 
 
 def test_forced_dense_with_vocab_sharding_raises():
@@ -298,8 +293,8 @@ def test_forced_dense_with_vocab_sharding_raises():
     with pytest.warns(UserWarning, match="left idle"):
         mesh = make_mesh(data=2, model=2)   # 2x2 of the 8 virtual devices
     trainer = LDATrainer(
-        LDAConfig(num_topics=4, dense_em="on"), num_terms=200, mesh=mesh,
-        vocab_sharded=True,
+        LDAConfig(num_topics=4, estep_engine="dense"), num_terms=200,
+        mesh=mesh, vocab_sharded=True,
     )
     batch = Batch(
         word_idx=np.zeros((16, 8), np.int32),
@@ -381,7 +376,7 @@ def test_trainer_dense_data_mesh_matches_unsharded():
     corpus = corpus_from_docs(docs, 200)
     base = LDAConfig(num_topics=3, em_max_iters=6, em_tol=0.0,
                      batch_size=32, min_bucket_len=64, seed=4,
-                     fused_em_chunk=3, dense_em="on")
+                     fused_em_chunk=3, estep_engine="dense")
     mesh = make_mesh(data=4, model=1, devices=jax.devices()[:4])
     res_mesh = train_corpus(corpus, base, mesh=mesh)
     res_single = train_corpus(corpus, base)
@@ -392,29 +387,6 @@ def test_trainer_dense_data_mesh_matches_unsharded():
     )
     np.testing.assert_allclose(
         res_mesh.log_beta, res_single.log_beta, rtol=5e-3, atol=5e-3
-    )
-
-
-def test_env_dense_does_not_leak_into_auto_dispatch(monkeypatch):
-    """ONI_ML_TPU_ESTEP=dense is a driver hint; per-call e_step auto must
-    not densify inline (that would re-scatter every EM iteration) and must
-    not raise on shapes the dense path can't block."""
-    monkeypatch.setenv("ONI_ML_TPU_ESTEP", "dense")
-    rng = np.random.default_rng(9)
-    # B=5 has no feasible dense block (not divisible by 8): auto dispatch
-    # must still succeed via the sparse paths.
-    word_idx, counts, doc_mask = _random_batch(rng, 5, 8, 60, 0)
-    log_beta = _log_beta(rng, 3, 60)
-    res = estep.e_step(
-        log_beta, 2.5, word_idx, counts, doc_mask,
-        var_max_iters=5, var_tol=1e-6,
-    )
-    ref = estep.e_step(
-        log_beta, 2.5, word_idx, counts, doc_mask,
-        var_max_iters=5, var_tol=1e-6, backend="xla",
-    )
-    np.testing.assert_allclose(
-        np.asarray(res.gamma), np.asarray(ref.gamma), rtol=1e-5
     )
 
 
@@ -446,7 +418,7 @@ def test_scoped_vmem_kib_and_multibatch_groups():
 def test_use_dense_auto_is_off_on_cpu():
     from oni_ml_tpu.models.lda import LDATrainer
 
-    cfg = LDAConfig(num_topics=4, dense_em="auto")
+    cfg = LDAConfig(num_topics=4, estep_engine="auto")
     trainer = LDATrainer(cfg, num_terms=200)
     batch = Batch(
         word_idx=np.zeros((16, 8), np.int32),
@@ -622,7 +594,7 @@ def test_trainer_dense_precision_bf16_tracks_f32():
         cfg = LDAConfig(
             num_topics=4, em_max_iters=5, em_tol=0.0,
             var_max_iters=20, fused_em_chunk=3, seed=1,
-            dense_em="on", dense_precision=prec,
+            estep_engine="dense", dense_precision=prec,
             # This test pins bf16-vs-f32 NUMERICS; warm start compounds
             # start-point differences across EM iterations by design.
             warm_start_gamma=False,
@@ -1056,10 +1028,9 @@ def test_plan_compact_widths_and_grouping():
     assert plan.corpus_bytes == expect
 
 
-def test_trainer_compact_mode_matches_sparse(monkeypatch):
-    """LDATrainer end-to-end: ONI_ML_TPU_ESTEP=compact (forced compact-
-    vocab dense) vs dense_em='off' on a tiny corpus with two batch
-    shapes."""
+def test_trainer_compact_mode_matches_sparse():
+    """LDATrainer end-to-end: estep_engine='compact' (forced compact-
+    vocab dense) vs 'xla' on a tiny corpus with two batch shapes."""
     from oni_ml_tpu.models.lda import LDATrainer
 
     rng = np.random.default_rng(23)
@@ -1074,21 +1045,16 @@ def test_trainer_compact_mode_matches_sparse(monkeypatch):
 
     batches = [batch(16, 16, 0), batch(16, 16, 16), batch(8, 8, 32)]
     results = {}
-    for force in ("compact", ""):
-        if force:
-            monkeypatch.setenv("ONI_ML_TPU_ESTEP", force)
-        else:
-            monkeypatch.delenv("ONI_ML_TPU_ESTEP", raising=False)
+    for engine in ("compact", "xla"):
         cfg = LDAConfig(
             num_topics=4, em_max_iters=6, em_tol=0.0,
             var_max_iters=20, fused_em_chunk=3, seed=1,
-            dense_em="off" if not force else "auto",
-            warm_start_gamma=False,
+            estep_engine=engine, warm_start_gamma=False,
         )
         trainer = LDATrainer(cfg, num_terms=v)
-        results[force] = trainer.fit(batches, num_docs=40)
+        results[engine] = trainer.fit(batches, num_docs=40)
 
-    on, off = results["compact"], results[""]
+    on, off = results["compact"], results["xla"]
     np.testing.assert_allclose(on.log_beta, off.log_beta, rtol=5e-3,
                                atol=5e-3)
     np.testing.assert_allclose(
@@ -1100,7 +1066,7 @@ def test_trainer_compact_mode_matches_sparse(monkeypatch):
     np.testing.assert_allclose(on.gamma, off.gamma, rtol=5e-3, atol=5e-3)
 
 
-def test_trainer_compact_warm_start_trajectory(monkeypatch):
+def test_trainer_compact_warm_start_trajectory():
     """Warm start through the compact path: same optimum as the fresh
     compact run within tolerance (mirrors the dense warm-start pin)."""
     from oni_ml_tpu.models.lda import LDATrainer
@@ -1112,12 +1078,12 @@ def test_trainer_compact_warm_start_trajectory(monkeypatch):
         word_idx=np.asarray(w), counts=np.asarray(c),
         doc_mask=np.asarray(m), doc_index=np.arange(16),
     )
-    monkeypatch.setenv("ONI_ML_TPU_ESTEP", "compact")
     res = {}
     for warm in (True, False):
         cfg = LDAConfig(
             num_topics=4, em_max_iters=8, em_tol=0.0, var_max_iters=20,
             fused_em_chunk=3, seed=1, warm_start_gamma=warm,
+            estep_engine="compact",
         )
         res[warm] = LDATrainer(cfg, num_terms=v).fit([batch], num_docs=16)
     np.testing.assert_allclose(
@@ -1130,7 +1096,7 @@ def test_trainer_compact_warm_start_trajectory(monkeypatch):
 
 
 def test_forced_dense_infeasible_rescues_to_compact():
-    """dense_em='on' with a full-V-infeasible vocabulary but feasible
+    """estep_engine='dense' with a full-V-infeasible vocabulary but feasible
     per-batch compact widths must route to the compact plan instead of
     raising."""
     from oni_ml_tpu.models.lda import LDATrainer
@@ -1144,23 +1110,22 @@ def test_forced_dense_infeasible_rescues_to_compact():
         doc_mask=np.asarray(m), doc_index=np.arange(16),
     )
     trainer = LDATrainer(
-        LDAConfig(num_topics=4, dense_em="on"), num_terms=v
+        LDAConfig(num_topics=4, estep_engine="dense"), num_terms=v
     )
     plan = trainer._plan_estep([batch])
     assert plan.family == "compact" and plan.kernel.startswith("compact_")
     assert plan.compact.widths[0] <= 512  # 16x16 tokens -> tiny compact width
 
 
-def test_forced_compact_with_mesh_raises(monkeypatch):
-    """ONI_ML_TPU_ESTEP=compact on a meshed trainer must fail loudly
-    (like every other forced-engine misconfiguration), not silently run
+def test_forced_compact_with_mesh_raises():
+    """estep_engine='compact' on a meshed trainer must fail loudly (like
+    every other forced-engine misconfiguration), not silently run
     sparse."""
     from oni_ml_tpu.models.lda import LDATrainer
     from oni_ml_tpu.parallel import make_mesh
 
-    monkeypatch.setenv("ONI_ML_TPU_ESTEP", "compact")
     trainer = LDATrainer(
-        LDAConfig(num_topics=4), num_terms=200,
+        LDAConfig(num_topics=4, estep_engine="compact"), num_terms=200,
         mesh=make_mesh(data=8, model=1),
     )
     batch = Batch(
@@ -1173,8 +1138,8 @@ def test_forced_compact_with_mesh_raises(monkeypatch):
         trainer._plan_estep([batch])
 
 
-def test_env_dense_infeasible_rescue_leaves_nothing_behind(monkeypatch):
-    """ONI_ML_TPU_ESTEP=dense with an infeasible full-V shape must
+def test_forced_dense_infeasible_rescue_leaves_nothing_behind():
+    """estep_engine='dense' with an infeasible full-V shape must
     route through the compact rescue — and the rescue is the plan's
     return value: the trainer holds nothing of it, so a later decision
     starts from nothing."""
@@ -1187,16 +1152,16 @@ def test_env_dense_infeasible_rescue_leaves_nothing_behind(monkeypatch):
         word_idx=np.asarray(w), counts=np.asarray(c),
         doc_mask=np.asarray(m), doc_index=np.arange(16),
     )
-    monkeypatch.setenv("ONI_ML_TPU_ESTEP", "dense")
-    trainer = LDATrainer(LDAConfig(num_topics=4), num_terms=v)
+    trainer = LDATrainer(LDAConfig(num_topics=4, estep_engine="dense"),
+                         num_terms=v)
     before = dict(vars(trainer))
     plan = trainer._plan_estep([batch])
     assert plan.family == "compact" and plan.compact is not None
     assert vars(trainer) == before               # no stash to consume
     again = trainer._plan_estep([batch])         # the same answer, afresh
     assert again.compact.widths == plan.compact.widths
-    # another pin for the same trainer: no rescue left over
-    monkeypatch.setenv("ONI_ML_TPU_ESTEP", "xla")
+    # another engine for the same trainer: no rescue left over
+    trainer.config = dataclasses.replace(trainer.config, estep_engine="xla")
     assert trainer._plan_estep([batch]).compact is None
 
 

@@ -1,8 +1,9 @@
-"""What the fused driver's plan decides, selector by selector.
+"""What the fused driver's plan decides, engine by engine.
 
 A characterisation: every expectation below was read off the five gates
-the plan was once spread over (PR 31 merged them into
-`LDATrainer._plan_estep`), so the kernel's label, the `fit.plan` span,
+the plan was once spread over (all of them now in
+`LDATrainer._plan_estep`, chosen among by `LDAConfig.estep_engine`
+alone), so the kernel's label, the `fit.plan` span,
 `plan_record["estep_kernel"]`, the width the roofline record multiplies
 by and the gates' error messages are held letter for letter.
 
@@ -21,7 +22,7 @@ import pytest
 from oni_ml_tpu.config import LDAConfig
 from oni_ml_tpu.io import make_batches
 from oni_ml_tpu.models import LDATrainer, fused, train_corpus
-from oni_ml_tpu.ops import dense_estep
+from oni_ml_tpu.ops import dense_estep, estep
 from oni_ml_tpu.parallel import make_mesh
 from oni_ml_tpu.telemetry import roofline, spans
 
@@ -52,8 +53,8 @@ def _token_width(batches, _cfg):
         b.word_idx.shape[0] for b in batches)
 
 
-def _compact_width(batches, cfg):
-    plan = fused.plan_compact(batches, K, wmajor=cfg.dense_wmajor)
+def _compact_width(batches, _cfg):
+    plan = fused.plan_compact(batches, K)
     by_shape = {}
     for b in batches:
         by_shape[b.word_idx.shape] = by_shape.get(b.word_idx.shape, 0) + 1
@@ -67,47 +68,52 @@ def _dense_width(_batches, _cfg):
     return dense_estep.padded_width(V)
 
 
-# case -> (ONI_ML_TPU_ESTEP, config, mesh (data, model) or None,
-#          vocab_sharded, trainer kwargs or None for train_corpus,
-#          kernel, width the roofline record sweeps, corpus slices)
+def _refuse_wmajor(monkeypatch):
+    """Row-major through the real gate: no shape blocks W-major."""
+    monkeypatch.setattr(dense_estep, "pick_block_w", lambda *a, **kw: None)
+
+
+# case -> (config, mesh (data, model) or None, vocab_sharded,
+#          trainer kwargs or None for train_corpus,
+#          kernel, width the roofline record sweeps, corpus slices);
+# a case named *_rowmajor runs with W-major refused (`_refuse_wmajor`)
 CASES = {
-    "env_dense": ("dense", {}, None, False, None,
-                  "dense_wmajor", _dense_width, 1),
-    "env_dense_rowmajor": ("dense", dict(dense_wmajor=False), None, False,
-                           None, "dense_rowmajor", _dense_width, 1),
-    "config_dense": ("", dict(dense_em="on"), None, False, None,
+    "config_dense": (dict(estep_engine="dense"), None, False, None,
                      "dense_wmajor", _dense_width, 1),
-    "env_compact": ("compact", {}, None, False, None,
-                    "compact_wmajor", _compact_width, 1),
-    "env_compact_rowmajor": ("compact", dict(dense_wmajor=False), None,
-                             False, None, "compact_rowmajor",
-                             _compact_width, 1),
-    "env_sparse": ("sparse", {}, None, False, None,
-                   "sparse_fused", _token_width, 1),
-    "env_xla": ("xla", {}, None, False, None, "xla", _token_width, 1),
-    "cpu_default": ("", {}, None, False, None, "xla", _token_width, 1),
-    "dense_off": ("", dict(dense_em="off"), None, False, None,
-                  "xla", _token_width, 1),
-    "rescue_config": ("", dict(dense_em="on"), None, False,
+    "config_dense_rowmajor": (dict(estep_engine="dense"), None, False,
+                              None, "dense_rowmajor", _dense_width, 1),
+    "config_compact": (dict(estep_engine="compact"), None, False, None,
+                       "compact_wmajor", _compact_width, 1),
+    "config_compact_rowmajor": (dict(estep_engine="compact"), None, False,
+                                None, "compact_rowmajor", _compact_width,
+                                1),
+    "config_sparse": (dict(estep_engine="sparse"), None, False, None,
+                      "sparse_fused", _token_width, 1),
+    "config_xla": (dict(estep_engine="xla"), None, False, None, "xla",
+                   _token_width, 1),
+    "config_pallas": (dict(estep_engine="pallas"), None, False, None,
+                      "pallas", _token_width, 1),
+    "cpu_default": ({}, None, False, None, "xla", _token_width, 1),
+    "rescue_config": (dict(estep_engine="dense"), None, False,
                       dict(num_terms=WIDE_V),
                       "compact_wmajor", _compact_width, 1),
-    "rescue_env": ("dense", {}, None, False, dict(num_terms=WIDE_V),
-                   "compact_wmajor", _compact_width, 1),
-    "custom_e_step": ("", {}, None, False,
+    "custom_e_step": ({}, None, False,
                       dict(num_terms=V, e_step_fn=_custom_e_step),
                       "custom", _token_width, 1),
-    "data_mesh_dense": ("", dict(dense_em="on", batch_size=32), (4, 1),
+    "data_mesh_dense": (dict(estep_engine="dense", batch_size=32), (4, 1),
                         False, None, "dense_wmajor_shard_map",
                         _dense_width, 4),
     "data_mesh_dense_rowmajor": (
-        "", dict(dense_em="on", dense_wmajor=False, batch_size=32), (4, 1),
+        dict(estep_engine="dense", batch_size=32), (4, 1),
         False, None, "dense_rowmajor_shard_map", _dense_width, 4),
-    "data_mesh_default": ("", dict(batch_size=32), (4, 1), False, None,
+    "data_mesh_default": (dict(batch_size=32), (4, 1), False, None,
                           "xla", _token_width, 4),
-    "vocab_mesh_dense": ("", dict(dense_em="on", batch_size=32), (2, 2),
+    "data_mesh_xla": (dict(estep_engine="xla", batch_size=32), (4, 1),
+                      False, None, "xla", _token_width, 4),
+    "vocab_mesh_dense": (dict(estep_engine="dense", batch_size=32), (2, 2),
                          True, None, "dense_vocab_sharded_xla",
                          lambda b, c: V, 4),
-    "vocab_mesh_default": ("", dict(batch_size=32), (2, 2), True, None,
+    "vocab_mesh_default": (dict(batch_size=32), (2, 2), True, None,
                            "xla_vocab_sharded", _token_width, 2),
 }
 
@@ -115,11 +121,10 @@ CASES = {
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_plan_names_the_kernel_and_the_width_it_sweeps(
         corpus, case, monkeypatch):
-    env, over, mesh_shape, vocab_sharded, direct, kernel, width, slices = (
+    over, mesh_shape, vocab_sharded, direct, kernel, width, slices = (
         CASES[case])
-    monkeypatch.delenv("ONI_ML_TPU_ESTEP", raising=False)
-    if env:
-        monkeypatch.setenv("ONI_ML_TPU_ESTEP", env)
+    if case.endswith("_rowmajor"):
+        _refuse_wmajor(monkeypatch)
     cfg = LDAConfig(**dict(CFG, **over))
     mesh = None
     if mesh_shape is not None:
@@ -146,7 +151,7 @@ def test_plan_names_the_kernel_and_the_width_it_sweeps(
             result = train_corpus(corpus, cfg, mesh=mesh,
                                   vocab_sharded=vocab_sharded)
             batches = seen
-            if env == "sparse":     # the bucketed layout's own batches
+            if cfg.estep_engine == "sparse":    # the bucketed layout's own
                 batches = None
         else:
             batches = make_batches(corpus, batch_size=cfg.batch_size,
@@ -189,28 +194,31 @@ def _one_batch(num_terms=V):
     )
 
 
-# case -> (ONI_ML_TPU_ESTEP, config, trainer kwargs, the whole message)
+# case -> (config, trainer kwargs, the whole message)
 ERRORS = {
     "dense_with_custom_e_step": (
-        "dense", {}, dict(num_terms=V, e_step_fn=_custom_e_step),
+        dict(estep_engine="dense"),
+        dict(num_terms=V, e_step_fn=_custom_e_step),
         "dense E-step forced but a custom e_step_fn is installed"),
     "compact_under_a_mesh": (
-        "compact", {}, dict(num_terms=V, mesh=(8, 1)),
+        dict(estep_engine="compact"), dict(num_terms=V, mesh=(8, 1)),
         "compact dense E-step forced but a mesh is active (the multi-chip "
         "huge-V story is the vocab-sharded dense plan)"),
     "compact_with_custom_e_step": (
-        "compact", {}, dict(num_terms=V, e_step_fn=_custom_e_step),
+        dict(estep_engine="compact"),
+        dict(num_terms=V, e_step_fn=_custom_e_step),
         "compact dense E-step forced but a custom e_step_fn is installed"),
     "no_block_and_no_rescue": (
-        "", dict(dense_em="on"), dict(num_terms=WIDE_V, mesh=(8, 1)),
+        dict(estep_engine="dense"), dict(num_terms=WIDE_V, mesh=(8, 1)),
         "dense E-step forced but a batch shape has no VMEM-feasible doc "
         f"block (V={WIDE_V}, K={K}) and the compact-vocab fallback is not "
         "feasible either"),
-    "bad_dense_em": (
-        "", dict(dense_em="true"), dict(num_terms=V),
-        "LDAConfig.dense_em='true': expected 'auto', 'on', or 'off'"),
+    "bad_estep_engine": (
+        dict(estep_engine="on"), dict(num_terms=V),
+        "LDAConfig.estep_engine='on': expected one of auto, dense, "
+        "compact, sparse, xla, pallas"),
     "dense_on_a_foreign_vocab_sharded_e_step": (
-        "", dict(dense_em="on"),
+        dict(estep_engine="dense"),
         dict(num_terms=V, mesh=(2, 2), vocab_sharded=True),
         "dense E-step forced but the vocabulary is sharded and the "
         "installed e_step_fn is not this package's vocab-sharded plan"),
@@ -219,10 +227,7 @@ ERRORS = {
 
 @pytest.mark.parametrize("case", sorted(ERRORS))
 def test_plan_refuses_with_the_gates_messages(case, monkeypatch):
-    env, over, kw, message = ERRORS[case]
-    monkeypatch.delenv("ONI_ML_TPU_ESTEP", raising=False)
-    if env:
-        monkeypatch.setenv("ONI_ML_TPU_ESTEP", env)
+    over, kw, message = ERRORS[case]
     kw = dict(kw)
     if "mesh" in kw:
         d, m = kw["mesh"]
@@ -232,7 +237,35 @@ def test_plan_refuses_with_the_gates_messages(case, monkeypatch):
             from oni_ml_tpu.parallel import sharded
 
             kw["e_step_fn"] = sharded.make_data_parallel_e_step(kw["mesh"])
-    trainer = LDATrainer(LDAConfig(**dict(CFG, **over)), **kw)
     with pytest.raises(ValueError) as err:
-        trainer.fit([_one_batch(kw["num_terms"])], 16)
+        LDATrainer(LDAConfig(**dict(CFG, **over)), **kw).fit(
+            [_one_batch(kw["num_terms"])], 16)
     assert str(err.value) == message
+
+
+@pytest.mark.parametrize("case", ["xla", "pallas", "sparse", "data_mesh_xla"])
+def test_forced_token_backend_reaches_the_e_step(corpus, case, monkeypatch):
+    """A token-list engine is bound into the stock E-step once (into each
+    shard's, under a data mesh): every call the fit traces asks
+    estep.e_step for that backend, and the plan names it."""
+    backend = case.rpartition("_")[2]
+    calls = []
+    real = estep.e_step
+
+    def spy(*a, backend="auto", **kw):
+        calls.append(backend)
+        return real(*a, backend=backend, **kw)
+
+    spy._oni_warm_capable = True
+    monkeypatch.setattr(estep, "e_step", spy)
+    fused.clear_programs()
+    cfg = LDAConfig(**dict(CFG, estep_engine=backend, batch_size=32))
+    if case.startswith("data_mesh"):
+        mesh = make_mesh(data=4, model=1, devices=jax.devices()[:4])
+        result = train_corpus(corpus, cfg, mesh=mesh)
+    else:
+        batches = make_batches(corpus, batch_size=cfg.batch_size,
+                               min_bucket_len=cfg.min_bucket_len)
+        result = LDATrainer(cfg, num_terms=V).fit(batches, corpus.num_docs)
+    assert calls and set(calls) == {backend}
+    assert result.plan["estep_kernel"]["value"] == backend

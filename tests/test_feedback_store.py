@@ -199,7 +199,8 @@ def _feedback_corpus(num_docs=96, v=160, seed=5):
 def _config(**kw):
     return LDAConfig(**dict(dict(
         num_topics=K, em_max_iters=4, em_tol=0.0, batch_size=32, seed=3,
-        fused_em_chunk=3, dense_em="on", dense_precision="bf16"), **kw))
+        fused_em_chunk=3, estep_engine="dense", dense_precision="bf16"),
+        **kw))
 
 
 def _fit(cfg, **kw):
@@ -227,8 +228,7 @@ def _assert_same_fit(a, b):
 
 
 @pytest.mark.parametrize("family", ["dense", "compact", "data_mesh"])
-def test_a_whole_fit_on_the_int16_store_is_the_float32_stores(
-        family, monkeypatch):
+def test_a_whole_fit_on_the_int16_store_is_the_float32_stores(family):
     """The gate climbs to int16 on the feedback day and the whole fit,
     through `train_corpus` under "bf16", is the float32-stored fit under
     "f32" to float32 rounding (`_assert_same_fit`; the name dates from when
@@ -242,8 +242,7 @@ def test_a_whole_fit_on_the_int16_store_is_the_float32_stores(
 
     kw, over = {}, {}
     if family == "compact":
-        monkeypatch.setenv("ONI_ML_TPU_ESTEP", "compact")
-        over["dense_em"] = "auto"
+        over["estep_engine"] = "compact"
     elif family == "data_mesh":
         kw["mesh"] = make_mesh(data=2, model=1, devices=jax.devices()[:2])
     got, gate, plan = _fit(_config(**over), **kw)

@@ -23,7 +23,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # driver -> config overrides, the fit.* children it must show once / twice
 DRIVERS = {
-    "fused_dense": (dict(dense_em="on"),
+    "fused_dense": (dict(estep_engine="dense"),
                     ["fit.engine", "fit.batches", "fit.plan", "fit.stack",
                      "fit.densify", "fit.runner", "fit.teardown"]),
     "fused_xla": (dict(),
@@ -416,7 +416,8 @@ def _corpus_with_a_feedback_doc():
 def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
                                                   cell_scan, stored):
     corpus = _corpus_with_a_feedback_doc() if feedback else _corpus()
-    cfg = _config(dense_em="on", dense_precision=precision, em_max_iters=1)
+    cfg = _config(estep_engine="dense", dense_precision=precision,
+                  em_max_iters=1)
     batches = _batches(corpus, cfg)
     padded_tokens = sum(b.word_idx.size for b in batches)
     rec = spans.Recorder()
@@ -486,7 +487,8 @@ def test_the_gate_sums_cells_only_in_rows_where_a_word_repeats(
     without one summed cell; the feedback document, the one row where a
     word repeats, is the only row the summed-cell reader sees."""
     corpus = _corpus_with_hub_docs(feedback=feedback)
-    cfg = _config(dense_em="on", dense_precision="bf16", em_max_iters=1)
+    cfg = _config(estep_engine="dense", dense_precision="bf16",
+                  em_max_iters=1)
     reader = dense_estep.max_dense_cell
     summed = []
 
@@ -632,7 +634,7 @@ def test_a_fit_under_the_profiler_puts_its_spans_in_the_trace(tmp_path):
         from benchmarks.jobs import fit_spans, fit_tail
     finally:
         sys.path.remove(ROOT)
-    corpus, cfg = _corpus(), _config(dense_em="on")
+    corpus, cfg = _corpus(), _config(estep_engine="dense")
     train_corpus(corpus, cfg)                      # compile outside the trace
     assert spans.current_recorder() is None
     jax.profiler.start_trace(str(tmp_path))
@@ -706,7 +708,7 @@ def test_a_fit_under_the_profiler_puts_its_spans_in_the_trace(tmp_path):
 
 
 def test_em_roofline_record_rests_on_the_counted_sweeps():
-    corpus, cfg = _corpus(), _config(dense_em="on")
+    corpus, cfg = _corpus(), _config(estep_engine="dense")
     rec = spans.Recorder()
     since = roofline.emit_count()
     with spans.use_recorder(rec):

@@ -317,7 +317,7 @@ def test_host_sync_every_bounds_dispatch_without_changing_results(
 
 DENSE_CFG = dict(num_topics=4, alpha_init=2.5, seed=3, em_max_iters=5,
                  em_tol=0.0, batch_size=16, min_bucket_len=4,
-                 dense_em="on")
+                 estep_engine="dense")
 
 
 def _recorded_fit(corpus, cfg, yield_hook=None):
@@ -394,10 +394,7 @@ def test_f32_fit_stores_what_the_exact_reader_would_without_reading(
     from oni_ml_tpu.ops import dense_estep
     from oni_ml_tpu.telemetry import spans
 
-    cfg = LDAConfig(**DENSE_CFG)
-    if family == "compact":
-        cfg = LDAConfig(**dict(DENSE_CFG, dense_em="auto"))
-        monkeypatch.setenv("ONI_ML_TPU_ESTEP", "compact")
+    cfg = LDAConfig(**dict(DENSE_CFG, estep_engine=family))
     gate = dense_estep.corpus_store_dtype
     asked = []
 
@@ -480,14 +477,22 @@ def _slow_m_step(ss):
     return estep.m_step(ss)
 
 
+def _forced_xla_e_step():
+    """The stock E-step as a trainer forcing estep_engine="xla" binds it."""
+    from oni_ml_tpu.ops import estep
+    from oni_ml_tpu.parallel import sharded
+
+    return sharded.bound(estep.e_step, backend="xla")
+
+
 @pytest.mark.parametrize("change", [
     dict(num_docs=17), dict(em_tol=1e-3), dict(var_max_iters=5),
     dict(alpha_max_iters=100), dict(warm_start=False),
     dict(estimate_alpha=False),
     dict(compiler_options={"xla_embed_ir_in_executable": True}),
-    dict(m_step_fn=_slow_m_step), dict(env="xla"),
+    dict(m_step_fn=_slow_m_step), dict(e_step_fn=_forced_xla_e_step()),
 ], ids=lambda c: next(iter(c)))
-def test_program_key_builds_for_anything_the_trace_reads(change, monkeypatch):
+def test_program_key_builds_for_anything_the_trace_reads(change):
     """Each value the chunk program's trace reads is part of its key: a
     change builds a new program, and that program computes what a build
     with nothing kept computes."""
@@ -499,9 +504,6 @@ def test_program_key_builds_for_anything_the_trace_reads(change, monkeypatch):
     assert base.program == "built"
     assert fused.make_chunk_runner(**kw).jitted is base.jitted
 
-    change = dict(change)
-    if "env" in change:
-        monkeypatch.setenv("ONI_ML_TPU_ESTEP", change.pop("env"))
     changed = fused.make_chunk_runner(**dict(kw, **change))
     assert changed.program == "built"
     assert changed.jitted is not base.jitted
@@ -514,6 +516,40 @@ def test_program_key_builds_for_anything_the_trace_reads(change, monkeypatch):
     assert fresh.program == "built" and fresh.jitted is not changed.jitted
     for a, b in zip(got, _run_chunk(fresh, log_beta, groups)):
         np.testing.assert_array_equal(a, b)
+
+
+def test_program_key_reads_no_variable_but_the_plan_caches(monkeypatch):
+    """Which E-step runs is the configuration's alone: finding a kept
+    program reads no `ONI_ML_TPU_*` variable of the environment but the
+    plan cache's (`_traced_plan_blocks`), so no stray value of a retired
+    selector can split or mix programs."""
+    import os
+
+    from oni_ml_tpu.models import fused
+
+    _, _, kw = _sparse_chunk_problem()
+    fused.clear_programs()
+    base = fused.make_chunk_runner(**kw)
+    read = []
+
+    class Reads(dict):
+        def get(self, key, default=None):
+            read.append(key)
+            return super().get(key, default)
+
+        def __getitem__(self, key):
+            read.append(key)
+            return super().__getitem__(key)
+
+        def __contains__(self, key):
+            read.append(key)
+            return super().__contains__(key)
+
+    monkeypatch.setattr(os, "environ", Reads(os.environ))
+    again = fused.make_chunk_runner(**kw)
+    assert again.program == "reused" and again.jitted is base.jitted
+    assert {key for key in read if key.startswith("ONI_ML_TPU_")} <= {
+        "ONI_ML_TPU_PLANS", "ONI_ML_TPU_PLAN_CACHE"}
 
 
 @pytest.mark.parametrize("change", [

@@ -43,10 +43,8 @@ def _worker_env():
         k: v
         for k, v in os.environ.items()
         # The workers configure their own backend; scrub the suite's
-        # single-process CPU/8-device env and the E-step engine
-        # override (it would pin every run's engine and hollow out the
-        # sparse-vs-dense cross checks).
-        if k not in ("XLA_FLAGS", "JAX_PLATFORMS", "ONI_ML_TPU_ESTEP")
+        # single-process CPU/8-device env.
+        if k not in ("XLA_FLAGS", "JAX_PLATFORMS")
     }
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["ONI_ML_TPU_ALLREDUCE_TIMEOUT_S"] = _WAIT_TIMEOUT_S

@@ -124,7 +124,7 @@ def test_full_training_parity(problem, mesh_shape, vocab_sharded):
 
 def test_full_training_parity_vocab_sharded_dense(problem):
     """End-to-end config-4 plan: train_corpus with vocab_sharded=True and
-    dense_em='on' must route through make_vocab_sharded_dense_e_step and
+    estep_engine='dense' must route through make_vocab_sharded_dense_e_step and
     reproduce the single-device trajectory (fresh start pinned: the
     single-device CPU run stays sparse, and warm start would change
     trajectories by design)."""
@@ -136,7 +136,8 @@ def test_full_training_parity_vocab_sharded_dense(problem):
     mesh = make_mesh(data=2, model=4)
     import dataclasses
 
-    multi = train_corpus(corpus, dataclasses.replace(cfg, dense_em="on"),
+    multi = train_corpus(corpus,
+                         dataclasses.replace(cfg, estep_engine="dense"),
                          mesh=mesh, vocab_sharded=True)
     np.testing.assert_allclose(
         [l for l, _ in multi.likelihoods], [l for l, _ in single.likelihoods],
@@ -306,7 +307,7 @@ def _fit(corpus, lda, mesh=None):
         em_max_iters=lda["em_max_iters"], em_tol=lda["em_tol"],
         var_max_iters=lda["var_max_iters"], var_tol=lda["var_tol"],
         warm_start_gamma=lda["warm_start"], seed=lda["seed"],
-        batch_size=64, min_bucket_len=4, dense_em="on")
+        batch_size=64, min_bucket_len=4, estep_engine="dense")
     return train_corpus(corpus, cfg, mesh=mesh)
 
 

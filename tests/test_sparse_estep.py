@@ -384,16 +384,6 @@ def test_crossover_measures_then_resolves_from_plan(plan_cache):
     assert rec3["shape"].startswith("dlog")
 
 
-def test_crossover_env_pin(plan_cache, monkeypatch):
-    monkeypatch.setenv("ONI_ML_TPU_ESTEP_ENGINE", "dense")
-    rec = sparse_estep.engine_crossover(4, 512, 32, 16)
-    assert rec == {"engine": "dense", "dense_s": None, "sparse_s": None,
-                   "source": "env", "shape": rec["shape"]}
-    monkeypatch.setenv("ONI_ML_TPU_ESTEP_ENGINE", "fastest")
-    with pytest.raises(ValueError, match="ONI_ML_TPU_ESTEP_ENGINE"):
-        sparse_estep.engine_crossover(4, 512, 32, 16)
-
-
 def test_crossover_journals_record(plan_cache, tmp_path):
     from oni_ml_tpu.telemetry import Journal, Recorder, use_recorder
 
@@ -435,27 +425,18 @@ def test_resolve_engine_cpu_auto_stays_dense_family(plan_cache):
     assert (engine, src) == ("dense", "default")
 
 
-def test_resolve_engine_forced_and_conflicts(plan_cache, monkeypatch):
-    from oni_ml_tpu.config import LDAConfig
+def test_resolve_engine_forced_and_conflicts(plan_cache):
+    from oni_ml_tpu.config import ESTEP_ENGINES, LDAConfig
     from oni_ml_tpu.models.lda import resolve_estep_engine
 
     corpus = _toy_corpus()
-    cfg = LDAConfig(num_topics=4, estep_engine="sparse")
-    assert resolve_estep_engine(corpus, cfg) == ("sparse", "config")
-    monkeypatch.setenv("ONI_ML_TPU_ESTEP", "sparse")
-    assert resolve_estep_engine(corpus, LDAConfig(num_topics=4)) == \
-        ("sparse", "env")
-    # env beats config.
-    assert resolve_estep_engine(
-        corpus, LDAConfig(num_topics=4, estep_engine="dense")
-    ) == ("sparse", "env")
-    monkeypatch.delenv("ONI_ML_TPU_ESTEP")
-    with pytest.raises(ValueError, match="dense_em"):
-        resolve_estep_engine(
-            corpus,
-            LDAConfig(num_topics=4, estep_engine="sparse", dense_em="on"),
-        )
-    with pytest.raises(ValueError, match="estep_engine"):
+    # Every engine but "auto" names its family, from the config.
+    for engine in ESTEP_ENGINES[1:]:
+        cfg = LDAConfig(num_topics=4, estep_engine=engine)
+        assert resolve_estep_engine(corpus, cfg) == (
+            "sparse" if engine == "sparse" else "dense", "config")
+    with pytest.raises(ValueError, match="estep_engine='fastest': "
+                       "expected one of auto, dense, compact, sparse"):
         resolve_estep_engine(
             corpus, LDAConfig(num_topics=4, estep_engine="fastest")
         )
@@ -484,7 +465,8 @@ def _train(cfg, corpus, out_dir=None):
 
 
 def test_train_sparse_engine_matches_dense_family(plan_cache, tmp_path):
-    """engine='sparse' vs engine='dense' (pinned) on the same corpus:
+    """engine='sparse' vs engine='dense' (full width, forced) on the same
+    corpus:
     final likelihood agrees within the bf16-class tolerance, gamma
     rows land in document order, and the plan record names the
     engine."""
@@ -537,18 +519,18 @@ def test_train_sparse_engine_pinned_is_byte_deterministic(
     assert outs[0] == outs[1]
 
 
-def test_train_sparse_engine_via_env(plan_cache, tmp_path, monkeypatch):
+def test_train_sparse_engine_via_config(plan_cache, tmp_path):
     from oni_ml_tpu.config import LDAConfig
 
-    monkeypatch.setenv("ONI_ML_TPU_ESTEP", "sparse")
     corpus = _toy_corpus(n_docs=40, v=80)
     res = _train(
         LDAConfig(num_topics=4, em_max_iters=3, batch_size=32,
                   sparse_min_bucket_len=16, fused_em_chunk=2,
-                  host_sync_every=0),
+                  host_sync_every=0, estep_engine="sparse"),
         corpus,
     )
-    assert res.plan["estep_engine"] == {"value": "sparse", "source": "env"}
+    assert res.plan["estep_engine"] == {"value": "sparse",
+                                        "source": "config"}
     assert np.isfinite(res.likelihoods[-1][0])
 
 

@@ -223,8 +223,8 @@ def _same_fit(a, b):
 
 FITS = {
     "fused_xla": dict(),
-    "fused_dense": dict(dense_em="on"),
-    "mesh": dict(dense_em="on", mesh=4),
+    "fused_dense": dict(estep_engine="dense"),
+    "mesh": dict(estep_engine="dense", mesh=4),
     "distributed": dict(em_shards=3, distributed=True),
 }
 

@@ -5,8 +5,6 @@ batch a fit with `make_batches`): 8 rows a shard up to 256, past that the
 the plan keeps the W-major kernel.  Where no tail passes 256 rows a shard
 the batches are the 8-row rule's, array for array."""
 
-import dataclasses
-
 import jax
 import numpy as np
 import pytest
@@ -97,7 +95,7 @@ def _plan(batches, cfg, data):
 def test_a_flow20_census_plans_the_wmajor_kernel(cell):
     law, data, batch, (old16, new16), (old256, new256), added = CENSUS[cell]
     corpus = _census({length: n * data for length, n in law.items()})
-    cfg = LDAConfig(num_topics=4, batch_size=batch, dense_em="on")
+    cfg = LDAConfig(num_topics=4, batch_size=batch, estep_engine="dense")
     batches, tail_pad = lda._fit_batches(corpus, cfg, data)
     shapes = {b.word_idx.shape for b in batches}
     assert {new16, new256} <= shapes and not {old16, old256} & shapes
@@ -138,7 +136,7 @@ def test_a_census_without_a_tail_past_256_rows_batches_as_before(cell):
                                           getattr(w, field))
 
 
-def test_the_wmajor_fit_on_the_new_tails_is_the_rowmajor_fit():
+def test_the_wmajor_fit_on_the_new_tails_is_the_rowmajor_fit(monkeypatch):
     """A bucketed day whose tail passes 256 rows (330 documents in batches
     of 512: 336 rows by the 8-row rule, 384 by the tail rule) fits W-major
     to what the row-major kernel fits on the 8-row rule's batches, at the
@@ -151,16 +149,17 @@ def test_the_wmajor_fit_on_the_new_tails_is_the_rowmajor_fit():
         num_docs=20, num_terms=v, num_topics=3, seed=6,
         doc_len_range=(40, 60))
     corpus = corpus_from_docs(docs + more, v)
-    cfg = LDAConfig(num_topics=3, batch_size=512, dense_em="on", seed=2,
-                    em_max_iters=4, em_tol=0.0)
+    cfg = LDAConfig(num_topics=3, batch_size=512, estep_engine="dense",
+                    seed=2, em_max_iters=4, em_tol=0.0)
     new, tail_pad = lda._fit_batches(corpus, cfg, 1)
     assert tail_pad > 0 and max(b.word_idx.shape[0] for b in new) == 384
     old = make_batches(corpus, 512, min_bucket_len=16, pad_multiple=8)
     assert max(b.word_idx.shape[0] for b in old) == 336
 
     got = LDATrainer(cfg, num_terms=v).fit(new, corpus.num_docs)
-    rowmajor = dataclasses.replace(cfg, dense_wmajor=False)
-    want = LDATrainer(rowmajor, num_terms=v).fit(old, corpus.num_docs)
+    # row-major through the plan's own gate: no shape blocks W-major
+    monkeypatch.setattr(dense_estep, "pick_block_w", lambda *a, **kw: None)
+    want = LDATrainer(cfg, num_terms=v).fit(old, corpus.num_docs)
     assert got.plan["estep_kernel"]["value"] == "dense_wmajor"
     assert want.plan["estep_kernel"]["value"] == "dense_rowmajor"
     assert got.em_iters == want.em_iters == 4
