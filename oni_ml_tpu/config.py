@@ -53,7 +53,11 @@ class LDAConfig:
     # iterate has reached its arithmetic's noise floor (on TPU the
     # MXU's bf16-truncated matmul inputs put a ~2^-8 relative floor
     # under the iterates, below which they jitter instead of
-    # contracting) and more iterations cannot improve gamma.  At 1e-6
+    # contracting) and more iterations cannot improve gamma (not where
+    # the products keep float32 accuracy, the day after analyst feedback
+    # on any store: there the reference's rule alone, since the exit
+    # stopped slowly converging one-word documents short of their fixed
+    # point; dense_estep._stall_exit).  At 1e-6
     # the relative test is still far tighter than lda-c's per-doc
     # relative-likelihood stop at its stock 1e-6 (the ELBO is quadratic
     # in delta-gamma near the fixed point); an absolute 1e-6 against
@@ -153,16 +157,22 @@ class LDAConfig:
     # makes cells of 1,000 and more), f32 otherwise — both 2-byte
     # stores halving the corpus' device memory and per-iteration HBM
     # streaming, the count promoted to f32 where the kernels use it:
-    # the bf16 store with bit-identical results, the int16 store with
-    # its products at f32 accuracy (a document that one word of 1,000
-    # counts dominates amplifies a bf16 rounding in the products by the
-    # sweeps): the kernels' fixed point from exact bf16 pieces packed
-    # into the MXU's contraction depth, the rest at HIGHEST
-    # (ops/dense_estep._sweep_products, _products).  The gate
-    # (ops/dense_estep.corpus_store_dtype, span `fit.plan.gate`, which
-    # says the `rung`) is exact and reads only what can change its
-    # answer: at "f32" no token; at "bf16" the largest raw count (over
-    # 32,767: f32; over 256: the int16 rung at best), then every
+    # the bf16 store with bit-identical results.  The products are
+    # keyed to the day's cells, not to the store: wherever a densified
+    # cell passes 256, at either precision and on any store, they keep
+    # f32 accuracy (a document that one word of 1,000 counts dominates
+    # amplifies a bf16 rounding in the products by the sweeps): the
+    # kernels' fixed point from exact bf16 pieces packed into the MXU's
+    # contraction depth, the rest at HIGHEST
+    # (ops/dense_estep._sweep_products, _products, accurate_products).
+    # The gate (ops/dense_estep.corpus_store_dtype, span `fit.plan.gate`,
+    # which says the `rung` and whether the products are `accurate`) is
+    # exact and reads only what can change its answer: at "f32" the
+    # store is f32 and the largest count of the fit's Corpus decides
+    # (one entry a (document, word) cell), or where the batches come
+    # from no Corpus the ladder's first rung over them; at "bf16" the
+    # largest raw count (over 32,767: f32; over 256: the int16 rung at
+    # best), then every
     # document's sum (all within the rung's maximum: decided), then, of
     # the documents over it, the per-(doc, word) sums of those where a
     # word repeats (one where every word is distinct has cells equal to

@@ -32,6 +32,10 @@ class Corpus:
     doc_names[d] is the document key (an IP address in the reference's
     pipelines); vocab[w] is the word string.  Token j of document d lives at
     word_idx[doc_ptr[d]:doc_ptr[d+1]] with multiplicity counts[...].
+    Each (document, word) pair appears once, as pre-LDA's sum by (IP,
+    word) gives a day: an entry is a cell of the document-word matrix,
+    and its count the densified cell (the fit's store gate at f32 reads
+    them so, ops/dense_estep.accurate_products).
     """
 
     doc_names: list[str]

@@ -333,6 +333,7 @@ def plan_compact(
     itemsize: int = 4,
     local_div: int = 1,
     store=None,
+    accurate: bool = False,
 ) -> CompactPlan | None:
     """Build a CompactPlan, or None when some group's compact width
     admits no VMEM-feasible doc block (then the sparse path is the
@@ -340,8 +341,9 @@ def plan_compact(
     ids (the corpus is static, so the per-batch vocabulary is fixed
     for the whole run).  `local_div` divides the per-kernel doc count
     (data-mesh shard factor); callers gate mesh support themselves.
-    `store`: the corpus' store dtype where known (the kernels' VMEM
-    need depends on it: dense_estep.pick_block)."""
+    `store`: the corpus' store dtype where known, and `accurate`, whether
+    its products keep float32 accuracy (the kernels' VMEM need depends on
+    both: dense_estep.pick_block)."""
     from ..ops import dense_estep
 
     groups: dict[tuple, list[int]] = {}
@@ -357,11 +359,11 @@ def plan_compact(
         wc = -(-wc // 128) * 128  # lane tile, like padded_width()
         b_local = shape[0] // local_div
         if dense_estep.pick_block(b_local, wc, num_topics,
-                                  precision, store) is None:
+                                  precision, store, accurate) is None:
             return None
         use_wmajor = use_wmajor and (
             dense_estep.pick_block_w(b_local, wc, num_topics, precision,
-                                     store)
+                                     store, accurate)
             is not None
         )
         uniques.append(us)
@@ -498,6 +500,7 @@ def make_em_accumulator(
     dense_wmajor: bool = False,
     dense_precision: str = "f32",
     warm_start: bool = False,
+    dense_accurate: bool = False,
 ):
     """Build `accumulate(log_beta, alpha, groups, gammas_prev, warm) ->
     (suff_stats [V, K], likelihood, alpha_ss, gammas, vi_max,
@@ -512,7 +515,11 @@ def make_em_accumulator(
     (models/lda.py `_distributed_loop`) jits it alone per document
     shard (`make_partial_runner`), reduces the partials across
     processes through parallel/allreduce, and only then runs the
-    identical M-step on every rank from the reduced stats."""
+    identical M-step on every rank from the reduced stats.
+
+    `dense_accurate`: some densified cell of the day passes 256, and the
+    dense kernels take their products at float32 accuracy whatever the
+    store (dense_estep._products)."""
     e_fn = e_step_fn or estep.e_step
     # Sparse groups warm-start only through callables that declare the
     # gamma_prev/warm kwargs (this package's e_step and its sharded
@@ -531,7 +538,7 @@ def make_em_accumulator(
             interpret=jax.default_backend() != "tpu",
             wmajor=dense_wmajor,
             gamma_prev=g_in, warm=warm, precision=dense_precision,
-            batch_index=batch_index,
+            batch_index=batch_index, accurate=dense_accurate,
         )
 
     dense_fn = dense_e_step_fn or _default_dense
@@ -553,6 +560,7 @@ def make_em_accumulator(
             interpret=jax.default_backend() != "tpu",
             wmajor=dense_wmajor,
             gamma_prev=g_in, warm=warm, precision=dense_precision,
+            accurate=dense_accurate,
         )
         ss = jnp.zeros((v, k), log_beta.dtype).at[vocab_map].add(
             res.suff_stats
@@ -786,6 +794,7 @@ def _build_chunk_program(
     dense_e_step_fn: Callable | None,
     dense_precision: str,
     alpha_max_iters: int,
+    dense_accurate: bool = False,
 ):
     """The PROGRAM half of `make_chunk_runner`: `jax.jit` of
     `run_chunk_dispatch(log_beta, alpha, ll_prev, groups, n_steps,
@@ -803,7 +812,7 @@ def _build_chunk_program(
         var_max_iters=var_max_iters, var_tol=var_tol,
         e_step_fn=e_step_fn, dense_e_step_fn=dense_e_step_fn,
         dense_wmajor=dense_wmajor, dense_precision=dense_precision,
-        warm_start=warm_start,
+        warm_start=warm_start, dense_accurate=dense_accurate,
     )
 
     def em_iteration(log_beta, alpha, groups, gammas_prev, warm):
@@ -911,6 +920,7 @@ def make_chunk_runner(
     dense_precision: str = "f32",
     alpha_max_iters: int = 100,
     yield_hook: Callable | None = None,
+    dense_accurate: bool = False,
 ):
     """Build `run_chunk(log_beta, alpha, ll_prev, groups, n_steps)`
     executing up to min(chunk, n_steps) EM iterations on device.
@@ -944,7 +954,7 @@ def make_chunk_runner(
         m_step_fn=m_fn, compiler_options=compiler_options,
         dense_wmajor=dense_wmajor, warm_start=warm_start,
         dense_e_step_fn=dense_e_step_fn, dense_precision=dense_precision,
-        alpha_max_iters=alpha_max_iters,
+        alpha_max_iters=alpha_max_iters, dense_accurate=dense_accurate,
     ))
     dispatch_no = itertools.count()
 

@@ -282,6 +282,8 @@ class _EStepPlan(NamedTuple):
     store: object = None    # the dense corpus' dtype; None: token lists
     cell_scan: str = "none"     # what dense_estep.corpus_store_dtype read
     scan_tokens: int = 0
+    accurate: bool = False      # a densified cell passes 256: float32-
+                                # accurate products (accurate_products)
     budget: int = 0             # dense_budget(): bytes a device the dense
     budget_source: str = "stated"   # families were held to, and where from
     compact: "fused.CompactPlan | None" = None
@@ -334,6 +336,7 @@ class LDATrainer:
         shard_plan=None,
         shard_batches=None,
         yield_hook: Callable | None = None,
+        corpus_counts: np.ndarray | None = None,
     ):
         """When `mesh` is set, batches are device_put ONCE with the
         data-axis layout (and beta with the vocab-sharded layout if
@@ -350,8 +353,15 @@ class LDATrainer:
         natural dispatch grain: the fused driver enters one slot per
         chunk dispatch, the stepwise driver one per EM iteration, the
         distributed driver one per local E-step round — a co-resident
-        serving plane wins the next dispatch slot at every boundary."""
+        serving plane wins the next dispatch slot at every boundary.
+
+        `corpus_counts`: the counts of the io.Corpus the fit's batches
+        are made from, one entry a (document, word) cell, from which the
+        plan's gate at f32 reads whether a cell passes 256 in one pass
+        (dense_estep.accurate_products); None where the batches come from
+        no Corpus."""
         self.config = config
+        self.corpus_counts = corpus_counts
         self.num_terms = num_terms
         self.mesh = mesh
         self.vocab_sharded = vocab_sharded
@@ -1134,14 +1144,22 @@ class LDATrainer:
             # (duplicate (doc, word) tokens sum), not the raw counts, and
             # sums cells only in the undecided rows where a word repeats;
             # the span says the `rung` and counts `over_bf16_tokens`,
-            # `undecided_rows` and `repeat_rows`.  At f32 it reads no
-            # token.
+            # `undecided_rows` and `repeat_rows`.  At f32 the store reads
+            # no token.  The same span answers whether a densified cell
+            # passes 256, which keys the products whatever the store
+            # (dense_estep.accurate_products: at bf16 the rung says so, at
+            # f32 the largest of the Corpus' counts, or the ladder's first
+            # rung over batches from no Corpus) and says `accurate`.
             with maybe_span("fit.plan.gate") as sp:
                 gate = dense_estep.corpus_store_dtype(batches, precision,
                                                       span=sp)
+                accurate = dense_estep.accurate_products(
+                    gate[0], precision, batches, span=sp,
+                    cells=self.corpus_counts)
                 sp.annotate(store=jnp.dtype(gate[0]).name,
-                            cell_scan=gate[1], scan_tokens=gate[2])
-            return gate
+                            cell_scan=gate[1], scan_tokens=gate[2],
+                            accurate=int(accurate))
+            return (*gate, accurate)
 
         # The kernels see each data shard's slice of a batch: feasibility
         # and VMEM limits are per (rows a shard, L) shape.
@@ -1217,15 +1235,16 @@ class LDATrainer:
             not dense and compact is None and not blocked
             and (engine == "compact" or engine == "auto" and on_tpu))
 
-        store, cell_scan, scan_tokens = None, "none", 0
+        store, cell_scan, scan_tokens, accurate = None, "none", 0, False
         if dense or compact is not None or try_compact:
             # A 2-byte store (bf16 or int16) halves the corpus' HBM
             # footprint and streaming; the counts it holds are exact.
-            store, cell_scan, scan_tokens = store_gate()
+            store, cell_scan, scan_tokens, accurate = store_gate()
         if try_compact:
             compact = fused.plan_compact(
                 batches, k, precision,
                 itemsize=jnp.dtype(store).itemsize, store=store,
+                accurate=accurate,
             )
             if compact is None and engine == "compact":
                 raise ValueError(
@@ -1248,8 +1267,8 @@ class LDATrainer:
         # blocks of its Pallas kernel, `kib` their scoped-VMEM need
         all_rows = sum(b.word_idx.shape[0] for b in batches)
         plan = dict(store=store, cell_scan=cell_scan,
-                    scan_tokens=scan_tokens, budget=budget,
-                    budget_source=budget_source)
+                    scan_tokens=scan_tokens, accurate=accurate,
+                    budget=budget, budget_source=budget_source)
         blocks, kib = [], None
         if dense and self.vocab_sharded:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1272,7 +1291,7 @@ class LDATrainer:
                 dense_mesh=self.mesh, dense_width=v, sweep_width=v,
                 dense_e_fn=sharded.bound(
                     sharded.make_vocab_sharded_dense_e_step(
-                        self.mesh, precision=precision),
+                        self.mesh, precision=precision, accurate=accurate),
                     var_max_iters=cfg.var_max_iters, var_tol=cfg.var_tol,
                 ),
             )
@@ -1280,7 +1299,8 @@ class LDATrainer:
             # W-major needs the doc axis on the 128-lane dimension; fall
             # back to row-major when any batch shape can't block that way.
             wmajor = all(
-                dense_estep.pick_block_w(rows, v, k, precision, store)
+                dense_estep.pick_block_w(rows, v, k, precision, store,
+                                         accurate)
                 for rows, _ in local
             )
             plan.update(
@@ -1299,7 +1319,8 @@ class LDATrainer:
                     dense_mesh=self.mesh,
                     dense_e_fn=sharded.bound(
                         sharded.make_data_parallel_dense_e_step(
-                            self.mesh, wmajor=wmajor, precision=precision),
+                            self.mesh, wmajor=wmajor, precision=precision,
+                            accurate=accurate),
                         var_max_iters=cfg.var_max_iters,
                         var_tol=cfg.var_tol,
                         interpret=not on_tpu,
@@ -1307,7 +1328,8 @@ class LDATrainer:
                 )
             blocks = [(rows, v) for rows, _ in local]
             kib = partial(dense_estep.scoped_vmem_kib, wmajor=wmajor,
-                          precision=precision, store=store)
+                          precision=precision, store=store,
+                          accurate=accurate)
         elif compact is not None:
             # Compact-vocab dense groups are built straight from the host
             # batches (no sparse stacked upload to discard).  The chunk
@@ -1328,7 +1350,7 @@ class LDATrainer:
                       for shape, wc in zip(shapes, compact.widths)]
             kib = partial(dense_estep.scoped_vmem_kib,
                           wmajor=compact.wmajor, precision=precision,
-                          store=store)
+                          store=store, accurate=accurate)
         else:
             plan.update(
                 family="tokens",
@@ -1396,20 +1418,24 @@ class LDATrainer:
             self.plan_record["dense_hbm_budget"] = {
                 "value": plan.budget, "source": plan.budget_source}
             self.plan_record["exchange"] = self._exchange(batches, num_docs)
-            # what the dense corpus is stored as (None: token lists), and
-            # the store ladder's rung
+            # what the dense corpus is stored as (None: token lists), the
+            # store ladder's rung, and whether a densified cell passes 256
+            # (1: every product keeps float32 accuracy)
             self.plan_record["corpus_store"] = {
                 "value": (None if plan.store is None
                           else jnp.dtype(plan.store).name),
                 "rung": (None if plan.store is None
                          else dense_estep.store_rung(plan.store)),
-                "cell_scan": plan.cell_scan}
+                "cell_scan": plan.cell_scan,
+                "accurate": (None if plan.store is None
+                             else int(plan.accurate))}
             # the products the fixed point takes over that store and the
             # phinorm's MXU passes a sweep (dense_estep.kernel_products;
             # the vocab-sharded XLA plan packs nothing)
             products = passes = None
             if plan.store is not None:
-                products = dense_estep.kernel_products(plan.store, k)
+                products = dense_estep.kernel_products(plan.store, k,
+                                                       plan.accurate)
                 if plan.family == "dense_vocab_sharded" and (
                         products != "default"):
                     products = "highest"
@@ -1476,6 +1502,7 @@ class LDATrainer:
                 dense_precision=cfg.dense_precision,
                 alpha_max_iters=cfg.alpha_max_iters,
                 yield_hook=self.yield_hook,
+                dense_accurate=plan.accurate,
             )
             # "reused": an earlier fit of this process built the chunk
             # program, and this fit's first dispatch traces nothing.
@@ -1731,6 +1758,8 @@ class WindowTrainer:
                 min_bucket_len=cfg.min_bucket_len,
             )
             num_docs = corpus.num_docs
+            # the window's cells, for the plan's gate (LDATrainer)
+            self._trainer.corpus_counts = corpus.counts
         result = self._trainer.fit(
             batches,
             num_docs,
@@ -2096,6 +2125,7 @@ def _train_corpus(
             m_step_fn=m_fn,
             mesh=mesh,
             vocab_sharded=vocab_sharded,
+            corpus_counts=corpus.counts,
         )
     ll_path = os.path.join(out_dir, "likelihood.dat") if out_dir else None
     ckpt_path = (

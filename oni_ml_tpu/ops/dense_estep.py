@@ -55,6 +55,7 @@ exactly (same fixed point, same convergence rule, same ELBO terms).
 from __future__ import annotations
 
 import functools
+from concurrent.futures import ThreadPoolExecutor
 
 import jax
 import jax.numpy as jnp
@@ -121,41 +122,62 @@ def _cast_for(precision: str):
     return (lambda x: x.astype(dt)) if dt else (lambda x: x)
 
 
-def _products(corpus_dtype, precision: str):
+def _products(corpus_dtype, precision: str, accurate: bool = False):
     """(operand cast, dot precision) of the matrix products over a corpus
     block stored as `corpus_dtype`, where they are not packed
     (`kernel_products`).
 
-    An int16 block (corpus_dtype's integer rung) comes from a day whose
-    cells pass 256, the day after analyst feedback, whose marked rows put
-    1,000 and more counts of ONE word into a document.  That document's
-    fixed point is a power iteration over the topics: each sweep
-    multiplies gamma_k by about beta_kw, so a bfloat16 rounding in the
-    products grows with the sweeps and decides where the document ends.
-    On the v5e, with bfloat16 operands the fit read `gammaN_max` 1.93
-    against the float32 reference (limit 0.05), and with only the fixed
-    point's phinorm left in bfloat16 one seed in eight still did (1.90;
-    PERF.md).  So a product over an int16 block keeps float32 accuracy:
-    the kernels' fixed point packs it (`_packed_products`) where K lets
-    it, and everything else over an int16 block, the kernels' tail pass
-    and the vocab-sharded XLA plan, runs at HIGHEST with no operand
-    cast; every other store keeps `_cast_for(precision)` under XLA's
-    DEFAULT, as before."""
-    if jnp.issubdtype(corpus_dtype, jnp.integer):
-        return (lambda x: x), _dot_precision(corpus_dtype)
+    The rule is keyed to what the day's cells hold, not to the store's
+    dtype: `accurate` says that some densified cell of the fit's day
+    passes 256 (`accurate_products`), and an int16 block always comes
+    from such a day (corpus_dtype's integer rung).  That is the day after
+    analyst feedback, whose marked rows put 1,000 and more counts of ONE
+    word into a document.  That document's fixed point is a power
+    iteration over the topics: each sweep multiplies gamma_k by about
+    beta_kw, so a bfloat16 rounding in the products grows with the sweeps
+    and decides where the document ends.  On the v5e, with bfloat16
+    operands the fit read `gammaN_max` 1.93 against the float32 reference
+    (limit 0.05), and with only the fixed point's phinorm left in
+    bfloat16 one seed in eight still did (1.90; PERF.md).  XLA's DEFAULT
+    takes bfloat16 operands from a float32 block too (`_cast_for`), so
+    the store's dtype does not save it.  So on such a day every product
+    keeps float32 accuracy: the kernels' fixed point packs it
+    (`_packed_products`) where K lets it, and everything else, the
+    kernels' tail pass and the vocab-sharded XLA plan, runs at HIGHEST
+    with no operand cast; any other day keeps `_cast_for(precision)`
+    under XLA's DEFAULT, as before."""
+    if _accurate(corpus_dtype, accurate):
+        return (lambda x: x), _dot_precision(corpus_dtype, accurate)
     return _cast_for(precision), None
 
 
-def _dot_precision(corpus_dtype):
-    """HIGHEST over an int16 block, else XLA's DEFAULT (None): the
-    precision of every product `_products` and the packing leave."""
-    if jnp.issubdtype(corpus_dtype, jnp.integer):
+def _accurate(corpus_dtype, accurate: bool) -> bool:
+    """Whether products over a block stored as `corpus_dtype` keep float32
+    accuracy: where the fit says its day holds a cell over 256, and always
+    over an int16 block (which only such a day is stored as)."""
+    return bool(accurate) or jnp.issubdtype(corpus_dtype, jnp.integer)
+
+
+def _stall_exit(corpus_dtype, accurate: bool) -> bool:
+    """Whether the fixed point keeps `ops/stop.fp_continue`'s stagnation
+    exit: where the products are XLA's DEFAULT, whose bfloat16 noise
+    floor the exit detects, and nowhere they keep float32 accuracy
+    (`_accurate`, whatever the store), where the fixed point follows the
+    reference's rule, var_tol or var_max_iters."""
+    return not _accurate(corpus_dtype, accurate)
+
+
+def _dot_precision(corpus_dtype, accurate: bool = False):
+    """HIGHEST where the products keep float32 accuracy (`_accurate`),
+    else XLA's DEFAULT (None): the precision of every product `_products`
+    and the packing leave."""
+    if _accurate(corpus_dtype, accurate):
         return jax.lax.Precision.HIGHEST
     return None
 
 
-# The fixed point's products over an int16 block, at float32 accuracy and
-# packed into the MXU's contraction depth.  A float32 product at HIGHEST
+# The fixed point's products at float32 accuracy (`_accurate`), packed into
+# the MXU's contraction depth.  A float32 product at HIGHEST
 # is six bfloat16 passes over the MXU; at K = 20 the phinorm product
 # contracts 20 of the v5e MXU's 128 rows a pass, so five sixths of what it
 # pays is padding (PERF.md).  Here the bfloat16 partial products that such
@@ -191,13 +213,16 @@ def _packed_passes(k: int) -> int:
     return -(-len(_SPLIT6) * k // _MXU_DEPTH)
 
 
-def kernel_products(corpus_dtype, k: int) -> str:
+def kernel_products(corpus_dtype, k: int, accurate: bool = False) -> str:
     """The kernels' fixed-point products over a block stored as
-    `corpus_dtype` at K topics: "default" (XLA's DEFAULT, the operand
-    cast of `_cast_for`) on a floating store; on the int16 store
-    "split6_packed" (`_packed_products`) where the packed phinorm takes
-    fewer MXU passes than HIGHEST's six (K <= 106), else "highest"."""
-    if not jnp.issubdtype(corpus_dtype, jnp.integer):
+    `corpus_dtype` at K topics, keyed to the day's cells (`_accurate`:
+    `accurate`, the fit's word that a densified cell passes 256, or an
+    int16 block): "default" (XLA's DEFAULT, the operand cast of
+    `_cast_for`) where no cell passes 256; where one does, whatever the
+    store, "split6_packed" (`_packed_products`) where the packed phinorm
+    takes fewer MXU passes than HIGHEST's six (K <= 106), else
+    "highest"."""
+    if not _accurate(corpus_dtype, accurate):
         return "default"
     if _packed_passes(k) < _HIGHEST_PASSES:
         return "split6_packed"
@@ -234,17 +259,19 @@ def _total(xs):
     return functools.reduce(jnp.add, xs)
 
 
-def _sweep_products(beta, corpus_dtype, precision: str, wmajor: bool):
+def _sweep_products(beta, corpus_dtype, precision: str, wmajor: bool,
+                    accurate: bool = False):
     """(phinorm, gamma_update, tail_phinorm) of the kernels over a block
     stored as `corpus_dtype`: the packed products where `kernel_products`
     says so (the tail's phinorm too), else beta cast once and each
     product one dot under `_products`, the tail's phinorm uncast.  The
     one helper both kernel bodies call (and so the data-parallel kernel
     under `shard_map`); the signatures are `_packed_products`'."""
-    if kernel_products(corpus_dtype, beta.shape[0]) == "split6_packed":
+    if kernel_products(corpus_dtype, beta.shape[0],
+                       accurate) == "split6_packed":
         phinorm, gamma_update = _packed_products(beta, wmajor)
         return phinorm, gamma_update, phinorm
-    cast, dot_precision = _products(corpus_dtype, precision)
+    cast, dot_precision = _products(corpus_dtype, precision, accurate)
     beta_m = cast(beta)
     # phinorm contracts K; + 1e-30 matches the sparse path's phinorm
     dims = ((((0,), (0,)), ((1,), (0,))) if wmajor
@@ -268,7 +295,7 @@ def _sweep_products(beta, corpus_dtype, precision: str, wmajor: bool):
 
 
 def _packed_products(beta, wmajor: bool, terms=_SPLIT6):
-    """(phinorm, gamma_update) of the fixed point over an int16 block from
+    """(phinorm, gamma_update) of the fixed point at float32 accuracy from
     the bfloat16 pieces of its float32 operands (`split3`).
 
     phinorm contracts over K: both operands' pieces are stacked along
@@ -333,15 +360,17 @@ def _packed_products(beta, wmajor: bool, terms=_SPLIT6):
 
 
 def _vmem_estimate(bb: int, v: int, k: int, precision: str = "f32",
-                   store=None) -> int:
+                   store=None, accurate: bool = False) -> int:
     """Modelled VMEM bytes of one grid step at doc block `bb` over a block
-    stored as `store` (None: a floating store).  One formula for every
+    stored as `store` (None: a floating store), under the products
+    `kernel_products(store, k, accurate)` gives.  One formula for every
     store's block: a 2-byte corpus block (bfloat16 or int16,
     corpus_dtype) is double-buffered at 2 B a cell and promoted to
     float32 where it is used, 2 x 2 + 4 bytes a cell, which is what the
     float32 block's double buffer counts."""
     est = (4 * bb * v + 2 * k * v) * 4
-    if store is not None and kernel_products(store, k) == "split6_packed":
+    if kernel_products(jnp.float32 if store is None else store, k,
+                       accurate) == "split6_packed":
         # the packed products' bfloat16 operands (`_packed_products`):
         # the ratio's three pieces, and beta's pieces stacked for phinorm
         # (6K of them, lanes padded to the tile) and the gamma update (3Kp)
@@ -356,16 +385,17 @@ def _vmem_estimate(bb: int, v: int, k: int, precision: str = "f32",
 
 
 def _vmem_limit(bb: int, v: int, k: int, precision: str = "f32",
-                store=None) -> int:
+                store=None, accurate: bool = False) -> int:
     # Mosaic's real stack allocation runs ~1.6x the modeled working set
     # (measured: 56.2MB actual vs 34.9MB modeled at BB=256, V=8192, K=20);
     # 2x keeps headroom without hitting the 128MB physical VMEM.
-    est = _vmem_estimate(bb, v, k, precision, store)
+    est = _vmem_estimate(bb, v, k, precision, store, accurate)
     return min(max(32 * 1024 * 1024, est * 2), 128 * 1024 * 1024)
 
 
 def scoped_vmem_kib(b: int, v: int, k: int, wmajor: bool = False,
-                    precision: str = "f32", store=None) -> int | None:
+                    precision: str = "f32", store=None,
+                    accurate: bool = False) -> int | None:
     """Scoped-VMEM KiB the dense kernel needs at pick_block's block size —
     for drivers to pass as the xla_tpu_scoped_vmem_limit_kib compiler
     option.  Needed because XLA drops the pallas_call's own
@@ -373,10 +403,11 @@ def scoped_vmem_kib(b: int, v: int, k: int, wmajor: bool = False,
     multi-batch lax.scan (observed: a [NB>=2] stacked group compiles the
     kernel as kCustom fusion with the default 16MB scoped limit)."""
     pick = pick_block_w if wmajor else pick_block
-    bb = pick(b, v, k, precision, store)
+    bb = pick(b, v, k, precision, store, accurate)
     if bb is None:
         return None
-    return _vmem_limit(bb, padded_width(v), k, precision, store) // 1024
+    return _vmem_limit(bb, padded_width(v), k, precision, store,
+                       accurate) // 1024
 
 
 def _planned_block(knob: str, b: int, v: int, k: int,
@@ -402,10 +433,11 @@ def _planned_block(knob: str, b: int, v: int, k: int,
 
 
 def pick_block(b: int, v: int, k: int, precision: str = "f32",
-               store=None) -> int | None:
+               store=None, accurate: bool = False) -> int | None:
     """Largest power-of-two doc block (<= 256) dividing `b` whose
     estimated working set fits the VMEM ceiling (over a block stored as
-    `store`, None a floating one) — or the plan cache's measured block
+    `store`, None a floating one, under the products `accurate` takes:
+    `_vmem_estimate`) — or the plan cache's measured block
     for this shape when one exists and passes the same feasibility
     checks.  None = infeasible."""
     w = padded_width(v)
@@ -418,13 +450,15 @@ def pick_block(b: int, v: int, k: int, precision: str = "f32",
         # analytic space only ever emits multiples of 8, and a
         # hand-edited entry must not hand Mosaic an unaligned tile.
         and planned % 8 == 0
-        and _vmem_estimate(planned, w, k, precision, store) <= _VMEM_CEILING
+        and _vmem_estimate(planned, w, k, precision, store,
+                           accurate) <= _VMEM_CEILING
     ):
         return planned
     bb = 8
     best = None
     while bb <= min(b, 256) and b % bb == 0:
-        if _vmem_estimate(bb, w, k, precision, store) > _VMEM_CEILING:
+        if _vmem_estimate(bb, w, k, precision, store,
+                          accurate) > _VMEM_CEILING:
             break
         best = bb
         bb *= 2
@@ -437,7 +471,7 @@ _WHOLE_BATCH_BLOCK = 256
 
 
 def pick_block_w(b: int, v: int, k: int, precision: str = "f32",
-                 store=None) -> int | None:
+                 store=None, accurate: bool = False) -> int | None:
     """Doc block for the W-major layout.  The doc axis is the LANE
     dimension of the C^T block there, so Mosaic requires it divisible by
     128 — or equal to the full batch (single-block grid).  None =
@@ -449,18 +483,20 @@ def pick_block_w(b: int, v: int, k: int, precision: str = "f32",
         and planned <= b
         and b % planned == 0
         and (planned % 128 == 0 or planned == b)
-        and _vmem_estimate(planned, w, k, precision, store) <= _VMEM_CEILING
+        and _vmem_estimate(planned, w, k, precision, store,
+                           accurate) <= _VMEM_CEILING
     ):
         return planned
     best = None
     bb = 128
     while bb <= min(b, 256) and b % bb == 0:
-        if _vmem_estimate(bb, w, k, precision, store) > _VMEM_CEILING:
+        if _vmem_estimate(bb, w, k, precision, store,
+                          accurate) > _VMEM_CEILING:
             break
         best = bb
         bb *= 2
     if best is None and b <= _WHOLE_BATCH_BLOCK and (
-        _vmem_estimate(b, w, k, precision, store) <= _VMEM_CEILING
+        _vmem_estimate(b, w, k, precision, store, accurate) <= _VMEM_CEILING
     ):
         best = b  # block == full array: any lane extent is legal
     return best
@@ -546,10 +582,14 @@ def corpus_dtype(cell_max: float, precision: str = "f32"):
     store's results are a float32 store's bit for bit, and the int16
     store's kernels take their products at float32 accuracy
     (`_sweep_products`).  At f32: float32."""
-    if precision == "bf16":
-        for top, dtype, _ in _RUNGS:
-            if cell_max <= top:
-                return dtype
+    return _rung(cell_max, _RUNGS) if precision == "bf16" else jnp.float32
+
+
+def _rung(cell_max: float, rungs):
+    """The first of `rungs` that holds `cell_max` exactly, else float32."""
+    for top, dtype, _ in rungs:
+        if cell_max <= top:
+            return dtype
     return jnp.float32
 
 
@@ -586,7 +626,9 @@ def corpus_store_dtype(batches, precision: str = "f32", span=None):
     0 where the bounds decided).
 
     Anything but bf16 operand mode stores f32 whatever the cells hold:
-    no token is read.  Under bf16 the answer is EXACT — equal to
+    no token is read (whether a cell passes 256 there, which keys the
+    products and not the store, is accurate_products').  Under bf16 the
+    answer is EXACT — equal to
     corpus_dtype(max over batches of max_dense_cell, "bf16") — from the
     cheapest evidence that decides it: a cell is at least its largest
     token and at most its row's sum.  The first pass finds the largest
@@ -611,10 +653,69 @@ def corpus_store_dtype(batches, precision: str = "f32", span=None):
     return dtype, cell_scan, read
 
 
-def _ladder_gate(batches):
-    """corpus_store_dtype under bf16, with its three counts: (dtype,
-    cell_scan, scan_tokens, over_bf16_tokens, undecided, repeats)."""
+def accurate_products(store, precision: str, batches, span=None,
+                      cells=None) -> bool:
+    """Whether some densified cell of the fit's day passes 256: what keys
+    the fit's products (`_products`, `kernel_products`), whatever the
+    store holds the cell as.
+
+    Under bf16 the store's gate has answered it (corpus_store_dtype):
+    every rung above bfloat16 holds such a cell, and no token is read.
+    At f32, which stores float32 whatever the cells hold, `cells`, where
+    given, are the counts of the io.Corpus the batches were made from,
+    whose entries are its (document, word) cells, each pair once: their
+    largest decides, in one pass.  Without them (batches from no Corpus,
+    whose rows may repeat a word) the ladder climbs its first rung alone
+    (`_ladder_gate` over bfloat16's), under the same exactness rules: the
+    largest raw count, where it passes 256, decides and the pass stops
+    there; else the row sums leave undecided the rows over 256, and of
+    those only the rows where a word repeats pay the summed cells.
+    `span`, at f32, is annotated with `accurate_scan_tokens`, the counts
+    or padded tokens the passes went over, and from a Corpus' counts
+    `largest_count`, from the ladder its `over_bf16_tokens`,
+    `undecided_rows` and `repeat_rows`."""
+    if precision == "bf16":
+        return jnp.dtype(store) != jnp.dtype(jnp.bfloat16)
+    if cells is not None:
+        top = _largest(np.asarray(cells))
+        counts = dict(largest_count=top, accurate_scan_tokens=np.size(cells))
+        accurate = top > _BF16_EXACT_MAX
+    else:
+        dtype, _, read, over, undecided, repeats = _ladder_gate(
+            batches, _RUNGS[:1])
+        counts = dict(over_bf16_tokens=over, undecided_rows=undecided,
+                      repeat_rows=repeats, accurate_scan_tokens=read)
+        accurate = dtype != jnp.bfloat16
+    if span is not None:
+        span.annotate(**counts)
+    return bool(accurate)
+
+
+# _largest's slices: at most four, read side by side, of at least 2**22
+# counts each (fewer do not repay the threads, about 1 ms).
+_LARGEST_SLICES, _LARGEST_SLICE_MIN = 4, 1 << 22
+
+
+def _largest(counts: np.ndarray) -> float:
+    """The largest of `counts` (0 where there are none).  The pass runs
+    at the host's memory bandwidth, which one core does not fill: a large
+    array's slices are read on threads of their own (numpy releases the
+    GIL for the reduction), 14 -> 5 ms on a flow20_fit_dp4 day of 30.7M
+    counts (PERF.md)."""
+    n = min(_LARGEST_SLICES, counts.size // _LARGEST_SLICE_MIN)
+    if n < 2:
+        return float(counts.max(initial=0))
+    with ThreadPoolExecutor(n) as pool:
+        return float(max(pool.map(np.max, np.array_split(counts, n))))
+
+
+def _ladder_gate(batches, rungs=_RUNGS):
+    """corpus_store_dtype under bf16 up the ladder `rungs` (the whole
+    ladder, or its first rung alone: accurate_products), with its three
+    counts: (dtype, cell_scan, scan_tokens, over_bf16_tokens, undecided,
+    repeats).  A cell over the last rung's maximum is float32."""
     counts = [np.asarray(b.counts) for b in batches]
+    ceiling = rungs[-1][0]
     read, top, over = 0, 0.0, 0
     for c in counts:
         read += c.size
@@ -624,9 +725,9 @@ def _ladder_gate(batches):
         if most > _BF16_EXACT_MAX:
             over += int(np.count_nonzero(c > _BF16_EXACT_MAX))
         top = max(top, most)
-        if top > _INT16_EXACT_MAX:
+        if top > ceiling:
             return jnp.float32, "bounds", read, over, 0, 0
-    bound = _BF16_EXACT_MAX if top <= _BF16_EXACT_MAX else _INT16_EXACT_MAX
+    bound = next(limit for limit, _, _ in rungs if top <= limit)
     undecided = []
     for b, c in zip(batches, counts):
         read += c.size
@@ -634,7 +735,7 @@ def _ladder_gate(batches):
         if rows.size:
             undecided.append((b.word_idx, c, rows))
     if not undecided:
-        return corpus_dtype(top, "bf16"), "bounds", read, over, 0, 0
+        return _rung(top, rungs), "bounds", read, over, 0, 0
     n_undecided = sum(rows.size for _, _, rows in undecided)
     repeats = 0
     for w, c, rows in undecided:
@@ -645,11 +746,10 @@ def _ladder_gate(batches):
         read += rep.size * c.shape[1]
         if rep.size:
             top = max(top, max_dense_cell(w[rep], c[rep]))
-            if top > _INT16_EXACT_MAX:
+            if top > ceiling:
                 return (jnp.float32, "exact", read, over, n_undecided,
                         repeats)
-    return (corpus_dtype(top, "bf16"), "exact", read, over, n_undecided,
-            repeats)
+    return (_rung(top, rungs), "exact", read, over, n_undecided, repeats)
 
 
 def densify(word_idx, counts, num_terms: int, width: int | None = None,
@@ -682,13 +782,14 @@ def densify(word_idx, counts, num_terms: int, width: int | None = None,
         return dense if dtype is None else dense.astype(dtype)
 
 
-_BLOCK_STATICS = ("var_max_iters", "var_tol", "precision")
+_BLOCK_STATICS = ("var_max_iters", "var_tol", "precision", "accurate")
 
 
 @functools.partial(jax.jit, static_argnames=_BLOCK_STATICS)
 def _block_e_step(
     alpha, warm, beta, c, mask, gamma_in,
     *, var_max_iters: int, var_tol: float, precision: str = "f32",
+    accurate: bool = False,
 ):
     """One block of BB documents' E-step, as values: beta [K, V]
     exp(log_beta), c [BB, V] f32, bf16 or int16, mask [BB, 1], gamma_in
@@ -706,22 +807,27 @@ def _block_e_step(
     warm selects the fixed point's start: 0 = the reference's fresh
     init alpha + N_d/K (lda-c semantics), 1 = resume from gamma_in
     (the previous EM iteration's posterior — same fixed point, fewer
-    iterations once beta stabilizes; config knob warm_start_gamma)."""
+    iterations once beta stabilizes; config knob warm_start_gamma).
+
+    `accurate`: the fit's day holds a densified cell over 256, and every
+    product keeps float32 accuracy whatever the store (`_products`)."""
     k_topics = beta.shape[0]
     # The corpus block may arrive STORED in 2 bytes (corpus_dtype:
     # bfloat16 for cells <= 256, int16 for cells <= 32,767, both exact;
     # halves its HBM streaming).  It is consumed via f32-promoting
     # elementwise ops, the count promoted where it is used (n_d, ratio,
-    # the token ELBO): a bf16 store changes no results, and an int16 one
-    # takes its products at float32 accuracy (`_sweep_products`).
+    # the token ELBO): a bf16 store changes no results, and an int16 one,
+    # like any block of a day with a cell over 256, takes its products at
+    # float32 accuracy (`_sweep_products`).
     n_d = jnp.sum(c, axis=1, keepdims=True, dtype=jnp.float32)
     # Relative stop normalizer: mean_k gamma = alpha + N_d/K for every
     # iterate (gamma rows sum to K*alpha + N_d exactly), making var_tol
     # a relative tolerance — reachable in f32 (see ops/estep.py).
     inv_scale = 1.0 / (alpha + n_d / k_topics)   # [BB, 1]
-    dot_precision = _dot_precision(c.dtype)   # the tail's statistics
+    dot_precision = _dot_precision(c.dtype, accurate)   # the tail's stats
     phinorm, gamma_update, tail_phinorm = _sweep_products(
-        beta, c.dtype, precision, wmajor=False)
+        beta, c.dtype, precision, wmajor=False, accurate=accurate)
+    stall = _stall_exit(c.dtype, accurate)
 
     def e_log_theta(gamma):
         return digamma_pos(gamma) - digamma_pos(
@@ -742,9 +848,10 @@ def _block_e_step(
         return gamma_new, it + 1, delta, delta_old
 
     def cond(state):
-        # var_tol or gated stagnation — the shared rule (ops/stop.py).
+        # var_tol or gated stagnation (none under accurate products:
+        # `_stall_exit`) — the shared rule (ops/stop.py).
         _, it, delta, prev = state
-        return fp_continue(it, delta, prev, var_max_iters, var_tol)
+        return fp_continue(it, delta, prev, var_max_iters, var_tol, stall)
 
     fresh0 = (alpha + n_d / k_topics) + jnp.zeros(
         (c.shape[0], k_topics), jnp.float32
@@ -815,6 +922,7 @@ def _store_block(gamma_ref, t_ref, docll_ref, ass_ref, iters_ref,
 def _block_e_step_w(
     alpha, warm, beta, ct, mask, gamma_in,
     *, var_max_iters: int, var_tol: float, precision: str = "f32",
+    accurate: bool = False,
 ):
     """W-major variant of _block_e_step: the corpus block rides as
     C^T [W, BB] and gamma as gamma^T [K, BB] (mask [1, BB]), so the
@@ -826,15 +934,17 @@ def _block_e_step_w(
     K-mixture).  Math is identical modulo float reassociation."""
     k_topics = beta.shape[0]
     # A 2-byte store (bf16 or int16) is consumed via f32-promoting ops,
-    # exact; an int16 one takes its products at float32 accuracy (see
+    # exact; an int16 one, like any block of a day with a cell over 256
+    # (`accurate`), takes its products at float32 accuracy (see
     # _block_e_step, `_sweep_products`).
     n_d = jnp.sum(ct, axis=0, keepdims=True,   # [1, BB]
                   dtype=jnp.float32)
     # Relative stop normalizer (see _dense_kernel / ops/estep.py).
     inv_scale = 1.0 / (alpha + n_d / k_topics)  # [1, BB]
-    dot_precision = _dot_precision(ct.dtype)  # the tail's statistics
+    dot_precision = _dot_precision(ct.dtype, accurate)  # the tail's stats
     phinorm, gamma_update, tail_phinorm = _sweep_products(
-        beta, ct.dtype, precision, wmajor=True)
+        beta, ct.dtype, precision, wmajor=True, accurate=accurate)
+    stall = _stall_exit(ct.dtype, accurate)
 
     def e_log_theta_t(gamma_t):
         return digamma_pos(gamma_t) - digamma_pos(
@@ -855,9 +965,10 @@ def _block_e_step_w(
         return gamma_new, it + 1, delta, delta_old
 
     def cond(state):
-        # var_tol or gated stagnation — the shared rule (ops/stop.py).
+        # var_tol or gated stagnation (none under accurate products:
+        # `_stall_exit`) — the shared rule (ops/stop.py).
         _, it, delta, prev = state
-        return fp_continue(it, delta, prev, var_max_iters, var_tol)
+        return fp_continue(it, delta, prev, var_max_iters, var_tol, stall)
 
     fresh0 = (alpha + n_d / k_topics) + jnp.zeros(
         (k_topics, ct.shape[1]), jnp.float32
@@ -971,13 +1082,14 @@ def dense_fixed_point_w(
     warm=None,                  # traced scalar bool/int gating gamma_prev
     precision: str = "f32",
     batch_index=None,           # dense_counts_t is a stack [NB, W, B]
+    accurate: bool = False,     # float32-accurate products (_products)
 ):
     """W-major twin of dense_fixed_point; same returns."""
     _check_corpus_rank(dense_counts_t, batch_index)
     k_topics, v = exp_beta.shape
     b = dense_counts_t.shape[-1]
     bb = block or pick_block_w(b, v, k_topics, precision,
-                               dense_counts_t.dtype)
+                               dense_counts_t.dtype, accurate)
     if bb is None:
         raise ValueError(
             f"no W-major-feasible doc block for B={b}, V={v}, K={k_topics} "
@@ -992,7 +1104,7 @@ def dense_fixed_point_w(
     grid = b // bb
     kernel = functools.partial(
         _dense_kernel_w, var_max_iters=var_max_iters, var_tol=var_tol,
-        precision=precision,
+        precision=precision, accurate=accurate,
     )
     # Outputs/state stay f32 whatever the corpus is STORED as
     # (corpus_dtype); the kernel promotes the block where it uses it.
@@ -1032,7 +1144,7 @@ def dense_fixed_point_w(
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision,
-                                         dense_counts_t.dtype)
+                                         dense_counts_t.dtype, accurate)
         ),
         interpret=interpret,
         name="dense_estep_wmajor",
@@ -1060,6 +1172,7 @@ def dense_fixed_point(
     warm=None,                  # traced scalar bool/int gating gamma_prev
     precision: str = "f32",
     batch_index=None,           # dense_counts is a stack [NB, B, V]
+    accurate: bool = False,     # float32-accurate products (_products)
 ):
     """Returns (gamma [B, K], T [K, V], docll [B], alpha_ss_part [B],
     iters scalar, doc_sweeps scalar) — docll is the full per-doc ELBO
@@ -1074,7 +1187,8 @@ def dense_fixed_point(
     _check_corpus_rank(dense_counts, batch_index)
     k_topics, v = exp_beta.shape
     b = dense_counts.shape[-2]
-    bb = block or pick_block(b, v, k_topics, precision, dense_counts.dtype)
+    bb = block or pick_block(b, v, k_topics, precision, dense_counts.dtype,
+                             accurate)
     if bb is None:
         raise ValueError(
             f"no VMEM-feasible doc block for B={b}, V={v}, K={k_topics}"
@@ -1087,7 +1201,7 @@ def dense_fixed_point(
     grid = b // bb
     kernel = functools.partial(
         _dense_kernel, var_max_iters=var_max_iters, var_tol=var_tol,
-        precision=precision,
+        precision=precision, accurate=accurate,
     )
     # Outputs/state stay f32 whatever the corpus is STORED as
     # (corpus_dtype); the kernel promotes the block where it uses it.
@@ -1127,7 +1241,7 @@ def dense_fixed_point(
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_vmem_limit(bb, v, k_topics, precision,
-                                         dense_counts.dtype)
+                                         dense_counts.dtype, accurate)
         ),
         interpret=interpret,
         name="dense_estep_rowmajor",
@@ -1156,6 +1270,7 @@ def e_step_dense(
     warm=None,                  # traced scalar gating gamma_prev
     precision: str = "f32",     # "bf16": half-precision MXU iterations
     batch_index=None,           # traced int32: dense_counts is a STACK
+    accurate: bool = False,     # the day holds a cell over 256 (_products)
 ) -> estep.EStepResult:
     """estep.e_step semantics over a pre-densified batch: `dense_counts`
     itself, or with `batch_index` batch `batch_index` of the stack
@@ -1176,7 +1291,7 @@ def e_step_dense(
     gamma, t, docll, ass, iters, sweeps = fp(
         exp_beta, alpha, dense_counts, doc_mask, var_max_iters, var_tol,
         block=block, interpret=interpret, gamma_prev=gamma_prev, warm=warm,
-        precision=precision, batch_index=batch_index,
+        precision=precision, batch_index=batch_index, accurate=accurate,
     )
     suff = (exp_beta * t)[:, :v].T             # [V, K]
     # The kernel emits the per-doc ELBO terms (token + gamma-Dirichlet)

@@ -38,7 +38,9 @@ Two exits beyond the iteration cap:
   the gate one such transient would abort the loop badly unconverged.
   On full-f32 backends (CPU tests, interpret mode) the gated region's
   deltas decrease strictly until var_tol in practice, so the exit
-  changes nothing there.
+  changes nothing there.  The dense plans leave it out wherever the
+  products keep float32 accuracy, whatever the store (`stall=False`,
+  dense_estep._stall_exit).
 """
 
 from __future__ import annotations
@@ -52,21 +54,27 @@ import jax.numpy as jnp
 STALL_GATE = 1e-2
 
 
-def fp_continue(it, delta, prev, var_max_iters: int, var_tol: float):
+def fp_continue(it, delta, prev, var_max_iters: int, var_tol: float,
+                stall: bool = True):
     """Traced continue-predicate for the fixed-point `while_loop`.
 
     Pure jnp on scalars, so it traces identically inside Pallas kernels,
     shard_map'd bodies (delta/prev may carry varying axes), and plain
     XLA.  `prev` is the previous iteration's delta (init: +inf with
     `it == 0` short-circuiting the first evaluation).
+
+    `stall=False` drops the stagnation exit: var_tol or var_max_iters
+    alone, the reference's rule.  For a block whose products keep float32
+    accuracy (dense_estep, `accurate`), where there is no bfloat16 floor
+    to detect: on the day after analyst feedback a document that one
+    large cell dominates converges slowly, and the chip's float32
+    rounding stalled its block's delta under STALL_GATE before it had
+    converged, which sent it to another topic than the reference's
+    (PERF.md).
     """
+    converging = delta > var_tol
+    if stall:
+        converging = jnp.logical_and(
+            converging, jnp.logical_or(delta >= STALL_GATE, delta < prev))
     return jnp.logical_and(
-        it < var_max_iters,
-        jnp.logical_or(
-            it == 0,
-            jnp.logical_and(
-                delta > var_tol,
-                jnp.logical_or(delta >= STALL_GATE, delta < prev),
-            ),
-        ),
-    )
+        it < var_max_iters, jnp.logical_or(it == 0, converging))

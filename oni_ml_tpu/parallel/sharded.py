@@ -114,7 +114,8 @@ def make_data_parallel_e_step(mesh: Mesh, backend: str = "auto"):
 
 @_memoised
 def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
-                                    precision: str = "f32"):
+                                    precision: str = "f32",
+                                    accurate: bool = False):
     """Dense-corpus E-step (ops/dense_estep.py) over batch-sharded dense
     counts: each data shard runs the MXU kernel on its local documents,
     suff-stats/likelihood psum over ICI — the dense analogue of
@@ -130,7 +131,10 @@ def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
     ([NB, B, W] / [NB, W, B], documents over `data` as before) and every
     shard's kernel reads batch `batch_index` of its slice of the stack in
     place (dense_estep._corpus_call); the callable says so with
-    `_oni_stack_capable` (models/fused.reads_stack_in_place)."""
+    `_oni_stack_capable` (models/fused.reads_stack_in_place).
+    `accurate`: the day holds a densified cell over 256, and each
+    shard's kernel takes float32-accurate products (dense_estep._products).
+    """
     from ..ops import dense_estep
 
     batch_axis = 1 if wmajor else 0
@@ -155,7 +159,7 @@ def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
             var_max_iters=var_max_iters, var_tol=var_tol,
             interpret=interpret, wmajor=wmajor,
             gamma_prev=gamma_prev, warm=warm, precision=precision,
-            batch_index=batch_index,
+            batch_index=batch_index, accurate=accurate,
         )
 
     def local(log_beta, alpha, dense, doc_mask, gamma_prev, warm,
@@ -215,7 +219,8 @@ def make_data_parallel_dense_e_step(mesh: Mesh, wmajor: bool = False,
 
 
 @_memoised
-def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
+def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32",
+                                    accurate: bool = False):
     """Dense-corpus E-step with the VOCABULARY sharded over `model` and
     documents over `data` — BASELINE.json config 4 (high-cardinality DNS
     vocab, dns_pre_lda.scala:320-326) at MXU density.
@@ -246,7 +251,9 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
 
     Semantics match ops/dense_estep.e_step_dense (same fresh init, same
     q + 1e-30 guard, same masked-delta stop, full-f32 tail with in-loop
-    optional bf16 operand storage, warm start via gamma_prev/warm).
+    optional bf16 operand storage, warm start via gamma_prev/warm, and
+    `accurate`: float32 products at HIGHEST where the day holds a
+    densified cell over 256, whatever the store).
     Requirements: dense width == log_beta width, both divisible by the
     model-axis size; batch divisible by the data-axis size.  Pad the
     vocab with pad_vocab + LOG_ZERO beta columns — padded C columns are
@@ -263,9 +270,11 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
               var_max_iters, var_tol):
         k = log_beta_l.shape[0]
         # The products of dense_estep._products: bf16 operands under
-        # "bf16", float32 ones at HIGHEST on an int16 store (XLA here: the
-        # kernels' packed products are Mosaic's, dense_estep._sweep_products).
-        cast, dot_precision = dense_estep._products(c_l.dtype, precision)
+        # "bf16", float32 ones at HIGHEST where a cell passes 256 (an int16
+        # store, or `accurate`; XLA here: the kernels' packed products are
+        # Mosaic's, dense_estep._sweep_products).
+        cast, dot_precision = dense_estep._products(c_l.dtype, precision,
+                                                    accurate)
         beta_l = jnp.exp(log_beta_l)               # [K, W_l]
         beta_m = cast(beta_l)
         mask_col = doc_mask[:, None]
@@ -316,7 +325,8 @@ def make_vocab_sharded_dense_e_step(mesh: Mesh, precision: str = "f32"):
             # var_tol or gated stagnation — the shared rule
             # (ops/stop.py), identical across the model group.
             _, it, delta, prev = state
-            return fp_continue(it, delta, prev, var_max_iters, var_tol)
+            return fp_continue(it, delta, prev, var_max_iters, var_tol,
+                               dense_estep._stall_exit(c_l.dtype, accurate))
 
         fresh0 = alpha + (n_d / k)[:, None] + jnp.zeros(
             (c_l.shape[0], k), jnp.float32

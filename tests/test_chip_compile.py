@@ -247,6 +247,36 @@ def test_the_rowmajor_kernel_packs_the_int16_products_on_one_chip(topo):
     assert dense_estep.kernel_products(jnp.int16, K) == "split6_packed"
 
 
+@pytest.mark.parametrize("rows", [ROWS, 3968, 3456])
+def test_the_wmajor_kernel_packs_the_products_over_a_float32_stack(
+        topo, rows):
+    """The day after analyst feedback on the default 4 B store
+    (`flow20_feedback_f32_fit`, flow20_fit's shapes): the W-major kernel
+    reads a batch of a float32 stack in place and takes the packed
+    products (`accurate`), at a full batch's block of 256 documents and
+    the tails' of 128: Mosaic fits the float32 block and the packed
+    operands in the VMEM the kernel asks for."""
+    assert dense_estep.pick_block_w(rows, V, K, "f32", jnp.float32,
+                                    True) == (256 if rows == ROWS else 128)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def e_step(lb, a, stack, m, g, w, n):
+        return dense_estep.e_step_dense(
+            lb, a, stack, m, var_max_iters=20, var_tol=1e-6, wmajor=True,
+            gamma_prev=g, warm=w, batch_index=n, accurate=True)
+
+    compiled = jax.jit(e_step).lower(
+        shape((K, V)), shape(()), shape((2, V, rows)), shape((rows,)),
+        shape((rows, K)), shape((), jnp.int32),
+        shape((), jnp.int32)).compile()
+    assert _kernels(compiled)
+    assert not [rest[:60] for _, rest in _instructions(compiled)
+                if re.match(rf"f32\[{V},{rows}\]", rest)]
+
+
 @pytest.mark.parametrize("rows", [3968, 3456])
 def test_the_wmajor_kernel_compiles_at_flow20_fits_tails_on_one_chip(
         topo, rows):

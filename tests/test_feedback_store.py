@@ -237,7 +237,8 @@ def test_a_whole_fit_on_the_int16_store_is_the_float32_stores(family):
     lists by the integer vocab_map, not by the corpus' dtype), and on a
     data mesh, where every device densifies its own rows into the int16
     store.  The plan and its `fit.plan` span say which products the
-    kernels took: packed on the int16 store, XLA's DEFAULT on float32."""
+    kernels took: packed on either store, since a cell of the day passes
+    256 (the rule is keyed to the cells, not to the store)."""
     from oni_ml_tpu.parallel import make_mesh
 
     kw, over = {}, {}
@@ -255,8 +256,10 @@ def test_a_whole_fit_on_the_int16_store_is_the_float32_stores(family):
         "compact" if family == "compact" else "dense")
     assert got.plan["products"] == {"value": "split6_packed",
                                     "phinorm_passes": 1}
-    assert want.plan["products"] == {"value": "default",
+    assert want.plan["products"] == {"value": "split6_packed",
                                      "phinorm_passes": 1}
+    assert (got.plan["corpus_store"]["accurate"],
+            want.plan["corpus_store"]["accurate"]) == (1, 1)
     assert (plan["products"], plan["phinorm_passes"]) == ("split6_packed", 1)
     _assert_same_fit(got, want)
 
@@ -288,25 +291,44 @@ def test_the_vocab_sharded_dense_plan_takes_the_int16_store():
             np.asarray(getattr(res[jnp.float32], field)), err_msg=field)
 
 
-def test_at_f32_the_feedback_day_reads_no_token_and_stores_float32(
+def test_at_f32_the_feedback_day_reads_its_first_rung_once_and_stores_float32(
         monkeypatch):
-    """`dense_precision="f32"`: the gate reads no token, the rung is f32,
-    and the densify says so; the ladder is bf16 operand mode's alone."""
-    def refuse(word_idx, counts):
-        raise AssertionError("an f32 fit read its tokens")
+    """`dense_precision="f32"`: the store is float32 whatever the cells
+    hold, and the store's gate reads no token (`cell_scan` "none",
+    `scan_tokens` 0); the same span asks the first rung's question, does a
+    cell pass 256, which keys the products: once a fit, from the Corpus'
+    counts, one entry a cell, in one pass, with no row sum and no summed
+    cell, and again in the next fit of the same corpus."""
+    def refuse(*a, **kw):
+        raise AssertionError("an f32 fit climbed the ladder")
 
     monkeypatch.setattr(dense_estep, "max_dense_cell", refuse)
-    rec = spans.Recorder()
-    with spans.use_recorder(rec):
-        res = train_corpus(_feedback_corpus(), _config(dense_precision="f32"))
-    gate, = [e["args"] for e in rec.events if e["name"] == "fit.plan.gate"]
-    densify, = [e["args"] for e in rec.events if e["name"] == "fit.densify"]
-    assert (gate["store"], gate["rung"], gate["cell_scan"],
-            gate["scan_tokens"], gate["over_bf16_tokens"]) == (
-        "float32", "f32", "none", 0, 0)
-    assert (densify["store"], densify["itemsize"]) == ("float32", 4)
-    assert res.plan["corpus_store"] == {"value": "float32", "rung": "f32",
-                                        "cell_scan": "none"}
+    monkeypatch.setattr(dense_estep, "_ladder_gate", refuse)
+    corpus = _feedback_corpus()
+    cfg = _config(dense_precision="f32")
+    gates = []
+    for _ in range(2):
+        rec = spans.Recorder()
+        with spans.use_recorder(rec):
+            res = train_corpus(corpus, cfg)
+        gates += [e["args"] for e in rec.events
+                  if e["name"] == "fit.plan.gate"]
+        densify, = [e["args"] for e in rec.events
+                    if e["name"] == "fit.densify"]
+        assert (densify["store"], densify["itemsize"]) == ("float32", 4)
+        assert res.plan["corpus_store"] == {
+            "value": "float32", "rung": "f32", "cell_scan": "none",
+            "accurate": 1}
+        assert res.plan["products"] == {"value": "split6_packed",
+                                        "phinorm_passes": 1}
+    for gate in gates:
+        assert (gate["store"], gate["rung"], gate["cell_scan"],
+                gate["scan_tokens"], gate["accurate"]) == (
+            "float32", "f32", "none", 0, 1)
+        assert (gate["undecided_rows"], gate["repeat_rows"]) == (0, 0)
+        assert (gate["largest_count"], gate["accurate_scan_tokens"]) == (
+            corpus.counts.max(), corpus.counts.size)
+        assert gate["largest_count"] > 256
 
 
 @pytest.mark.parametrize("low,high", [(1e-12, 1.0), (1e-6, 1e6)],

@@ -397,15 +397,18 @@ def test_doc_sweeps_lies_between_one_sweep_and_the_cap(recorded_fits, driver):
     assert isinstance(result.doc_sweeps, int)
 
 
-def _corpus_with_a_feedback_doc():
-    """_corpus() plus one document of 300 count-1 tokens of one word:
-    raw counts of 1, a row sum and a cell of 300."""
+def _corpus_with_a_feedback_doc(tokens=300):
+    """_corpus() plus one document of one word in a cell of 300: as
+    `tokens` count-1 tokens of it (raw counts of 1, a row sum of 300), or
+    with `tokens` 1 as a Corpus from pre-LDA's sum by (IP, word) holds
+    it, one entry of 300."""
     base = _corpus()
     return Corpus(
         doc_names=base.doc_names + ["feedback"], vocab=base.vocab,
-        doc_ptr=np.append(base.doc_ptr, base.doc_ptr[-1] + 300),
-        word_idx=np.append(base.word_idx, np.full(300, 5, np.int32)),
-        counts=np.append(base.counts, np.ones(300, np.float32)))
+        doc_ptr=np.append(base.doc_ptr, base.doc_ptr[-1] + tokens),
+        word_idx=np.append(base.word_idx, np.full(tokens, 5, np.int32)),
+        counts=np.append(base.counts,
+                         np.full(tokens, 300 / tokens, np.float32)))
 
 
 @pytest.mark.parametrize("precision,feedback,cell_scan,stored", [
@@ -415,7 +418,10 @@ def _corpus_with_a_feedback_doc():
 ])
 def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
                                                   cell_scan, stored):
-    corpus = _corpus_with_a_feedback_doc() if feedback else _corpus()
+    # at f32 the gate reads the Corpus' counts, one entry a cell (the
+    # ladder, which the bf16 cases climb, sums repeated tokens)
+    corpus = (_corpus_with_a_feedback_doc(1 if precision == "f32" else 300)
+              if feedback else _corpus())
     cfg = _config(estep_engine="dense", dense_precision=precision,
                   em_max_iters=1)
     batches = _batches(corpus, cfg)
@@ -427,10 +433,11 @@ def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
     gate, = [e["args"] for e in rec.events if e["name"] == "fit.plan.gate"]
     densify, = [e["args"] for e in rec.events if e["name"] == "fit.densify"]
     assert plan["cell_scan"] == cell_scan
-    # the products the kernels take over the store, and the phinorm's MXU
-    # passes a sweep: packed on int16 (K = 4: 24 rows of contraction, one
-    # pass), XLA's DEFAULT on a floating store
-    products = "split6_packed" if stored == jnp.int16 else "default"
+    # the products the kernels take, keyed to the day's cells and not to
+    # the store, and the phinorm's MXU passes a sweep: packed where a cell
+    # passes 256, on either store (K = 4: 24 rows of contraction, one
+    # pass), XLA's DEFAULT elsewhere
+    products = "split6_packed" if feedback else "default"
     assert (plan["products"], plan["phinorm_passes"]) == (products, 1)
     assert result.plan["products"] == {"value": products,
                                        "phinorm_passes": 1}
@@ -448,12 +455,20 @@ def test_fit_plan_says_what_the_storage_gate_read(precision, feedback,
                     if (b.counts.sum(axis=1) > 256).any()]
         assert plan["scan_tokens"] == 2 * padded_tokens + 2 * row_len
         assert 2 * padded_tokens < plan["scan_tokens"] < 3 * padded_tokens
+    # at f32 the store reads no token, and the Corpus' largest count says
+    # whether a cell passes 256: its one entry of 300, no row undecided;
+    # under bf16 the feedback row of repeated tokens is the one over 256
+    # and the one that repeats
     assert (gate["undecided_rows"], gate["repeat_rows"]) == (
-        (1, 1) if cell_scan == "exact" else (0, 0))
-    # the ladder's rung, and no raw count over 256: the feedback cell is
-    # 300 count-1 tokens
+        (1, 1) if feedback and precision == "bf16" else (0, 0))
+    assert gate["accurate"] == int(feedback)
+    # the ladder's rung, and no raw count over 256 in its pass (none at
+    # f32, and under bf16 the feedback cell is 300 count-1 tokens); at
+    # f32 the Corpus' largest count, the cell of 300
     assert (gate["rung"], gate["over_bf16_tokens"]) == (
         dense_estep.store_rung(stored), 0)
+    assert gate.get("largest_count") == (
+        300 if precision == "f32" and feedback else None)
     assert (densify["store"], densify["itemsize"]) == (
         jnp.dtype(stored).name, jnp.dtype(stored).itemsize)
 
